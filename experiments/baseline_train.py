@@ -120,9 +120,9 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
 
-    # same persistent-compile-cache treatment as the engine arm
-    # (TrainJob enables it): TTA comparisons must not hand either arm a
-    # one-time-per-host compile the other amortizes
+    # same persistent-compile-cache treatment as the engine arm (every
+    # process entry enables it): TTA comparisons must not hand either
+    # arm a one-time-per-host compile the other amortizes
     from kubeml_tpu.utils.env import enable_compile_cache
     enable_compile_cache()
 

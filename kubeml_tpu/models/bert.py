@@ -22,7 +22,6 @@ from typing import Optional
 
 import flax.linen as nn
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 import optax
 from jax import lax
@@ -67,7 +66,7 @@ class EncoderBlock(nn.Module):
             from kubeml_tpu.parallel.manual import (TPHeadsDense,
                                                     validate_tp_geometry)
             validate_tp_geometry(self.heads, self.ffn,
-                                 compat.axis_size(self.tp_axis))
+                                 jax.lax.axis_size(self.tp_axis))
             mk_qkv = partial(TPHeadsDense, self.heads, head_dim,
                              self.tp_axis, self.dtype)
         else:
@@ -164,7 +163,8 @@ class BertModule(nn.Module):
         # reduces over the seq axis — so the module computes exactly the
         # global-sequence forward while no chip ever holds the full T.
         B, T = x.shape
-        n_shards = 1 if self.seq_axis is None else compat.axis_size(self.seq_axis)
+        n_shards = 1 if self.seq_axis is None \
+            else jax.lax.axis_size(self.seq_axis)
         if T * n_shards > self.max_len:  # static trace-time guard.
             # InferenceInputError (a ValueError) so the serving layer
             # returns 4xx when the overlong sequence came from a client
@@ -269,7 +269,7 @@ class BertTiny(ClassifierModel):
         if T > module.max_len:
             raise InferenceInputError(
                 f"sequence length {T} exceeds max_len {module.max_len}")
-        n_stage = compat.axis_size(STAGE_AXIS)
+        n_stage = jax.lax.axis_size(STAGE_AXIS)
         per = module.layers // n_stage
         M = self._pp_microbatches
         if B % M:
@@ -367,7 +367,7 @@ class BertTiny(ClassifierModel):
             def fwd(variables, x_local):
                 return sp_module.apply(variables, x_local, train=False)
 
-            self._sp_cache[key] = jax.jit(compat.shard_map(
+            self._sp_cache[key] = jax.jit(jax.shard_map(
                 fwd, mesh=mesh, in_specs=(P(), P(None, SEQ_AXIS)),
                 out_specs=P(), check_vma=False))
         return self._sp_cache[key](variables, x)

@@ -31,7 +31,6 @@ from typing import Any, Optional
 
 import flax.linen as nn
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -113,7 +112,7 @@ class DecoderBlock(nn.Module):
                                  "decode path; decode with the dense "
                                  "module (same variables)")
             validate_tp_geometry(self.heads, self.ffn,
-                                 compat.axis_size(self.tp_axis))
+                                 jax.lax.axis_size(self.tp_axis))
             mk_qkv = partial(TPHeadsDense, self.heads, head_dim,
                              self.tp_axis, self.dtype)
         else:
@@ -249,10 +248,10 @@ class MoEFFN(nn.Module):
             if self.ep_mesh is not None:
                 raise ValueError("ep_axis (manual) and ep_mesh (GSPMD) "
                                  "are mutually exclusive")
-            if e % compat.axis_size(self.ep_axis):
+            if e % jax.lax.axis_size(self.ep_axis):
                 raise ValueError(
                     f"{e} experts do not divide over a "
-                    f"{compat.axis_size(self.ep_axis)}-way expert axis")
+                    f"{jax.lax.axis_size(self.ep_axis)}-way expert axis")
             from kubeml_tpu.parallel.ep import route_tokens
             x = h.reshape(B * T, D)
             if self.ep_impl == "alltoall":
@@ -262,7 +261,7 @@ class MoEFFN(nn.Module):
                 # all_gather restores the replicated activation the
                 # surrounding (replicated-token) trunk expects
                 from kubeml_tpu.parallel.manual import ep_alltoall_ffn
-                nl = compat.axis_size(self.ep_axis)
+                nl = jax.lax.axis_size(self.ep_axis)
                 if (B * T) % nl:
                     raise ValueError(
                         f"{B * T} tokens do not divide over a "
@@ -347,7 +346,8 @@ class GPTModule(nn.Module):
         # step instead of a full re-forward. cache_len (static) sizes the
         # cache on the first decode call.
         B, T = x.shape
-        n_shards = 1 if self.seq_axis is None else compat.axis_size(self.seq_axis)
+        n_shards = 1 if self.seq_axis is None \
+            else jax.lax.axis_size(self.seq_axis)
         if (not decode) and T * n_shards > self.max_len:
             # trace-time guard; InferenceInputError (a ValueError) so
             # client-supplied overlong sequences surface as 4xx in serving
@@ -1082,7 +1082,7 @@ def _shift_targets_sp(x_local: jax.Array, axis_name: str):
     shard's last column) keeps dense semantics — the ring wraps shard
     0's first token to it, so it is explicitly masked out.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     nxt_first = lax.ppermute(x_local[:, :1], axis_name,
                              perm=[((s + 1) % n, s) for s in range(n)])
@@ -1197,7 +1197,7 @@ class GPTMini(KubeModel):
         if T > module.max_len:
             raise InferenceInputError(
                 f"sequence length {T} exceeds max_len {module.max_len}")
-        n_stage = compat.axis_size(STAGE_AXIS)
+        n_stage = jax.lax.axis_size(STAGE_AXIS)
         per = module.layers // n_stage
         M = self._pp_microbatches
         if B % M:
@@ -1316,7 +1316,7 @@ class GPTMini(KubeModel):
 
         Serving entry point (the controller's /infer path calls this).
         Full-length prompts — the common serving case — take the KV-cache
-        scan decode (`generate`, ~100x faster on tunneled backends);
+        scan decode (`generate`: one dispatch, not one per token);
         ragged rows fall back to the per-token window re-forward below,
         whose continuation starts at each row's own last real token.
         Generated tokens are never PAD_ID.
@@ -1630,7 +1630,7 @@ class GPTMini(KubeModel):
                 return sp_module.apply(variables, x_local, train=False)
 
             # logits come back seq-sharded: out spec reassembles [B, T, V]
-            self._sp_cache[key] = jax.jit(compat.shard_map(
+            self._sp_cache[key] = jax.jit(jax.shard_map(
                 fwd, mesh=mesh, in_specs=(P(), P(None, SEQ_AXIS)),
                 out_specs=P(None, SEQ_AXIS), check_vma=False))
         return self._sp_cache[key](variables, x)
@@ -1751,7 +1751,7 @@ class GPTMoEMini(GPTMini):
             # per-example loss is seq-INVARIANT (the vma-checked round's
             # contract — see KAvgEngine.batch_seq_dims)
             axis = self.module.seq_axis
-            aux = lax.psum(aux, axis) / compat.axis_size(axis)
+            aux = lax.psum(aux, axis) / jax.lax.axis_size(axis)
             per_ex = _lm_per_example_sp(logits, x, axis)
         else:
             per_ex = _lm_per_example(logits, x)

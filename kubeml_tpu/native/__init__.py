@@ -4,14 +4,16 @@ The shared library is built on demand from the checked-in source with the
 toolchain g++ (no pip/pybind dependency — plain `extern "C"` + ctypes, so
 the binding layer has zero install requirements). The build is cached
 next to the source and rebuilt only when the source is newer. Hosts
-without a compiler simply report `available() == False` and every caller
-falls back to the pure-numpy path — the native library is a fast path,
-never a hard dependency.
+without a compiler report `available() == False` and every caller falls
+back to the pure-numpy path — the native library is a fast path, never a
+hard dependency — but the build error is logged ONCE at warning level,
+so a host that silently lost the fast path says so.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -23,6 +25,8 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "roundloader.cc")
 _SO = os.path.join(_DIR, "libkubeml_native.so")
 _ABI_VERSION = 1
+
+logger = logging.getLogger("kubeml_tpu.native")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -61,8 +65,15 @@ def _load() -> Optional[ctypes.CDLL]:
                 p_u8, p_u8, p_f32, p_f32, p_f32, i64]
             lib.kml_assemble_round.restype = None
             _lib = lib
-        except Exception:
-            _failed = True
+        except Exception as e:
+            _failed = True  # one attempt, one warning per process
+            detail = (getattr(e, "stderr", None) or b"").decode(
+                errors="replace").strip()
+            logger.warning(
+                "native round loader unavailable (%s: %s)%s — round "
+                "assembly falls back to the numpy path",
+                type(e).__name__, e,
+                f"; compiler said: {detail[-500:]}" if detail else "")
         return _lib
 
 

@@ -61,21 +61,6 @@ def composed_bias(pad_mask: jax.Array, causal: bool, T: int) -> jax.Array:
     return bias
 
 
-def _flash_safe_context() -> bool:
-    """Whether a pallas (Mosaic) kernel may be emitted here.
-
-    The SPMD partitioner refuses to auto-partition Mosaic custom calls:
-    under a mesh context with any Auto (GSPMD-managed) axis — e.g. the
-    inner axes of a partially-manual shard_map, even when they have size
-    1 — lowering raises "Mosaic kernels cannot be automatically
-    partitioned". Safe contexts are fully-manual shard_map bodies and
-    plain jit with no surrounding mesh (compat.flash_safe_context holds
-    the per-JAX-version introspection).
-    """
-    from kubeml_tpu import compat
-    return compat.flash_safe_context()
-
-
 def _flash_tiles(T: int) -> bool:
     """T tiles onto the flash kernel's grid: a multiple of 128 lanes, or
     a single sublane-aligned block (T <= 128, T % 8 == 0)."""

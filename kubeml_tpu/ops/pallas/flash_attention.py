@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kubeml_tpu import compat
 from kubeml_tpu.ops.attention import NEG_INF
 from kubeml_tpu.ops.pallas import gate
 
@@ -208,9 +207,9 @@ def _fa_forward(q, k, v, pad_mask, causal: bool, block_q: int, block_k: int,
             row_spec,
         ],
         out_shape=[
-            compat.shape_dtype_struct((B * H, T, D), q.dtype, vma=vma),
-            compat.shape_dtype_struct((B * H, 1, T), jnp.float32, vma=vma),
-            compat.shape_dtype_struct((B * H, 1, T), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((B * H, T, D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
@@ -364,8 +363,8 @@ def _fa_backward(q, k, v, pad_mask, out, m_rows, l_rows, g, causal,
             pl.BlockSpec((1, bk, D), lambda bh, jk, iq: (bh, jk, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_shape=[compat.shape_dtype_struct((B * H, T, D), k.dtype, vma=vma),
-                   compat.shape_dtype_struct((B * H, T, D), v.dtype, vma=vma)],
+        out_shape=[jax.ShapeDtypeStruct((B * H, T, D), k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((B * H, T, D), v.dtype, vma=vma)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
@@ -394,7 +393,7 @@ def _fa_backward(q, k, v, pad_mask, out, m_rows, l_rows, g, causal,
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, iq, jk: (bh, iq, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=compat.shape_dtype_struct((B * H, T, D), q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
     )(mask, *row_args, kb, vb)

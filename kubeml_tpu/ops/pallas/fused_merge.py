@@ -15,7 +15,7 @@ The flat [N] f32 bucket is padded and viewed as [rows, 128] (f32 native
 lane tiling, rows padded to the 8-sublane minimum), the grid walks row
 blocks, and the three scalars ride SMEM. The lax fallback — used under
 `JAX_PLATFORMS=cpu` and on any mesh context where a Mosaic kernel cannot
-be emitted (compat.flash_safe_context) — computes the identical IEEE op
+be emitted (gate.mosaic_safe_context) — computes the identical IEEE op
 chain, so CPU-tier results are bit-identical to the kernel's and the
 engines' bit-identity suite covers both paths.
 """
@@ -29,9 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from kubeml_tpu import compat
 from kubeml_tpu.ops.pallas import gate
-from kubeml_tpu.ops.pallas.gate import (HAS_PALLAS, LANES as _LANES,
+from kubeml_tpu.ops.pallas.gate import (LANES as _LANES,
                                         SUBLANES as _SUBLANES, pl, pltpu)
 
 _BLOCK_ROWS = 256  # rows per grid step (256*128*4B = 128 KiB per operand)
@@ -87,7 +86,7 @@ def _bucket_apply(mode: str, s, ref, count, raw_count, lr,
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
-        out_shape=compat.shape_dtype_struct(
+        out_shape=jax.ShapeDtypeStruct(
             (rows_p, _LANES), jnp.float32, vma=_out_vma(s, ref)),
         interpret=bool(interpret),
     )(scal, s2, r2)

@@ -15,16 +15,30 @@ scalar-prefetch operand, the BlockSpec index map walks it, and each KV
 page streams HBM -> VMEM exactly once — no contiguous KV tensor ever
 exists in HBM.
 
-Math contract: the kernel's op chain is EXACTLY the reference path's —
-same f32-score matmul, the same `1/sqrt(D)` scale expression, the same
-additive-bias convention, `jax.nn.softmax` in f32, the same
-cast-weights-then-matmul finish — so the serving bit-identity suite can
-assert_array_equal the kernel (interpret mode) against the gather
-programs instead of settling for allclose. One (slot, head) owns a grid
-point; pages land in a [C, D] VMEM scratch tile (C = Pmax*G tokens,
-e.g. 512x64 bf16 = 64 KiB — far below the ~16 MB/core budget), and the
-softmax runs once over the full masked context exactly like the
-reference, preserving the engine's masking/determinism contract.
+Math contract: the kernel's op chain is the reference path's — same
+f32-accumulated score matmul, the same `1/sqrt(D)` scale expression,
+the same additive-bias convention, `jax.nn.softmax` in f32, the same
+cast-weights-then-matmul finish (f32 accumulator, cast after) — so the
+serving bit-identity suite can assert_array_equal the kernel (interpret
+mode) against the gather programs instead of settling for allclose.
+
+Layout (what the v5e's compiler accepts — tests/test_chip_compile.py
+compiles it for a described chip): one (slot, page) owns a grid point.
+Each page block arrives [G, H, D] as the slab stores it, is swapped to
+heads-leading [H, G, D] in VMEM and lands in a [H, C, D] scratch pair
+(C = Pmax*G tokens), so the tiled last-two dims are (context, head_dim)
+and the batched matmuls carry heads as the leading batch dim. The last
+page step runs the softmax once over the full masked context exactly
+like the reference, preserving the engine's masking/determinism
+contract. The live set therefore GROWS with the context:
+`paged_vmem_bytes` bounds it from the shapes — the scratch pair
+2*H*C*pad128(D)*itemsize dominates (1 MiB at gpt-mini's H=4, D=64,
+C=512 in bf16; 8 MiB at H=16, D=128, C=1024), plus the double-buffered
+q/out/page/bias blocks and the f32 [H, T, C] score temporaries — and
+`paged_eligible` sends geometries over `VMEM_BUDGET` to the gather
+path. The bound was checked against the compiler's own scoped-VMEM
+accounting (binary search on vmem_limit_bytes) and overestimates it by
+1.1-1.5x at every geometry tried.
 
 int8 KV pages (serve/pager.py kv_dtype="int8") dequantize INSIDE the
 kernel: pages are int8 with one symmetric f32 scale per page riding as
@@ -41,17 +55,22 @@ everywhere else, `interpret=True` for CPU kernel tests.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from kubeml_tpu import compat
 from kubeml_tpu.ops.attention import multi_head_attention
 from kubeml_tpu.ops.pallas import gate
-from kubeml_tpu.ops.pallas.gate import SUBLANES, pl, pltpu
+from kubeml_tpu.ops.pallas.gate import LANES, SUBLANES, pl, pltpu
 
 IMPLS = ("auto", "pallas", "gather")
+
+# The kernel's VMEM ceiling. A v5e core has 128 MiB of VMEM; Mosaic's
+# default scoped limit is 16 MiB and is raised per call to the computed
+# bound (vmem_limit_bytes). 40 MiB leaves the rest to XLA's own fusions
+# around the call and holds on every current TPU generation.
+VMEM_BUDGET = 40 * 2 ** 20
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
 
 
 def _dequant(pages: jax.Array, scale: jax.Array, dtype) -> jax.Array:
@@ -63,11 +82,58 @@ def _dequant(pages: jax.Array, scale: jax.Array, dtype) -> jax.Array:
             ).astype(dtype)
 
 
-def paged_eligible(page: int) -> bool:
-    """Geometry gate for the Mosaic kernel: page rows are the sublane
-    dimension of the KV block DMA, so they must be sublane-aligned.
+def _pad(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def paged_vmem_bytes(q_len: int, heads: int, head_dim: int, page: int,
+                     max_pages: int, dtype, quantized: bool) -> int:
+    """Upper bound on the kernel's scoped VMEM, from shapes alone.
+
+    Every buffer is counted at its TILED size (last dim padded to 128
+    lanes, second-to-last to the dtype's sublane tile): the heads-leading
+    [H, C, D] scratch pair, the double-buffered q/out, K/V page and bias
+    blocks, and two f32 [H, T, C] score-sized temporaries for the
+    softmax. Compared against the compiler's own accounting it is
+    conservative (see module docstring)."""
+    item = jnp.dtype(dtype).itemsize
+    page_item = 1 if quantized else item
+
+    def sub(n, itemsize):
+        return _pad(n, SUBLANES * (4 // itemsize))
+
+    C = page * max_pages
+    Dp = _pad(head_dim, LANES)
+    scratch = 2 * heads * C * Dp * item
+    kv_blocks = 2 * 2 * page * sub(heads, page_item) * Dp * page_item
+    q_out = 2 * 2 * heads * sub(q_len, item) * Dp * item
+    bias = 2 * sub(q_len, 4) * _pad(C, LANES) * 4
+    scores = 2 * heads * sub(q_len, 4) * _pad(C, LANES) * 4
+    return scratch + kv_blocks + q_out + bias + scores
+
+
+def paged_eligible(page: int, *, q_len: int, heads: int, head_dim: int,
+                   max_pages: int, dtype, quantized: bool = False) -> bool:
+    """Geometry gate for the Mosaic kernel, from shapes and dtype only:
+    page rows are the sublane offset of the scratch store, so they must
+    be sublane-aligned, and the computed VMEM bound must fit the budget.
     Ineligible geometries fall back to the gather path under 'auto'."""
-    return page % SUBLANES == 0
+    return page % SUBLANES == 0 and paged_vmem_bytes(
+        q_len, heads, head_dim, page, max_pages, dtype,
+        quantized) <= VMEM_BUDGET
+
+
+def resolve_impl(impl: str, interpret: bool, **geometry) -> str:
+    """The implementation `paged_attention(impl=...)` takes for this
+    geometry (paged_eligible's keywords plus `page`): 'pallas' or
+    'gather'. One rule for the dispatch below and for the engine's
+    per-program `attn_impl_*` stats, so what is printed is what ran."""
+    if impl not in IMPLS:
+        raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
+    if impl == "auto":
+        return "pallas" if gate.use_pallas(interpret) \
+            and paged_eligible(**geometry) else "gather"
+    return impl
 
 
 def _pa_kernel(tables_ref, kscale_ref, vscale_ref, q_ref, k_ref, v_ref,
@@ -77,15 +143,16 @@ def _pa_kernel(tables_ref, kscale_ref, vscale_ref, q_ref, k_ref, v_ref,
 
     The page loop is the LAST grid dimension (sequential per core): each
     step lands one KV page — fetched straight from its slab position via
-    the page-table index map, dequantized here if int8 — into the
-    [C, H, D] VMEM scratch, and the final step runs the full-context
-    attention for this slot. Heads stay INSIDE the block (not a grid
-    dimension): the einsums below then carry the reference path's exact
-    head-batched contraction shapes, which is what keeps the kernel
-    bit-identical to multi_head_attention rather than merely allclose —
-    per-head 2D dots reassociate the same sums differently.
-    q_ref [1, T, H, D]; k_ref/v_ref [1, G, H, D]; bias_ref [1, 1, T, C];
-    out_ref [1, T, H, D].
+    the page-table index map, dequantized here if int8, swapped to
+    heads-leading — into the [H, C, D] VMEM scratch, and the final step
+    runs the full-context attention for this slot. Heads stay INSIDE the
+    block as the matmuls' leading batch dim: the einsums below then
+    contract exactly what the reference path's head-batched einsums
+    contract, which is what keeps the kernel bit-identical to
+    multi_head_attention rather than merely allclose — per-head 2D dots
+    reassociate the same sums differently.
+    q_ref/out_ref [1, H, T, D]; k_ref/v_ref [1, G, H, D];
+    bias_ref [1, 1, T, C].
     """
     s = pl.program_id(0)
     j = pl.program_id(1)
@@ -95,23 +162,27 @@ def _pa_kernel(tables_ref, kscale_ref, vscale_ref, q_ref, k_ref, v_ref,
         pid = tables_ref[s, j]
         k_blk = _dequant(k_blk, kscale_ref[pid], k_scr.dtype)
         v_blk = _dequant(v_blk, vscale_ref[pid], v_scr.dtype)
-    k_scr[pl.ds(j * page, page), :, :] = k_blk
-    v_scr[pl.ds(j * page, page), :, :] = v_blk
+    rows = pl.ds(pl.multiple_of(j * page, page), page)
+    k_scr[:, rows, :] = jnp.swapaxes(k_blk, 0, 1)
+    v_scr[:, rows, :] = jnp.swapaxes(v_blk, 0, 1)
 
     @pl.when(j == n_pages - 1)
     def _compute():
-        q = q_ref[0]                                         # [T, H, D]
+        q = q_ref[0]                                         # [H, T, D]
         d = q.shape[-1]
-        # the reference chain, verbatim (ops/attention.py
-        # multi_head_attention): f32-accumulated scores, the identical
-        # scale expression, additive bias, f32 softmax, cast-then-matmul
-        scores = jnp.einsum("qhd,khd->hqk", q, k_scr[...],
+        # the reference chain (ops/attention.py multi_head_attention):
+        # f32-accumulated scores, the identical scale expression,
+        # additive bias, f32 softmax, cast-then-matmul. Mosaic requires
+        # the f32 accumulator on BOTH matmuls; the cast back to the
+        # compute dtype after the second is the same rounding XLA's
+        # bf16-output dot applies to its own f32 accumulator.
+        scores = jnp.einsum("hqd,hkd->hqk", q, k_scr[...],
                             preferred_element_type=jnp.float32)
         scores = scores * (1.0 / jnp.sqrt(jnp.float32(d)))
         scores = scores + bias_ref[0].astype(jnp.float32)    # [H, T, C]
         weights = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("hqk,khd->qhd", weights.astype(q.dtype),
-                         v_scr[...])
+        out = jnp.einsum("hqk,hkd->hqd", weights.astype(q.dtype),
+                         v_scr[...], preferred_element_type=jnp.float32)
         out_ref[0] = out.astype(out_ref.dtype)
 
 
@@ -126,7 +197,7 @@ def _pa_pallas(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
         (1, G, H, D),
         lambda s, j, tables, ks, vs: (tables[s, j], 0, 0, 0),
         memory_space=pltpu.VMEM)
-    q_spec = pl.BlockSpec((1, T, H, D),
+    q_spec = pl.BlockSpec((1, H, T, D),
                           lambda s, j, tables, ks, vs: (s, 0, 0, 0),
                           memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -142,26 +213,33 @@ def _pa_pallas(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
         ],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((C, H, D), compute_dtype),
-            pltpu.VMEM((C, H, D), compute_dtype),
+            pltpu.VMEM((H, C, D), compute_dtype),
+            pltpu.VMEM((H, C, D), compute_dtype),
         ],
     )
-    return pl.pallas_call(
+    vmem = paged_vmem_bytes(T, H, D, G, Pmax, compute_dtype, quantized)
+    out = pl.pallas_call(
         functools.partial(_pa_kernel, n_pages=Pmax, page=G,
                           quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=compat.shape_dtype_struct((S, T, H, D), q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((S, H, T, D), q.dtype, vma=vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(vmem, _DEFAULT_SCOPED_VMEM)),
+        name="paged_attention",
         interpret=interpret,
-    )(page_tables, k_scale, v_scale, q, k_pages, v_pages,
-      jnp.broadcast_to(bias, (S, 1, T, C)))
+    )(page_tables, k_scale, v_scale, q.transpose(0, 2, 1, 3), k_pages,
+      v_pages, jnp.broadcast_to(bias, (S, 1, T, C)))
+    return out.transpose(0, 2, 1, 3)
 
 
 def _pa_gather(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
                quantized: bool, compute_dtype):
     """The pre-kernel op chain, verbatim: materialize the contiguous
     context with a page gather, then the shared attention primitive.
-    This IS the fallback (CPU tier, non-Mosaic mesh contexts) and the
-    bit-identity reference the kernel is asserted against."""
+    This IS the fallback (CPU tier, non-Mosaic mesh contexts, contexts
+    over the VMEM budget) and the bit-identity reference the kernel is
+    asserted against."""
     S, T, H, D = q.shape
     G = k_pages.shape[1]
     C = page_tables.shape[1] * G
@@ -190,24 +268,25 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     broadcastable to [S, 1, T, C], C = Pmax*G — validity and causality
     are entirely the caller's bias, exactly like multi_head_attention.
 
-    impl='auto' follows the package gate (Mosaic kernel on TPU when the
-    page size is sublane-aligned, gather fallback elsewhere); 'pallas'
+    impl='auto' follows the package gate and this module's geometry
+    gate (Mosaic kernel on TPU when the page size is sublane-aligned and
+    the computed VMEM bound fits, gather fallback elsewhere); 'pallas'
     and 'gather' force a path; interpret runs the forced kernel in the
     pallas interpreter (CPU bit-identity tests).
     """
-    if impl not in IMPLS:
-        raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
+    S, T, H, D = q.shape
     G = k_pages.shape[1]
     if compute_dtype is None:
         compute_dtype = q.dtype
-    if impl == "auto":
-        impl = "pallas" if gate.use_pallas(interpret) \
-            and paged_eligible(G) else "gather"
-    if impl == "pallas":
-        if not paged_eligible(G):
+    geometry = dict(page=G, q_len=T, heads=H, head_dim=D,
+                    max_pages=page_tables.shape[1], dtype=compute_dtype,
+                    quantized=quantized)
+    if resolve_impl(impl, interpret, **geometry) == "pallas":
+        if not paged_eligible(**geometry):
             raise ValueError(
-                f"page size {G} is not sublane-aligned "
-                f"({SUBLANES}); use impl='gather'")
+                f"page size {G} is not sublane-aligned ({SUBLANES}) or "
+                f"the kernel's VMEM bound exceeds {VMEM_BUDGET} B for "
+                f"{geometry}; use impl='gather'")
         return _pa_pallas(q, k_pages, v_pages, k_scale, v_scale,
                           page_tables, bias, quantized, compute_dtype,
                           interpret)

@@ -32,7 +32,6 @@ wire bytes dominate.
 from __future__ import annotations
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 
@@ -45,18 +44,9 @@ def ring_psum(x: jax.Array, axis_name: str, wire_dtype) -> jax.Array:
     exact up to reduction order. Works on partially-manual meshes where
     a sub-f32 `lax.psum` crashes the partitioner (module docstring).
     """
-    D = compat.axis_size(axis_name)
+    D = jax.lax.axis_size(axis_name)
     if D == 1:
         return x
-    if not compat.HAS_NATIVE_SHARD_MAP:
-        # Legacy JAX (0.4.x): the partitioner bug this ring dodges does
-        # not exist there — a direct sub-f32 psum partitions fine even
-        # under partial-manual meshes — while the ring itself cannot
-        # build: its lax.axis_index lowers to a PartitionId instruction
-        # the legacy partial-manual partitioner rejects. Same wire
-        # compression; accumulation rides the wire dtype instead of
-        # f32-with-wire-hops (same ~D·2^-8 worst-case error model).
-        return lax.psum(x.astype(wire_dtype), axis_name).astype(x.dtype)
     r = lax.axis_index(axis_name)
     shape, n = x.shape, x.size
     pad = (-n) % D

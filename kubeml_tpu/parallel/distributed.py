@@ -93,17 +93,23 @@ def initialize(coordinator_address: Optional[str] = None,
                process_id: Optional[int] = None) -> None:
     """Join the multi-host JAX cluster (idempotent).
 
-    On Cloud TPU pod slices, call with no arguments — JAX discovers the
-    coordinator and process topology from the TPU metadata environment.
-    For DCN-connected CPU/GPU hosts or manual bring-up, pass all three.
+    On Cloud TPU pod slices the launch environment names the cluster
+    (the env-var families in CLUSTER_ENV_VARS) and JAX discovers the
+    coordinator and process topology from it. For DCN-connected CPU/GPU
+    hosts or manual bring-up, pass all three arguments (or the
+    KUBEML_COORDINATOR_ADDRESS / _NUM_PROCESSES / _PROCESS_ID trio).
     Must be the FIRST JAX call in the process (jax.distributed's own
     contract): touching the backend first makes joining impossible, so
     this function deliberately makes no other JAX calls before the join.
 
-    With explicit arguments a rendezvous failure raises — silently
-    training N independent model copies would be wrong results, not
-    degraded service. With no arguments and no environment to discover
-    from, this is a single-process run and returns quietly.
+    With explicit arguments, or an environment that names a multi-host
+    cluster, a rendezvous failure raises — silently training N
+    independent model copies would be wrong results, not degraded
+    service. With NEITHER — no arguments, no KUBEML_COORDINATOR_ADDRESS,
+    no cluster environment — this is a single-process run and returns
+    WITHOUT calling jax.distributed.initialize() at all: on a TPU host
+    with no network, JAX's own cluster detection may wait on a metadata
+    server that is not there, and `kubeml serve` must start regardless.
 
     This replaces the reference's Kubernetes Service discovery + HTTP
     rendezvous (ml/pkg/api/const.go:4-14, ml/pkg/ps/job_pod.go:96-137):
@@ -128,16 +134,16 @@ def initialize(coordinator_address: Optional[str] = None,
         kwargs["num_processes"] = num_processes
     if process_id is not None:
         kwargs["process_id"] = process_id
-    try:
-        jax.distributed.initialize(**kwargs)
-        logger.info("joined cluster: process %d/%d, %d devices",
-                    jax.process_index(), jax.process_count(),
-                    len(jax.devices()))
-    except (RuntimeError, ValueError) as e:
-        if kwargs or _cluster_env_present():
-            raise  # a real cluster must not silently degrade to 1 process
-        logger.info("single-process run (jax.distributed unavailable: %s)",
-                    e)
+    if not kwargs and not _cluster_env_present():
+        logger.info("single-process run (no cluster named by arguments "
+                    "or environment)")
+        return
+    # a named cluster must not silently degrade to one process: failures
+    # propagate
+    jax.distributed.initialize(**kwargs)
+    logger.info("joined cluster: process %d/%d, %d devices",
+                jax.process_index(), jax.process_count(),
+                len(jax.devices()))
 
 
 def group_by_slice(devices: Sequence,

@@ -44,7 +44,6 @@ from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -70,9 +69,9 @@ class RoundStats:
     """Host-side view of one sync round's outcome.
 
     `loss_sum` and `dropped` materialize LAZILY: reading either blocks on
-    the round and costs a device->host readback (tens of ms on tunneled
-    backends), so dispatch loops should accumulate `loss_sum_device` /
-    `dropped_device` on device and read back once per epoch; a loop that
+    the round and costs a device->host readback, so dispatch loops
+    should accumulate `loss_sum_device` / `dropped_device` on device
+    and read back once per epoch; a loop that
     only wants an opportunistic progress number must use the
     non-blocking `peek()` instead. `step_count` and `sample_count` are
     host-derived from the masks (free). `contributors` counts the
@@ -499,7 +498,7 @@ class KAvgEngine:
                 # for the carry types to match. Values stay seq-INVARIANT
                 # throughout — that is what vma's backward enforces.
                 params, model_state, opt_state = jax.tree_util.tree_map(
-                    lambda x: compat.pcast(x, DATA_AXIS, to="varying"),
+                    lambda x: jax.lax.pcast(x, DATA_AXIS, to="varying"),
                     (params, model_state, opt_state))
 
             def step(carry, xs):
@@ -649,7 +648,7 @@ class KAvgEngine:
 
     def _build_train_round(self, w_per_lane: int, batch_template=None):
         """Compile the sync-round program: one sync round per dispatch."""
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             self._make_lane_fn(w_per_lane), mesh=self.mesh,
             in_specs=(P(), self._batch_in_specs(batch_template),
                       P(DATA_AXIS), P(DATA_AXIS),
@@ -664,11 +663,11 @@ class KAvgEngine:
         """Compile the R-round program: a lax.scan of the SAME per-lane
         round body, R sync rounds (merges between them preserved) in ONE
         dispatch. Identical math to R single-round dispatches; what it
-        buys is R x fewer submissions — on tunneled/high-latency
-        backends per-round dispatch costs host work + wire latency that
-        a ~50 ms round cannot fully hide (experiments/round_probe.py
-        quantifies it). R is baked into the program via the leading axis
-        of every non-variables input."""
+        buys is R x fewer submissions — per-round dispatch costs host
+        work + dispatch latency that a short round may not fully hide
+        (experiments/round_probe.py is the probe for it). R is baked
+        into the program via the leading axis of every non-variables
+        input."""
         lane_fn = self._make_lane_fn(w_per_lane)
         ef = self._ef
 
@@ -700,7 +699,7 @@ class KAvgEngine:
         batch_specs = (jax.tree_util.tree_map(lift, batch_specs)
                        if isinstance(batch_specs, dict)
                        else lift(batch_specs))
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             multi_lane, mesh=self.mesh,
             in_specs=(P(), batch_specs,
                       lift(P(DATA_AXIS)), lift(P(DATA_AXIS)),
@@ -875,7 +874,7 @@ class KAvgEngine:
                 for k in cache.arrays}
 
     def _build_train_round_indexed(self, w_per_lane: int, cache):
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             self._indexed_lane_fn(w_per_lane, cache), mesh=self.mesh,
             in_specs=(P(), self._cache_in_specs(cache),
                       P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
@@ -917,7 +916,7 @@ class KAvgEngine:
         def lift(spec: P) -> P:
             return P(None, *spec)
 
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             multi_lane, mesh=self.mesh,
             in_specs=(P(), self._cache_in_specs(cache),
                       lift(P(DATA_AXIS)), lift(P(DATA_AXIS)),
@@ -1043,7 +1042,7 @@ class KAvgEngine:
             totals = {k: lax.psum(v, DATA_AXIS) for k, v in sums.items()}
             return totals, total_n
 
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             lane_fn, mesh=mesh,
             in_specs=(P(), self._batch_in_specs(batch_template),
                       P(DATA_AXIS)),

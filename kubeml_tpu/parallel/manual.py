@@ -48,7 +48,6 @@ from typing import Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -58,7 +57,7 @@ def axis_slice(arr: jax.Array, axis_name: str, dim: int) -> jax.Array:
     """This lane's contiguous shard of `arr` along `dim` over the manual
     mesh axis `axis_name`. The dimension must divide evenly (callers
     validate with a readable error at module level)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     size = arr.shape[dim] // n
     start = lax.axis_index(axis_name) * size
     return lax.dynamic_slice_in_dim(arr, start, size, axis=dim)

@@ -33,7 +33,6 @@ from __future__ import annotations
 from typing import Any, Callable, Sequence
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -80,14 +79,14 @@ def pipeline_lane(stage_fn: StageFn, local_params: PyTree, xs: jax.Array,
     Returns (outputs [M, B, ...], aux_sum) — both replicated over the
     stage axis; aux_sum is 0.0 unless has_aux.
     """
-    n_stage = compat.axis_size(axis_name)
+    n_stage = jax.lax.axis_size(axis_name)
     sid = lax.axis_index(axis_name)
     m = xs.shape[0]
     if vma:
-        xs = compat.pcast(xs, axis_name, to="varying")
+        xs = jax.lax.pcast(xs, axis_name, to="varying")
         if consts is not None:
             consts = jax.tree_util.tree_map(
-                lambda c: compat.pcast(c, axis_name, to="varying"), consts)
+                lambda c: jax.lax.pcast(c, axis_name, to="varying"), consts)
     perm = [(i, (i + 1) % n_stage) for i in range(n_stage)]
     # scalar zero derived from xs so its vma matches the varying aux
     # accumulated into it (a literal 0.0 would be invariant and fail
@@ -159,7 +158,7 @@ def pipeline_apply(stage_fn: StageFn, stage_params: PyTree, x: jax.Array,
         return pipeline_lane(stage_fn, params, xs, STAGE_AXIS,
                              has_aux=has_aux)
 
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         lane, mesh=mesh,
         in_specs=(P(STAGE_AXIS), P()),
         out_specs=(P(), P()),

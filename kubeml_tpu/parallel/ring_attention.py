@@ -27,7 +27,6 @@ from __future__ import annotations
 import functools
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -135,7 +134,7 @@ def _causal_step_mask(maskb, causal, sid, s, n):
 def _ring_flash_core(q, k, v, kv_mask, causal, axis_name, interpret):
     """Flash forward ring: returns (normalized out f32, m, l) with m/l
     the GLOBAL row stats [B, H, Tq] the backward needs."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     sid = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     acc0, m0, l0 = _block_attn_flash(q, k, v, kv_mask, causal, interpret)
@@ -176,7 +175,7 @@ def _ring_flash_bwd(causal, axis_name, interpret, res, g):
                                                        _fa_backward)
 
     q, k, v, kv_mask, out, m, l = res
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     sid = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     B, T, H, D = q.shape
@@ -251,7 +250,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     masked key set). interpret runs the kernel in the pallas
     interpreter (CPU tests).
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     if use_flash:
@@ -349,7 +348,7 @@ def ring_self_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               interpret=interpret)
 
     seq_spec = P(None, SEQ_AXIS, None, None)
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec,
                   P(None, SEQ_AXIS), P(None, SEQ_AXIS), P(None, SEQ_AXIS)),

@@ -52,7 +52,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubeml_tpu import compat
 from kubeml_tpu.metrics.ledger import CostLedger
 from kubeml_tpu.parallel import merge as merge_lib
 from kubeml_tpu.parallel.kavg import (_select_tree, masked_scalar_loss,
@@ -308,7 +307,7 @@ class SyncDPEngine:
         if self.mesh.size != self.n_lanes:
             kw["axis_names"] = {DATA_AXIS}
         ef_specs = (P(DATA_AXIS),) if ef else ()
-        return compat.shard_map(
+        return jax.shard_map(
             lane, mesh=self.mesh,
             in_specs=(P(), P(), P(DATA_AXIS), P(DATA_AXIS), P())
             + ef_specs,

@@ -31,7 +31,6 @@ shard_map over a named mesh axis.
 from __future__ import annotations
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -58,7 +57,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     impl is forwarded to ops.masked_attention ('auto' picks the pallas
     flash kernel on TPU when the global T tiles cleanly).
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     H = q.shape[2]
     if H % n:
         raise ValueError(
@@ -100,7 +99,7 @@ def ulysses_self_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return ulysses_attention(q, k, v, kv_mask, causal=causal)
 
     seq_spec = P(None, SEQ_AXIS, None, None)
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec, P(None, SEQ_AXIS)),
         out_specs=seq_spec, check_vma=False)

@@ -73,6 +73,7 @@ from kubeml_tpu.models.gpt import (PAD_ID, build_paged_decode_step,
                                    build_paged_multi_step_decode,
                                    build_paged_prefill_step,
                                    build_paged_spec_verify_step)
+from kubeml_tpu.ops.pallas.paged_attention import resolve_impl
 from kubeml_tpu.serve.flight import FlightRecorder
 from kubeml_tpu.serve.pager import (KVPageSlab, PageAllocator, PageGeometry,
                                     chain_hash)
@@ -334,7 +335,7 @@ class DecodeEngine:
         # dispatches_per_token); "compiles" stays single-step-program
         # only (the PR-6 meaning the pinning tests rely on) — the
         # accelerator programs have their own compile lanes below.
-        self.stats: Dict[str, float] = {
+        self.stats: Dict[str, object] = {
             "dispatches": 0, "generated_tokens": 0, "occupancy_sum": 0,
             "stalls": 0, "compiles": 0,
             "prefill_dispatches": 0, "prefill_tokens": 0,
@@ -348,6 +349,22 @@ class DecodeEngine:
             "draft_tokens": 0, "accepted_tokens": 0,
             "rejected_tokens": 0,
         }
+        # which implementation each attention call site takes, resolved
+        # by the SAME rule paged_attention applies at trace time
+        # (ops/pallas/paged_attention.resolve_impl: platform gate +
+        # shapes/dtype VMEM bound) — so a silent fallback to the gather
+        # path shows up here (and in chip_smoke.py) instead of as an
+        # unexplained number
+        geometry = dict(page=self.geom.page, heads=module.heads,
+                        head_dim=head_dim,
+                        max_pages=self.geom.pages_per_slot,
+                        dtype=module.dtype,
+                        quantized=kv_dtype == "int8")
+        self.stats["attn_impl_decode"] = resolve_impl(
+            attn_impl, self.attn_interpret, q_len=1, **geometry)
+        self.stats["attn_impl_prefill"] = resolve_impl(
+            attn_impl, self.attn_interpret, q_len=prefill_chunk,
+            **geometry) if prefill_chunk > 0 else "off"
 
     # ------------------------------------------------------------- capacity
     @property

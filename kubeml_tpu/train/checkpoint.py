@@ -154,9 +154,8 @@ class AsyncCheckpointer:
     fast HBM copy, so the snapshot survives the engines' buffer donation
     of the live variables on the next round) and returns immediately; a
     single daemon worker performs the expensive part (full-model
-    device→host readback, hundreds of ms on tunneled backends, plus the
-    atomic directory publish) off the training thread. Pending saves are
-    latest-wins per job id: if epochs outpace the writer, intermediate
+    device→host readback plus the atomic directory publish) off the
+    training thread. Pending saves are latest-wins per job id: if epochs outpace the writer, intermediate
     snapshots are dropped and the newest wins — each published checkpoint
     is always a complete, consistent epoch state.
 

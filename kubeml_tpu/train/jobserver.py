@@ -282,6 +282,10 @@ def main(argv=None):
     args = p.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO)
+    # process entry: same persistent compile cache as the parent PS
+    # (utils/env.py) — a restarted job deserializes its round program
+    from kubeml_tpu.utils.env import enable_compile_cache
+    enable_compile_cache()
     # a wedged child (backend init, collective, IO) is otherwise a
     # silent readiness-timeout for the PS: dump every thread's stack to
     # stderr periodically so the parent's captured output shows WHERE

@@ -7,45 +7,50 @@ free-port finder.
 import os
 import socket
 
-_COMPILE_CACHE_ENABLED = None  # cache dir currently configured, or None
+# The compile cache's home when JAX_COMPILATION_CACHE_DIR does not place
+# it: a FIXED path inside the checkout. The directory is part of the
+# cache key's lookup, so one that moves (a temp home, a pid, a time)
+# never hits across processes.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_compile_cache(path: str = None) -> bool:
-    """Point JAX's persistent compilation cache under $KUBEML_TPU_HOME.
+def enable_compile_cache():
+    """Turn on JAX's persistent compilation cache for this process;
+    returns the directory in use, or None when it is off.
 
     Elastic parallelism re-lowers the round program whenever the round
-    shape changes; with the cache on, each (program, shape) pays XLA
-    compilation ONCE PER HOST EVER — later jobs (and restarts of this
-    one) deserialize the executable in well under a second instead of
-    the 20-200 s compiles measured in results/*-autoscale-v5e.jsonl.
+    shape changes, and a cold ResNet-18 round compiles for minutes on a
+    v5e; with the cache on, each (program, shape) pays XLA compilation
+    once per host — later processes deserialize the executable instead.
     The reference never needed this because Fission functions are
-    eagerly-executed torch (no compile step at all); on TPU it is the
-    difference between elasticity being free and fighting the hardware.
+    eagerly-executed torch (no compile step at all).
 
-    Idempotent; returns whether the cache is on. Opt out with
+    Placement is the OPERATOR's: if JAX_COMPILATION_CACHE_DIR is set,
+    JAX already points there and the directory is left alone; otherwise
+    the cache goes to DEFAULT_COMPILE_CACHE_DIR. Either way only the two
+    admission thresholds are set here. Called once at each process
+    entry (cli main, jobserver main, chip_smoke.py, bench.py) so train
+    and serve programs alike are cached. Idempotent. Opt out with
     KUBEML_COMPILE_CACHE=0 (e.g. for compile-time benchmarking).
     """
-    global _COMPILE_CACHE_ENABLED
     if os.environ.get("KUBEML_COMPILE_CACHE", "").lower() in ("0", "false",
                                                               "no"):
-        return False
+        return None
     import jax
 
-    from kubeml_tpu.api.const import kubeml_home
-    path = path or os.path.join(kubeml_home(), "compile_cache")
-    if _COMPILE_CACHE_ENABLED == path:
-        return True
-    os.makedirs(path, exist_ok=True)
-    # re-pointing on a changed $KUBEML_TPU_HOME keeps test isolation:
-    # each test home gets its own cache dir instead of the first one won
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_COMPILE_CACHE_DIR
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.set_cache_dir(path)
     # default thresholds skip sub-second programs; the round program's
     # *steady* recompiles are the target, so keep a small floor to avoid
     # churning the cache with trivial host-side jits (loss reducers etc.)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _COMPILE_CACHE_ENABLED = path
-    return True
+    return path
 
 
 def is_debug_env() -> bool:

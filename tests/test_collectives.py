@@ -3,7 +3,6 @@ meshes whose inner axes stay Auto (a partially-manual sub-f32 lax.psum
 is a fatal partitioner miscompile — parallel/collectives.py)."""
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,7 +13,7 @@ from kubeml_tpu.parallel.mesh import DATA_AXIS, make_mesh
 
 
 def run_ring(mesh, x, wire_dtype, **shmap_kw):
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         lambda v: ring_psum(v, DATA_AXIS, wire_dtype), mesh=mesh,
         in_specs=P(DATA_AXIS), out_specs=P(), check_vma=False,
         **shmap_kw))(jnp.asarray(x))
@@ -62,7 +61,7 @@ def test_ring_lane_identity(mesh8, wire):
     (which, pre-fix, the owner kept in unrounded f32 while everyone
     else stored the wire-rounded copy)."""
     x = np.random.RandomState(5).randn(8, 193).astype(np.float32)
-    per_lane = jax.jit(compat.shard_map(
+    per_lane = jax.jit(jax.shard_map(
         lambda v: ring_psum(v, DATA_AXIS, wire)[None],
         mesh=mesh8, in_specs=P(DATA_AXIS), out_specs=P(DATA_AXIS),
         check_vma=False))(jnp.asarray(x))

@@ -5,10 +5,11 @@ engine.SERVE_PATH_VARIANTS are pinned here, quoted, next to exactness
 assertions (tools/check_serve_parity.py enforces this coupling):
 
   * 'pallas_paged' — the paged-attention kernel (interpret mode on CPU)
-    is BIT-IDENTICAL to the gather-based reference programs, at the op
-    level and through a full engine lifecycle (joins, leaves, mixed
-    prompt lengths, copy-on-write splits), with the same dispatch and
-    compile counts — the kernel is a bandwidth lever, not a math change.
+    matches the gather-based reference programs (bit-identical in bf16,
+    within 4 f32 ulps in f32 at the op level; token-identical through a
+    full engine lifecycle: joins, leaves, mixed prompt lengths,
+    copy-on-write splits), with the same dispatch and compile counts —
+    the kernel is a bandwidth lever, not a math change.
   * 'int8_kv' — quantized KV pages keep the row-independence contract:
     a stream's tokens are identical solo vs continuously batched, the
     prefix cache serves quantized pages, CoW splits carry per-page
@@ -86,10 +87,28 @@ def _rand_paged(key, S, Pmax, G, H, D, dtype, T, quantized):
 
 # ------------------------------------------------------- kernel parity
 
-def test_pallas_paged_kernel_bit_identical_to_gather():
+def _assert_kernel_matches_gather(ker, ref, dtype):
+    """bf16 stays bit-identical. In f32 the heads-leading layout the
+    TPU compiler accepts hands XLA-CPU a differently-strided batched
+    matmul than the reference's [C, H, D] operands, and its f32 dot
+    reassociates: the tightest bound that holds is 4 f32 ulps of the
+    largest output magnitude (measured: <= 3.3 ulps over these cases)."""
+    import jax.numpy as jnp
+    ker, ref = np.asarray(ker), np.asarray(ref)
+    if dtype == jnp.float32:
+        atol = 4 * np.spacing(np.float32(np.abs(ref).max()))
+        np.testing.assert_allclose(ker, ref, rtol=0, atol=atol)
+    else:
+        np.testing.assert_array_equal(ker, ref)
+
+
+@pytest.mark.parametrize("seed,dtype_name,T", [
+    (0, "float32", 1), (1, "float32", 16),
+    (2, "bfloat16", 1), (3, "bfloat16", 16)])
+def test_pallas_paged_kernel_bit_identical_to_gather(seed, dtype_name, T):
     """'pallas_paged': the kernel (interpret) reproduces the gather
-    reference BIT-FOR-BIT — f32 and bf16, single-token decode and
-    chunked-prefill query shapes."""
+    reference — BIT-FOR-BIT in bf16, within 4 f32 ulps in f32 — for
+    single-token decode and chunked-prefill query shapes."""
     import functools
 
     import jax
@@ -97,20 +116,23 @@ def test_pallas_paged_kernel_bit_identical_to_gather():
 
     from kubeml_tpu.ops.pallas.paged_attention import paged_attention
 
-    for seed, dtype, T in ((0, jnp.float32, 1), (1, jnp.float32, 16),
-                           (2, jnp.bfloat16, 1), (3, jnp.bfloat16, 16)):
-        args = _rand_paged(jax.random.PRNGKey(seed), S=4, Pmax=4, G=8,
-                           H=4, D=64, dtype=dtype, T=T, quantized=False)
-        ker = jax.jit(functools.partial(paged_attention, impl="pallas",
-                                        interpret=True))(*args)
-        ref = jax.jit(functools.partial(
-            paged_attention, impl="gather"))(*args)
-        np.testing.assert_array_equal(np.asarray(ker), np.asarray(ref))
+    dtype = getattr(jnp, dtype_name)
+    args = _rand_paged(jax.random.PRNGKey(seed), S=4, Pmax=4, G=8,
+                       H=4, D=64, dtype=dtype, T=T, quantized=False)
+    ker = jax.jit(functools.partial(paged_attention, impl="pallas",
+                                    interpret=True))(*args)
+    ref = jax.jit(functools.partial(
+        paged_attention, impl="gather"))(*args)
+    _assert_kernel_matches_gather(ker, ref, dtype)
 
 
-def test_pallas_paged_kernel_int8_dequant_bit_identical():
+@pytest.mark.parametrize("seed,dtype_name,T", [
+    (4, "float32", 1), (5, "bfloat16", 16)])
+def test_pallas_paged_kernel_int8_dequant_bit_identical(seed, dtype_name,
+                                                        T):
     """int8 pages: the kernel's in-VMEM dequant and the gather path's
-    pre-gather dequant are ONE expression — outputs bit-identical."""
+    pre-gather dequant are ONE expression — outputs bit-identical in
+    bf16, within the f32 matmul bound above in f32."""
     import functools
 
     import jax
@@ -118,26 +140,47 @@ def test_pallas_paged_kernel_int8_dequant_bit_identical():
 
     from kubeml_tpu.ops.pallas.paged_attention import paged_attention
 
-    for seed, dtype, T in ((4, jnp.float32, 1), (5, jnp.bfloat16, 16)):
-        args = _rand_paged(jax.random.PRNGKey(seed), S=3, Pmax=3, G=8,
-                           H=2, D=32, dtype=dtype, T=T, quantized=True)
-        ker = jax.jit(functools.partial(
-            paged_attention, quantized=True, compute_dtype=dtype,
-            impl="pallas", interpret=True))(*args)
-        ref = jax.jit(functools.partial(
-            paged_attention, quantized=True, compute_dtype=dtype,
-            impl="gather"))(*args)
-        np.testing.assert_array_equal(np.asarray(ker), np.asarray(ref))
+    dtype = getattr(jnp, dtype_name)
+    args = _rand_paged(jax.random.PRNGKey(seed), S=3, Pmax=3, G=8,
+                       H=2, D=32, dtype=dtype, T=T, quantized=True)
+    ker = jax.jit(functools.partial(
+        paged_attention, quantized=True, compute_dtype=dtype,
+        impl="pallas", interpret=True))(*args)
+    ref = jax.jit(functools.partial(
+        paged_attention, quantized=True, compute_dtype=dtype,
+        impl="gather"))(*args)
+    _assert_kernel_matches_gather(ker, ref, dtype)
 
 
 def test_paged_attention_validates_impl_and_geometry():
     import jax
     import jax.numpy as jnp
 
-    from kubeml_tpu.ops.pallas.paged_attention import (paged_attention,
-                                                       paged_eligible)
-    assert paged_eligible(8) and paged_eligible(16)
-    assert not paged_eligible(4)
+    from kubeml_tpu.ops.pallas.paged_attention import (VMEM_BUDGET,
+                                                       paged_attention,
+                                                       paged_eligible,
+                                                       paged_vmem_bytes,
+                                                       resolve_impl)
+    # alignment: page rows are the scratch store's sublane offset.
+    # The VMEM bound is part of the gate too: gpt-mini's serve geometry
+    # fits, a context whose heads-leading scratch pair alone exceeds
+    # the budget does not (2 * 16 * 8192 * 128 * 2 B = 64 MiB)
+    mini = dict(q_len=16, heads=4, head_dim=64, max_pages=32,
+                dtype=jnp.bfloat16)
+    assert paged_eligible(8, **mini) and paged_eligible(16, **mini)
+    assert not paged_eligible(4, **mini)
+    assert paged_vmem_bytes(16, 4, 64, 16, 32, jnp.bfloat16, False) \
+        >= 2 * 4 * 512 * 128 * 2
+    long = dict(q_len=1, heads=16, head_dim=128, max_pages=512,
+                dtype=jnp.bfloat16)
+    assert paged_vmem_bytes(1, 16, 128, 16, 512, jnp.bfloat16, False) \
+        > VMEM_BUDGET
+    assert not paged_eligible(16, **long)
+    # 'auto' resolves from the same rule; a forced impl passes through
+    assert resolve_impl("auto", True, page=16, **mini) == "pallas"
+    assert resolve_impl("auto", True, page=16, **long) == "gather"
+    assert resolve_impl("auto", False, page=16, **mini) == "gather"  # CPU
+    assert resolve_impl("gather", True, page=16, **mini) == "gather"
     args = _rand_paged(jax.random.PRNGKey(0), S=2, Pmax=2, G=4, H=2,
                        D=8, dtype=jnp.float32, T=1, quantized=False)
     with pytest.raises(ValueError, match="impl"):
@@ -447,3 +490,21 @@ def test_serve_kv_dtype_knob_threading(monkeypatch):
     assert ps.serve_kv_dtype == "int8"
     ps2 = ParameterServer(port=0, serve_kv_dtype="f32")
     assert ps2.serve_kv_dtype == "f32"
+
+
+def test_engine_records_attention_impl_per_program():
+    """engine.stats names the implementation each attention call site
+    took, from the same rule paged_attention dispatches on: CPU 'auto'
+    is the gather path, a forced interpret kernel reads 'pallas', and a
+    disabled prefill program reads 'off'."""
+    from kubeml_tpu.serve.engine import DecodeEngine
+    _model, module, variables = _nano()
+    auto = DecodeEngine(module, variables, slots=2, page=8,
+                        prefill_chunk=8)
+    assert auto.stats["attn_impl_decode"] == "gather"
+    assert auto.stats["attn_impl_prefill"] == "gather"
+    forced = DecodeEngine(module, variables, slots=2, page=8,
+                          prefill_chunk=0, attn_impl="pallas",
+                          attn_interpret=True)
+    assert forced.stats["attn_impl_decode"] == "pallas"
+    assert forced.stats["attn_impl_prefill"] == "off"

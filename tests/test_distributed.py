@@ -88,6 +88,41 @@ def test_initialize_single_process_noop():
     assert distributed.is_coordinator()
 
 
+def test_initialize_bare_environment_never_dials(monkeypatch):
+    """No arguments, no KUBEML_COORDINATOR_ADDRESS, no cluster
+    environment: initialize() returns WITHOUT calling
+    jax.distributed.initialize at all — on a TPU host with no network
+    JAX's own cluster detection may wait on a metadata server, and
+    `kubeml serve` must start regardless."""
+    for var in distributed.CLUSTER_ENV_VARS:
+        monkeypatch.delenv(var, raising=False)
+
+    def dial(*a, **kw):
+        raise AssertionError("jax.distributed.initialize was called")
+
+    monkeypatch.setattr(distributed.jax.distributed, "initialize", dial)
+    distributed.initialize()
+    distributed.initialize(None, None, None)   # cli/main.py's call
+
+
+def test_initialize_cluster_environment_still_dials(monkeypatch):
+    """An environment that names a multi-host cluster is joined as
+    before (and a failed join propagates)."""
+    for var in distributed.CLUSTER_ENV_VARS:
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setenv("KUBEML_COORDINATOR_ADDRESS", "127.0.0.1:1")
+    monkeypatch.setenv("KUBEML_NUM_PROCESSES", "2")
+    monkeypatch.setenv("KUBEML_PROCESS_ID", "1")
+    calls = []
+    monkeypatch.setattr(distributed.jax.distributed, "initialize",
+                        lambda **kw: calls.append(kw) or (_ for _ in ())
+                        .throw(RuntimeError("no coordinator")))
+    with pytest.raises(RuntimeError, match="no coordinator"):
+        distributed.initialize()
+    assert calls == [{"coordinator_address": "127.0.0.1:1",
+                      "num_processes": 2, "process_id": 1}]
+
+
 def test_initialize_explicit_args_failure_raises():
     # explicit bring-up must not silently degrade to single-process: here
     # the backend is already initialized, so the join fails immediately

@@ -1,6 +1,8 @@
 """TrainJob end-to-end: epoch loop, history, checkpoint, callbacks,
 dynamic parallelism, goal accuracy, stop."""
 
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -1069,20 +1071,44 @@ def test_job_pipeline_parallel_bert_matches_dense(tmp_home):
                                rtol=2e-2, atol=0.5)
 
 
-def test_enable_compile_cache_repoints_per_home(monkeypatch, tmp_path):
-    """enable_compile_cache follows $KUBEML_TPU_HOME (test isolation:
-    each home gets its own cache dir, not first-caller-wins) and
-    honors the KUBEML_COMPILE_CACHE=0 opt-out."""
+@pytest.mark.parametrize("case", ["env_set", "env_unset", "opted_out"])
+def test_enable_compile_cache_placement(monkeypatch, tmp_path, case):
+    """One compile cache, placeable from outside: with
+    JAX_COMPILATION_CACHE_DIR set the directory JAX already holds is
+    left untouched; unset, the cache goes to the FIXED in-checkout path
+    (never $KUBEML_TPU_HOME, a temp dir, a pid or a time);
+    KUBEML_COMPILE_CACHE=0 keeps its meaning (off, nothing touched)."""
     from kubeml_tpu.utils import env as env_mod
 
-    monkeypatch.setenv("KUBEML_TPU_HOME", str(tmp_path / "h1"))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fixed = os.path.join(repo, ".jax_cache")
+    assert env_mod.DEFAULT_COMPILE_CACHE_DIR == fixed
+    monkeypatch.setenv("KUBEML_TPU_HOME", str(tmp_path / "home"))
     monkeypatch.delenv("KUBEML_COMPILE_CACHE", raising=False)
-    assert env_mod.enable_compile_cache() is True
-    assert jax.config.jax_compilation_cache_dir == \
-        str(tmp_path / "h1" / "compile_cache")
-    monkeypatch.setenv("KUBEML_TPU_HOME", str(tmp_path / "h2"))
-    assert env_mod.enable_compile_cache() is True
-    assert jax.config.jax_compilation_cache_dir == \
-        str(tmp_path / "h2" / "compile_cache")
-    monkeypatch.setenv("KUBEML_COMPILE_CACHE", "0")
-    assert env_mod.enable_compile_cache() is False
+    was = jax.config.jax_compilation_cache_dir
+    placed = []
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(compilation_cache, "set_cache_dir", placed.append)
+    try:
+        if case == "env_set":
+            outside = str(tmp_path / "operator_cache")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+            assert env_mod.enable_compile_cache() == outside
+            assert placed == []  # the directory is JAX's, not ours
+            assert jax.config.jax_compilation_cache_dir == was
+        elif case == "env_unset":
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert env_mod.enable_compile_cache() == fixed
+            assert placed == [fixed]
+            assert str(tmp_path) not in fixed
+        else:
+            monkeypatch.setenv("KUBEML_COMPILE_CACHE", "0")
+            assert env_mod.enable_compile_cache() is None
+            assert placed == []
+            assert jax.config.jax_compilation_cache_dir == was
+    finally:
+        # the two admission thresholds are process-global; restore so
+        # later tests keep the suite's default (no persistent cache)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          1.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)

@@ -9,7 +9,6 @@ Runs on the 8-virtual-CPU-device mesh (conftest).
 """
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -47,7 +46,7 @@ def _manual_forward(model, variables, x, mesh):
     def fwd(v, x):
         return tp_module.apply(v, x, train=False)
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         fwd, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
         check_vma=False))(variables, x)
 
@@ -127,7 +126,7 @@ def test_manual_tp_grads_match_dense(tp2_mesh):
     def tp_grads(v, x, y):
         return jax.grad(lambda v: scalar(tp_model, v, x, y))(v)
 
-    g_tp = jax.jit(compat.shard_map(
+    g_tp = jax.jit(jax.shard_map(
         tp_grads, mesh=tp2_mesh, in_specs=(P(), P(), P()), out_specs=P(),
         check_vma=True))(variables, x, y)
     for a, b in zip(jax.tree_util.tree_leaves(g_ref),
@@ -323,5 +322,5 @@ def test_manual_tp_rejects_indivisible_heads(tp2_mesh):
         return module.init(jax.random.PRNGKey(0), x)
 
     with pytest.raises(ValueError, match="heads do not divide"):
-        jax.jit(compat.shard_map(fwd, mesh=tp2_mesh, in_specs=P(),
+        jax.jit(jax.shard_map(fwd, mesh=tp2_mesh, in_specs=P(),
                               out_specs=P(), check_vma=False))(x)

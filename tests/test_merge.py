@@ -32,7 +32,6 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from kubeml_tpu import compat
 from kubeml_tpu.parallel import merge as merge_lib
 from kubeml_tpu.parallel.kavg import KAvgEngine
 from kubeml_tpu.parallel.mesh import DATA_AXIS
@@ -387,7 +386,7 @@ def _strategy_lane_merge(mesh, strategy, contribs, alive, residual):
             lane_alive=lane_alive, residual={"b0": res.reshape(L)})
         return avg["w"].reshape(1, L), nr["b0"].reshape(1, L)
 
-    f = compat.shard_map(
+    f = jax.shard_map(
         jax.jit(body), mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(), P(DATA_AXIS)), check_vma=False)

@@ -118,3 +118,29 @@ def test_assemble_round_cycle_pads():
     np.testing.assert_array_equal(stm, [[1, 1], [0, 0]])
     np.testing.assert_array_equal(wm, [1, 0])
     assert not xo[1].any() and not yo[1].any()
+
+
+def test_failed_native_build_warns_once_and_falls_back(monkeypatch, caplog):
+    """A host without a working compiler keeps the numpy path, but says
+    so: the build error is logged once at warning level, not swallowed."""
+    import logging
+    import subprocess
+
+    from kubeml_tpu import native
+
+    def no_compiler():
+        raise subprocess.CalledProcessError(
+            1, ["g++"], stderr=b"g++: fatal error: no input files")
+
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_failed", False)
+    monkeypatch.setattr(native, "_SO", native._SO + ".missing")
+    monkeypatch.setattr(native, "_build", no_compiler)
+    with caplog.at_level(logging.WARNING, logger="kubeml_tpu.native"):
+        assert native.available() is False
+        assert native.available() is False   # second probe: no new attempt
+    warnings = [r for r in caplog.records
+                if r.name == "kubeml_tpu.native"]
+    assert len(warnings) == 1
+    assert "no input files" in warnings[0].getMessage()
+    assert "numpy path" in warnings[0].getMessage()

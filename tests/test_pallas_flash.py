@@ -1,7 +1,6 @@
 """Flash-attention pallas kernel vs the jnp reference (interpret mode)."""
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -313,7 +312,7 @@ def test_ring_flash_causal_noncontiguous_layout_poisons():
         return ring_attention(q, k, v, pos, pos, pad, causal=True,
                               use_flash=True, interpret=True)
 
-    out = jax.jit(compat.shard_map(
+    out = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, SEQ_AXIS), P(None, SEQ_AXIS),
                   P(None, SEQ_AXIS), P(SEQ_AXIS), P(None, SEQ_AXIS)),
@@ -322,7 +321,7 @@ def test_ring_flash_causal_noncontiguous_layout_poisons():
         "layout violation must poison the flash output"
 
     # the contiguous layout stays finite through the same call path
-    out2 = jax.jit(compat.shard_map(
+    out2 = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, SEQ_AXIS), P(None, SEQ_AXIS),
                   P(None, SEQ_AXIS), P(SEQ_AXIS), P(None, SEQ_AXIS)),

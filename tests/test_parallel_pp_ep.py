@@ -5,7 +5,6 @@ checked against unpipelined / per-token dense references.
 """
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -396,7 +395,7 @@ def test_ep_alltoall_ffn_matches_dense():
         return ep_alltoall_ffn(wi, bi, wo, bo, disp, comb, x_l,
                                EXPERT_AXIS, dtype=jnp.float32)
 
-    y = jax.jit(compat.shard_map(
+    y = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(EXPERT_AXIS), P(EXPERT_AXIS), P(), P(), P(), P(), P()),
         out_specs=P(EXPERT_AXIS), check_vma=False))(

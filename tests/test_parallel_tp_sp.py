@@ -4,7 +4,6 @@ Runs on the 8-virtual-CPU-device mesh (conftest).
 """
 
 import jax
-from kubeml_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -443,7 +442,7 @@ def test_sp_loss_handles_padding_across_shards():
         logits = sp_module.apply(v, x_local, train=False)
         return _lm_per_example_sp(logits, x_local, SEQ_AXIS)
 
-    out = jax.jit(compat.shard_map(
+    out = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P(None, SEQ_AXIS)),
         out_specs=P(), check_vma=False))(variables, jnp.asarray(x))
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-4)

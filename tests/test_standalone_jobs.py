@@ -473,3 +473,19 @@ def test_sigterm_preemption_reschedules_without_budget(standalone_stack,
     assert history.data.preemptions == 1
     assert history.data.restarts == 0
     assert dep.ps.wait_for_job(job_id, timeout=60)
+
+
+def test_standalone_jobs_with_parent_accelerator_mesh_is_an_error():
+    """One process per chip: a parent that built an accelerator mesh
+    holds the chip its job children need — the combination is refused
+    at start-up with a message that names it (a CPU mesh, which is only
+    mirrored into the children as a device count, stays legal)."""
+    import types
+
+    import numpy as np
+
+    from kubeml_tpu.control.ps import ParameterServer
+    chip = types.SimpleNamespace(platform="tpu")
+    mesh = types.SimpleNamespace(devices=np.array([chip], dtype=object))
+    with pytest.raises(ValueError, match="one process per chip"):
+        ParameterServer(mesh=mesh, standalone_jobs=True)
