@@ -1,0 +1,5 @@
+def read(ctx, m, spec):
+    trace = m.get("trace")
+    if not trace or not trace.get("window_s") or not trace.get("busy_s"):
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
