@@ -794,8 +794,8 @@ class ParameterServer(JsonService):
 
     def _h_trace(self, req: Request):
         """Merged Chrome trace for a job (?id=<jobId>): every process's
-        TraceSink file plus any xla_profile capture, one Perfetto-
-        loadable document."""
+        TraceSink files, one Perfetto-loadable document. For a serving
+        model that is the request trees and the loop phases."""
         job_id = req.query.get("id", "")
         if not job_id:
             raise InvalidArgsError("id query parameter required")
@@ -1018,14 +1018,15 @@ class ParameterServer(JsonService):
                     f"decode with the configured serve knobs: {e}") \
                     from e
             # serving observability is always on in the product path:
-            # the tracer shares the service clock (perf_counter), and
+            # the tracer shares the service clock (time.monotonic, the
+            # phase ring's and the load generators' too), and
             # each replica sinks under the serve:<model> pseudo-job id
             # with its own process name so GET /trace?id=serve:<model>
             # renders the whole fleet on one timeline
             return ServeService(model_id, engine,
                                 max_queue=self.serve_queue_depth,
                                 metrics=self.metrics,
-                                tracer=Tracer(clock=time.perf_counter),
+                                tracer=Tracer(clock=time.monotonic),
                                 trace_sink=TraceSink(
                                     f"serve:{model_id}",
                                     f"serve-r{index}"))
@@ -1100,7 +1101,7 @@ class ParameterServer(JsonService):
             # their own process in the serve:<model> trace dir, so the
             # merged document stitches one tree per request across the
             # router and every replica it touched
-            tracer=Tracer(clock=time.perf_counter),
+            tracer=Tracer(clock=time.monotonic),
             trace_sink=TraceSink(f"serve:{model_id}", "fleet"),
             slo_ttft_s=self.serve_slo_ttft_ms / 1000.0,
             slo_tpot_s=self.serve_slo_tpot_ms / 1000.0,

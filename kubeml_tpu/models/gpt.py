@@ -430,6 +430,12 @@ def _prompt_lengths(window: np.ndarray) -> np.ndarray:
 # quantizes pages on write with per-page symmetric scales
 _KV_DTYPES = ("f32", "int8")
 
+# jax.named_scope names inside the paged programs, in program order;
+# the last five of a layer appear as layer_<i>/<name>. Trace readers
+# find a program's parts by these, not by kernel or fusion names.
+PAGED_SCOPES = ("cow_split", "embed", "mask", "qkv", "kv_write", "attn",
+                "proj", "mlp", "head", "sample")
+
 
 def _int8_write_decode(pages, scales, layer, rows, write_page, write_off):
     """Quantize-on-write for one layer's decode rows [S, H, Dh] (f32)
@@ -590,85 +596,106 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
         S = tokens.shape[0]
         G = valid_pages.shape[1]
         C = page_tables.shape[1] * G
+        # jax.named_scope below is metadata only: each block's
+        # operations carry its name in the compiled program, so a
+        # profiler trace says which of them an operation serves
+        # (PAGED_SCOPES; the per-layer ones read layer_<i>/<name>)
+        #
         # copy-on-write splits first: the gather of copy_src pages
         # happens before any scatter in this dispatch (functional
         # update semantics), so splitting a page and reusing its id are
         # safe in the same step. 0 -> 0 rows are null-page no-ops.
         # Scales are page metadata and split with their page.
-        k_pages = k_pages.at[:, copy_dst].set(k_pages[:, copy_src])
-        v_pages = v_pages.at[:, copy_dst].set(v_pages[:, copy_src])
-        k_scales = k_scales.at[:, copy_dst].set(k_scales[:, copy_src])
-        v_scales = v_scales.at[:, copy_dst].set(v_scales[:, copy_src])
-        valid_pages = valid_pages.at[copy_dst].set(valid_pages[copy_src])
-        h = tok_embed.apply({"params": params["tok_embed"]}, tokens[:, None])
-        h = h + pos_embed.apply({"params": params["pos_embed"]},
-                                pos[:, None])
+        with jax.named_scope("cow_split"):
+            k_pages = k_pages.at[:, copy_dst].set(k_pages[:, copy_src])
+            v_pages = v_pages.at[:, copy_dst].set(v_pages[:, copy_src])
+            k_scales = k_scales.at[:, copy_dst].set(k_scales[:, copy_src])
+            v_scales = v_scales.at[:, copy_dst].set(v_scales[:, copy_src])
+            valid_pages = valid_pages.at[copy_dst].set(
+                valid_pages[copy_src])
+        with jax.named_scope("embed"):
+            h = tok_embed.apply({"params": params["tok_embed"]},
+                                tokens[:, None])
+            h = h + pos_embed.apply({"params": params["pos_embed"]},
+                                    pos[:, None])
         # this token's validity, written BEFORE the gather so a slot's
         # first token attends to itself (offset-0 decode semantics of
         # the contiguous path). Inactive slots write 0 to the null page.
-        tok_valid = active * (tokens != PAD_ID).astype(jnp.float32)
-        valid_pages = valid_pages.at[write_page, write_off].set(tok_valid)
-        ctx_valid = valid_pages[page_tables].reshape(S, C)
-        causal = (jnp.arange(C)[None, :] <= pos[:, None]) \
-            .astype(jnp.float32)
-        bias = (1.0 - ctx_valid * causal)[:, None, None, :] * NEG_INF
+        with jax.named_scope("mask"):
+            tok_valid = active * (tokens != PAD_ID).astype(jnp.float32)
+            valid_pages = valid_pages.at[write_page, write_off].set(
+                tok_valid)
+            ctx_valid = valid_pages[page_tables].reshape(S, C)
+            causal = (jnp.arange(C)[None, :] <= pos[:, None]) \
+                .astype(jnp.float32)
+            bias = (1.0 - ctx_valid * causal)[:, None, None, :] * NEG_INF
         for i in range(module.layers):
             p = params[f"layer_{i}"]
-            x = ln.apply({"params": p["LayerNorm_0"]}, h)
-            q = qkv.apply({"params": p["q"]}, x)
-            k = qkv.apply({"params": p["k"]}, x)
-            v = qkv.apply({"params": p["v"]}, x)
-            if quantized:
-                k_pages, k_scales = _int8_write_decode(
-                    k_pages, k_scales, i, k[:, 0].astype(jnp.float32),
-                    write_page, write_off)
-                v_pages, v_scales = _int8_write_decode(
-                    v_pages, v_scales, i, v[:, 0].astype(jnp.float32),
-                    write_page, write_off)
-            else:
-                k_pages = k_pages.at[i, write_page, write_off].set(
-                    k[:, 0].astype(dtype))
-                v_pages = v_pages.at[i, write_page, write_off].set(
-                    v[:, 0].astype(dtype))
-            attn = paged_attention(
-                q, k_pages[i], v_pages[i], k_scales[i], v_scales[i],
-                page_tables, bias, quantized=quantized,
-                compute_dtype=dtype, impl=attn_impl,
-                interpret=attn_interpret)
-            attn = out_proj.apply({"params": p["out"]}, attn)
-            h = h + attn
-            x = ln.apply({"params": p["LayerNorm_1"]}, h)
-            x = ffn_in.apply({"params": p["Dense_0"]}, x)
-            x = nn.gelu(x)
-            x = ffn_out.apply({"params": p["Dense_1"]}, x)
-            h = h + x
-        h = ln.apply({"params": params["LayerNorm_0"]}, h)
-        logits = tok_embed.apply(
-            {"params": params["tok_embed"]}, h.astype(dtype),
-            method=tok_embed.attend).astype(jnp.float32)[:, 0]
-        # fault lane: a raised poison row goes non-finite here, BEFORE
-        # the guard — injection and genuine weight poison trip the same
-        # path (where-select, never 0*NaN: that would stay NaN)
-        logits = jnp.where(poison[:, None] > 0, jnp.nan, logits)
-        # non-finite guard, per lane. Must run BEFORE the PAD mask
-        # below writes a legitimate -inf into every row; flagged rows
-        # are sanitized to zeros so argmax/categorical stay well-defined
-        # (their pick is discarded by the host and forced to 0 anyway).
-        bad = active * (1.0 - jnp.all(
-            jnp.isfinite(logits), axis=-1).astype(jnp.float32))
-        logits = jnp.where(bad[:, None] > 0,
-                           jnp.zeros_like(logits), logits)
-        logits = logits.at[:, PAD_ID].set(-jnp.inf)  # never emit PAD
+            with jax.named_scope(f"layer_{i}/qkv"):
+                x = ln.apply({"params": p["LayerNorm_0"]}, h)
+                q = qkv.apply({"params": p["q"]}, x)
+                k = qkv.apply({"params": p["k"]}, x)
+                v = qkv.apply({"params": p["v"]}, x)
+            with jax.named_scope(f"layer_{i}/kv_write"):
+                if quantized:
+                    k_pages, k_scales = _int8_write_decode(
+                        k_pages, k_scales, i, k[:, 0].astype(jnp.float32),
+                        write_page, write_off)
+                    v_pages, v_scales = _int8_write_decode(
+                        v_pages, v_scales, i, v[:, 0].astype(jnp.float32),
+                        write_page, write_off)
+                else:
+                    k_pages = k_pages.at[i, write_page, write_off].set(
+                        k[:, 0].astype(dtype))
+                    v_pages = v_pages.at[i, write_page, write_off].set(
+                        v[:, 0].astype(dtype))
+            with jax.named_scope(f"layer_{i}/attn"):
+                attn = paged_attention(
+                    q, k_pages[i], v_pages[i], k_scales[i], v_scales[i],
+                    page_tables, bias, quantized=quantized,
+                    compute_dtype=dtype, impl=attn_impl,
+                    interpret=attn_interpret)
+            with jax.named_scope(f"layer_{i}/proj"):
+                attn = out_proj.apply({"params": p["out"]}, attn)
+                h = h + attn
+            with jax.named_scope(f"layer_{i}/mlp"):
+                x = ln.apply({"params": p["LayerNorm_1"]}, h)
+                x = ffn_in.apply({"params": p["Dense_0"]}, x)
+                x = nn.gelu(x)
+                x = ffn_out.apply({"params": p["Dense_1"]}, x)
+                h = h + x
+        with jax.named_scope("head"):
+            h = ln.apply({"params": params["LayerNorm_0"]}, h)
+            logits = tok_embed.apply(
+                {"params": params["tok_embed"]}, h.astype(dtype),
+                method=tok_embed.attend).astype(jnp.float32)[:, 0]
+        with jax.named_scope("sample"):
+            # fault lane: a raised poison row goes non-finite here,
+            # BEFORE the guard — injection and genuine weight poison
+            # trip the same path (where-select, never 0*NaN: that would
+            # stay NaN)
+            logits = jnp.where(poison[:, None] > 0, jnp.nan, logits)
+            # non-finite guard, per lane. Must run BEFORE the PAD mask
+            # below writes a legitimate -inf into every row; flagged
+            # rows are sanitized to zeros so argmax/categorical stay
+            # well-defined (their pick is discarded by the host and
+            # forced to 0 anyway).
+            bad = active * (1.0 - jnp.all(
+                jnp.isfinite(logits), axis=-1).astype(jnp.float32))
+            logits = jnp.where(bad[:, None] > 0,
+                               jnp.zeros_like(logits), logits)
+            logits = logits.at[:, PAD_ID].set(-jnp.inf)  # never emit PAD
 
-        def pick_one(kd, lg, t):
-            greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-            safe_t = jnp.where(t > 0, t, 1.0)
-            sampled = jax.random.categorical(
-                jax.random.wrap_key_data(kd), lg / safe_t).astype(jnp.int32)
-            return jnp.where(t > 0, sampled, greedy)
+            def pick_one(kd, lg, t):
+                greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+                safe_t = jnp.where(t > 0, t, 1.0)
+                sampled = jax.random.categorical(
+                    jax.random.wrap_key_data(kd),
+                    lg / safe_t).astype(jnp.int32)
+                return jnp.where(t > 0, sampled, greedy)
 
-        nxt = jax.vmap(pick_one)(key_data, logits, temps)
-        nxt = jnp.where(bad > 0, 0, nxt)
+            nxt = jax.vmap(pick_one)(key_data, logits, temps)
+            nxt = jnp.where(bad > 0, 0, nxt)
         return nxt, bad, k_pages, v_pages, k_scales, v_scales, valid_pages
 
     return step
@@ -746,47 +773,57 @@ def build_paged_prefill_step(module: GPTModule, chunk: int,
                 write_offs, in_chunk):
         G = valid_pages.shape[1]
         C = page_table.shape[0] * G
-        h = tok_embed.apply({"params": params["tok_embed"]}, tokens[None, :])
-        h = h + pos_embed.apply({"params": params["pos_embed"]},
-                                pos[None, :])
+        with jax.named_scope("embed"):
+            h = tok_embed.apply({"params": params["tok_embed"]},
+                                tokens[None, :])
+            h = h + pos_embed.apply({"params": params["pos_embed"]},
+                                    pos[None, :])
         # chunk validity lands before the gather (write-then-attend,
         # like the decode step); pad-tail rows write 0 to the null page
-        tok_valid = in_chunk * (tokens != PAD_ID).astype(jnp.float32)
-        valid_pages = valid_pages.at[write_pages, write_offs].set(tok_valid)
-        ctx_valid = valid_pages[page_table].reshape(C)
-        causal = (jnp.arange(C)[None, :] <= pos[:, None]) \
-            .astype(jnp.float32)                      # [chunk, C]
-        bias = (1.0 - ctx_valid[None, :] * causal)[None, None] * NEG_INF
+        with jax.named_scope("mask"):
+            tok_valid = in_chunk * (tokens != PAD_ID).astype(jnp.float32)
+            valid_pages = valid_pages.at[write_pages, write_offs].set(
+                tok_valid)
+            ctx_valid = valid_pages[page_table].reshape(C)
+            causal = (jnp.arange(C)[None, :] <= pos[:, None]) \
+                .astype(jnp.float32)                      # [chunk, C]
+            bias = (1.0 - ctx_valid[None, :] * causal)[None, None] \
+                * NEG_INF
         for i in range(module.layers):
             p = params[f"layer_{i}"]
-            x = ln.apply({"params": p["LayerNorm_0"]}, h)
-            q = qkv.apply({"params": p["q"]}, x)
-            k = qkv.apply({"params": p["k"]}, x)
-            v = qkv.apply({"params": p["v"]}, x)
-            if quantized:
-                k_pages, k_scales = _int8_write_prefill(
-                    k_pages, k_scales, i, k[0].astype(jnp.float32),
-                    write_pages, write_offs, in_chunk)
-                v_pages, v_scales = _int8_write_prefill(
-                    v_pages, v_scales, i, v[0].astype(jnp.float32),
-                    write_pages, write_offs, in_chunk)
-            else:
-                k_pages = k_pages.at[i, write_pages, write_offs].set(
-                    k[0].astype(dtype))
-                v_pages = v_pages.at[i, write_pages, write_offs].set(
-                    v[0].astype(dtype))
-            attn = paged_attention(
-                q, k_pages[i], v_pages[i], k_scales[i], v_scales[i],
-                page_table[None], bias, quantized=quantized,
-                compute_dtype=dtype, impl=attn_impl,
-                interpret=attn_interpret)
-            attn = out_proj.apply({"params": p["out"]}, attn)
-            h = h + attn
-            x = ln.apply({"params": p["LayerNorm_1"]}, h)
-            x = ffn_in.apply({"params": p["Dense_0"]}, x)
-            x = nn.gelu(x)
-            x = ffn_out.apply({"params": p["Dense_1"]}, x)
-            h = h + x
+            with jax.named_scope(f"layer_{i}/qkv"):
+                x = ln.apply({"params": p["LayerNorm_0"]}, h)
+                q = qkv.apply({"params": p["q"]}, x)
+                k = qkv.apply({"params": p["k"]}, x)
+                v = qkv.apply({"params": p["v"]}, x)
+            with jax.named_scope(f"layer_{i}/kv_write"):
+                if quantized:
+                    k_pages, k_scales = _int8_write_prefill(
+                        k_pages, k_scales, i, k[0].astype(jnp.float32),
+                        write_pages, write_offs, in_chunk)
+                    v_pages, v_scales = _int8_write_prefill(
+                        v_pages, v_scales, i, v[0].astype(jnp.float32),
+                        write_pages, write_offs, in_chunk)
+                else:
+                    k_pages = k_pages.at[i, write_pages, write_offs].set(
+                        k[0].astype(dtype))
+                    v_pages = v_pages.at[i, write_pages, write_offs].set(
+                        v[0].astype(dtype))
+            with jax.named_scope(f"layer_{i}/attn"):
+                attn = paged_attention(
+                    q, k_pages[i], v_pages[i], k_scales[i], v_scales[i],
+                    page_table[None], bias, quantized=quantized,
+                    compute_dtype=dtype, impl=attn_impl,
+                    interpret=attn_interpret)
+            with jax.named_scope(f"layer_{i}/proj"):
+                attn = out_proj.apply({"params": p["out"]}, attn)
+                h = h + attn
+            with jax.named_scope(f"layer_{i}/mlp"):
+                x = ln.apply({"params": p["LayerNorm_1"]}, h)
+                x = ffn_in.apply({"params": p["Dense_0"]}, x)
+                x = nn.gelu(x)
+                x = ffn_out.apply({"params": p["Dense_1"]}, x)
+                h = h + x
         return k_pages, v_pages, k_scales, v_scales, valid_pages
 
     return prefill

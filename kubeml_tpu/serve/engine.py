@@ -78,6 +78,7 @@ from kubeml_tpu.serve.flight import FlightRecorder
 from kubeml_tpu.serve.pager import (KVPageSlab, PageAllocator, PageGeometry,
                                     chain_hash)
 from kubeml_tpu.serve.slots import GenerateRequest
+from kubeml_tpu.utils.trace import phase
 
 logger = logging.getLogger("kubeml_tpu.serve.engine")
 
@@ -140,6 +141,37 @@ SERVE_SPAN_KINDS = (
     "drain",           # instant: graceful-drain onset (admission -> 503)
 )
 
+# Loop-phase names (utils/trace.py `phase`): spans of what the serving
+# LOOP THREAD is doing, in the process-wide phase ring and, whenever a
+# profiler session is on, in its .xplane.pb. serve.loop.* tile one
+# iteration of ServeService._loop, serve.step.* tile serve.loop.step
+# (one DecodeEngine.step), serve.trace.flush is a child of
+# serve.loop.publish. Every record's args hold the engine `step`, which
+# joins an iteration's phases to each other and to the flight record of
+# that step. The benchmark's readers and dashboards key on the literal
+# names, so tools/check_serve_spans.py holds each one to a quoted
+# assertion in tests/, like the kinds above.
+SERVE_PHASE_KINDS = (
+    "serve.loop.wait",      # parked on the condition, nothing to do
+    "serve.loop.admit",     # lock, weight swap, deadline sweep, attach
+    "serve.loop.step",      # engine.step, bisection retries included;
+                            # args: active_slots, tokens
+    "serve.loop.terminal",  # lock, account the finished requests
+    "serve.loop.publish",   # health snapshot, Prometheus, trace flush
+    "serve.trace.flush",    # the sink rewrite; args: events, bytes
+    "serve.step.reap",      # cancellations, deadlines, fault hooks
+    "serve.step.prefill",   # one chunk dispatch: page grant, pack,
+                            # enqueue; args: tokens
+    "serve.step.pages",     # decode-lane page grants, copy-on-write
+    "serve.step.pack",      # numpy arrays and jnp.asarray transfers
+    "serve.step.enqueue",   # the jitted call until it returns; args:
+                            # compiled
+    "serve.step.readback",  # np.asarray of the picks: the host blocked
+                            # on the device
+    "serve.step.emit",      # per-slot advance, prefix registration,
+                            # emit_token, release
+)
+
 
 class _Slot:
     """Host-side state of one occupied decode slot."""
@@ -182,7 +214,7 @@ class DecodeEngine:
 
     def __init__(self, module, variables, geom: Optional[PageGeometry] = None,
                  slots: int = 8, page: int = 16,
-                 clock=time.perf_counter, prefill_chunk: int = 16,
+                 clock=time.monotonic, prefill_chunk: int = 16,
                  prefix_cache: bool = True,
                  prefill_budget: Optional[int] = None,
                  tracer=None, flight_steps: int = 256,
@@ -724,6 +756,11 @@ class DecodeEngine:
         prompt tokens of KV, advance the cursor. Returns the number of
         prompt tokens processed; 0 means the slot STALLED on page
         exhaustion before making any progress."""
+        with phase("serve.step.prefill", step=self._step_count) as args:
+            n = args["tokens"] = self._prefill_chunk(s, slot)
+            return n
+
+    def _prefill_chunk(self, s: int, slot: _Slot) -> int:
         G = self.geom.page
         C = self.prefill_chunk
         start = slot.pos
@@ -1002,65 +1039,73 @@ class DecodeEngine:
         caller falls through to the single-step path for this round."""
         K = self.decode_steps
         S = self.geom.slots
-        grants: Dict[int, List[int]] = {}
-        for s in members:
-            slot = self._slots[s]
-            budget = slot.req.max_new_tokens - len(slot.req.tokens)
-            g = self._grant_range(s, slot.pos, min(K, max(budget, 1)))
-            if g is None:
-                for gs, gl in grants.items():
-                    self._ungrant(gs, gl)
-                return False
-            grants[s] = g
-        tokens = np.zeros(S, np.int32)
-        pos = np.zeros(S, np.int32)
-        live = np.zeros(S, np.int32)
-        temps = np.zeros(S, np.float32)
-        seeds = np.zeros(S, np.uint32)
-        eos_ids = np.full(S, -1, np.int32)
-        budgets = np.zeros(S, np.int32)
-        for s in members:
-            slot = self._slots[s]
-            live[s] = 1
-            tokens[s] = slot.prompt[slot.pos] \
-                if slot.pos < slot.n_prompt else slot.req.tokens[-1]
-            pos[s] = slot.pos
-            temps[s] = slot.req.temperature
-            seeds[s] = np.uint32(slot.req.seed & 0xFFFFFFFF)
-            if slot.req.eos_id is not None:
-                eos_ids[s] = slot.req.eos_id
-            budgets[s] = slot.req.max_new_tokens - len(slot.req.tokens)
-        args = (self._params_by_gen[self.weight_generation],
-                self.slab.k, self.slab.v, self.slab.k_scale,
-                self.slab.v_scale, self.slab.valid,
-                jnp.asarray(tokens), jnp.asarray(pos),
-                jnp.asarray(self._tables), jnp.asarray(live),
-                jnp.asarray(temps), jnp.asarray(seeds),
-                jnp.asarray(eos_ids), jnp.asarray(budgets))
-        self._ledger_capture("serve.multi_step", self._multi, args,
-                             steps=K)
-        before = self._multi._cache_size()
-        t0 = self.clock()
-        (toks, bads, self.slab.k, self.slab.v, self.slab.k_scale,
-         self.slab.v_scale, self.slab.valid) = self._multi(*args)
-        compiled = self._multi._cache_size() > before
-        t1 = self.clock()
-        self.compile_tracker.note(compiled, t1 - t0,
-                                  program="serve.multi_step")
-        self._dispatch_wall_s += t1 - t0
-        self.stats["dispatches"] += 1
-        self.stats["multi_step_dispatches"] += 1
-        self.stats["multi_step_compiles"] += int(compiled)
-        self.stats["occupancy_sum"] += len(members)
-        toks_host = np.asarray(toks)
-        bads_host = np.asarray(bads)
-        g0 = self.stats["generated_tokens"]
-        for s in members:
-            self._walk_emitted(s, toks_host[:, s], bads_host[:, s], K,
-                               t0, t1, finished)
-        self.ledger.note_dispatch(
-            "serve.multi_step",
-            tokens=self.stats["generated_tokens"] - g0)
+        step = self._step_count
+        with phase("serve.step.pages", step=step):
+            grants: Dict[int, List[int]] = {}
+            for s in members:
+                slot = self._slots[s]
+                budget = slot.req.max_new_tokens - len(slot.req.tokens)
+                g = self._grant_range(s, slot.pos, min(K, max(budget, 1)))
+                if g is None:
+                    for gs, gl in grants.items():
+                        self._ungrant(gs, gl)
+                    return False
+                grants[s] = g
+        with phase("serve.step.pack", step=step):
+            tokens = np.zeros(S, np.int32)
+            pos = np.zeros(S, np.int32)
+            live = np.zeros(S, np.int32)
+            temps = np.zeros(S, np.float32)
+            seeds = np.zeros(S, np.uint32)
+            eos_ids = np.full(S, -1, np.int32)
+            budgets = np.zeros(S, np.int32)
+            for s in members:
+                slot = self._slots[s]
+                live[s] = 1
+                tokens[s] = slot.prompt[slot.pos] \
+                    if slot.pos < slot.n_prompt else slot.req.tokens[-1]
+                pos[s] = slot.pos
+                temps[s] = slot.req.temperature
+                seeds[s] = np.uint32(slot.req.seed & 0xFFFFFFFF)
+                if slot.req.eos_id is not None:
+                    eos_ids[s] = slot.req.eos_id
+                budgets[s] = slot.req.max_new_tokens - len(slot.req.tokens)
+            args = (self._params_by_gen[self.weight_generation],
+                    self.slab.k, self.slab.v, self.slab.k_scale,
+                    self.slab.v_scale, self.slab.valid,
+                    jnp.asarray(tokens), jnp.asarray(pos),
+                    jnp.asarray(self._tables), jnp.asarray(live),
+                    jnp.asarray(temps), jnp.asarray(seeds),
+                    jnp.asarray(eos_ids), jnp.asarray(budgets))
+            self._ledger_capture("serve.multi_step", self._multi, args,
+                                 steps=K)
+        with phase("serve.step.enqueue", step=step) as span:
+            before = self._multi._cache_size()
+            t0 = self.clock()
+            (toks, bads, self.slab.k, self.slab.v, self.slab.k_scale,
+             self.slab.v_scale, self.slab.valid) = self._multi(*args)
+            compiled = self._multi._cache_size() > before
+            t1 = self.clock()
+            span["compiled"] = int(compiled)
+            self.compile_tracker.note(compiled, t1 - t0,
+                                      program="serve.multi_step")
+            self._dispatch_wall_s += t1 - t0
+            self.stats["dispatches"] += 1
+            self.stats["multi_step_dispatches"] += 1
+            self.stats["multi_step_compiles"] += int(compiled)
+            self.stats["occupancy_sum"] += len(members)
+        with phase("serve.step.readback", step=step):
+            toks_host = np.asarray(toks)
+            bads_host = np.asarray(bads)
+        with phase("serve.step.emit", step=step):
+            g0 = self.stats["generated_tokens"]
+            for s in members:
+                self._walk_emitted(s, toks_host[:, s], bads_host[:, s], K,
+                                   t0, t1, finished)
+            self.ledger.note_dispatch(
+                "serve.multi_step",
+                tokens=self.stats["generated_tokens"] - g0)
+            del args, toks, bads     # released inside a phase
         return True
 
     def _dispatch_spec(self, members: List[int], finished) -> bool:
@@ -1079,91 +1124,99 @@ class DecodeEngine:
         W = self.spec_window
         G = self.geom.page
         S = self.geom.slots
-        wlens: Dict[int, int] = {}
-        for s in members:
-            slot = self._slots[s]
-            # the draft scatters proposals into window rows pos+1 ..
-            # pos+K; a lane whose cursor outruns the window falls back
-            if slot.pos + K + 1 > W:
-                return False
-            budget = slot.req.max_new_tokens - len(slot.req.tokens)
-            wlens[s] = min(K + 1, max(budget, 1))
-        grants: Dict[int, List[int]] = {}
-        for s in members:
-            g = self._grant_range(s, self._slots[s].pos, wlens[s])
-            if g is None:
-                for gs, gl in grants.items():
-                    self._ungrant(gs, gl)
-                return False
-            grants[s] = g
-        window = np.zeros((S, W), np.int32)
-        pos = np.zeros(S, np.int32)
-        live = np.zeros(S, np.int32)
-        temps = np.zeros(S, np.float32)
-        seeds = np.zeros(S, np.uint32)
-        wlen_arr = np.zeros(S, np.int32)
-        for s in members:
-            slot = self._slots[s]
-            # full context = prompt + emitted tokens; in the steady
-            # state its length is exactly pos+1
-            ctx = slot.prompt + [int(t) for t in slot.req.tokens]
-            live[s] = 1
-            pos[s] = slot.pos
-            window[s, :slot.pos + 1] = ctx[:slot.pos + 1]
-            temps[s] = slot.req.temperature
-            seeds[s] = np.uint32(slot.req.seed & 0xFFFFFFFF)
-            wlen_arr[s] = wlens[s]
-        args = (self._params_by_gen[self.weight_generation],
-                self._draft_params,
-                self.slab.k, self.slab.v, self.slab.k_scale,
-                self.slab.v_scale, self.slab.valid,
-                jnp.asarray(window), jnp.asarray(pos),
-                jnp.asarray(self._tables), jnp.asarray(live),
-                jnp.asarray(temps), jnp.asarray(seeds),
-                jnp.asarray(wlen_arr))
-        self._ledger_capture("serve.spec_verify", self._verify, args,
-                             steps=K + 1)
-        before = self._verify._cache_size()
-        t0 = self.clock()
-        (picks, bads, acc, self.slab.k, self.slab.v, self.slab.k_scale,
-         self.slab.v_scale, self.slab.valid) = self._verify(*args)
-        compiled = self._verify._cache_size() > before
-        t1 = self.clock()
-        self.compile_tracker.note(compiled, t1 - t0,
-                                  program="serve.spec_verify")
-        self._dispatch_wall_s += t1 - t0
-        self.stats["dispatches"] += 1
-        self.stats["verify_dispatches"] += 1
-        self.stats["verify_compiles"] += int(compiled)
-        self.stats["occupancy_sum"] += len(members)
-        picks_host = np.asarray(picks)
-        bads_host = np.asarray(bads)
-        acc_host = np.asarray(acc)
-        gen_before_walk = self.stats["generated_tokens"]
-        for s in members:
-            slot = self._slots[s]
-            a = int(acc_host[s])
-            p_start = slot.pos
-            self.stats["draft_tokens"] += K
-            # accepted prefix + the bonus pick (what the verifier kept;
-            # emission may still stop earlier at EOS)
-            self.stats["accepted_tokens"] += a + 1
-            self.stats["rejected_tokens"] += K - a
-            self._walk_emitted(s, picks_host[:a + 1, s],
-                               bads_host[:a + 1, s], a + 1, t0, t1,
-                               finished)
-            if self._slots[s] is None:
-                continue   # released: its pages were freed wholesale
-            keep_pi = (slot.pos - 1) // G
-            for pi in range(keep_pi + 1,
-                            (p_start + wlens[s] - 1) // G + 1):
-                pid = int(self._tables[s, pi])
-                if pid:
-                    self.pager.free([pid])
-                    self._tables[s, pi] = 0
-        self.ledger.note_dispatch(
-            "serve.spec_verify",
-            tokens=self.stats["generated_tokens"] - gen_before_walk)
+        step = self._step_count
+        with phase("serve.step.pages", step=step):
+            wlens: Dict[int, int] = {}
+            for s in members:
+                slot = self._slots[s]
+                # the draft scatters proposals into window rows pos+1 ..
+                # pos+K; a lane whose cursor outruns the window falls back
+                if slot.pos + K + 1 > W:
+                    return False
+                budget = slot.req.max_new_tokens - len(slot.req.tokens)
+                wlens[s] = min(K + 1, max(budget, 1))
+            grants: Dict[int, List[int]] = {}
+            for s in members:
+                g = self._grant_range(s, self._slots[s].pos, wlens[s])
+                if g is None:
+                    for gs, gl in grants.items():
+                        self._ungrant(gs, gl)
+                    return False
+                grants[s] = g
+        with phase("serve.step.pack", step=step):
+            window = np.zeros((S, W), np.int32)
+            pos = np.zeros(S, np.int32)
+            live = np.zeros(S, np.int32)
+            temps = np.zeros(S, np.float32)
+            seeds = np.zeros(S, np.uint32)
+            wlen_arr = np.zeros(S, np.int32)
+            for s in members:
+                slot = self._slots[s]
+                # full context = prompt + emitted tokens; in the steady
+                # state its length is exactly pos+1
+                ctx = slot.prompt + [int(t) for t in slot.req.tokens]
+                live[s] = 1
+                pos[s] = slot.pos
+                window[s, :slot.pos + 1] = ctx[:slot.pos + 1]
+                temps[s] = slot.req.temperature
+                seeds[s] = np.uint32(slot.req.seed & 0xFFFFFFFF)
+                wlen_arr[s] = wlens[s]
+            args = (self._params_by_gen[self.weight_generation],
+                    self._draft_params,
+                    self.slab.k, self.slab.v, self.slab.k_scale,
+                    self.slab.v_scale, self.slab.valid,
+                    jnp.asarray(window), jnp.asarray(pos),
+                    jnp.asarray(self._tables), jnp.asarray(live),
+                    jnp.asarray(temps), jnp.asarray(seeds),
+                    jnp.asarray(wlen_arr))
+            self._ledger_capture("serve.spec_verify", self._verify, args,
+                                 steps=K + 1)
+        with phase("serve.step.enqueue", step=step) as span:
+            before = self._verify._cache_size()
+            t0 = self.clock()
+            (picks, bads, acc, self.slab.k, self.slab.v, self.slab.k_scale,
+             self.slab.v_scale, self.slab.valid) = self._verify(*args)
+            compiled = self._verify._cache_size() > before
+            t1 = self.clock()
+            span["compiled"] = int(compiled)
+            self.compile_tracker.note(compiled, t1 - t0,
+                                      program="serve.spec_verify")
+            self._dispatch_wall_s += t1 - t0
+            self.stats["dispatches"] += 1
+            self.stats["verify_dispatches"] += 1
+            self.stats["verify_compiles"] += int(compiled)
+            self.stats["occupancy_sum"] += len(members)
+        with phase("serve.step.readback", step=step):
+            picks_host = np.asarray(picks)
+            bads_host = np.asarray(bads)
+            acc_host = np.asarray(acc)
+        with phase("serve.step.emit", step=step):
+            gen_before_walk = self.stats["generated_tokens"]
+            for s in members:
+                slot = self._slots[s]
+                a = int(acc_host[s])
+                p_start = slot.pos
+                self.stats["draft_tokens"] += K
+                # accepted prefix + the bonus pick (what the verifier kept;
+                # emission may still stop earlier at EOS)
+                self.stats["accepted_tokens"] += a + 1
+                self.stats["rejected_tokens"] += K - a
+                self._walk_emitted(s, picks_host[:a + 1, s],
+                                   bads_host[:a + 1, s], a + 1, t0, t1,
+                                   finished)
+                if self._slots[s] is None:
+                    continue   # released: its pages were freed wholesale
+                keep_pi = (slot.pos - 1) // G
+                for pi in range(keep_pi + 1,
+                                (p_start + wlens[s] - 1) // G + 1):
+                    pid = int(self._tables[s, pi])
+                    if pid:
+                        self.pager.free([pid])
+                        self._tables[s, pi] = 0
+            self.ledger.note_dispatch(
+                "serve.spec_verify",
+                tokens=self.stats["generated_tokens"] - gen_before_walk)
+            del args, picks, bads, acc   # released inside a phase
         return True
 
     def _step_inner(self, exclude: frozenset = frozenset()
@@ -1171,43 +1224,45 @@ class DecodeEngine:
         S = self.geom.slots
         G = self.geom.page
         stalled: List[int] = []
+        step = self._step_count
 
-        # reap cancellations FIRST: a cancelled slot's pages go back to
-        # the pool before this round's tables are snapshotted, so the
-        # device never writes through a freed page
-        finished: List[GenerateRequest] = []
-        for s, slot in enumerate(self._slots):
-            if slot is not None and slot.req.cancelled:
+        with phase("serve.step.reap", step=step):
+            # reap cancellations FIRST: a cancelled slot's pages go back to
+            # the pool before this round's tables are snapshotted, so the
+            # device never writes through a freed page
+            finished: List[GenerateRequest] = []
+            for s, slot in enumerate(self._slots):
+                if slot is not None and slot.req.cancelled:
+                    req = slot.req
+                    self.release(s, "cancelled")
+                    finished.append(req)
+
+            # deadline reaper: expired streams release with the terminal
+            # `deadline` outcome — slot, pages, and prefix refs restore
+            # exactly like any other release, whatever phase the stream was
+            # in (queued requests are swept by the service before attach)
+            now = self.clock()
+            for s, slot in enumerate(self._slots):
+                if slot is None or slot.req.deadline_at is None \
+                        or now < slot.req.deadline_at:
+                    continue
                 req = slot.req
-                self.release(s, "cancelled")
+                self.stats["deadline_expired"] += 1
+                self.release(s, "deadline",
+                             f"deadline of {req.deadline_ms:g}ms exceeded "
+                             f"after {len(req.tokens)} token(s)")
                 finished.append(req)
 
-        # deadline reaper: expired streams release with the terminal
-        # `deadline` outcome — slot, pages, and prefix refs restore
-        # exactly like any other release, whatever phase the stream was
-        # in (queued requests are swept by the service before attach)
-        now = self.clock()
-        for s, slot in enumerate(self._slots):
-            if slot is None or slot.req.deadline_at is None \
-                    or now < slot.req.deadline_at:
-                continue
-            req = slot.req
-            self.stats["deadline_expired"] += 1
-            self.release(s, "deadline",
-                         f"deadline of {req.deadline_ms:g}ms exceeded "
-                         f"after {len(req.tokens)} token(s)")
-            finished.append(req)
-
-        # deterministic fault hooks, BEFORE any page maintenance: an
-        # injected crash leaves this step free of side effects, so the
-        # service's bisection can retry it with lanes masked and every
-        # successful retry starts from untouched tables
-        if self.fault_plan is not None:
-            occupants = [(s, sl.req.rid)
-                         for s, sl in enumerate(self._slots)
-                         if sl is not None and sl.req.rid not in exclude]
-            self.fault_plan.check_crash(self._step_count, occupants)
-            self.fault_plan.sleep(self._step_count)
+            # deterministic fault hooks, BEFORE any page maintenance: an
+            # injected crash leaves this step free of side effects, so the
+            # service's bisection can retry it with lanes masked and every
+            # successful retry starts from untouched tables
+            if self.fault_plan is not None:
+                occupants = [(s, sl.req.rid)
+                             for s, sl in enumerate(self._slots)
+                             if sl is not None and sl.req.rid not in exclude]
+                self.fault_plan.check_crash(self._step_count, occupants)
+                self.fault_plan.sleep(self._step_count)
 
         # ------------------------------------------------- prefill lane
         progressed = False
@@ -1239,56 +1294,57 @@ class DecodeEngine:
         # and copy pair appear only in its own generation's dispatch
         # (other dispatches see 0 there, landing writes in the null
         # page), so generations never clobber each other's KV.
-        ready: List[int] = []
-        cow: Dict[int, tuple] = {}
-        for s, slot in enumerate(self._slots):
-            if slot is None or self._in_prefill(slot) \
-                    or slot.req.rid in exclude:
-                continue
-            pi = slot.pos // G
-            pid = int(self._tables[s, pi])
-            if pid == 0:
-                pid = self.pager.alloc()
-                if pid is None:
-                    stalled.append(s)   # no page: sit this round out
+        with phase("serve.step.pages", step=step):
+            ready: List[int] = []
+            cow: Dict[int, tuple] = {}
+            for s, slot in enumerate(self._slots):
+                if slot is None or self._in_prefill(slot) \
+                        or slot.req.rid in exclude:
                     continue
-                self._tables[s, pi] = pid
-            elif not self.pager.writable(pid):
-                # shared or cache-registered page: copy-on-write split
-                # inside this dispatch (copies run before any write)
-                dst = self.pager.alloc()
-                if dst is None:
-                    stalled.append(s)
-                    continue
-                cow[s] = (pid, dst)
-                self._tables[s, pi] = dst
-                self.pager.free([pid])  # drop this slot's share
-                self.stats["cow_splits"] += 1
-            ready.append(s)
+                pi = slot.pos // G
+                pid = int(self._tables[s, pi])
+                if pid == 0:
+                    pid = self.pager.alloc()
+                    if pid is None:
+                        stalled.append(s)   # no page: sit this round out
+                        continue
+                    self._tables[s, pi] = pid
+                elif not self.pager.writable(pid):
+                    # shared or cache-registered page: copy-on-write split
+                    # inside this dispatch (copies run before any write)
+                    dst = self.pager.alloc()
+                    if dst is None:
+                        stalled.append(s)
+                        continue
+                    cow[s] = (pid, dst)
+                    self._tables[s, pi] = dst
+                    self.pager.free([pid])  # drop this slot's share
+                    self.stats["cow_splits"] += 1
+                ready.append(s)
 
-        if not ready:
+            if not ready:
+                if stalled:
+                    self.stats["stalls"] += len(stalled)
+                    if not progressed:
+                        # every runnable slot is out of pages and nothing
+                        # moved this round: shed the NEWEST stream (oldest
+                        # is closest to finishing and freeing)
+                        victim = max(stalled, key=lambda s: self._slots[s].seq)
+                        req = self._slots[victim].req
+                        logger.warning("KV slab exhausted with all slots "
+                                       "stalled; shedding newest stream")
+                        self._shed_count += 1
+                        self.release(victim, "error",
+                                     "KV cache pages exhausted; request shed")
+                        finished.append(req)
+                return finished
             if stalled:
                 self.stats["stalls"] += len(stalled)
-                if not progressed:
-                    # every runnable slot is out of pages and nothing
-                    # moved this round: shed the NEWEST stream (oldest
-                    # is closest to finishing and freeing)
-                    victim = max(stalled, key=lambda s: self._slots[s].seq)
-                    req = self._slots[victim].req
-                    logger.warning("KV slab exhausted with all slots "
-                                   "stalled; shedding newest stream")
-                    self._shed_count += 1
-                    self.release(victim, "error",
-                                 "KV cache pages exhausted; request shed")
-                    finished.append(req)
-            return finished
-        if stalled:
-            self.stats["stalls"] += len(stalled)
 
-        # snapshot each ready slot's generation up front: an earlier
-        # generation's dispatch may finish-and-release its members, and
-        # re-reading self._slots for the next generation would hit None
-        gen_of = {s: self._slots[s].gen for s in ready}
+            # snapshot each ready slot's generation up front: an earlier
+            # generation's dispatch may finish-and-release its members, and
+            # re-reading self._slots for the next generation would hit None
+            gen_of = {s: self._slots[s].gen for s in ready}
 
         # all-decode steady state: every ready slot is past its prompt,
         # nothing prefilled/stalled/CoW-split this round, no fault
@@ -1315,117 +1371,128 @@ class DecodeEngine:
 
         for gen in sorted(set(gen_of.values())):
             members = [s for s in ready if gen_of[s] == gen]
-            tokens = np.zeros(S, np.int32)
-            pos = np.zeros(S, np.int32)
-            write_page = np.zeros(S, np.int32)
-            write_off = np.zeros(S, np.int32)
-            active = np.zeros(S, np.float32)
-            temps = np.zeros(S, np.float32)
-            key_data = np.zeros((S, 2), np.uint32)
-            copy_src = np.zeros(S, np.int32)
-            copy_dst = np.zeros(S, np.int32)
-            poison = np.zeros(S, np.float32)
-            if self.fault_plan is not None:
-                for s in self.fault_plan.nan_hits(self._step_count,
-                                                  members):
-                    poison[s] = 1.0
-            for s in members:
-                slot = self._slots[s]
-                active[s] = 1.0
-                tokens[s] = slot.prompt[slot.pos] \
-                    if slot.pos < slot.n_prompt else slot.req.tokens[-1]
-                pos[s] = slot.pos
-                write_page[s] = int(self._tables[s, slot.pos // G])
-                write_off[s] = slot.pos % G
-                temps[s] = slot.req.temperature
-                # per-(request, position) key: sampling is independent of
-                # co-resident streams — the sampled-path bit-identity hinge
-                key_data[s] = (np.uint32(slot.req.seed & 0xFFFFFFFF),
-                               np.uint32(slot.pos))
-                if s in cow:
-                    copy_src[s], copy_dst[s] = cow[s]
+            with phase("serve.step.pack", step=step):
+                tokens = np.zeros(S, np.int32)
+                pos = np.zeros(S, np.int32)
+                write_page = np.zeros(S, np.int32)
+                write_off = np.zeros(S, np.int32)
+                active = np.zeros(S, np.float32)
+                temps = np.zeros(S, np.float32)
+                key_data = np.zeros((S, 2), np.uint32)
+                copy_src = np.zeros(S, np.int32)
+                copy_dst = np.zeros(S, np.int32)
+                poison = np.zeros(S, np.float32)
+                if self.fault_plan is not None:
+                    for s in self.fault_plan.nan_hits(self._step_count,
+                                                      members):
+                        poison[s] = 1.0
+                for s in members:
+                    slot = self._slots[s]
+                    active[s] = 1.0
+                    tokens[s] = slot.prompt[slot.pos] \
+                        if slot.pos < slot.n_prompt else slot.req.tokens[-1]
+                    pos[s] = slot.pos
+                    write_page[s] = int(self._tables[s, slot.pos // G])
+                    write_off[s] = slot.pos % G
+                    temps[s] = slot.req.temperature
+                    # per-(request, position) key: sampling is independent
+                    # of co-resident streams — the sampled-path
+                    # bit-identity hinge
+                    key_data[s] = (np.uint32(slot.req.seed & 0xFFFFFFFF),
+                                   np.uint32(slot.pos))
+                    if s in cow:
+                        copy_src[s], copy_dst[s] = cow[s]
 
-            step_args = (
-                self._params_by_gen[gen],
-                self.slab.k, self.slab.v, self.slab.k_scale,
-                self.slab.v_scale, self.slab.valid,
-                jnp.asarray(tokens), jnp.asarray(pos),
-                jnp.asarray(self._tables), jnp.asarray(write_page),
-                jnp.asarray(write_off), jnp.asarray(active),
-                jnp.asarray(temps), jnp.asarray(key_data),
-                jnp.asarray(copy_src), jnp.asarray(copy_dst),
-                jnp.asarray(poison))
-            self._ledger_capture("serve.decode", self._step, step_args)
-            before = self._step._cache_size()
-            t0 = self.clock()
-            (nxt, bad, self.slab.k, self.slab.v, self.slab.k_scale,
-             self.slab.v_scale, self.slab.valid) = \
-                self._step(*step_args)
-            compiled = self._step._cache_size() > before
-            t1 = self.clock()
-            self.compile_tracker.note(compiled, t1 - t0,
-                                      program="serve.decode")
-            self._dispatch_wall_s += t1 - t0
-            self.stats["dispatches"] += 1
-            self.stats["compiles"] += int(compiled)
-            self.stats["occupancy_sum"] += len(members)
-            self.stats["decode_tokens"] += len(members)
-            # decode-bandwidth proxy: every decode-phase lane reads its
-            # whole paged context once per layer (geometry x dtype —
-            # deterministic, no timers)
-            self.stats["kv_bytes"] += \
-                len(members) * self.slab.decode_bytes_per_token
-            nxt_host = np.asarray(nxt)
-            bad_host = np.asarray(bad)
+                step_args = (
+                    self._params_by_gen[gen],
+                    self.slab.k, self.slab.v, self.slab.k_scale,
+                    self.slab.v_scale, self.slab.valid,
+                    jnp.asarray(tokens), jnp.asarray(pos),
+                    jnp.asarray(self._tables), jnp.asarray(write_page),
+                    jnp.asarray(write_off), jnp.asarray(active),
+                    jnp.asarray(temps), jnp.asarray(key_data),
+                    jnp.asarray(copy_src), jnp.asarray(copy_dst),
+                    jnp.asarray(poison))
+                self._ledger_capture("serve.decode", self._step, step_args)
+            with phase("serve.step.enqueue", step=step) as span:
+                before = self._step._cache_size()
+                t0 = self.clock()
+                (nxt, bad, self.slab.k, self.slab.v, self.slab.k_scale,
+                 self.slab.v_scale, self.slab.valid) = \
+                    self._step(*step_args)
+                compiled = self._step._cache_size() > before
+                t1 = self.clock()
+                span["compiled"] = int(compiled)
+                self.compile_tracker.note(compiled, t1 - t0,
+                                          program="serve.decode")
+                self._dispatch_wall_s += t1 - t0
+                self.stats["dispatches"] += 1
+                self.stats["compiles"] += int(compiled)
+                self.stats["occupancy_sum"] += len(members)
+                self.stats["decode_tokens"] += len(members)
+                # decode-bandwidth proxy: every decode-phase lane reads its
+                # whole paged context once per layer (geometry x dtype —
+                # deterministic, no timers)
+                self.stats["kv_bytes"] += \
+                    len(members) * self.slab.decode_bytes_per_token
+            with phase("serve.step.readback", step=step):
+                nxt_host = np.asarray(nxt)
+                bad_host = np.asarray(bad)
 
-            gen_before_emit = self.stats["generated_tokens"]
-            for s in members:
-                slot = self._slots[s]
-                p = slot.pos
-                slot.pos = p + 1
-                if bad_host[s] > 0:
-                    # on-device non-finite guard fired for this lane:
-                    # terminate ONLY this stream. Checked before the
-                    # prefix-cache registration below so a poisoned
-                    # stream never publishes its (suspect) KV pages.
-                    req = slot.req
-                    self.stats["poisoned"] += 1
-                    self.release(s, "error",
-                                 "non-finite logits at position "
-                                 f"{p}; request poisoned and isolated")
-                    finished.append(req)
-                    continue
-                if p <= slot.n_prompt - 1:
-                    # this dispatch computed prompt context for the slot
-                    # (token-by-token prefill, or the first-token step)
-                    # — it belongs to the TTFT prefill-compute term
-                    slot.prefill_s += t1 - t0
-                if self.prefix_cache:
-                    # a prompt whose length is a page multiple completes
-                    # its final page on this very advance — publish it
-                    self._register_full_pages(s, slot)
-                if p < slot.n_prompt - 1:
-                    continue  # token-by-token prefill: output discarded
-                tok = int(nxt_host[s])
-                if slot.req.first_token_at is None:
-                    slot.req.first_token_at = t1
-                    self._note_first_token(slot, t1)
-                slot.req.emit_token(tok)
-                self.stats["generated_tokens"] += 1
-                n_out = len(slot.req.tokens)
-                if self.tracer is not None and n_out > 1 \
-                        and n_out % self.decode_span_every == 0:
-                    # sampled: one decode span every Nth output token
-                    # (the first token has its own instant) — enough to
-                    # see cadence without drowning the timeline
-                    self._span("decode", t0, t1, slot.req, pos=p,
-                               token_index=n_out, cow=int(s in cow))
-                if (slot.req.eos_id is not None
-                        and tok == slot.req.eos_id) \
-                        or len(slot.req.tokens) >= slot.req.max_new_tokens:
-                    self.release(s, "ok")
-                    finished.append(slot.req)
-            self.ledger.note_dispatch(
-                "serve.decode",
-                tokens=self.stats["generated_tokens"] - gen_before_emit)
+            with phase("serve.step.emit", step=step):
+                gen_before_emit = self.stats["generated_tokens"]
+                for s in members:
+                    slot = self._slots[s]
+                    p = slot.pos
+                    slot.pos = p + 1
+                    if bad_host[s] > 0:
+                        # on-device non-finite guard fired for this lane:
+                        # terminate ONLY this stream. Checked before the
+                        # prefix-cache registration below so a poisoned
+                        # stream never publishes its (suspect) KV pages.
+                        req = slot.req
+                        self.stats["poisoned"] += 1
+                        self.release(s, "error",
+                                     "non-finite logits at position "
+                                     f"{p}; request poisoned and isolated")
+                        finished.append(req)
+                        continue
+                    if p <= slot.n_prompt - 1:
+                        # this dispatch computed prompt context for the slot
+                        # (token-by-token prefill, or the first-token step)
+                        # — it belongs to the TTFT prefill-compute term
+                        slot.prefill_s += t1 - t0
+                    if self.prefix_cache:
+                        # a prompt whose length is a page multiple completes
+                        # its final page on this very advance — publish it
+                        self._register_full_pages(s, slot)
+                    if p < slot.n_prompt - 1:
+                        continue  # token-by-token prefill: output discarded
+                    tok = int(nxt_host[s])
+                    if slot.req.first_token_at is None:
+                        slot.req.first_token_at = t1
+                        self._note_first_token(slot, t1)
+                    slot.req.emit_token(tok)
+                    self.stats["generated_tokens"] += 1
+                    n_out = len(slot.req.tokens)
+                    if self.tracer is not None and n_out > 1 \
+                            and n_out % self.decode_span_every == 0:
+                        # sampled: one decode span every Nth output token
+                        # (the first token has its own instant) — enough to
+                        # see cadence without drowning the timeline
+                        self._span("decode", t0, t1, slot.req, pos=p,
+                                   token_index=n_out, cow=int(s in cow))
+                    if (slot.req.eos_id is not None
+                            and tok == slot.req.eos_id) \
+                            or len(slot.req.tokens) >= slot.req.max_new_tokens:
+                        self.release(s, "ok")
+                        finished.append(slot.req)
+                self.ledger.note_dispatch(
+                    "serve.decode",
+                    tokens=self.stats["generated_tokens"] - gen_before_emit)
+                # drop the dispatch's argument and result buffers inside
+                # a phase: left to the function's return, their release
+                # (a millisecond on the CPU backend) is host time under
+                # no name
+                del step_args, nxt, bad
         return finished

@@ -82,6 +82,7 @@ from kubeml_tpu.serve.service import TRACE_FLUSH_EVERY, ServeService
 from kubeml_tpu.serve.slo import DEFAULT_SLO_TARGET, SLOEngine
 from kubeml_tpu.serve.slots import (GenerateRequest, ServeDraining,
                                     ServeSaturated)
+from kubeml_tpu.utils.trace import phases
 
 logger = logging.getLogger("kubeml_tpu.serve.fleet")
 
@@ -150,6 +151,26 @@ def _ring_point(idx: int, vnode: int) -> int:
     return int.from_bytes(h[:8], "big")
 
 
+def _model_phases(model_id: str) -> list:
+    """The process ring's loop-phase records of one model's replicas.
+    serve.loop.* and serve.trace.flush records name their model; the
+    engine's serve.step.* records do not (an engine knows no model),
+    but a loop thread appends them just before the serve.loop.step
+    that encloses them, so they go where that record goes."""
+    out: list = []
+    pending: Dict[int, list] = {}
+    for r in phases():
+        if "model" not in r.args:
+            pending.setdefault(r.tid, []).append(r)
+            continue
+        inner = pending.pop(r.tid, ()) \
+            if r.name == "serve.loop.step" else ()
+        if r.args["model"] == model_id:
+            out.extend(inner)
+            out.append(r)
+    return out
+
+
 class ServeFleet:
     """Router + lifecycle manager + autoscaler for one model's replicas.
 
@@ -179,7 +200,7 @@ class ServeFleet:
                  slo_ttft_s: float = 0.0,
                  slo_tpot_s: float = 0.0,
                  slo_target: float = DEFAULT_SLO_TARGET,
-                 clock=time.perf_counter):
+                 clock=time.monotonic):
         if routing not in ("affine", "random"):
             raise ValueError(f"routing must be 'affine' or 'random', "
                              f"got {routing!r}")
@@ -733,6 +754,12 @@ class ServeFleet:
         for svc in svcs:
             svc.flush_trace()
         self._flush_trace(force=True)
+        if self.trace_sink is not None:
+            try:
+                self.trace_sink.write_phases(_model_phases(self.model_id))
+            except OSError:
+                logger.exception("fleet phase flush failed for %s",
+                                 self.model_id)
 
     # ------------------------------------------------------ failure domains
     def _all_ejected_error(self) -> ServeDraining:

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import collections
 import logging
+import os
 import threading
 import time
 from typing import Callable, Deque, List, Optional
@@ -28,6 +29,7 @@ from kubeml_tpu.models.base import InferenceInputError
 from kubeml_tpu.serve.engine import DecodeEngine
 from kubeml_tpu.serve.slots import (GenerateRequest, ServeDraining,
                                     ServeSaturated)
+from kubeml_tpu.utils.trace import phase
 
 logger = logging.getLogger("kubeml_tpu.serve.service")
 
@@ -67,7 +69,7 @@ class ServeService:
     def __init__(self, model_id: str, engine: DecodeEngine,
                  max_queue: int = 16, metrics=None,
                  health_cb: Optional[Callable[[dict], None]] = None,
-                 clock=time.perf_counter,
+                 clock=time.monotonic,
                  tracer=None, trace_sink=None,
                  wedge_timeout_s: float = 30.0,
                  watchdog_interval_s: float = 0.25,
@@ -501,8 +503,14 @@ class ServeService:
         # if this (wedged-then-unstuck) thread ever resumes, it must
         # exit instead of double-driving abandoned slot state
         engine = self.engine
+        model = self.model_id
+        # every statement of an iteration runs inside exactly one
+        # serve.loop.* phase (utils/trace.py phase; SERVE_PHASE_KINDS
+        # in serve/engine.py), so the phases tile the thread's time;
+        # `step` is the engine step the iteration runs
         while True:
-            with self._cv:
+            with phase("serve.loop.admit", model=model,
+                       step=engine._step_count + 1), self._cv:
                 if self.engine is not engine:
                     self._cv.notify_all()
                     return
@@ -513,61 +521,34 @@ class ServeService:
                     self._cv.notify_all()
                     return
                 self._beat = self.clock()
-                while not self._stopped and not self._killed \
-                        and not self._pending \
-                        and self._pending_weights is None \
-                        and engine.active() == 0:
-                    self._publish()
-                    self._cv.wait()
-                    self._beat = self.clock()
-                    if self.engine is not engine or self._killed:
-                        self._cv.notify_all()
-                        return
-                if self._killed:
-                    self._cv.notify_all()
-                    return
+                idle = self._idle(engine)
                 if self._stopped:
                     break
-                if self._pending_weights is not None:
-                    # apply the hot-swap before this round's admissions:
-                    # queued requests attach to the NEW generation,
-                    # already-attached streams stay pinned to theirs
-                    variables, stamp = self._pending_weights
-                    self._pending_weights = None
-                    gen = engine.install_weights(variables)
-                    self.weight_stamp = stamp
-                    logger.info("model %s hot-swapped to weight "
-                                "generation %d", self.model_id, gen)
-                # queued requests can expire before a slot frees: reap
-                # them here so a deadline never waits on capacity
-                if any(r.deadline_at is not None for r in self._pending):
-                    now = self.clock()
-                    keep: Deque[GenerateRequest] = collections.deque()
-                    while self._pending:
-                        r = self._pending.popleft()
-                        if r.deadline_at is not None and now >= r.deadline_at:
-                            self._terminal(
-                                r, "deadline",
-                                f"deadline of {r.deadline_ms:g}ms exceeded "
-                                f"before a slot was free")
-                        else:
-                            keep.append(r)
-                    self._pending = keep
-                while self._pending and engine.free_slots() > 0:
-                    req = self._pending.popleft()
-                    if req.cancelled:
-                        self._terminal(req, "cancelled")
-                        continue
-                    try:
-                        engine.attach(req)
-                    except Exception as e:  # geometry raced a config change
-                        self._terminal(req, "error", str(e))
-                self._stepping = True
-            try:
-                finished = engine.step()
-            except Exception as e:
-                finished = self._bisect_step_failure(engine, e)
-            with self._cv:
+                if not idle:
+                    self._admit(engine)
+                    self._stepping = True
+            if idle:
+                self._publish()
+                with phase("serve.loop.wait", model=model,
+                           step=engine._step_count + 1), self._cv:
+                    # the lock was released for the publish: wait only
+                    # if nothing arrived meanwhile (a submit notifies
+                    # under the lock, so none is lost)
+                    if self._idle(engine):
+                        self._cv.wait()
+                continue
+            with phase("serve.loop.step", model=model) as args:
+                made = engine.stats["generated_tokens"]
+                try:
+                    finished = engine.step()
+                except Exception as e:
+                    finished = self._bisect_step_failure(engine, e)
+                args.update(
+                    step=engine._step_count,
+                    active_slots=engine.active(),
+                    tokens=int(engine.stats["generated_tokens"] - made))
+            with phase("serve.loop.terminal", model=model,
+                       step=engine._step_count), self._cv:
                 self._stepping = False
                 if self.engine is not engine:
                     # recovery swapped the engine mid-step: the finished
@@ -602,6 +583,53 @@ class ServeService:
                     engine.release(s, "error", msg)
                     self._terminal(req, None)
         self._publish()
+
+    def _idle(self, engine: DecodeEngine) -> bool:
+        """Nothing to admit, install or decode (cv held): the loop
+        parks on the condition until a submit, a weight install, a
+        stop or a kill notifies it."""
+        return not self._stopped and not self._killed \
+            and not self._pending and self._pending_weights is None \
+            and engine.active() == 0
+
+    def _admit(self, engine: DecodeEngine) -> None:
+        """This round's admissions (cv held): the queued weight
+        hot-swap, the deadline sweep of the queue, then attach while
+        slots are free."""
+        if self._pending_weights is not None:
+            # apply the hot-swap before this round's admissions:
+            # queued requests attach to the NEW generation,
+            # already-attached streams stay pinned to theirs
+            variables, stamp = self._pending_weights
+            self._pending_weights = None
+            gen = engine.install_weights(variables)
+            self.weight_stamp = stamp
+            logger.info("model %s hot-swapped to weight "
+                        "generation %d", self.model_id, gen)
+        # queued requests can expire before a slot frees: reap
+        # them here so a deadline never waits on capacity
+        if any(r.deadline_at is not None for r in self._pending):
+            now = self.clock()
+            keep: Deque[GenerateRequest] = collections.deque()
+            while self._pending:
+                r = self._pending.popleft()
+                if r.deadline_at is not None and now >= r.deadline_at:
+                    self._terminal(
+                        r, "deadline",
+                        f"deadline of {r.deadline_ms:g}ms exceeded "
+                        f"before a slot was free")
+                else:
+                    keep.append(r)
+            self._pending = keep
+        while self._pending and engine.free_slots() > 0:
+            req = self._pending.popleft()
+            if req.cancelled:
+                self._terminal(req, "cancelled")
+                continue
+            try:
+                engine.attach(req)
+            except Exception as e:  # geometry raced a config change
+                self._terminal(req, "error", str(e))
 
     def _bisect_step_failure(self, engine: DecodeEngine,
                              exc: Exception) -> List[GenerateRequest]:
@@ -820,12 +848,15 @@ class ServeService:
         n = self.tracer.event_count()
         if not force and n - self._events_flushed < TRACE_FLUSH_EVERY:
             return
-        try:
-            self.trace_sink.write(self.tracer)
-            self._events_flushed = n
-        except OSError:
-            logger.exception("serve trace flush failed for %s",
-                             self.model_id)
+        with phase("serve.trace.flush", model=self.model_id,
+                   step=self.engine._step_count, events=n) as args:
+            try:
+                args["bytes"] = os.path.getsize(
+                    self.trace_sink.write(self.tracer))
+                self._events_flushed = n
+            except OSError:
+                logger.exception("serve trace flush failed for %s",
+                                 self.model_id)
 
     # ------------------------------------------------------------ telemetry
     def _note_outcome(self, outcome: str) -> None:
@@ -959,6 +990,11 @@ class ServeService:
         }
 
     def _publish(self) -> None:
+        with phase("serve.loop.publish", model=self.model_id,
+                   step=self.engine._step_count):
+            self._publish_inner()
+
+    def _publish_inner(self) -> None:
         snap = self.snapshot()
         if self.metrics is not None:
             if self.publish_state_gauges:

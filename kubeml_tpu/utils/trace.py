@@ -1,4 +1,4 @@
-"""Cross-process span tracing + Chrome-trace export + XLA profiler capture.
+"""Cross-process span tracing, loop-phase spans, and Chrome-trace export.
 
 The reference has no tracing subsystem — only ad-hoc zap timings around
 the merge and epoch loops (ml/pkg/train/job.go:307,397,412) and an
@@ -20,16 +20,28 @@ Here tracing is structural, Dapper-style:
     merge/readback};
   - `TraceSink` writes each process's events to
     ``$KUBEML_HOME/traces/<job_id>/<process>-<pid>.trace.json`` and
-    `merge_job_trace` combines all of them — plus any `xla_profile`
-    capture dropped in the same directory — into one Perfetto-viewable
+    `merge_job_trace` combines all of them into one Perfetto-viewable
     file (served by the PS ``/trace?id=`` endpoint and
     ``kubeml trace --id``);
-  - `xla_profile(dir)` captures a real XLA profiler trace (viewable in
-    TensorBoard / Perfetto) around any block, for kernel-level work.
+  - `phase(name, **args)` is a span of what a LOOP THREAD is doing (the
+    serve loop's wait / admit / step / publish, the engine step's pack
+    / enqueue / readback / emit). It enters a
+    ``jax.profiler.TraceAnnotation``, so whenever a profiler session is
+    on (anybody's ``jax.profiler.start_trace``) the span is in the
+    ``.xplane.pb`` on the device trace's own clock, and it appends one
+    record to a bounded process-wide ring (`PHASES`) that `phases()`
+    reads back. There is no switch: with no session the annotation is a
+    flag test and the ring append is all that is left.
 
-All timing goes through an injectable ``clock`` (default
-``time.time``, so cross-process timestamps align) which tests replace
-with a fake to assert exact span trees deterministically.
+All `Tracer` timing goes through an injectable ``clock`` which tests
+replace with a fake to assert exact span trees deterministically. The
+default is ``time.time``: wall clocks agree across the processes of one
+job only as far as the hosts' clocks do, which is enough to order
+epochs and rounds. The serve plane passes ``time.monotonic``, the
+clock of the phase ring, so on one host request trees and loop phases
+stand on one timebase; the profiler's file has its own clock, and what
+carries the program's spans onto it is the annotation, not a
+conversion.
 
 Host-side spans are the right default on TPU: the device timeline
 belongs to XLA's profiler, while the host loop — input assembly, round
@@ -41,7 +53,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import gzip
 import json
 import os
 import threading
@@ -51,6 +62,11 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 TRACE_HEADER = "X-KubeML-Trace-Id"
 TRACE_ENV = "KUBEML_TRACE_ID"
+
+# durations a Tracer keeps per span name between two reset() calls: an
+# epoch's rounds fit many times over, and a tracer nobody resets (the
+# serve plane's) stays bounded
+DURATIONS_KEPT = 4096
 
 _context = threading.local()
 
@@ -98,7 +114,12 @@ class Tracer:
         self.max_events = max_events
         self.dropped_events = 0
         self._lock = threading.Lock()
-        self._spans: Dict[str, List[float]] = collections.defaultdict(list)
+        # per name: [count, total seconds, newest DURATIONS_KEPT
+        # durations]. The log summary reads the first two, which stay
+        # exact; the phase histograms read the third. Train jobs
+        # reset() every epoch; a serve tracer is never reset, so
+        # nothing here may grow with uptime.
+        self._spans: Dict[str, list] = {}
         self._events: List[dict] = []
         self._tls = threading.local()
 
@@ -112,7 +133,13 @@ class Tracer:
     def _record(self, name: str, t0: float, dur: float,
                 parent: Optional[str], args: dict) -> None:
         with self._lock:
-            self._spans[name].append(dur)
+            rec = self._spans.get(name)
+            if rec is None:
+                rec = self._spans[name] = [
+                    0, 0.0, collections.deque(maxlen=DURATIONS_KEPT)]
+            rec[0] += 1
+            rec[1] += dur
+            rec[2].append(dur)
             if len(self._events) >= self.max_events:
                 self.dropped_events += 1
                 return
@@ -200,17 +227,18 @@ class Tracer:
         with self._lock:
             return {
                 name: {
-                    "count": len(xs),
-                    "total_s": round(sum(xs), 4),
-                    "mean_s": round(sum(xs) / len(xs), 6),
+                    "count": n,
+                    "total_s": round(total, 4),
+                    "mean_s": round(total / n, 6),
                 }
-                for name, xs in self._spans.items()
+                for name, (n, total, _kept) in self._spans.items()
             }
 
     def durations(self) -> Dict[str, List[float]]:
-        """Raw per-span duration lists (feeds the PS phase histograms)."""
+        """Per-span durations since the last reset(), the newest
+        DURATIONS_KEPT of each name (feeds the PS phase histograms)."""
         with self._lock:
-            return {name: list(xs) for name, xs in self._spans.items()}
+            return {name: list(rec[2]) for name, rec in self._spans.items()}
 
     def format_summary(self) -> str:
         parts = []
@@ -230,6 +258,94 @@ class Tracer:
     def events(self) -> List[dict]:
         with self._lock:
             return list(self._events)
+
+
+# --------------------------------------------------------------- loop phases
+# records the process-wide phase ring keeps, newest kept: the busiest
+# serving loop measured writes about 20 iterations a second x 12 phases
+# = 240 records a second, so 131,072 hold nine minutes of it
+PHASE_RING_SIZE = 1 << 17
+
+Phase = collections.namedtuple("Phase", "name t0 t1 tid args")
+
+
+_annotation = None
+
+
+def _resolve_annotation():
+    """``jax.profiler.TraceAnnotation``, looked up once, at the first
+    phase and not at import (importing this module must not import
+    jax); a no-op where jax is not importable, since the controller
+    and the scheduler import this module too."""
+    global _annotation
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        TraceAnnotation = contextlib.nullcontext
+    _annotation = TraceAnnotation
+    return TraceAnnotation
+
+
+class _PhaseSpan:
+    __slots__ = ("_ring", "_name", "_args", "_ann", "_t0")
+
+    def __init__(self, ring: "PhaseRing", name: str, args: dict):
+        self._ring = ring
+        self._name = name
+        self._args = args
+
+    def __enter__(self) -> dict:
+        self._ann = (_annotation or _resolve_annotation())(self._name)
+        self._ann.__enter__()
+        self._t0 = self._ring._clock()
+        return self._args
+
+    def __exit__(self, *exc):
+        t1 = self._ring._clock()
+        self._ann.__exit__(*exc)
+        self._ring._records.append(
+            (self._name, self._t0, t1, threading.get_ident(), self._args))
+        return False
+
+
+class PhaseRing:
+    """Spans of what loop threads are doing, newest `maxlen` kept.
+
+    Apart from the request trees on purpose: phases arrive some two
+    hundred a second, and in a `Tracer`'s capped event list they would
+    evict or exhaust the per-request spans. The process has ONE ring,
+    `PHASES` (one process drives one chip); the constructor exists for
+    tests, which pass a fake clock and a small `maxlen`.
+    """
+
+    def __init__(self, maxlen: int = PHASE_RING_SIZE,
+                 clock: Callable[[], float] = time.monotonic):
+        self._clock = clock
+        # deque.append and deque.copy are one C call each, so loop
+        # threads append and any thread reads without a lock
+        self._records: collections.deque = collections.deque(maxlen=maxlen)
+
+    def phase(self, name: str, **args) -> _PhaseSpan:
+        """Context manager around one phase of a loop thread. Enter and
+        exit bracket a ``jax.profiler.TraceAnnotation(name)`` and
+        append ``(name, t0, t1, thread id, args)`` to the ring, nothing
+        else. Yields the args dict, which is read at exit, so the body
+        can attach what it only learns while running."""
+        return _PhaseSpan(self, name, args)
+
+    def phases(self, t0: Optional[float] = None,
+               t1: Optional[float] = None) -> List[Phase]:
+        """Copies of the records that overlap [t0, t1] (either end
+        open), oldest first. Reads the ring, not any service: it
+        answers after the deployment has stopped."""
+        return [Phase(name, a, b, tid, dict(args))
+                for name, a, b, tid, args in self._records.copy()
+                if (t1 is None or a <= t1) and (t0 is None or b >= t0)]
+
+
+PHASES = PhaseRing()
+phase = PHASES.phase
+phases = PHASES.phases
 
 
 def trace_dir(job_id: str, home: Optional[str] = None) -> str:
@@ -263,44 +379,53 @@ class TraceSink:
         self._write_lock = threading.Lock()
 
     def write(self, tracer: Tracer) -> str:
-        with self._write_lock:
-            return self._write_locked(tracer)
+        return self._write_doc(
+            self.path, tracer.events(),
+            {"trace_id": tracer.trace_id or "",
+             # events silently refused by the max_events cap —
+             # surfaced (not resurrected) so a merged timeline says it
+             # is PARTIAL instead of reading as a complete record
+             # (kubeml_trace_events_dropped_total carries the same
+             # count to Prometheus)
+             "dropped_events": tracer.dropped_events})
 
-    def _write_locked(self, tracer: Tracer) -> str:
+    def write_phases(self, records: List[Phase]) -> str:
+        """Loop-phase records into ``<process>-<pid>.phases.trace.json``
+        beside the request trees as Chrome ``X`` events: same pid, so
+        the merged document shows them in the same process, one track
+        per loop thread, on the ring's clock (the serve tracers' too).
+        Written on demand (``GET /trace``), never by a loop thread: the
+        ring can hold minutes of phases."""
         pid = os.getpid()
-        events = [{
-            "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
-            "args": {"name": f"{self.process}:{self.job_id}"},
-        }]
-        events.extend(tracer.events())
-        doc = {"traceEvents": events, "displayTimeUnit": "ms",
+        return self._write_doc(
+            f"{self.path[:-len('.trace.json')]}.phases.trace.json",
+            [{"name": r.name, "ph": "X", "ts": round(r.t0 * 1e6),
+              "dur": round((r.t1 - r.t0) * 1e6), "pid": pid,
+              "tid": r.tid % (1 << 31), "args": r.args}
+             for r in records], {})
+
+    def _write_doc(self, path: str, events: List[dict],
+                   metadata: dict) -> str:
+        pid = os.getpid()
+        head = {"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
+                "args": {"name": f"{self.process}:{self.job_id}"}}
+        doc = {"traceEvents": [head] + events, "displayTimeUnit": "ms",
                "metadata": {"process": self.process,
-                            "job_id": self.job_id,
-                            "trace_id": tracer.trace_id or "",
-                            # events silently refused by the max_events
-                            # cap — surfaced (not resurrected) so a
-                            # merged timeline says it is PARTIAL instead
-                            # of reading as a complete record
-                            # (kubeml_trace_events_dropped_total carries
-                            # the same count to Prometheus)
-                            "dropped_events": tracer.dropped_events}}
-        os.makedirs(self.dir, exist_ok=True)
-        tmp = f"{self.path}.tmp.{pid}"
-        with open(tmp, "w") as f:
-            json.dump(doc, f)
-        os.replace(tmp, self.path)
-        return self.path
+                            "job_id": self.job_id, **metadata}}
+        with self._write_lock:
+            os.makedirs(self.dir, exist_ok=True)
+            tmp = f"{path}.tmp.{pid}"
+            with open(tmp, "w") as f:
+                json.dump(doc, f)
+            os.replace(tmp, path)
+        return path
 
 
 def _load_trace_doc(path: str) -> Tuple[List[dict], int]:
     """(events, dropped_events) from one trace file; bare Chrome trace
     arrays (no metadata envelope) report 0 drops."""
-    if path.endswith(".gz"):
-        with gzip.open(path, "rt") as f:
-            doc = json.load(f)
-    else:
-        with open(path) as f:
-            doc = json.load(f)
+    with open(path) as f:
+        doc = json.load(f)
     if isinstance(doc, list):  # bare Chrome trace array form
         return doc, 0
     meta = doc.get("metadata") or {}
@@ -312,10 +437,8 @@ def _load_trace_doc(path: str) -> Tuple[List[dict], int]:
 
 
 def merge_job_trace(job_id: str, home: Optional[str] = None) -> dict:
-    """Merge every per-process trace file under traces/<job_id>/ — our
-    own `TraceSink` output plus any `xla_profile` capture (the XLA
-    profiler drops ``*.trace.json.gz`` under plugins/profile/) — into
-    one Chrome trace-event document, sorted by timestamp.
+    """Merge every per-process `TraceSink` file under traces/<job_id>/
+    into one Chrome trace-event document, sorted by timestamp.
 
     Raises FileNotFoundError when the job has no trace directory.
     """
@@ -326,8 +449,7 @@ def merge_job_trace(job_id: str, home: Optional[str] = None) -> dict:
     dropped_events = 0
     for dirpath, _dirs, files in os.walk(root):
         for name in sorted(files):
-            if not (name.endswith(".trace.json")
-                    or name.endswith(".trace.json.gz")):
+            if not name.endswith(".trace.json"):
                 continue
             path = os.path.join(dirpath, name)
             try:
@@ -348,31 +470,3 @@ def merge_job_trace(job_id: str, home: Optional[str] = None) -> dict:
                          # this many spans hit the writers' max_events
                          # caps and never made it to disk
                          "dropped_events": dropped_events}}
-
-
-@contextlib.contextmanager
-def xla_profile(log_dir: str):
-    """Capture an XLA profiler trace into log_dir (TensorBoard-viewable).
-
-    Degrades to a no-op (with a logged warning, never silently) when the
-    backend lacks profiler support or the trace cannot start."""
-    import logging
-
-    import jax
-
-    try:
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception as e:  # backend without profiler support / bad dir
-        logging.getLogger("kubeml_tpu.trace").warning(
-            "xla_profile: could not start trace in %s: %s", log_dir, e)
-        started = False
-    try:
-        yield
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:  # export failure must not kill the run
-                logging.getLogger("kubeml_tpu.trace").warning(
-                    "xla_profile: could not stop/export trace: %s", e)

@@ -17,8 +17,15 @@ The contracts pinned here:
     request through the merged GET /trace?id=serve:<model> document;
     serving-sink drops land in kubeml_trace_events_dropped_total under
     the serve pseudo-job id and in the merge metadata
-  * lint — tools/check_serve_spans.py holds every SERVE_SPAN_KINDS name
-    to a quoted assertion in tests/ (this file carries them)
+  * loop phases — utils/trace.py phase(): serve.loop.* tile an iteration
+    of the serving loop, serve.step.* tile its engine step, every record
+    of an iteration carries that step's number, the ring answers after
+    stop(), a profiler session finds the same names in its file, GET
+    /trace shows them beside the request trees, and none of it changes
+    a decoded token
+  * lint — tools/check_serve_spans.py holds every SERVE_SPAN_KINDS and
+    SERVE_PHASE_KINDS name to a quoted assertion in tests/ (this file
+    carries them)
 """
 
 import itertools
@@ -492,11 +499,303 @@ def test_top_renders_ttft_breakdown_line():
     assert "ttft breakdown" not in out
 
 
+# ----------------------------------------------------------- loop phases
+
+def _phase_service(model_id, tmp_home=None):
+    """A started ServeService on gpt-nano (chunked prefill on, so a
+    long prompt costs prefill dispatches) and what it served: two
+    requests, awaited. Returns (service, requests, t_before)."""
+    from kubeml_tpu.serve.engine import DecodeEngine
+    from kubeml_tpu.serve.service import ServeService
+    from kubeml_tpu.utils.trace import TraceSink, Tracer
+
+    _model, module, variables = _nano()
+    engine = DecodeEngine(module, variables, slots=2, page=8,
+                          prefill_chunk=16)
+    sink = TraceSink(f"serve:{model_id}", "serve") if tmp_home else None
+    t_before = time.monotonic()
+    svc = ServeService(model_id, engine, max_queue=4,
+                       tracer=Tracer(clock=time.monotonic),
+                       trace_sink=sink).start()
+    reqs = [svc.submit(list(range(2, 42)), max_new_tokens=6),
+            svc.submit([5, 6, 7], max_new_tokens=9, temperature=0.7,
+                       seed=3)]
+    for r in reqs:
+        assert r.wait(120) and r.outcome == "ok"
+    return svc, reqs, t_before
+
+
+def _loop_records(svc, t_before):
+    """The service's loop-thread phase records since t_before, oldest
+    first (the ring is the process's: other tests' services wrote to it
+    too)."""
+    from kubeml_tpu.utils.trace import phases
+
+    tid = svc._thread.ident
+    return [r for r in phases(t0=t_before) if r.tid == tid]
+
+
+def test_loop_phases_tile_the_iteration_and_the_step(tmp_home):
+    svc, _reqs, t_before = _phase_service("phase-tile", tmp_home)
+    try:
+        # the loop parks once both streams finish
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline and not any(
+                r.name == "serve.loop.wait"
+                for r in _loop_records(svc, t_before)):
+            time.sleep(0.01)
+        svc.flush_trace()       # a forced sink write, on this thread
+    finally:
+        svc.stop()
+    from kubeml_tpu.utils.trace import phases
+
+    recs = _loop_records(svc, t_before)
+    names = {r.name for r in recs}
+    # every registered phase name, by its literal
+    assert "serve.loop.wait" in names
+    assert "serve.loop.admit" in names
+    assert "serve.loop.step" in names
+    assert "serve.loop.terminal" in names
+    assert "serve.loop.publish" in names
+    assert "serve.step.reap" in names
+    assert "serve.step.prefill" in names
+    assert "serve.step.pages" in names
+    assert "serve.step.pack" in names
+    assert "serve.step.enqueue" in names
+    assert "serve.step.readback" in names
+    assert "serve.step.emit" in names
+    # the forced flush ran on this test's thread, under the same name
+    mine = [r for r in phases(t0=t_before)
+            if r.args.get("model") == "phase-tile"]
+    assert "serve.trace.flush" in {r.name for r in mine}
+    assert all(r.args["events"] > 0 and r.args["bytes"] > 0
+               for r in mine if r.name == "serve.trace.flush")
+
+    loop = [r for r in recs if r.name.startswith("serve.loop.")]
+    steps = [r for r in loop if r.name == "serve.loop.step"]
+    inner = [r for r in recs if r.name.startswith("serve.step.")]
+    assert all(r.args["model"] == "phase-tile" for r in loop)
+    # tiling: on the loop thread no serve.loop.* phase starts before
+    # the previous one ended, and together they cover the thread's
+    # time from the first to the last (the gaps are a `with` header)
+    assert all(b.t0 >= a.t1 for a, b in zip(loop, loop[1:]))
+    covered = sum(r.t1 - r.t0 for r in loop)
+    assert covered >= 0.9 * (loop[-1].t1 - loop[0].t0)
+    # every serve.step.* phase lies inside the serve.loop.step of its
+    # step number, they do not overlap, and they cover it
+    by_step = {r.args["step"]: r for r in steps}
+    for r in inner:
+        outer = by_step[r.args["step"]]
+        assert outer.t0 <= r.t0 and r.t1 <= outer.t1
+    assert all(b.t0 >= a.t1 for a, b in zip(inner, inner[1:]))
+    busy = [s for s in steps if s.args["active_slots"] or s.args["tokens"]]
+    assert sum(r.t1 - r.t0 for r in inner) >= \
+        0.8 * sum(s.t1 - s.t0 for s in busy)
+    # what the phases say they did
+    assert sum(s.args["tokens"] for s in steps) == 6 + 9
+    chunks = [r for r in inner if r.name == "serve.step.prefill"]
+    assert sorted(r.args["tokens"] for r in chunks) == [2, 7, 16, 16]
+    assert all(r.args["compiled"] in (0, 1) for r in inner
+               if r.name == "serve.step.enqueue")
+
+
+def test_iteration_phases_share_the_step_of_its_flight_record():
+    svc, _reqs, t_before = _phase_service("phase-step")
+    svc.stop()
+    recs = _loop_records(svc, t_before)
+    flight = {r["step"]: r for r in svc.engine.flight.snapshot()}
+    steps = [r for r in recs if r.name == "serve.loop.step"]
+    assert steps
+    for st in steps:
+        n = st.args["step"]
+        # the flight recorder's record of that engine step exists and
+        # agrees on what the step produced
+        assert flight[n]["tokens"] == st.args["tokens"]
+        assert flight[n]["active_slots"] == st.args["active_slots"]
+        mine = [r for r in recs if st.t0 <= r.t0 and r.t1 <= st.t1]
+        assert {r.args["step"] for r in mine} == {n}
+    # the iteration around a step carries the same number: its admit
+    # before, its terminal and publish after
+    for name in ("serve.loop.admit", "serve.loop.terminal",
+                 "serve.loop.publish"):
+        around = {r.args["step"] for r in recs if r.name == name}
+        assert {st.args["step"] for st in steps} <= around, name
+
+
+def test_phases_answer_after_the_service_stopped():
+    from kubeml_tpu.utils.trace import phases
+
+    svc, _reqs, t_before = _phase_service("phase-stop")
+    svc.stop()
+    assert not svc._thread.is_alive()
+    del svc
+    t_after = time.monotonic()
+    recs = [r for r in phases(t_before, t_after)
+            if r.args.get("model") == "phase-stop"]
+    assert [r for r in recs if r.name == "serve.loop.step"]
+    # nothing of this service is newer than its stop
+    assert phases(t0=t_after + 1.0) == [] or all(
+        r.args.get("model") != "phase-stop"
+        for r in phases(t0=t_after + 1.0))
+
+
+def _profiled_decode(tmp_path, profile: bool):
+    """Three requests through a bare engine, with a profiler session
+    around the steps or without; returns (tokens, trace dir)."""
+    import jax
+
+    from kubeml_tpu.serve.engine import DecodeEngine
+    from kubeml_tpu.serve.slots import GenerateRequest
+
+    _model, module, variables = _nano()
+    engine = DecodeEngine(module, variables, slots=4, page=4)
+    reqs = [GenerateRequest(list(p), max_new_tokens=n, temperature=t,
+                            seed=sd)
+            for p, n, t, sd in (([5, 6, 7], 6, 0.0, 0),
+                                ([9, 10, 11, 12], 8, 0.7, 1),
+                                ([3, 4], 5, 1.1, 2))]
+    for r in reqs:
+        engine.attach(r)
+    engine.step()               # compile outside the session
+    out = str(tmp_path / ("prof" if profile else "noprof"))
+    if profile:
+        jax.profiler.start_trace(out)
+    try:
+        _drive(engine)
+    finally:
+        if profile:
+            jax.profiler.stop_trace()
+    return [list(r.tokens) for r in reqs], out
+
+
+def test_decode_bit_identical_with_profiler_session_on_or_off(tmp_path):
+    """The phases' TraceAnnotation records only while a session is on:
+    either way the decoded tokens are the same."""
+    on, _ = _profiled_decode(tmp_path, profile=True)
+    off, _ = _profiled_decode(tmp_path, profile=False)
+    assert on == off and all(on)
+
+
+def test_profiler_session_finds_the_phases_in_its_file(tmp_path):
+    """Anybody's jax.profiler.start_trace gets the program's phases on
+    the profiler's own clock: the .xplane.pb of a session around three
+    or more engine steps holds serve.step.readback events."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    _tokens, out = _profiled_decode(tmp_path, profile=True)
+    paths = glob.glob(f"{out}/plugins/profile/*/*.xplane.pb")
+    if not paths:
+        pytest.skip("this build's profiler wrote no .xplane.pb")
+    host = [p for p in ProfileData.from_file(paths[-1]).planes
+            if p.name.startswith("/host:CPU")]
+    if not host:
+        pytest.skip("this build's profiler wrote no host plane")
+    names = [e.name for p in host for line in p.lines
+             for e in line.events]
+    assert names.count("serve.step.readback") >= 3
+    assert names.count("serve.step.enqueue") == \
+        names.count("serve.step.readback")
+
+
+def test_get_trace_shows_loop_phases_beside_request_trees(serve_ps):
+    ps, _model, _variables = serve_ps
+    _post(f"{ps.url}/generate",
+          {"model_id": "obsnano", "prompt": list(range(2, 30)),
+           "max_new_tokens": 4}).read()
+    doc = _get_json(f"{ps.url}/trace?id=serve:obsnano")
+    spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    steps = [e for e in spans if e["name"] == "serve.loop.step"]
+    assert steps and all(e["args"]["model"] == "obsnano" for e in steps)
+    assert sum(e["args"]["tokens"] for e in steps) >= 4
+    assert [e for e in spans if e["name"] == "serve.step.readback"]
+    # same process as the request tree, and the request's prefill
+    # chunk lies inside a serve.step.prefill phase of the loop thread
+    (chunk,) = [e for e in spans if e["name"] == "prefill_chunk"][:1]
+    assert chunk["pid"] == steps[0]["pid"]
+    assert any(e["ts"] <= chunk["ts"] and chunk["ts"] + chunk["dur"]
+               <= e["ts"] + e["dur"] + 1
+               for e in spans if e["name"] == "serve.step.prefill")
+    assert any(s.endswith(".phases.trace.json")
+               for s in doc["metadata"]["sources"])
+
+
+def test_paged_programs_carry_named_scopes():
+    """Metadata only (the bit-identity suites pin the math): the
+    lowered decode and prefill programs name their parts."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeml_tpu.models.gpt import (PAGED_SCOPES,
+                                       build_paged_decode_step,
+                                       build_paged_prefill_step)
+    from kubeml_tpu.serve.pager import KVPageSlab, PageGeometry
+
+    _model, module, variables = _nano()
+    geom = PageGeometry.for_module(slots=2, page=8, max_len=module.max_len)
+    slab = KVPageSlab(geom, module.layers, module.heads,
+                      module.hidden // module.heads, module.dtype)
+    S, P = geom.slots, geom.pages_per_slot
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    f32 = lambda *shape: jnp.zeros(shape, jnp.float32)
+    slabs = (variables["params"], slab.k, slab.v, slab.k_scale,
+             slab.v_scale, slab.valid)
+    decode = jax.jit(build_paged_decode_step(module)).lower(
+        *slabs, i32(S), i32(S), i32(S, P), i32(S), i32(S), f32(S), f32(S),
+        jnp.zeros((S, 2), jnp.uint32), i32(S), i32(S), f32(S)
+    ).as_text(debug_info=True)
+    prefill = jax.jit(build_paged_prefill_step(module, 16)).lower(
+        *slabs, i32(16), i32(16), i32(P), i32(16), i32(16), f32(16)
+    ).as_text(debug_info=True)
+    # the prefill program returns pages only, so its last layer's
+    # attention and MLP are dead code: look at its first layer
+    for text, layer, scopes in ((decode, module.layers - 1, PAGED_SCOPES),
+                                (prefill, 0, PAGED_SCOPES[1:-2])):
+        for name in scopes:
+            per_layer = name in ("qkv", "kv_write", "attn", "proj", "mlp")
+            want = f"/layer_{layer}/{name}/" if per_layer else f"/{name}/"
+            assert want in text, want
+    assert "cow_split" not in prefill and "sample" not in prefill
+
+
 # ------------------------------------------------------------------- lint
 
 def test_serve_span_lint_passes_on_this_repo():
     import tools.check_serve_spans as lint
     assert lint.main(["check_serve_spans.py"]) == 0
+
+
+def test_serve_phase_registry_is_linted():
+    """The thirteen loop-phase names are a registry the lint reads (the
+    benchmark's readers key on them), dotted names included."""
+    import os
+
+    import tools.check_serve_spans as lint
+    from kubeml_tpu.serve.engine import SERVE_PHASE_KINDS
+
+    engine_py = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "kubeml_tpu", "serve", "engine.py")
+    assert lint.phase_kinds(engine_py) == list(SERVE_PHASE_KINDS)
+    assert len(SERVE_PHASE_KINDS) == 13
+    assert {k.rsplit(".", 1)[0] for k in SERVE_PHASE_KINDS} == {
+        "serve.loop", "serve.step", "serve.trace"}
+
+
+def test_serve_span_lint_holds_phase_kinds(tmp_path):
+    import tools.check_serve_spans as lint
+
+    (tmp_path / "kubeml_tpu" / "serve").mkdir(parents=True)
+    (tmp_path / "tests").mkdir()
+    eng = tmp_path / "kubeml_tpu" / "serve" / "engine.py"
+    eng.write_text('SERVE_SPAN_KINDS = ("zz_alpha",)\n'
+                   'SERVE_PHASE_KINDS = ("zz.loop.beta",)\n')
+    t = tmp_path / "tests" / "test_spans.py"
+    t.write_text('assert "zz_alpha" in kinds\n')
+    assert lint.main(["x", str(tmp_path)]) == 1
+    t.write_text('assert "zz_alpha" in kinds\n'
+                 'assert "zz.loop.beta" in names\n')
+    assert lint.main(["x", str(tmp_path)]) == 0
 
 
 def test_serve_span_lint_self_test(tmp_path):

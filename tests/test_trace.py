@@ -7,9 +7,10 @@ import threading
 
 import pytest
 
-from kubeml_tpu.utils.trace import (TraceSink, Tracer, get_trace_context,
+from kubeml_tpu.utils.trace import (DURATIONS_KEPT, PHASE_RING_SIZE, PhaseRing,
+                                    TraceSink, Tracer, get_trace_context,
                                     make_trace_id, merge_job_trace,
-                                    trace_context, trace_dir, xla_profile)
+                                    trace_context, trace_dir)
 
 
 class FakeClock:
@@ -153,31 +154,139 @@ def test_trace_sink_and_merge(tmp_home):
         merge_job_trace("nosuchjob1")
 
 
-def test_xla_profile_noop_safe(tmp_path):
-    # must not raise even if the backend lacks profiler support
-    with xla_profile(str(tmp_path / "prof")):
-        import jax.numpy as jnp
-        jnp.ones(4).sum()
+def test_unreset_tracer_stays_bounded():
+    """A serve tracer is never reset(): the summary stays exact (count
+    and total) while the kept durations are the newest DURATIONS_KEPT."""
+    tr = Tracer(clock=FakeClock(), max_events=4)
+    n = DURATIONS_KEPT + 10
+    for i in range(n):
+        tr.add_span("decode", 0.0, float(i))
+    s = tr.summary()["decode"]
+    assert s["count"] == n
+    assert s["total_s"] == sum(range(n))
+    kept = tr.durations()["decode"]
+    assert len(kept) == DURATIONS_KEPT
+    assert kept[0] == 10.0 and kept[-1] == float(n - 1)
+    tr.reset()
+    assert tr.summary() == {} and tr.durations() == {}
 
 
-def test_xla_profile_fallback_on_start_failure(tmp_path, monkeypatch,
-                                               caplog):
-    # start_trace failure: warn, run the block, and never call stop_trace
-    import jax
+# ----------------------------------------------------------- loop phases
 
-    def boom(*a, **k):
-        raise RuntimeError("no profiler here")
+def test_phase_ring_keeps_newest_maxlen_records():
+    ring = PhaseRing(maxlen=4, clock=FakeClock())
+    for i in range(10):
+        with ring.phase("serve.step.pack", step=i):
+            pass
+    recs = ring.phases()
+    assert [r.args["step"] for r in recs] == [6, 7, 8, 9]
+    # t0 and t1 are consecutive readings of the ring's clock
+    assert [(r.t0, r.t1) for r in recs] == [
+        (13.0, 14.0), (15.0, 16.0), (17.0, 18.0), (19.0, 20.0)]
+    assert all(r.tid == threading.get_ident() for r in recs)
+    # the process ring holds five minutes of the busiest loop measured:
+    # about 20 iterations a second x 12 phases
+    assert PHASE_RING_SIZE >= 300 * 20 * 12
 
-    stopped = []
-    monkeypatch.setattr(jax.profiler, "start_trace", boom)
-    monkeypatch.setattr(jax.profiler, "stop_trace",
-                        lambda: stopped.append(True))
-    ran = []
-    with caplog.at_level("WARNING", logger="kubeml_tpu.trace"):
-        with xla_profile(str(tmp_path / "prof")):
-            ran.append(True)
-    assert ran and not stopped
-    assert "could not start trace" in caplog.text
+
+def test_phases_cut_by_overlap_and_return_copies():
+    ring = PhaseRing(maxlen=16, clock=FakeClock())
+    for name in ("a", "b", "c", "d"):       # (1,2) (3,4) (5,6) (7,8)
+        with ring.phase(name):
+            pass
+    names = lambda recs: [r.name for r in recs]
+    assert names(ring.phases()) == ["a", "b", "c", "d"]
+    # a record that only overlaps the cut is in it; one that ends
+    # before it starts or starts after it ends is not
+    assert names(ring.phases(3.5, 5.5)) == ["b", "c"]
+    assert names(ring.phases(t0=6.0)) == ["c", "d"]
+    assert names(ring.phases(t1=2.5)) == ["a"]
+    assert ring.phases(2.1, 2.9) == []
+    # copies: a reader that edits its records leaves the ring alone
+    ring.phases()[0].args["x"] = 1
+    assert ring.phases()[0].args == {}
+
+
+def test_phase_args_are_read_at_exit_and_survive_an_exception():
+    ring = PhaseRing(maxlen=4, clock=FakeClock())
+    with ring.phase("serve.step.enqueue", step=7) as args:
+        args["compiled"] = 1
+    with pytest.raises(KeyError):
+        with ring.phase("serve.step.emit", step=7):
+            raise KeyError("boom")
+    enq, emit = ring.phases()
+    assert enq.args == {"step": 7, "compiled": 1}
+    assert emit.name == "serve.step.emit" and emit.t1 > emit.t0
+
+
+def test_phase_ring_concurrent_appends_lose_nothing():
+    """Loop threads append while another thread reads, with no lock:
+    every record of every writer arrives whole."""
+    import sys
+
+    ring = PhaseRing(maxlen=100_000)
+    n_threads, n_each = 8, 2_000
+    stop = threading.Event()
+    seen = []
+
+    def write(k):
+        for i in range(n_each):
+            with ring.phase("w", k=k, i=i):
+                pass
+
+    def read():
+        while not stop.is_set():
+            seen.append(len(ring.phases()))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        reader = threading.Thread(target=read)
+        writers = [threading.Thread(target=write, args=(k,))
+                   for k in range(n_threads)]
+        reader.start()
+        for t in writers:
+            t.start()
+        for t in writers:
+            t.join(60)
+        stop.set()
+        reader.join(60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not reader.is_alive() and not any(t.is_alive() for t in writers)
+    recs = ring.phases()
+    assert len(recs) == n_threads * n_each
+    for k in range(n_threads):
+        assert [r.args["i"] for r in recs if r.args["k"] == k] == \
+            list(range(n_each))
+    assert seen == sorted(seen)
+
+
+def test_sink_writes_phases_beside_the_request_trees(tmp_home):
+    """write_phases lands in its own <process>-<pid>.phases.trace.json;
+    the merged document shows the phases as X events of the same
+    process as the request trees, on the same clock."""
+    clk = FakeClock()
+    ring = PhaseRing(maxlen=8, clock=clk)
+    tr = Tracer(clock=clk)
+    with ring.phase("serve.loop.step", model="m", step=3):
+        tr.add_span("generate", 1.25, 1.75, rid="r1")
+    sink = TraceSink("serve:m", "fleet")
+    sink.write(tr)
+    path = sink.write_phases(ring.phases())
+    assert path.endswith(".phases.trace.json") and path != sink.path
+    doc = merge_job_trace("serve:m")
+    spans = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
+    assert set(spans) == {"generate", "serve.loop.step"}
+    assert spans["serve.loop.step"]["ts"] == 1_000_000 \
+        and spans["serve.loop.step"]["dur"] == 1_000_000
+    assert spans["serve.loop.step"]["args"] == {"model": "m", "step": 3}
+    assert spans["serve.loop.step"]["pid"] == spans["generate"]["pid"]
+    # the request span lies inside the phase on the shared timeline
+    st = spans["serve.loop.step"]
+    assert st["ts"] <= spans["generate"]["ts"] \
+        and spans["generate"]["ts"] + spans["generate"]["dur"] \
+        <= st["ts"] + st["dur"]
 
 
 def test_job_logs_trace_summary(tmp_path, tmp_home, mesh8):

@@ -7,7 +7,9 @@ the flight-recorder snapshot instant. Dashboards, the trace merger,
 and the TTFT-attribution tests all key on these literal names — a
 kind that can be renamed or dropped without failing a test is an
 observability contract nobody is holding. So this lint walks the
-SERVE_SPAN_KINDS tuple in engine.py — and the FLEET_SPAN_KINDS tuple
+SERVE_SPAN_KINDS tuple in engine.py, the SERVE_PHASE_KINDS tuple beside
+it (the loop-thread phases of utils/trace.py `phase`, which the
+benchmark's readers key on) — and the FLEET_SPAN_KINDS tuple
 in fleet.py, the cross-replica routing/migration/hedging events the
 fleet stitches onto the same request tree — and fails unless each
 name appears QUOTED on an assertion line (a code line containing
@@ -32,27 +34,35 @@ _KINDS_RE = re.compile(
     r"SERVE_SPAN_KINDS\s*=\s*\(([^)]*)\)", re.DOTALL)
 _FLEET_KINDS_RE = re.compile(
     r"FLEET_SPAN_KINDS\s*=\s*\(([^)]*)\)", re.DOTALL)
-_NAME_RE = re.compile(r"['\"]([A-Za-z0-9_]+)['\"]")
+_PHASE_KINDS_RE = re.compile(
+    r"SERVE_PHASE_KINDS\s*=\s*\(([^)]*)\)", re.DOTALL)
+_NAME_RE = re.compile(r"['\"]([A-Za-z0-9_.]+)['\"]")
+
+
+def _declared(path: str, tuple_re) -> list:
+    """The quoted names inside the tuple `tuple_re` finds in `path`;
+    empty where the file has no such tuple."""
+    with open(path, encoding="utf-8") as f:
+        m = tuple_re.search(f.read())
+    return _NAME_RE.findall(m.group(1)) if m else []
 
 
 def span_kinds(engine_path: str) -> list:
     """Span-kind names declared in engine.py's SERVE_SPAN_KINDS."""
-    with open(engine_path, encoding="utf-8") as f:
-        m = _KINDS_RE.search(f.read())
-    if m is None:
-        return []
-    return _NAME_RE.findall(m.group(1))
+    return _declared(engine_path, _KINDS_RE)
+
+
+def phase_kinds(engine_path: str) -> list:
+    """Loop-phase names declared in engine.py's SERVE_PHASE_KINDS
+    (utils/trace.py `phase`: what the serving loop thread is doing)."""
+    return _declared(engine_path, _PHASE_KINDS_RE)
 
 
 def fleet_span_kinds(fleet_path: str) -> list:
     """Span-kind names declared in fleet.py's FLEET_SPAN_KINDS — the
     cross-replica events (routing, migration, hedging) the fleet
     router stitches onto each request's trace tree."""
-    with open(fleet_path, encoding="utf-8") as f:
-        m = _FLEET_KINDS_RE.search(f.read())
-    if m is None:
-        return []
-    return _NAME_RE.findall(m.group(1))
+    return _declared(fleet_path, _FLEET_KINDS_RE)
 
 
 def _code_lines(path: str):
@@ -123,7 +133,10 @@ def main(argv) -> int:
               "miswired", file=sys.stderr)
         return 1
     missing = unasserted_kinds(engine_path, tests_dir)
-    registries = "kubeml_tpu/serve/engine.py SERVE_SPAN_KINDS"
+    missing += _unasserted(phase_kinds(engine_path),
+                           _test_files(tests_dir))
+    registries = "kubeml_tpu/serve/engine.py SERVE_SPAN_KINDS / " \
+        "SERVE_PHASE_KINDS"
     # fleet registry: same contract, separate tuple. A tree without
     # fleet.py (the lint's own self-test fixtures) only checks the
     # engine registry; a tree WITH fleet.py but no tuple is miswired.
