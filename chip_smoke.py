@@ -292,17 +292,20 @@ def kernel_vs_gather(module, page, slots, chunk, seed, interpret):
             P = S * Pmax + 1
             q = jax.random.normal(ks[0], (S, T, H, D),
                                   jnp.float32).astype(dtype)
+            # operands in the slab's shape (serve/pager.py KVPageSlab):
+            # two layers of [P, page, H*D] rows, the kernel reads layer 1
             if quantized:
                 kp, vp = (jax.random.randint(
-                    k_, (P, page, H, D), -127, 128, jnp.int32)
+                    k_, (2, P, page, H * D), -127, 128, jnp.int32)
                     .astype(jnp.int8) for k_ in ks[1:3])
                 kscale, vscale = (jax.random.uniform(
-                    k_, (P,), jnp.float32, 0.001, 0.02) for k_ in ks[3:5])
+                    k_, (2, P), jnp.float32, 0.001, 0.02)
+                    for k_ in ks[3:5])
             else:
                 kp, vp = (jax.random.normal(
-                    k_, (P, page, H, D), jnp.float32).astype(dtype)
+                    k_, (2, P, page, H * D), jnp.float32).astype(dtype)
                     for k_ in ks[1:3])
-                kscale = vscale = jnp.zeros((P,), jnp.float32)
+                kscale = vscale = jnp.zeros((2, P), jnp.float32)
             tables = 1 + np.arange(S * Pmax,
                                    dtype=np.int32).reshape(S, Pmax)
             # each slot sees a different context length; rest is masked
@@ -313,7 +316,7 @@ def kernel_vs_gather(module, page, slots, chunk, seed, interpret):
                 (S, 1, T, C)).astype(np.float32)
             operands = (q, kp, vp, kscale, vscale, jnp.asarray(tables),
                         jnp.asarray(bias))
-            kw = dict(quantized=quantized, compute_dtype=dtype)
+            kw = dict(layer=1, quantized=quantized, compute_dtype=dtype)
             ker = jax.jit(functools.partial(
                 paged_attention, impl="pallas", interpret=interpret,
                 **kw))(*operands)

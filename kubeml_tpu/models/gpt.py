@@ -437,9 +437,38 @@ PAGED_SCOPES = ("cow_split", "embed", "mask", "qkv", "kv_write", "attn",
                 "proj", "mlp", "head", "sample")
 
 
+def _cow_split_pages(pages, copy_src, copy_dst):
+    """Copy-on-write lane of the decode program over one slab
+    [L, P, G, H*Dh]: page copy_src[s] -> page copy_dst[s], every layer,
+    for each slot s. All S source pages are read BEFORE any is written
+    (the functional gather-before-scatter semantics of
+    `pages.at[:, dst].set(pages[:, src])`, which this replaces), then
+    written one slot at a time, in place. Real splits land on freshly
+    allocated pages, so their destinations are distinct; the 0 -> 0
+    lanes of slots with nothing to split rewrite the null page with its
+    own bytes, whatever the order.
+
+    Why S dynamic slices and not one gather: at 36 layers a gather whose
+    slice is [L, 1, G, H*Dh] is compiled (v5e, PR 26) as four gathers
+    over lane chunks of the slab, each fed by a copy of that chunk of
+    the WHOLE slab — a relayout by another name, 1.5 GB a step (on the
+    chip 2.0 ms a step for each of K and V, PERF.md). A dynamic slice
+    moves the page and nothing else; the barrier keeps the reads from
+    being fused into the writes, which would hold the unwritten slab
+    alive beside the written one."""
+    srcs = lax.optimization_barrier(
+        [lax.dynamic_slice_in_dim(pages, copy_src[s], 1, axis=1)
+         for s in range(copy_src.shape[0])])
+    for s, src in enumerate(srcs):
+        pages = lax.dynamic_update_slice_in_dim(pages, src, copy_dst[s],
+                                                axis=1)
+    return pages
+
+
 def _int8_write_decode(pages, scales, layer, rows, write_page, write_off):
-    """Quantize-on-write for one layer's decode rows [S, H, Dh] (f32)
-    into int8 pages with PER-PAGE symmetric scales (the PR-7 EFInt8
+    """Quantize-on-write for one layer's decode rows [S, H*Dh] (f32,
+    the slab's lane-dense token rows — serve/pager.py KVPageSlab) into
+    int8 pages with PER-PAGE symmetric scales (the PR-7 EFInt8
     convention from parallel/merge.py: scale = amax/127, value =
     q * scale, zero-amax rows quantize to 0).
 
@@ -458,14 +487,14 @@ def _int8_write_decode(pages, scales, layer, rows, write_page, write_off):
     order-free, deterministic, never attended."""
     old = scales[layer, write_page]
     old = jnp.where(write_off == 0, 0.0, old)
-    amax = jnp.max(jnp.abs(rows), axis=(1, 2))
+    amax = jnp.max(jnp.abs(rows), axis=1)
     new = jnp.maximum(old, amax / 127.0)
     safe = jnp.where(new > 0, new, 1.0)
     factor = jnp.where(new > 0, old / safe, 0.0)
     requant = jnp.round(pages[layer, write_page].astype(jnp.float32)
-                        * factor[:, None, None, None])
+                        * factor[:, None, None])
     pages = pages.at[layer, write_page].set(requant.astype(jnp.int8))
-    qrow = jnp.clip(jnp.round(rows / safe[:, None, None]), -127, 127)
+    qrow = jnp.clip(jnp.round(rows / safe[:, None]), -127, 127)
     pages = pages.at[layer, write_page, write_off].set(qrow.astype(jnp.int8))
     scales = scales.at[layer, write_page].set(new)
     return pages, scales
@@ -473,7 +502,7 @@ def _int8_write_decode(pages, scales, layer, rows, write_page, write_off):
 
 def _int8_write_prefill(pages, scales, layer, rows, write_pages,
                         write_offs, in_chunk):
-    """Chunked twin of _int8_write_decode: C rows [C, H, Dh] (f32)
+    """Chunked twin of _int8_write_decode: C rows [C, H*Dh] (f32)
     land across up to two pages per chunk. Per-page amaxes accumulate
     with scatter-max (duplicate page indices reduce associatively —
     deterministic); the reset rule is the same, applied per page when
@@ -484,14 +513,14 @@ def _int8_write_prefill(pages, scales, layer, rows, write_pages,
     reset = jnp.zeros_like(base).at[write_pages].max(
         (write_offs == 0).astype(jnp.float32) * in_chunk)
     base = jnp.where(reset > 0, 0.0, base)
-    amax = jnp.max(jnp.abs(rows), axis=(1, 2)) * in_chunk
+    amax = jnp.max(jnp.abs(rows), axis=1) * in_chunk
     new = base.at[write_pages].max(amax / 127.0)
     safe = jnp.where(new > 0, new, 1.0)
     factor = jnp.where(new > 0, base / safe, 0.0)
     requant = jnp.round(pages[layer, write_pages].astype(jnp.float32)
-                        * factor[write_pages][:, None, None, None])
+                        * factor[write_pages][:, None, None])
     pages = pages.at[layer, write_pages].set(requant.astype(jnp.int8))
-    qrows = jnp.clip(jnp.round(rows / safe[write_pages][:, None, None]),
+    qrows = jnp.clip(jnp.round(rows / safe[write_pages][:, None]),
                      -127, 127)
     pages = pages.at[layer, write_pages, write_offs].set(
         qrows.astype(jnp.int8))
@@ -530,7 +559,13 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
     paged_attention — the context read streams pages through the page
     table on TPU instead of materializing a contiguous [S, C, H, D]
     gather, which is the decode bandwidth attack this builder exists
-    for; the 'gather' fallback is the old chain verbatim.
+    for; the 'gather' fallback is the old chain verbatim. k_pages and
+    v_pages are the slab as serve/pager.py KVPageSlab holds it,
+    [layers, pages, page, H*Dh]: every write here is a token row at
+    [layer, page, offset], every read a page through the table, and the
+    kernel gets the slab whole with the layer as a static index, so the
+    compiled program never relays the slab out or copies a layer's
+    plane (tests/test_chip_compile.py).
 
     Every per-request quantity is DATA (the kavg worker-mask trick), so
     slot membership changes never recompile. Inactive slots compute
@@ -551,7 +586,8 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
     inside the SAME dispatch as the write, so CoW costs zero extra
     programs and the compile count stays pinned at two (prefill +
     decode). Slots with nothing to split pass 0 -> 0, a no-op through
-    the null page.
+    the null page. The pages move as whole [layers, 1, page, H*Dh]
+    slices of the slab, in place (_cow_split_pages).
 
     bad[S] is the ON-DEVICE NON-FINITE GUARD (the kavg merge guard's
     serving twin): 1.0 for an active row whose logits went non-finite
@@ -607,8 +643,8 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
         # safe in the same step. 0 -> 0 rows are null-page no-ops.
         # Scales are page metadata and split with their page.
         with jax.named_scope("cow_split"):
-            k_pages = k_pages.at[:, copy_dst].set(k_pages[:, copy_src])
-            v_pages = v_pages.at[:, copy_dst].set(v_pages[:, copy_src])
+            k_pages = _cow_split_pages(k_pages, copy_src, copy_dst)
+            v_pages = _cow_split_pages(v_pages, copy_src, copy_dst)
             k_scales = k_scales.at[:, copy_dst].set(k_scales[:, copy_src])
             v_scales = v_scales.at[:, copy_dst].set(v_scales[:, copy_src])
             valid_pages = valid_pages.at[copy_dst].set(
@@ -637,22 +673,26 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
                 k = qkv.apply({"params": p["k"]}, x)
                 v = qkv.apply({"params": p["v"]}, x)
             with jax.named_scope(f"layer_{i}/kv_write"):
+                # a token's K (and V) is ONE lane-dense row of the
+                # slab, heads side by side (serve/pager.py KVPageSlab)
+                k_row = k[:, 0].reshape(S, hidden)
+                v_row = v[:, 0].reshape(S, hidden)
                 if quantized:
                     k_pages, k_scales = _int8_write_decode(
-                        k_pages, k_scales, i, k[:, 0].astype(jnp.float32),
+                        k_pages, k_scales, i, k_row.astype(jnp.float32),
                         write_page, write_off)
                     v_pages, v_scales = _int8_write_decode(
-                        v_pages, v_scales, i, v[:, 0].astype(jnp.float32),
+                        v_pages, v_scales, i, v_row.astype(jnp.float32),
                         write_page, write_off)
                 else:
                     k_pages = k_pages.at[i, write_page, write_off].set(
-                        k[:, 0].astype(dtype))
+                        k_row.astype(dtype))
                     v_pages = v_pages.at[i, write_page, write_off].set(
-                        v[:, 0].astype(dtype))
+                        v_row.astype(dtype))
             with jax.named_scope(f"layer_{i}/attn"):
                 attn = paged_attention(
-                    q, k_pages[i], v_pages[i], k_scales[i], v_scales[i],
-                    page_tables, bias, quantized=quantized,
+                    q, k_pages, v_pages, k_scales, v_scales,
+                    page_tables, bias, layer=i, quantized=quantized,
                     compute_dtype=dtype, impl=attn_impl,
                     interpret=attn_interpret)
             with jax.named_scope(f"layer_{i}/proj"):
@@ -797,22 +837,24 @@ def build_paged_prefill_step(module: GPTModule, chunk: int,
                 k = qkv.apply({"params": p["k"]}, x)
                 v = qkv.apply({"params": p["v"]}, x)
             with jax.named_scope(f"layer_{i}/kv_write"):
+                k_rows = k[0].reshape(-1, hidden)     # [chunk, H*Dh]
+                v_rows = v[0].reshape(-1, hidden)
                 if quantized:
                     k_pages, k_scales = _int8_write_prefill(
-                        k_pages, k_scales, i, k[0].astype(jnp.float32),
+                        k_pages, k_scales, i, k_rows.astype(jnp.float32),
                         write_pages, write_offs, in_chunk)
                     v_pages, v_scales = _int8_write_prefill(
-                        v_pages, v_scales, i, v[0].astype(jnp.float32),
+                        v_pages, v_scales, i, v_rows.astype(jnp.float32),
                         write_pages, write_offs, in_chunk)
                 else:
                     k_pages = k_pages.at[i, write_pages, write_offs].set(
-                        k[0].astype(dtype))
+                        k_rows.astype(dtype))
                     v_pages = v_pages.at[i, write_pages, write_offs].set(
-                        v[0].astype(dtype))
+                        v_rows.astype(dtype))
             with jax.named_scope(f"layer_{i}/attn"):
                 attn = paged_attention(
-                    q, k_pages[i], v_pages[i], k_scales[i], v_scales[i],
-                    page_table[None], bias, quantized=quantized,
+                    q, k_pages, v_pages, k_scales, v_scales,
+                    page_table[None], bias, layer=i, quantized=quantized,
                     compute_dtype=dtype, impl=attn_impl,
                     interpret=attn_interpret)
             with jax.named_scope(f"layer_{i}/proj"):

@@ -23,22 +23,34 @@ serving bit-identity suite can assert_array_equal the kernel (interpret
 mode) against the gather programs instead of settling for allclose.
 
 Layout (what the v5e's compiler accepts — tests/test_chip_compile.py
-compiles it for a described chip): one (slot, page) owns a grid point.
-Each page block arrives [G, H, D] as the slab stores it, is swapped to
-heads-leading [H, G, D] in VMEM and lands in a [H, C, D] scratch pair
-(C = Pmax*G tokens), so the tiled last-two dims are (context, head_dim)
-and the batched matmuls carry heads as the leading batch dim. The last
-page step runs the softmax once over the full masked context exactly
-like the reference, preserving the engine's masking/determinism
-contract. The live set therefore GROWS with the context:
+compiles it, and the four serve programs around it, for a described
+chip): the kernel takes the slab WHOLE, [L, P, G, H*D] as
+serve/pager.py KVPageSlab holds it, with the layer as a static index in
+the BlockSpec's index map. Heads ride the lane dimension of the slab:
+the TPU tiles an array's two minor dimensions, (H, D) = (20, 64) fits
+no tile while (G, H*D) = (16, 1280) tiles exactly, so the slab has one
+unpadded row-major layout that the page writes, the copy-on-write split
+and this kernel all work in, in place — a [.., H, D] slab made every
+program relay the whole slab out at its edges, and a per-layer operand
+`k_pages[layer]` made XLA materialize that layer's plane for the custom
+call (PERF.md, PR 26). One (slot, page) owns a grid point. Each page
+block arrives [G, H*D], and head h's lanes [h*D, (h+1)*D) land in a
+[H, C, D] scratch pair (C = Pmax*G tokens), so the tiled last-two dims
+are (context, head_dim) and the batched matmuls carry heads as the
+leading batch dim. The last page step runs the softmax once over the
+full masked context exactly like the reference, preserving the engine's
+masking/determinism contract. The live set therefore GROWS with the
+context:
 `paged_vmem_bytes` bounds it from the shapes — the scratch pair
 2*H*C*pad128(D)*itemsize dominates (1 MiB at gpt-mini's H=4, D=64,
 C=512 in bf16; 8 MiB at H=16, D=128, C=1024), plus the double-buffered
 q/out/page/bias blocks and the f32 [H, T, C] score temporaries — and
-`paged_eligible` sends geometries over `VMEM_BUDGET` to the gather
-path. The bound was checked against the compiler's own scoped-VMEM
-accounting (binary search on vmem_limit_bytes) and overestimates it by
-1.1-1.5x at every geometry tried.
+`paged_eligible` sends geometries over `VMEM_BUDGET`, and geometries
+whose H*D is not a multiple of the 128 lanes (their slab would be
+padded and relaid again), to the gather path. The bound was checked
+against the compiler's own scoped-VMEM accounting (binary search on
+vmem_limit_bytes, PR 26: ten geometries from gpt-mini to the budget's
+edge, bf16, f32 and int8 pages) and overestimates it by 1.08-1.39x.
 
 int8 KV pages (serve/pager.py kv_dtype="int8") dequantize INSIDE the
 kernel: pages are int8 with one symmetric f32 scale per page riding as
@@ -92,10 +104,12 @@ def paged_vmem_bytes(q_len: int, heads: int, head_dim: int, page: int,
 
     Every buffer is counted at its TILED size (last dim padded to 128
     lanes, second-to-last to the dtype's sublane tile): the heads-leading
-    [H, C, D] scratch pair, the double-buffered q/out, K/V page and bias
-    blocks, and two f32 [H, T, C] score-sized temporaries for the
-    softmax. Compared against the compiler's own accounting it is
-    conservative (see module docstring)."""
+    [H, C, D] scratch pair, the double-buffered q/out, K/V page
+    ([G, H*D], as the slab stores them) and bias blocks, one K and one V
+    page in f32 and in the compute dtype (the dequantized block the
+    head slices are taken from), and two f32 [H, T, C] score-sized
+    temporaries for the softmax. Compared against the compiler's own
+    accounting it is conservative (see module docstring)."""
     item = jnp.dtype(dtype).itemsize
     page_item = 1 if quantized else item
 
@@ -104,23 +118,28 @@ def paged_vmem_bytes(q_len: int, heads: int, head_dim: int, page: int,
 
     C = page * max_pages
     Dp = _pad(head_dim, LANES)
+    row = _pad(heads * head_dim, LANES)
     scratch = 2 * heads * C * Dp * item
-    kv_blocks = 2 * 2 * page * sub(heads, page_item) * Dp * page_item
+    kv_blocks = 2 * 2 * sub(page, page_item) * row * page_item
+    kv_values = 2 * sub(page, 4) * row * (4 + item)
     q_out = 2 * 2 * heads * sub(q_len, item) * Dp * item
     bias = 2 * sub(q_len, 4) * _pad(C, LANES) * 4
     scores = 2 * heads * sub(q_len, 4) * _pad(C, LANES) * 4
-    return scratch + kv_blocks + q_out + bias + scores
+    return scratch + kv_blocks + kv_values + q_out + bias + scores
 
 
 def paged_eligible(page: int, *, q_len: int, heads: int, head_dim: int,
                    max_pages: int, dtype, quantized: bool = False) -> bool:
     """Geometry gate for the Mosaic kernel, from shapes and dtype only:
     page rows are the sublane offset of the scratch store, so they must
-    be sublane-aligned, and the computed VMEM bound must fit the budget.
-    Ineligible geometries fall back to the gather path under 'auto'."""
-    return page % SUBLANES == 0 and paged_vmem_bytes(
-        q_len, heads, head_dim, page, max_pages, dtype,
-        quantized) <= VMEM_BUDGET
+    be sublane-aligned; a token row of H*D lanes must be a whole number
+    of 128-lane tiles — the slab is lane-dense and unpadded only then,
+    which is the point of the kernel's operand layout; and the computed
+    VMEM bound must fit the budget. Ineligible geometries fall back to
+    the gather path under 'auto'."""
+    return page % SUBLANES == 0 and (heads * head_dim) % LANES == 0 \
+        and paged_vmem_bytes(q_len, heads, head_dim, page, max_pages,
+                             dtype, quantized) <= VMEM_BUDGET
 
 
 def resolve_impl(impl: str, interpret: bool, **geometry) -> str:
@@ -142,34 +161,38 @@ def _pa_kernel(tables_ref, kscale_ref, vscale_ref, q_ref, k_ref, v_ref,
     """One (slot, page) grid point.
 
     The page loop is the LAST grid dimension (sequential per core): each
-    step lands one KV page — fetched straight from its slab position via
-    the page-table index map, dequantized here if int8, swapped to
-    heads-leading — into the [H, C, D] VMEM scratch, and the final step
-    runs the full-context attention for this slot. Heads stay INSIDE the
-    block as the matmuls' leading batch dim: the einsums below then
-    contract exactly what the reference path's head-batched einsums
-    contract, which is what keeps the kernel bit-identical to
+    step lands one KV page — fetched straight from its slab position
+    [layer, tables[s, j]] via the index map, dequantized here if int8 —
+    into the [H, C, D] VMEM scratch, head h taking lanes [h*D, (h+1)*D)
+    of the page's [G, H*D] rows (static lane slices: the slab keeps
+    heads in the lane dimension, see the module docstring), and the
+    final step runs the full-context attention for this slot. Heads
+    stay INSIDE the block as the matmuls' leading batch dim: the einsums
+    below then contract exactly what the reference path's head-batched
+    einsums contract, which is what keeps the kernel bit-identical to
     multi_head_attention rather than merely allclose — per-head 2D dots
     reassociate the same sums differently.
-    q_ref/out_ref [1, H, T, D]; k_ref/v_ref [1, G, H, D];
-    bias_ref [1, 1, T, C].
+    q_ref/out_ref [1, H, T, D]; k_ref/v_ref [1, 1, G, H*D];
+    bias_ref [1, 1, T, C]; kscale_ref/vscale_ref [P], this layer's.
     """
     s = pl.program_id(0)
     j = pl.program_id(1)
-    k_blk = k_ref[0]
-    v_blk = v_ref[0]
+    k_blk = k_ref[0, 0]
+    v_blk = v_ref[0, 0]
     if quantized:
         pid = tables_ref[s, j]
         k_blk = _dequant(k_blk, kscale_ref[pid], k_scr.dtype)
         v_blk = _dequant(v_blk, vscale_ref[pid], v_scr.dtype)
     rows = pl.ds(pl.multiple_of(j * page, page), page)
-    k_scr[:, rows, :] = jnp.swapaxes(k_blk, 0, 1)
-    v_scr[:, rows, :] = jnp.swapaxes(v_blk, 0, 1)
+    heads, _, d = k_scr.shape
+    for h in range(heads):
+        lanes = slice(h * d, (h + 1) * d)
+        k_scr[h, rows, :] = k_blk[:, lanes]
+        v_scr[h, rows, :] = v_blk[:, lanes]
 
     @pl.when(j == n_pages - 1)
     def _compute():
         q = q_ref[0]                                         # [H, T, D]
-        d = q.shape[-1]
         # the reference chain (ops/attention.py multi_head_attention):
         # f32-accumulated scores, the identical scale expression,
         # additive bias, f32 softmax, cast-then-matmul. Mosaic requires
@@ -187,15 +210,16 @@ def _pa_kernel(tables_ref, kscale_ref, vscale_ref, q_ref, k_ref, v_ref,
 
 
 def _pa_pallas(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
-               quantized: bool, compute_dtype, interpret: bool):
+               layer: int, quantized: bool, compute_dtype,
+               interpret: bool):
     S, T, H, D = q.shape
-    _, G, _, _ = k_pages.shape
+    _, _, G, HD = k_pages.shape
     Pmax = page_tables.shape[1]
     C = Pmax * G
     vma = gate.out_vma(q, k_pages, v_pages, page_tables, bias)
     kv_spec = pl.BlockSpec(
-        (1, G, H, D),
-        lambda s, j, tables, ks, vs: (tables[s, j], 0, 0, 0),
+        (1, 1, G, HD),
+        lambda s, j, tables, ks, vs: (layer, tables[s, j], 0, 0),
         memory_space=pltpu.VMEM)
     q_spec = pl.BlockSpec((1, H, T, D),
                           lambda s, j, tables, ks, vs: (s, 0, 0, 0),
@@ -228,24 +252,27 @@ def _pa_pallas(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
             vmem_limit_bytes=max(vmem, _DEFAULT_SCOPED_VMEM)),
         name="paged_attention",
         interpret=interpret,
-    )(page_tables, k_scale, v_scale, q.transpose(0, 2, 1, 3), k_pages,
-      v_pages, jnp.broadcast_to(bias, (S, 1, T, C)))
+    )(page_tables, k_scale[layer], v_scale[layer],
+      q.transpose(0, 2, 1, 3), k_pages, v_pages,
+      jnp.broadcast_to(bias, (S, 1, T, C)))
     return out.transpose(0, 2, 1, 3)
 
 
 def _pa_gather(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
-               quantized: bool, compute_dtype):
-    """The pre-kernel op chain, verbatim: materialize the contiguous
-    context with a page gather, then the shared attention primitive.
-    This IS the fallback (CPU tier, non-Mosaic mesh contexts, contexts
-    over the VMEM budget) and the bit-identity reference the kernel is
-    asserted against."""
+               layer: int, quantized: bool, compute_dtype):
+    """The pre-kernel op chain: materialize the contiguous context with
+    a page gather out of the layer's plane, split the gathered rows
+    into heads, then the shared attention primitive. This IS the
+    fallback (CPU tier, non-Mosaic mesh contexts, contexts over the
+    VMEM budget, token rows that are no whole number of lane tiles) and
+    the bit-identity reference the kernel is asserted against."""
     S, T, H, D = q.shape
-    G = k_pages.shape[1]
+    G = k_pages.shape[2]
     C = page_tables.shape[1] * G
+    k_pages, v_pages = k_pages[layer], v_pages[layer]
     if quantized:
-        k_pages = _dequant(k_pages, k_scale, compute_dtype)
-        v_pages = _dequant(v_pages, v_scale, compute_dtype)
+        k_pages = _dequant(k_pages, k_scale[layer], compute_dtype)
+        v_pages = _dequant(v_pages, v_scale[layer], compute_dtype)
     ck = k_pages[page_tables].reshape(S, C, H, D)
     cv = v_pages[page_tables].reshape(S, C, H, D)
     return multi_head_attention(q, ck, cv, bias)
@@ -254,6 +281,7 @@ def _pa_gather(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     k_scale: jax.Array, v_scale: jax.Array,
                     page_tables: jax.Array, bias: jax.Array, *,
+                    layer: int,
                     quantized: bool = False,
                     compute_dtype=None,
                     impl: str = "auto",
@@ -261,34 +289,49 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Attention of [S, T, H, D] queries over paged KV, through the
     page table — one layer's context read of the serving programs.
 
-    k_pages/v_pages: [P, G, H, D] slab planes (compute dtype, or int8
-    with quantized=True); k_scale/v_scale: [P] f32 per-page symmetric
-    scales (ignored unless quantized); page_tables: [S, Pmax] int32
-    (tails point at the reserved null page 0); bias: additive f32 mask
-    broadcastable to [S, 1, T, C], C = Pmax*G — validity and causality
-    are entirely the caller's bias, exactly like multi_head_attention.
+    k_pages/v_pages: the WHOLE slab, [L, P, G, H*D] (compute dtype, or
+    int8 with quantized=True), in the one layout serve/pager.py
+    KVPageSlab holds it in: a token's K or V is one lane-dense row,
+    head h in lanes [h*D, (h+1)*D). `layer` is static and picks the
+    plane inside the kernel's index map, so no per-layer copy of a
+    plane is ever made for the call; H and D come from q.
+    k_scale/v_scale: [L, P] f32 per-page symmetric scales (ignored
+    unless quantized); page_tables: [S, Pmax] int32 (tails point at the
+    reserved null page 0); bias: additive f32 mask broadcastable to
+    [S, 1, T, C], C = Pmax*G — validity and causality are entirely the
+    caller's bias, exactly like multi_head_attention.
 
     impl='auto' follows the package gate and this module's geometry
-    gate (Mosaic kernel on TPU when the page size is sublane-aligned and
-    the computed VMEM bound fits, gather fallback elsewhere); 'pallas'
-    and 'gather' force a path; interpret runs the forced kernel in the
-    pallas interpreter (CPU bit-identity tests).
+    gate (Mosaic kernel on TPU when `paged_eligible`: sublane-aligned
+    pages, a lane-aligned token row, the computed VMEM bound within
+    budget; gather fallback elsewhere); 'pallas' and 'gather' force a
+    path; interpret runs the forced kernel in the pallas interpreter
+    (CPU bit-identity tests).
     """
     S, T, H, D = q.shape
-    G = k_pages.shape[1]
+    G = k_pages.shape[2]
+    if k_pages.shape[3] != H * D:
+        raise ValueError(
+            f"slab rows hold {k_pages.shape[3]} lanes, q has "
+            f"{H} heads of {D}")
     if compute_dtype is None:
         compute_dtype = q.dtype
     geometry = dict(page=G, q_len=T, heads=H, head_dim=D,
                     max_pages=page_tables.shape[1], dtype=compute_dtype,
                     quantized=quantized)
     if resolve_impl(impl, interpret, **geometry) == "pallas":
-        if not paged_eligible(**geometry):
+        # a forced kernel is refused only for what it cannot run at
+        # all; an unaligned token row (paged_eligible's third clause)
+        # costs padding, not correctness
+        if G % SUBLANES or paged_vmem_bytes(
+                T, H, D, G, page_tables.shape[1], compute_dtype,
+                quantized) > VMEM_BUDGET:
             raise ValueError(
                 f"page size {G} is not sublane-aligned ({SUBLANES}) or "
                 f"the kernel's VMEM bound exceeds {VMEM_BUDGET} B for "
                 f"{geometry}; use impl='gather'")
         return _pa_pallas(q, k_pages, v_pages, k_scale, v_scale,
-                          page_tables, bias, quantized, compute_dtype,
-                          interpret)
+                          page_tables, bias, layer, quantized,
+                          compute_dtype, interpret)
     return _pa_gather(q, k_pages, v_pages, k_scale, v_scale, page_tables,
-                      bias, quantized, compute_dtype)
+                      bias, layer, quantized, compute_dtype)

@@ -125,12 +125,26 @@ class KVPageSlab:
     """The device-resident arrays: K/V pages for every layer plus the
     shared per-page validity plane and (int8 mode) per-page scales.
 
-    k/v: [L, P, G, H, Dh] in the module dtype — the jitted step scatters
-    one token row per active slot per dispatch and gathers each slot's
-    table-worth back as its attention context. valid: [P, G] float32 —
-    1.0 where a real (non-pad, active) token was written; multiplied
-    into the attention bias so null/stale positions read as masked, not
-    as garbage.
+    k/v: [L, P, G, H*Dh] in the module dtype — ONE layout, lane-dense,
+    that every serve program reads and writes in place. A token's K (or
+    V) is one row of H*Dh lanes, head h in lanes [h*Dh, (h+1)*Dh), at
+    [layer, page, offset]; a page is the [G, H*Dh] tile under it. Heads
+    ride the lane dimension because the TPU tiles an array's two minor
+    dimensions (8 x 128 words): a minor pair (H, Dh) = (20, 64) fits no
+    tile, so the client, the scatters and the kernel each picked
+    another padded layout for a 5-D slab and every program relaid the
+    whole slab out at its edges (PERF.md, PR 26). (G, H*Dh) =
+    (16, 1280) tiles exactly in bf16, row-major is everyone's layout,
+    and nothing is padded. Any new user of the slab follows the same
+    rule: write rows `pages.at[layer, page, offset].set(row[H*Dh])`,
+    read pages `pages[layer, page]` (or hand the whole slab and a
+    static layer to ops/pallas paged_attention) — never reshape the
+    slab itself to split heads; split the row or the gathered context.
+    The jitted step scatters one token row per active slot per dispatch
+    and reads each slot's table-worth back as its attention context.
+    valid: [P, G] float32 — 1.0 where a real (non-pad, active) token
+    was written; multiplied into the attention bias so null/stale
+    positions read as masked, not as garbage.
 
     kv_dtype="int8" stores k/v as int8 with per-page SYMMETRIC scales
     (the PR-7 EFInt8 convention: scale = amax/127, value = q * scale)
@@ -153,7 +167,9 @@ class KVPageSlab:
         self.geom = geom
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype == "int8"
-        shape = (layers, geom.pages, geom.page, heads, head_dim)
+        self.heads = heads
+        self.head_dim = head_dim
+        shape = (layers, geom.pages, geom.page, heads * head_dim)
         store = jnp.int8 if self.quantized else dtype
         self.k = jnp.zeros(shape, store)
         self.v = jnp.zeros(shape, store)
@@ -184,8 +200,9 @@ class KVPageSlab:
         The int8/f32 ratio is ~itemsize(f32)/1 (~4x for f32 models,
         the bench arm's >= 3.5x self-assert).
         """
-        L, _, _, H, Dh = self.k.shape
-        per_layer = 2 * (self.geom.context + 1) * H * Dh \
+        L = self.k.shape[0]
+        per_layer = 2 * (self.geom.context + 1) * self.heads \
+            * self.head_dim \
             * self.k.dtype.itemsize
         if self.quantized:
             per_layer += 2 * 4 * (self.geom.pages_per_slot + 1)

@@ -61,7 +61,9 @@ def _compile(fn, one_chip, *shapes):
 # (slots, q_len, heads, head_dim, page, max_pages, compute dtype, int8)
 # — the decode and prefill programs DecodeEngine builds for gpt-mini
 # (8 slots, page 16, Pmax = 512/16), for each page storage mode, plus
-# one wide point so the layout is not fitted to a toy
+# one wide point so the layout is not fitted to a toy. The kernel's
+# page operands are the slab whole, [layers, P, page, heads*head_dim]
+# (serve/pager.py KVPageSlab), read at a static layer
 PAGED_GEOMETRIES = {
     "decode-bf16": (8, 1, 4, 64, 16, 32, "bfloat16", False),
     "decode-f32": (8, 1, 4, 64, 16, 32, "float32", False),
@@ -71,10 +73,11 @@ PAGED_GEOMETRIES = {
     "prefill-int8": (1, 16, 4, 64, 16, 32, "bfloat16", True),
     "wide-decode-bf16": (8, 1, 16, 128, 16, 64, "bfloat16", False),
     # the largest context the VMEM gate still sends to the kernel at the
-    # wide geometry (bound 39.0 of the 40 MiB budget): what
+    # wide geometry (bound 39.4 of the 40 MiB budget): what
     # paged_eligible admits, the compiler must accept
     "wide-budget-edge-bf16": (2, 1, 16, 128, 16, 272, "bfloat16", False),
 }
+_PAGED_LAYERS = 2
 
 
 @pytest.mark.parametrize("name", sorted(PAGED_GEOMETRIES))
@@ -88,17 +91,173 @@ def test_paged_attention_compiles_for_v5e(one_chip, name):
     # the kernel 'auto' would pick on the chip is the one compiled here
     assert paged_eligible(G, q_len=T, heads=H, head_dim=D,
                           max_pages=Pmax, dtype=dtype, quantized=quantized)
-    P = S * Pmax + 1
+    L, P = _PAGED_LAYERS, S * Pmax + 1
     page_dtype = jnp.int8 if quantized else dtype
     hlo = _compile(
-        functools.partial(paged_attention, quantized=quantized,
-                          compute_dtype=dtype, impl="pallas"),
+        functools.partial(paged_attention, layer=L - 1,
+                          quantized=quantized, compute_dtype=dtype,
+                          impl="pallas"),
         one_chip,
-        ((S, T, H, D), dtype), ((P, G, H, D), page_dtype),
-        ((P, G, H, D), page_dtype), ((P,), jnp.float32),
-        ((P,), jnp.float32), ((S, Pmax), jnp.int32),
+        ((S, T, H, D), dtype), ((L, P, G, H * D), page_dtype),
+        ((L, P, G, H * D), page_dtype), ((L, P), jnp.float32),
+        ((L, P), jnp.float32), ((S, Pmax), jnp.int32),
         ((S, 1, T, Pmax * G), jnp.float32))
     assert "tpu_custom_call" in hlo
+
+
+# ------------------------------------------------ the serve programs
+
+# The four programs of serve/engine.py at the widths of the benchmark's
+# serve cell (GPT-2 large: 20 heads of 64, FFN 5120, vocabulary 50257,
+# context 1024; 8 slots, page 16, 513 pages), cut to a few layers so a
+# compile stays under 10 s. What they guard is the slab's ONE layout
+# (serve/pager.py KVPageSlab): before PR 26 every decode program relaid
+# the whole slab out six times and every prefill program four times
+# (71% of the device's busy time, PERF.md), and each layer's plane was
+# copied out for the kernel.
+_SERVE = dict(heads=20, head_dim=64, ffn=5120, vocab=50257, max_len=1024,
+              slots=8, page=16, chunk=16, steps=4, window=128)
+# name -> (builder, layers, bound in bytes on the temporaries the
+# program may ask for beside its arguments). Read (sandbox compile,
+# PR 26), bf16 / int8 pages: decode 133 / 133 MB, prefill 134 / 136,
+# multi-step 141 / 140, verify 430 / 355 (at 2 layers the compiler also
+# stages the 42 MB bf16 slab in VMEM), the cell's own 36-layer decode
+# program 183. 129 MB of each is the tied head's bf16 copy of
+# the float32 embedding [50257, 1280]; the verify program adds the
+# draft's float32 logits over its window (206 MB) and, by design, a
+# second copy of K and V (it scans twice from the input slab). The 5-D
+# slab read 363 MB for decode at 2 layers and 8.65 GB at 36: a relayout
+# of either slab is at least 42 MB here (int8, 2 layers), 756 MB at 36.
+# The deep case is there because depth changes what the compiler does:
+# at 24 layers and more it served the copy-on-write gather of whole
+# pages by copying the slab in lane chunks ([36, 513, 16, 384] x 3 and
+# [.., 128]), which no 2-layer compile shows.
+SERVE_PROGRAMS = {
+    "decode": ("decode", 2, 160e6),
+    "prefill": ("prefill", 4, 160e6),
+    "multi": ("multi", 3, 165e6),
+    "verify": ("verify", 2, 470e6),
+    "decode-36-layers": ("decode", 36, 400e6),
+}
+SERVE_CASES = [(name, kv) for name in SERVE_PROGRAMS
+               for kv in ("f32", "int8")
+               if (name, kv) != ("decode-36-layers", "int8")]
+
+
+def _serve_program(which, L, kv_dtype, sds):
+    """(fn, args, donate_argnums, slab shape) of one serve program at
+    the cell's widths and L layers, arguments as shapes on the
+    described chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeml_tpu.models import gpt
+    c = _SERVE
+
+    def trunk(hidden, heads, ffn, layers):
+        module = gpt.GPTModule(
+            vocab_size=c["vocab"], max_len=c["max_len"], hidden=hidden,
+            layers=layers, heads=heads, ffn=ffn, dropout=0.0,
+            dtype=jnp.bfloat16)
+        params = jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))["params"])
+        return module, jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), params)
+
+    module, params = trunk(c["heads"] * c["head_dim"], c["heads"],
+                           c["ffn"], L)
+    params = [params]                 # the leading, undonated arguments
+    S, G = c["slots"], c["page"]
+    Pmax = c["max_len"] // G
+    P = S * Pmax + 1
+    store = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
+    rows = (L, P, G, c["heads"] * c["head_dim"])
+    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+    slab = [sds(rows, store), sds(rows, store), sds((L, P), f32),
+            sds((L, P), f32), sds((P, G), f32)]
+    kw = dict(kv_dtype=kv_dtype, attn_impl="pallas")
+    if which == "decode":
+        fn = gpt.build_paged_decode_step(module, **kw)
+        rest = [sds((S,), i32), sds((S,), i32), sds((S, Pmax), i32),
+                sds((S,), i32), sds((S,), i32), sds((S,), f32),
+                sds((S,), f32), sds((S, 2), u32), sds((S,), i32),
+                sds((S,), i32), sds((S,), f32)]
+    elif which == "prefill":
+        C = c["chunk"]
+        fn = gpt.build_paged_prefill_step(module, C, **kw)
+        rest = [sds((C,), i32), sds((C,), i32), sds((Pmax,), i32),
+                sds((C,), i32), sds((C,), i32), sds((C,), f32)]
+    elif which == "multi":
+        fn = gpt.build_paged_multi_step_decode(module, c["steps"], **kw)
+        rest = [sds((S,), i32), sds((S,), i32), sds((S, Pmax), i32),
+                sds((S,), i32), sds((S,), f32), sds((S,), u32),
+                sds((S,), i32), sds((S,), i32)]
+    else:
+        draft, draft_params = trunk(256, 4, 1024, 2)
+        draft_params = [draft_params]
+        W = c["window"]
+        fn = gpt.build_paged_spec_verify_step(module, draft, c["steps"],
+                                              W, **kw)
+        params = params + draft_params
+        rest = [sds((S, W), i32), sds((S,), i32), sds((S, Pmax), i32),
+                sds((S,), i32), sds((S,), f32), sds((S,), u32),
+                sds((S,), i32)]
+    donate = tuple(range(len(params), len(params) + 5))
+    return fn, params + slab + rest, donate, rows
+
+
+def _result_shapes(hlo):
+    """(name, element type, dims, layout, opcode) of every array-valued
+    instruction in a compiled HLO text; layout is the braces' content up
+    to the memory space, e.g. '3,2,1,0:T(8,128)(2,1)'."""
+    import re
+    pat = re.compile(
+        r"^\s*(?:ROOT )?(%\S+) = (\w+)\[([0-9,]*)\]\{([^}]*)\} "
+        r"([\w-]+)\(", re.M)
+    return [(n, t, tuple(int(d) for d in dims.split(",") if d),
+             re.sub(r"S\(\d+\)$", "", lay), op)
+            for n, t, dims, lay, op in pat.findall(hlo)]
+
+
+@pytest.mark.parametrize("name,kv_dtype", SERVE_CASES,
+                         ids=["-".join(c) for c in SERVE_CASES])
+def test_serve_program_keeps_the_slab_in_place_on_v5e(one_chip, name,
+                                                      kv_dtype):
+    """Compiled for the described chip, a serve program (a) yields no
+    slab-shaped array in a layout other than its entry parameter's — no
+    relayout of the slab, anywhere; (b) materializes no per-layer
+    [pages, page_tokens, H*D] plane — the kernel indexes the slab by
+    layer — and no cut of every page of every layer (the slab copied
+    in lane chunks); (c) asks for temporaries under the stated bound; and still holds the
+    Pallas kernel."""
+    import jax
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    which, layers, temp_bound = SERVE_PROGRAMS[name]
+    fn, args, donate, rows = _serve_program(which, layers, kv_dtype, sds)
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    el = "s8" if kv_dtype == "int8" else "bf16"
+    shapes = [x for x in _result_shapes(hlo) if x[1] == el]
+    entry = {lay for _, _, dims, lay, op in shapes
+             if op == "parameter" and dims == rows}
+    assert len(entry) == 1, entry
+    relaid = [(n, lay, op) for n, _, dims, lay, op in shapes
+              if dims == rows and lay not in entry]
+    assert not relaid, relaid
+    # row-major and unpadded: heads*head_dim is the minor dimension
+    assert next(iter(entry)).startswith("3,2,1,0"), entry
+    planes = [(n, op) for n, _, dims, _, op in shapes
+              if dims == rows[1:] and op != "parameter"]
+    assert not planes, planes
+    chunks = [(n, dims, op) for n, _, dims, _, op in shapes
+              if dims != rows and dims[:2] == rows[:2]]
+    assert not chunks, chunks
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < temp_bound, temp
 
 
 # ---------------------------------------------------- flash attention

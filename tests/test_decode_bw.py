@@ -47,9 +47,15 @@ def _drive(engine, limit=10_000):
     return finished
 
 
+# the kernel suites read plane 1 of a 2-layer slab, so a kernel that
+# ignored its static layer index would read the wrong pages
+_LAYER = 1
+
+
 def _rand_paged(key, S, Pmax, G, H, D, dtype, T, quantized):
-    """Random paged-attention operands with realistic masking: page 0
-    reserved (tails), per-slot valid prefix, NEG_INF bias."""
+    """Random paged-attention operands with realistic masking, in the
+    slab's shape (pages [L, P, G, H*D], scales [L, P]): page 0 reserved
+    (tails), per-slot valid prefix, NEG_INF bias."""
     import jax
     import jax.numpy as jnp
 
@@ -58,20 +64,31 @@ def _rand_paged(key, S, Pmax, G, H, D, dtype, T, quantized):
     C = Pmax * G
     ks = jax.random.split(key, 6)
     q = jax.random.normal(ks[0], (S, T, H, D), jnp.float32).astype(dtype)
+
+    def slab(plane):
+        # the plane the kernel is asked for is drawn [.., H, D] and
+        # merged to rows of H*D lanes; the other plane holds its
+        # negation, finite and wrong
+        if plane.ndim == 4:
+            plane = plane.reshape(P, G, H * D)
+        return jnp.stack([-plane, plane])
+
     if quantized:
-        k_pages = jax.random.randint(ks[1], (P, G, H, D), -127, 128,
-                                     jnp.int32).astype(jnp.int8)
-        v_pages = jax.random.randint(ks[2], (P, G, H, D), -127, 128,
-                                     jnp.int32).astype(jnp.int8)
-        k_scale = jax.random.uniform(ks[3], (P,), jnp.float32, 0.001, 0.1)
-        v_scale = jax.random.uniform(ks[4], (P,), jnp.float32, 0.001, 0.1)
+        k_pages = slab(jax.random.randint(
+            ks[1], (P, G, H, D), -127, 128, jnp.int32).astype(jnp.int8))
+        v_pages = slab(jax.random.randint(
+            ks[2], (P, G, H, D), -127, 128, jnp.int32).astype(jnp.int8))
+        k_scale = slab(jax.random.uniform(ks[3], (P,), jnp.float32,
+                                          0.001, 0.1))
+        v_scale = slab(jax.random.uniform(ks[4], (P,), jnp.float32,
+                                          0.001, 0.1))
     else:
-        k_pages = jax.random.normal(ks[1], (P, G, H, D),
-                                    jnp.float32).astype(dtype)
-        v_pages = jax.random.normal(ks[2], (P, G, H, D),
-                                    jnp.float32).astype(dtype)
-        k_scale = jnp.zeros((P,), jnp.float32)
-        v_scale = jnp.zeros((P,), jnp.float32)
+        k_pages = slab(jax.random.normal(ks[1], (P, G, H, D),
+                                         jnp.float32).astype(dtype))
+        v_pages = slab(jax.random.normal(ks[2], (P, G, H, D),
+                                         jnp.float32).astype(dtype))
+        k_scale = jnp.zeros((2, P), jnp.float32)
+        v_scale = jnp.zeros((2, P), jnp.float32)
     # slot s holds s+1 pages, the rest of its table points at null 0
     tables = np.zeros((S, Pmax), np.int32)
     for s in range(S):
@@ -119,10 +136,11 @@ def test_pallas_paged_kernel_bit_identical_to_gather(seed, dtype_name, T):
     dtype = getattr(jnp, dtype_name)
     args = _rand_paged(jax.random.PRNGKey(seed), S=4, Pmax=4, G=8,
                        H=4, D=64, dtype=dtype, T=T, quantized=False)
-    ker = jax.jit(functools.partial(paged_attention, impl="pallas",
+    ker = jax.jit(functools.partial(paged_attention, layer=_LAYER,
+                                    impl="pallas",
                                     interpret=True))(*args)
     ref = jax.jit(functools.partial(
-        paged_attention, impl="gather"))(*args)
+        paged_attention, layer=_LAYER, impl="gather"))(*args)
     _assert_kernel_matches_gather(ker, ref, dtype)
 
 
@@ -144,11 +162,11 @@ def test_pallas_paged_kernel_int8_dequant_bit_identical(seed, dtype_name,
     args = _rand_paged(jax.random.PRNGKey(seed), S=3, Pmax=3, G=8,
                        H=2, D=32, dtype=dtype, T=T, quantized=True)
     ker = jax.jit(functools.partial(
-        paged_attention, quantized=True, compute_dtype=dtype,
-        impl="pallas", interpret=True))(*args)
+        paged_attention, layer=_LAYER, quantized=True,
+        compute_dtype=dtype, impl="pallas", interpret=True))(*args)
     ref = jax.jit(functools.partial(
-        paged_attention, quantized=True, compute_dtype=dtype,
-        impl="gather"))(*args)
+        paged_attention, layer=_LAYER, quantized=True,
+        compute_dtype=dtype, impl="gather"))(*args)
     _assert_kernel_matches_gather(ker, ref, dtype)
 
 
@@ -184,9 +202,10 @@ def test_paged_attention_validates_impl_and_geometry():
     args = _rand_paged(jax.random.PRNGKey(0), S=2, Pmax=2, G=4, H=2,
                        D=8, dtype=jnp.float32, T=1, quantized=False)
     with pytest.raises(ValueError, match="impl"):
-        paged_attention(*args, impl="mosaic")
+        paged_attention(*args, layer=_LAYER, impl="mosaic")
     with pytest.raises(ValueError, match="sublane"):
-        paged_attention(*args, impl="pallas", interpret=True)
+        paged_attention(*args, layer=_LAYER, impl="pallas",
+                        interpret=True)
 
 
 # --------------------------------------------------- engine-level parity
@@ -333,9 +352,9 @@ def test_int8_quantize_roundtrip_per_page_scales():
     from kubeml_tpu.models.gpt import _int8_write_decode
 
     L, P, G, H, D = 1, 3, 4, 2, 4
-    pages = jnp.zeros((L, P, G, H, D), jnp.int8)
+    pages = jnp.zeros((L, P, G, H * D), jnp.int8)
     scales = jnp.zeros((L, P), jnp.float32)
-    row0 = jnp.full((1, H, D), 0.5, jnp.float32)
+    row0 = jnp.full((1, H * D), 0.5, jnp.float32)
     pages, scales = _int8_write_decode(
         pages, scales, 0, row0, jnp.array([1]), jnp.array([0]))
     s0 = float(scales[0, 1])
@@ -344,7 +363,7 @@ def test_int8_quantize_roundtrip_per_page_scales():
     np.testing.assert_allclose(got, np.asarray(row0[0]), atol=s0 / 2)
     # a larger row grows the scale; row 0 is requantized, still within
     # half of the NEW step
-    row1 = jnp.full((1, H, D), 2.0, jnp.float32)
+    row1 = jnp.full((1, H * D), 2.0, jnp.float32)
     pages, scales = _int8_write_decode(
         pages, scales, 0, row1, jnp.array([1]), jnp.array([1]))
     s1 = float(scales[0, 1])
@@ -355,7 +374,7 @@ def test_int8_quantize_roundtrip_per_page_scales():
     np.testing.assert_allclose(got1, np.asarray(row1[0]), atol=s1 / 2)
     # page reuse: the first write of a page always lands at offset 0,
     # which resets the stale scale (no max against dead data)
-    tiny = jnp.full((1, H, D), 0.01, jnp.float32)
+    tiny = jnp.full((1, H * D), 0.01, jnp.float32)
     pages, scales = _int8_write_decode(
         pages, scales, 0, tiny, jnp.array([1]), jnp.array([0]))
     assert float(scales[0, 1]) == pytest.approx(0.01 / 127.0)
