@@ -329,6 +329,107 @@ def kernel_vs_gather(module, page, slots, chunk, seed, interpret):
     return out
 
 
+def latent_kernel_vs_plain(module, page, slots, seed, interpret):
+    """Max abs difference between the latent-page kernel
+    (ops/pallas/mla_paged_attention.py) and its plain gather path on
+    random operands at the engine's decode geometry, slots at different
+    lengths and one idle. Returns (max_abs_diff, max_abs_reference)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubeml_tpu.ops.pallas.mla_paged_attention import \
+        mla_paged_attention
+    S, Pmax = slots, module.max_len // page
+    C = Pmax * page
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    pad = module.row_lanes - module.latent_lanes
+    q = jax.random.normal(ks[0], (S, module.heads, module.latent_lanes),
+                          jnp.float32)
+    slab = jax.random.normal(ks[1], (2, S * Pmax + 1, page,
+                                     module.latent_lanes), jnp.float32)
+    q, slab = (jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+               .astype(module.dtype) for a in (q, slab))
+    tables = 1 + np.arange(S * Pmax, dtype=np.int32).reshape(S, Pmax)
+    lengths = np.minimum(C, (np.arange(S) + 1) * (C // S)).astype(np.int32)
+    lengths[0] = 0                       # an idle slot
+    if S > 1:
+        lengths[1] = 1
+    kw = dict(layer=1, value_lanes=module.kv_lora_rank, scale=0.1)
+    operands = (q, slab, jnp.asarray(tables), jnp.asarray(lengths))
+    ker = jax.jit(functools.partial(
+        mla_paged_attention, impl="pallas", interpret=interpret,
+        **kw))(*operands)
+    ref = jax.jit(functools.partial(
+        mla_paged_attention, impl="gather", **kw))(*operands)
+    ker, ref = (np.asarray(a, np.float32)[1:] for a in (ker, ref))
+    assert np.isfinite(ker).all() and np.isfinite(ref).all()
+    return float(np.abs(ker - ref).max()), float(np.abs(ref).max())
+
+
+def phase_serve_latent(args, on_tpu):
+    """The DeepSeek-V2 family (models/deepseek_v2.py) at a small size
+    through the engine itself: latent pages, the absorbed decode kernel,
+    the dropless expert layer of one share, both programs compiled once;
+    then the kernel against its plain path."""
+    import jax
+
+    from kubeml_tpu.models.deepseek_v2 import DeepSeekV2Module
+    from kubeml_tpu.serve.engine import DecodeEngine
+    from kubeml_tpu.serve.slots import GenerateRequest
+    module = DeepSeekV2Module() if args.tiny else DeepSeekV2Module(
+        vocab_size=4096, max_len=512, hidden=512, layers=3, heads=16,
+        q_lora_rank=192, kv_lora_rank=512, qk_nope_head_dim=64,
+        qk_rope_head_dim=64, v_head_dim=64, intermediate_size=1024,
+        moe_intermediate_size=256, n_routed_experts=32, n_held_experts=8,
+        n_group=8, topk_group=3, experts_per_tok=6)
+    variables = module.init(jax.random.PRNGKey(args.seed))
+    chunk = 32 if args.tiny else 128
+    eng = DecodeEngine(module, variables, slots=4, page=16,
+                       prefill_chunk=chunk)
+    rng = np.random.RandomState(args.seed)
+    n_new = 6 if args.tiny else 16
+    reqs = [GenerateRequest(rng.randint(1, module.vocab_size, n).tolist(),
+                            max_new_tokens=n_new, temperature=0.0, seed=0)
+            for n in (5, chunk + 9, 12)]
+    for r in reqs:
+        eng.attach(r)
+    while eng.active():
+        eng.step()
+    stats = eng.stats
+    emit(phase="serve", family="deepseek_v2", layers=module.layers,
+         hidden=module.hidden, heads=module.heads,
+         latent_lanes=module.latent_lanes, row_lanes=module.row_lanes,
+         held_experts=module.n_held_experts,
+         router_outputs=module.n_routed_experts,
+         outcomes=[r.outcome for r in reqs],
+         new_tokens=[len(r.tokens) for r in reqs],
+         attn_impl_decode=stats["attn_impl_decode"],
+         compiles={"decode": int(stats["compiles"]),
+                   "prefill": int(stats["prefill_compiles"])},
+         dispatches={"decode": int(stats["dispatches"]),
+                     "prefill": int(stats["prefill_dispatches"])},
+         moe_assignments=int(stats["moe_assignments"]),
+         moe_local_assignments=int(stats["moe_local_assignments"]),
+         moe_experts_touched=int(stats["moe_experts_touched"]))
+    assert all(r.outcome == "ok" and len(r.tokens) == n_new for r in reqs)
+    assert stats["compiles"] == 1 and stats["prefill_compiles"] == 1
+    assert stats["prefill_dispatches"] >= 2
+    assert 0 < stats["moe_local_assignments"] < stats["moe_assignments"]
+    want = "pallas" if on_tpu else "gather"
+    assert stats["attn_impl_decode"] == want, stats["attn_impl_decode"]
+    diff, ref = latent_kernel_vs_plain(module, eng.geom.page,
+                                       eng.geom.slots, args.seed,
+                                       interpret=not on_tpu)
+    bound = KERNEL_RTOL * max(1.0, ref)
+    emit(phase="serve", family="deepseek_v2",
+         latent_kernel_vs_plain_max_abs_diff=diff,
+         latent_kernel_vs_plain_bound=bound,
+         kernel_mode="mosaic" if on_tpu else "interpret")
+    assert diff <= bound, (diff, bound)
+
+
 def phase_serve(args, dep, on_tpu):
     import jax
 
@@ -432,6 +533,7 @@ def phase_serve(args, dep, on_tpu):
          kernel_mode="mosaic" if on_tpu else "interpret")
     assert all(d <= bound[c] for c, (d, _r) in diffs.items()), \
         (diffs, bound)
+    phase_serve_latent(args, on_tpu)
 
 
 # ------------------------------------------------------------- four chips

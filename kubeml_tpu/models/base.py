@@ -29,6 +29,7 @@ Models carry a flax `nn.Module`; variables are the flax variable dict
 from __future__ import annotations
 
 import abc
+import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -44,6 +45,94 @@ class InferenceInputError(ValueError):
     overlong prompt, ...). Serving layers translate exactly this type to
     the 4xx error envelope; any other exception from infer() stays a
     server fault (5xx)."""
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What a model family keeps per token in the serving plane's paged
+    cache (serve/pager.py KVPageSlab builds its arrays from this and
+    nothing else): `planes` arrays of `[layers, pages, page_tokens,
+    row_lanes]`, each token one row per plane and layer. `lanes` is what
+    a row means (GPT: heads * head_dim, twice: K and V; DeepSeek-V2: one
+    plane of 512 latent + 64 position lanes), `row_lanes` what it
+    occupies (>= lanes; 0 = lanes, unpadded), `dtype` the rows' dtype
+    under kv_dtype "f32". `sidecars`: the family's programs carry the
+    per-page int8 scales ([layers, pages] float32, one per plane), so
+    kv_dtype "int8" can be served. `validity`: they carry the shared
+    [pages, page_tokens] float32 validity plane (padding tokens masked
+    out of attention); a family without it masks by position alone."""
+
+    layers: int
+    planes: int
+    lanes: int
+    dtype: Any
+    row_lanes: int = 0
+    sidecars: bool = False
+    validity: bool = False
+
+    @property
+    def width(self) -> int:
+        return self.row_lanes or self.lanes
+
+
+class ServeFamily:
+    """What a model hands the serving engine (serve/engine.py): its
+    cache declaration and its paged programs. A module is servable when
+    it has a `serve_family()` method returning one of these; the engine
+    reads no other field of a module.
+
+    Program signatures, `state` being the slab's arrays in KVPageSlab's
+    order (the planes, then the sidecars, then the validity plane, each
+    only where the cache declares it), donated and returned in place:
+
+      decode_step(...)  -> step(params, *state, tokens[S], pos[S],
+          page_tables[S, Pmax], write_page[S], write_off[S], active[S],
+          temps[S], key_data[S, 2], copy_src[S], copy_dst[S], poison[S])
+          -> (next_tokens[S + len(step_counters)], bad[S], *state)
+      prefill_step(chunk, ...) -> prefill(params, *state, tokens[C],
+          pos[C], page_table[Pmax], write_pages[C], write_offs[C],
+          in_chunk[C]) -> state
+
+    `step_counters` names int32 counts the decode program appends to
+    its token row (read back in the same transfer); the engine sums
+    them into `stats` and puts the step's own on its `serve.step.emit`
+    phase record. The multi-step and speculative-verify programs are
+    optional: a family that has none is refused by name when a
+    deployment asks for them."""
+
+    name = ""
+    cache: CacheSpec
+    max_len = 0
+    pad_id = 0
+    step_counters: Tuple[str, ...] = ()
+
+    def decode_step(self, kv_dtype: str, attn_impl: str,
+                    attn_interpret: bool):
+        raise NotImplementedError
+
+    def prefill_step(self, chunk: int, kv_dtype: str, attn_impl: str,
+                     attn_interpret: bool):
+        raise NotImplementedError
+
+    def multi_step(self, steps: int, kv_dtype: str, attn_impl: str,
+                   attn_interpret: bool):
+        raise ValueError(
+            f"serve family {self.name!r} provides no multi-step decode "
+            f"program (decode_steps must stay 1)")
+
+    def spec_verify(self, draft: "ServeFamily", steps: int, window: int,
+                    kv_dtype: str, attn_impl: str, attn_interpret: bool):
+        raise ValueError(
+            f"serve family {self.name!r} provides no speculative verify "
+            f"program (no draft model can be configured)")
+
+    def attn_impls(self, page: int, max_pages: int, prefill_chunk: int,
+                   kv_dtype: str, attn_impl: str,
+                   attn_interpret: bool) -> Tuple[str, str]:
+        """Which implementation the decode and the prefill attention
+        take for this geometry ('off' where there is no prefill
+        program): what the engine prints as `attn_impl_*`."""
+        raise NotImplementedError
 
 
 class KubeModel(abc.ABC):

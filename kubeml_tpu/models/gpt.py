@@ -37,7 +37,8 @@ import optax
 from jax import lax
 
 from kubeml_tpu.models import register_model
-from kubeml_tpu.models.base import InferenceInputError, KubeModel
+from kubeml_tpu.models.base import (CacheSpec, InferenceInputError,
+                                    KubeModel, ServeFamily)
 from kubeml_tpu.parallel.tp import TRANSFORMER_TP_RULES
 from kubeml_tpu.ops.attention import masked_attention
 
@@ -330,6 +331,11 @@ class GPTModule(nn.Module):
     tp_axis: Optional[str] = None   # manual tensor-parallel mode
     attn_impl: str = "auto"         # 'auto' | 'flash' | 'reference'
     flash_interpret: bool = False   # pallas interpreter (CPU tests)
+
+    def serve_family(self) -> "GPTServeFamily":
+        """What the serving engine takes of this trunk: its per-head
+        K/V cache and the four paged programs below."""
+        return GPTServeFamily(self)
 
     @nn.compact
     def __call__(self, x, train: bool = False, decode: bool = False,
@@ -1127,6 +1133,60 @@ def build_paged_spec_verify_step(module: GPTModule,
                 v_scales, valid_pages)
 
     return verify
+
+
+class GPTServeFamily(ServeFamily):
+    """The GPT trunk as the serving engine sees it (models/base.py
+    ServeFamily): two cache planes (K, V) of heads * head_dim lanes
+    with int8 sidecars and the validity plane, and all four programs —
+    each one of the builders above, unchanged."""
+
+    name = "gpt"
+    pad_id = PAD_ID
+
+    def __init__(self, module: GPTModule):
+        self.module = module
+        self.max_len = module.max_len
+        self.cache = CacheSpec(layers=module.layers, planes=2,
+                               lanes=module.hidden, dtype=module.dtype,
+                               sidecars=True, validity=True)
+
+    def decode_step(self, kv_dtype, attn_impl, attn_interpret):
+        return build_paged_decode_step(self.module, kv_dtype, attn_impl,
+                                       attn_interpret)
+
+    def prefill_step(self, chunk, kv_dtype, attn_impl, attn_interpret):
+        return build_paged_prefill_step(self.module, chunk, kv_dtype,
+                                        attn_impl, attn_interpret)
+
+    def multi_step(self, steps, kv_dtype, attn_impl, attn_interpret):
+        return build_paged_multi_step_decode(self.module, steps, kv_dtype,
+                                             attn_impl, attn_interpret)
+
+    def spec_verify(self, draft, steps, window, kv_dtype, attn_impl,
+                    attn_interpret):
+        if not isinstance(draft, GPTServeFamily):
+            raise ValueError(
+                f"a {self.name!r} target verifies a {self.name!r} draft, "
+                f"not serve family {draft.name!r}")
+        return build_paged_spec_verify_step(
+            self.module, draft.module, steps, window, kv_dtype, attn_impl,
+            attn_interpret)
+
+    def attn_impls(self, page, max_pages, prefill_chunk, kv_dtype,
+                   attn_impl, attn_interpret):
+        # resolved by the SAME rule paged_attention applies at trace
+        # time (platform gate + shapes/dtype VMEM bound), so a silent
+        # fallback to the gather path shows up in the engine's stats
+        # (and in chip_smoke.py) instead of as an unexplained number
+        from kubeml_tpu.ops.pallas.paged_attention import resolve_impl
+        m = self.module
+        geometry = dict(page=page, heads=m.heads,
+                        head_dim=m.hidden // m.heads, max_pages=max_pages,
+                        dtype=m.dtype, quantized=kv_dtype == "int8")
+        return (resolve_impl(attn_impl, attn_interpret, q_len=1, **geometry),
+                resolve_impl(attn_impl, attn_interpret, q_len=prefill_chunk,
+                             **geometry) if prefill_chunk > 0 else "off")
 
 
 def _lm_per_example(logits: jax.Array, x: jax.Array) -> jax.Array:

@@ -1,5 +1,14 @@
 """Continuous-batching decode engine: slot state + the persistent steps.
 
+The engine serves any model FAMILY that declares its cache and hands
+over its paged programs (models/base.py ServeFamily, reached through
+`module.serve_family()`): the GPT trunk (models/gpt.py: per-head K/V
+pages, all four programs below) and DeepSeek-V2 (models/deepseek_v2.py:
+one plane of latent pages, decode and prefill). Slots, page tables,
+allocation, copy-on-write, the prefix cache and the step loop below are
+the same for every family; a family that lacks an optional program is
+refused by name when a deployment asks for it.
+
 An EXACT, documented inventory of jitted programs serves every stream
 (compile count pinned by tests/test_serving.py and
 tests/test_spec_decode.py, no matter how requests churn):
@@ -12,13 +21,13 @@ tests/test_spec_decode.py, no matter how requests churn):
                prompt lengths never recompile); built when
                prefill_chunk > 0.
   multi-step — K decode step bodies lax.scanned into one dispatch
-               (models/gpt.py build_paged_multi_step_decode); built
+               (the family's multi_step program); built
                when decode_steps > 1 and selected only in the
                all-decode steady state, where it cuts the host
                round-trip cost to dispatches_per_token == 1/K with
                bit-identical output.
   verify     — draft-propose + target-verify + rollback-replay
-               (build_paged_spec_verify_step); built when a draft
+               (the family's spec_verify); built when a draft
                module is configured. One dispatch emits the accepted
                prefix plus one bonus token; rejected tokens roll back
                positions, page-table cursors, and int8 page scales as
@@ -31,7 +40,8 @@ for, so the inventory above is exhaustive and recompilation-free.
 
 A token-budget scheduler in step() interleaves the two: each engine
 step spends at most `prefill_budget` prompt tokens on prefill chunks
-(FIFO over admission order), then runs one decode dispatch for the
+(FIFO over admission order; a dispatch is charged as a whole chunk,
+so by default a step runs one), then runs one decode dispatch for the
 streams that are past their prompt — so in-flight streams' inter-token
 latency stays bounded while new prompts load, instead of every stream
 stalling behind a 512-token prompt fed one token per dispatch.
@@ -68,12 +78,7 @@ import numpy as np
 
 from kubeml_tpu.metrics.ledger import CostLedger
 from kubeml_tpu.metrics.runtime import JitCompileTracker
-from kubeml_tpu.models.base import InferenceInputError
-from kubeml_tpu.models.gpt import (PAD_ID, build_paged_decode_step,
-                                   build_paged_multi_step_decode,
-                                   build_paged_prefill_step,
-                                   build_paged_spec_verify_step)
-from kubeml_tpu.ops.pallas.paged_attention import resolve_impl
+from kubeml_tpu.models.base import InferenceInputError, ServeFamily
 from kubeml_tpu.serve.flight import FlightRecorder
 from kubeml_tpu.serve.pager import (KVPageSlab, PageAllocator, PageGeometry,
                                     chain_hash)
@@ -173,6 +178,18 @@ SERVE_PHASE_KINDS = (
 )
 
 
+def _serve_family(module) -> ServeFamily:
+    """The module's serve family, or a ValueError that names what a
+    module must provide to be served (the PS turns it into a 4xx)."""
+    make = getattr(module, "serve_family", None)
+    if make is None:
+        raise ValueError(
+            f"{type(module).__name__} declares no serve family: a module "
+            f"is served when its serve_family() returns its cache "
+            f"declaration and paged programs (models/base.py ServeFamily)")
+    return make()
+
+
 class _Slot:
     """Host-side state of one occupied decode slot."""
 
@@ -209,7 +226,8 @@ class DecodeEngine:
     token per dispatch (the PR-6 path, kept as the parity reference).
     prefix_cache: share full prompt pages across requests by content
     hash (pager.py). prefill_budget: prompt tokens the scheduler may
-    spend on prefill per engine step (default: one chunk).
+    spend on prefill per engine step (default: one chunk), a dispatch
+    counting as a whole chunk: budget / chunk dispatches a step.
     """
 
     def __init__(self, module, variables, geom: Optional[PageGeometry] = None,
@@ -230,18 +248,22 @@ class DecodeEngine:
                 f"serve prefill chunk must be >= 0 (0 disables chunked "
                 f"prefill), got {prefill_chunk}")
         self.module = module
-        # KV storage mode + attention dispatch (pager.py / ops/pallas
-        # paged_attention): both are knobs of the two persistent
+        # the seam between the engine and a model: its cache
+        # declaration and its programs, nothing else of the module
+        self.family = _serve_family(module)
+        # KV storage mode + attention dispatch (pager.py / the family's
+        # attention kernel): both are knobs of the two persistent
         # programs, so they live here and every derived engine
         # (spawn_recovered, fleet re-spawn) must inherit them.
         self.kv_dtype = kv_dtype
         self.attn_impl = attn_impl
         self.attn_interpret = bool(attn_interpret)
+        family = self.family
         # validates module + kv_dtype + attn_impl
-        self._step_raw = build_paged_decode_step(
-            module, kv_dtype, attn_impl, self.attn_interpret)
+        self._step_raw = family.decode_step(
+            kv_dtype, attn_impl, self.attn_interpret)
         self.geom = geom or PageGeometry.for_module(
-            slots=slots, page=page, max_len=module.max_len)
+            slots=slots, page=page, max_len=family.max_len)
         self.clock = clock
         self.prefill_chunk = prefill_chunk
         self.prefix_cache = bool(prefix_cache)
@@ -250,19 +272,19 @@ class DecodeEngine:
         if self.prefill_budget < 1:
             raise ValueError(
                 f"prefill budget must be >= 1, got {self.prefill_budget}")
-        head_dim = module.hidden // module.heads
-        self.slab = KVPageSlab(self.geom, module.layers, module.heads,
-                               head_dim, module.dtype, kv_dtype=kv_dtype)
+        self.slab = KVPageSlab(self.geom, family.cache, kv_dtype=kv_dtype)
         self.pager = PageAllocator(self.geom)
         # donating the slab buffers keeps HBM flat across steps; the CPU
         # backend warns (donation unimplemented), so gate on backend
-        donate = () if jax.default_backend() == "cpu" else (1, 2, 3, 4, 5)
+        n_state = len(self.slab.state)
+        donate = () if jax.default_backend() == "cpu" \
+            else tuple(range(1, 1 + n_state))
         self._step = jax.jit(self._step_raw, donate_argnums=donate)
         self._prefill = None
         if prefill_chunk > 0:
             self._prefill = jax.jit(
-                build_paged_prefill_step(module, prefill_chunk, kv_dtype,
-                                         attn_impl, self.attn_interpret),
+                family.prefill_step(prefill_chunk, kv_dtype, attn_impl,
+                                    self.attn_interpret),
                 donate_argnums=donate)
         # decode accelerators: the multi-step scan program and the
         # speculative verify program — OPTIONAL members of the exact
@@ -277,9 +299,8 @@ class DecodeEngine:
         self._multi = None
         if decode_steps > 1:
             self._multi = jax.jit(
-                build_paged_multi_step_decode(
-                    module, decode_steps, kv_dtype, attn_impl,
-                    self.attn_interpret),
+                family.multi_step(decode_steps, kv_dtype, attn_impl,
+                                  self.attn_interpret),
                 donate_argnums=donate)
         # speculation depth K: decode_steps when raised past 1, else 4
         # proposals per dispatch; the verify window is the largest
@@ -294,16 +315,16 @@ class DecodeEngine:
             if draft_variables is None:
                 raise ValueError(
                     "serving with a draft module needs draft_variables")
+            draft_family = _serve_family(draft_module)
             self.spec_steps = decode_steps if decode_steps > 1 else 4
-            self.spec_window = min(module.max_len, draft_module.max_len,
+            self.spec_window = min(family.max_len, draft_family.max_len,
                                    self.geom.context)
             verify_donate = () if jax.default_backend() == "cpu" \
-                else (2, 3, 4, 5, 6)
+                else tuple(range(2, 2 + n_state))
             self._verify = jax.jit(
-                build_paged_spec_verify_step(
-                    module, draft_module, self.spec_steps,
-                    self.spec_window, kv_dtype, attn_impl,
-                    self.attn_interpret),
+                family.spec_verify(
+                    draft_family, self.spec_steps, self.spec_window,
+                    kv_dtype, attn_impl, self.attn_interpret),
                 donate_argnums=verify_donate)
             self._draft_params = jax.device_put(draft_variables["params"])
         # weight generations: params are per-slot DATA, not program
@@ -359,6 +380,11 @@ class DecodeEngine:
         # no-op, so a wedged loop thread that wakes after the swap can
         # never double-emit tokens the replacement engine re-decodes
         self._abandoned = False
+        # token events held back until the next decode enqueue (see
+        # _emit_token)
+        self._outbox: List[tuple] = []
+        self._outbox_lock = threading.Lock()
+        self._outbox_stale = False
         self._step_count = 0
         self._dispatch_wall_s = 0.0   # cumulative prefill+decode wall time
         self._shed_count = 0          # KV-exhaustion sheds (flight 'kind')
@@ -381,22 +407,19 @@ class DecodeEngine:
             "draft_tokens": 0, "accepted_tokens": 0,
             "rejected_tokens": 0,
         }
+        # counts the family's decode program appends to its token row
+        # (ServeFamily.step_counters), summed over decode dispatches
+        for name in family.step_counters:
+            self.stats[name] = 0
         # which implementation each attention call site takes, resolved
-        # by the SAME rule paged_attention applies at trace time
-        # (ops/pallas/paged_attention.resolve_impl: platform gate +
-        # shapes/dtype VMEM bound) — so a silent fallback to the gather
-        # path shows up here (and in chip_smoke.py) instead of as an
-        # unexplained number
-        geometry = dict(page=self.geom.page, heads=module.heads,
-                        head_dim=head_dim,
-                        max_pages=self.geom.pages_per_slot,
-                        dtype=module.dtype,
-                        quantized=kv_dtype == "int8")
-        self.stats["attn_impl_decode"] = resolve_impl(
-            attn_impl, self.attn_interpret, q_len=1, **geometry)
-        self.stats["attn_impl_prefill"] = resolve_impl(
-            attn_impl, self.attn_interpret, q_len=prefill_chunk,
-            **geometry) if prefill_chunk > 0 else "off"
+        # by the family with the SAME rule its kernel's dispatch applies
+        # at trace time — so a silent fallback to the gather path shows
+        # up here (and in chip_smoke.py) instead of as an unexplained
+        # number
+        (self.stats["attn_impl_decode"],
+         self.stats["attn_impl_prefill"]) = family.attn_impls(
+            self.geom.page, self.geom.pages_per_slot, prefill_chunk,
+            kv_dtype, attn_impl, self.attn_interpret)
 
     # ------------------------------------------------------------- capacity
     @property
@@ -562,14 +585,14 @@ class DecodeEngine:
         feeding trailing pads would burn context on masked garbage;
         interior pads stay, as masked-but-position-holding context."""
         prompt = [int(t) for t in prompt]
-        while prompt and prompt[-1] == PAD_ID:
+        while prompt and prompt[-1] == self.family.pad_id:
             prompt.pop()
         if not prompt:
             raise InferenceInputError(
                 "prompt needs at least one non-pad token")
         if max_new_tokens < 1:
             raise InferenceInputError("max_new_tokens must be >= 1")
-        limit = min(self.geom.context, self.module.max_len)
+        limit = min(self.geom.context, self.family.max_len)
         if len(prompt) + max_new_tokens > limit:
             raise InferenceInputError(
                 f"prompt ({len(prompt)}) + max_new_tokens "
@@ -698,6 +721,7 @@ class DecodeEngine:
         self._instant(kind, slot.req.finished_at, slot.req,
                       outcome=outcome, tokens=len(slot.req.tokens),
                       **({"error": error} if error else {}))
+        self.flush_events(only=slot.req)
         slot.req.finish(outcome, error)
         # last reader of a superseded weight generation detaching frees
         # that generation's params and cache partition
@@ -791,17 +815,14 @@ class DecodeEngine:
             write_pages[j] = self._tables[s, p // G]
             write_offs[j] = p % G
             in_chunk[j] = 1.0
-        args = (self._params_by_gen[slot.gen],
-                self.slab.k, self.slab.v, self.slab.k_scale,
-                self.slab.v_scale, self.slab.valid,
+        args = (self._params_by_gen[slot.gen], *self.slab.state,
                 jnp.asarray(tokens), jnp.asarray(pos),
                 jnp.asarray(self._tables[s]), jnp.asarray(write_pages),
                 jnp.asarray(write_offs), jnp.asarray(in_chunk))
         self._ledger_capture("serve.prefill", self._prefill, args)
         before = self._prefill._cache_size()
         t0 = self.clock()
-        (self.slab.k, self.slab.v, self.slab.k_scale, self.slab.v_scale,
-         self.slab.valid) = self._prefill(*args)
+        self.slab.state = tuple(self._prefill(*args))
         compiled = self._prefill._cache_size() > before
         t1 = self.clock()
         self.compile_tracker.note(compiled, t1 - t0,
@@ -832,8 +853,55 @@ class DecodeEngine:
         swapped a replacement in. Step becomes a no-op, so the old loop
         thread — possibly still wedged inside a fault hook — can wake
         at any time without double-emitting tokens the new engine is
-        re-decoding; it also unblocks ServeFaultPlan.maybe_wedge."""
+        re-decoding; it also unblocks ServeFaultPlan.maybe_wedge. Token
+        events still held back go out now: their tokens are in
+        `req.tokens`, which a resumed stream never emits again."""
         self._abandoned = True
+        self.flush_events()
+
+    # ------------------------------------------------- deferred events
+    def _emit_token(self, req: GenerateRequest, tok: int) -> None:
+        """The token joins `req.tokens` now and its event waits in the
+        outbox. Handing 64 streams their tokens wakes 64 handler threads
+        that want the interpreter lock the loop thread holds: done
+        inside the emit phase, with the device idle, it cost the loop
+        7.9 ms a step at 64 slots (0.45 ms with nobody listening;
+        PERF.md, PR 27). Held back until the next step's decode program
+        is enqueued, the handlers run while the device does. A caller
+        that steps the engine itself and stops mid-stream calls
+        flush_events() for the last step's tokens."""
+        with self._outbox_lock:
+            if self._abandoned:
+                # a step still running on an engine that was given up:
+                # nothing flushes after it, so hand over at once, behind
+                # whatever this request still has waiting
+                self._hand_over(req)
+                req.emit_token(tok)
+            else:
+                req.emit_token(tok, hold=True)
+                self._outbox.append((req, tok))
+
+    def _hand_over(self, only: Optional[GenerateRequest] = None) -> None:
+        """Outbox -> streams, in emission order (lock held): all of it,
+        or one request's."""
+        if only is None:
+            box, self._outbox = self._outbox, []
+            self._outbox_stale = False
+        else:
+            box = [e for e in self._outbox if e[0] is only]
+            if box:
+                self._outbox = [e for e in self._outbox
+                                if e[0] is not only]
+        for req, tok in box:
+            req.post_token(tok)
+
+    def flush_events(self, only: Optional[GenerateRequest] = None) -> None:
+        """Hand the held-back token events to their streams: all of
+        them, or one request's (before its terminal event). Called once
+        a step has enqueued its decode program, by the serving loop
+        before it parks, and by abandon()."""
+        with self._outbox_lock:
+            self._hand_over(only)
 
     def spawn_recovered(self) -> "DecodeEngine":
         """Build this engine's replacement after a crash or wedge:
@@ -891,9 +959,15 @@ class DecodeEngine:
             self.stats["generated_tokens"], self.stats["cow_splits"],
             self._dispatch_wall_s, self._shed_count,
             self.stats["deadline_expired"])
+        # events the last step's emit held back go out once this step's
+        # decode program is enqueued; a step that enqueues none hands
+        # them over as it returns
+        self._outbox_stale = bool(self._outbox)
         try:
             return self._step_inner(exclude)
         finally:
+            if self._outbox_stale:
+                self.flush_events()
             if mark is not None:
                 self._record_flight(mark)
 
@@ -1011,7 +1085,7 @@ class DecodeEngine:
             if slot.req.first_token_at is None:
                 slot.req.first_token_at = t1
                 self._note_first_token(slot, t1)
-            slot.req.emit_token(tok)
+            self._emit_token(slot.req, tok)
             self.stats["generated_tokens"] += 1
             n_out = len(slot.req.tokens)
             if self.tracer is not None and n_out > 1 \
@@ -1071,8 +1145,7 @@ class DecodeEngine:
                     eos_ids[s] = slot.req.eos_id
                 budgets[s] = slot.req.max_new_tokens - len(slot.req.tokens)
             args = (self._params_by_gen[self.weight_generation],
-                    self.slab.k, self.slab.v, self.slab.k_scale,
-                    self.slab.v_scale, self.slab.valid,
+                    *self.slab.state,
                     jnp.asarray(tokens), jnp.asarray(pos),
                     jnp.asarray(self._tables), jnp.asarray(live),
                     jnp.asarray(temps), jnp.asarray(seeds),
@@ -1082,8 +1155,8 @@ class DecodeEngine:
         with phase("serve.step.enqueue", step=step) as span:
             before = self._multi._cache_size()
             t0 = self.clock()
-            (toks, bads, self.slab.k, self.slab.v, self.slab.k_scale,
-             self.slab.v_scale, self.slab.valid) = self._multi(*args)
+            toks, bads, *state = self._multi(*args)
+            self.slab.state = tuple(state)
             compiled = self._multi._cache_size() > before
             t1 = self.clock()
             span["compiled"] = int(compiled)
@@ -1094,6 +1167,7 @@ class DecodeEngine:
             self.stats["multi_step_dispatches"] += 1
             self.stats["multi_step_compiles"] += int(compiled)
             self.stats["occupancy_sum"] += len(members)
+            self.flush_events()
         with phase("serve.step.readback", step=step):
             toks_host = np.asarray(toks)
             bads_host = np.asarray(bads)
@@ -1162,9 +1236,7 @@ class DecodeEngine:
                 seeds[s] = np.uint32(slot.req.seed & 0xFFFFFFFF)
                 wlen_arr[s] = wlens[s]
             args = (self._params_by_gen[self.weight_generation],
-                    self._draft_params,
-                    self.slab.k, self.slab.v, self.slab.k_scale,
-                    self.slab.v_scale, self.slab.valid,
+                    self._draft_params, *self.slab.state,
                     jnp.asarray(window), jnp.asarray(pos),
                     jnp.asarray(self._tables), jnp.asarray(live),
                     jnp.asarray(temps), jnp.asarray(seeds),
@@ -1174,8 +1246,8 @@ class DecodeEngine:
         with phase("serve.step.enqueue", step=step) as span:
             before = self._verify._cache_size()
             t0 = self.clock()
-            (picks, bads, acc, self.slab.k, self.slab.v, self.slab.k_scale,
-             self.slab.v_scale, self.slab.valid) = self._verify(*args)
+            picks, bads, acc, *state = self._verify(*args)
+            self.slab.state = tuple(state)
             compiled = self._verify._cache_size() > before
             t1 = self.clock()
             span["compiled"] = int(compiled)
@@ -1186,6 +1258,7 @@ class DecodeEngine:
             self.stats["verify_dispatches"] += 1
             self.stats["verify_compiles"] += int(compiled)
             self.stats["occupancy_sum"] += len(members)
+            self.flush_events()
         with phase("serve.step.readback", step=step):
             picks_host = np.asarray(picks)
             bads_host = np.asarray(bads)
@@ -1281,7 +1354,16 @@ class DecodeEngine:
                         stalled.append(s)
                         break
                     progressed = True
-                    budget -= n
+                    # a dispatch costs a whole chunk's time whatever
+                    # part of it holds prompt tokens (the shape is
+                    # static: the weights are read once either way), so
+                    # it is charged as one. Charged by its tokens, a
+                    # prompt's short last chunk left budget for a second
+                    # and a third dispatch in the same step, and the
+                    # gap between tokens had a rare third mode that
+                    # its 95th percentile sat on the edge of (PERF.md,
+                    # PR 27: 102 or 105.5 ms by the seed)
+                    budget -= max(n, self.prefill_chunk)
                 if budget <= 0:
                     break
 
@@ -1404,9 +1486,7 @@ class DecodeEngine:
                         copy_src[s], copy_dst[s] = cow[s]
 
                 step_args = (
-                    self._params_by_gen[gen],
-                    self.slab.k, self.slab.v, self.slab.k_scale,
-                    self.slab.v_scale, self.slab.valid,
+                    self._params_by_gen[gen], *self.slab.state,
                     jnp.asarray(tokens), jnp.asarray(pos),
                     jnp.asarray(self._tables), jnp.asarray(write_page),
                     jnp.asarray(write_off), jnp.asarray(active),
@@ -1417,9 +1497,8 @@ class DecodeEngine:
             with phase("serve.step.enqueue", step=step) as span:
                 before = self._step._cache_size()
                 t0 = self.clock()
-                (nxt, bad, self.slab.k, self.slab.v, self.slab.k_scale,
-                 self.slab.v_scale, self.slab.valid) = \
-                    self._step(*step_args)
+                nxt, bad, *state = self._step(*step_args)
+                self.slab.state = tuple(state)
                 compiled = self._step._cache_size() > before
                 t1 = self.clock()
                 span["compiled"] = int(compiled)
@@ -1435,11 +1514,20 @@ class DecodeEngine:
                 # deterministic, no timers)
                 self.stats["kv_bytes"] += \
                     len(members) * self.slab.decode_bytes_per_token
+                self.flush_events()
             with phase("serve.step.readback", step=step):
                 nxt_host = np.asarray(nxt)
                 bad_host = np.asarray(bad)
 
-            with phase("serve.step.emit", step=step):
+            with phase("serve.step.emit", step=step) as emitted:
+                # the family's own counts ride behind the S picks, in
+                # the transfer that brought them; the step's go on this
+                # phase record, where a reader reaches them after the
+                # deployment has stopped (utils/trace.py phases())
+                for name, n in zip(self.family.step_counters,
+                                   nxt_host[S:]):
+                    self.stats[name] += int(n)
+                    emitted[name] = int(n)
                 gen_before_emit = self.stats["generated_tokens"]
                 for s in members:
                     slot = self._slots[s]
@@ -1472,7 +1560,7 @@ class DecodeEngine:
                     if slot.req.first_token_at is None:
                         slot.req.first_token_at = t1
                         self._note_first_token(slot, t1)
-                    slot.req.emit_token(tok)
+                    self._emit_token(slot.req, tok)
                     self.stats["generated_tokens"] += 1
                     n_out = len(slot.req.tokens)
                     if self.tracer is not None and n_out > 1 \

@@ -51,6 +51,8 @@ from typing import Dict, List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from kubeml_tpu.models.base import CacheSpec
+
 
 def chain_hash(prefix_digest: bytes, tokens: Sequence[int]) -> bytes:
     """Rolling content hash for prefix caching: the key of page i is
@@ -122,65 +124,97 @@ KV_DTYPES = ("f32", "int8")
 
 
 class KVPageSlab:
-    """The device-resident arrays: K/V pages for every layer plus the
-    shared per-page validity plane and (int8 mode) per-page scales.
+    """The device-resident arrays of a model family's cache, built from
+    its declaration (models/base.py CacheSpec): `planes` page arrays for
+    every layer and, where the family's programs carry them, the
+    per-page int8 scales and the shared validity plane. GPT declares
+    two planes (K, V) of H*Dh lanes with both; DeepSeek-V2 one plane of
+    latent rows with neither.
 
-    k/v: [L, P, G, H*Dh] in the module dtype — ONE layout, lane-dense,
-    that every serve program reads and writes in place. A token's K (or
-    V) is one row of H*Dh lanes, head h in lanes [h*Dh, (h+1)*Dh), at
-    [layer, page, offset]; a page is the [G, H*Dh] tile under it. Heads
-    ride the lane dimension because the TPU tiles an array's two minor
-    dimensions (8 x 128 words): a minor pair (H, Dh) = (20, 64) fits no
-    tile, so the client, the scatters and the kernel each picked
-    another padded layout for a 5-D slab and every program relaid the
-    whole slab out at its edges (PERF.md, PR 26). (G, H*Dh) =
-    (16, 1280) tiles exactly in bf16, row-major is everyone's layout,
-    and nothing is padded. Any new user of the slab follows the same
-    rule: write rows `pages.at[layer, page, offset].set(row[H*Dh])`,
-    read pages `pages[layer, page]` (or hand the whole slab and a
-    static layer to ops/pallas paged_attention) — never reshape the
-    slab itself to split heads; split the row or the gathered context.
+    planes[i]: [L, P, G, row_lanes] in the cache dtype — ONE layout,
+    lane-dense, that every serve program reads and writes in place. A
+    token is one row of lanes per plane at [layer, page, offset]; a
+    page is the [G, row_lanes] tile under it. For GPT a row is H*Dh
+    lanes, head h in lanes [h*Dh, (h+1)*Dh). Heads ride the lane
+    dimension because the TPU tiles an array's two minor dimensions
+    (8 x 128 words): a minor pair (H, Dh) = (20, 64) fits no tile, so
+    the client, the scatters and the kernel each picked another padded
+    layout for a 5-D slab and every program relaid the whole slab out
+    at its edges (PERF.md, PR 26). (G, H*Dh) = (16, 1280) tiles exactly
+    in bf16, row-major is everyone's layout, and nothing is padded. A
+    family whose row is no whole number of lane tiles (DeepSeek-V2's
+    576) declares the padded `row_lanes` itself (640) and keeps the pad
+    lanes zero, so the layout stays the one everybody sees. Any new
+    user of the slab follows the same rule: write rows
+    `pages.at[layer, page, offset].set(row)`, read pages
+    `pages[layer, page]` (or hand the whole slab and a static layer to
+    the family's kernel) — never reshape the slab itself; split the row
+    or the gathered context.
     The jitted step scatters one token row per active slot per dispatch
     and reads each slot's table-worth back as its attention context.
-    valid: [P, G] float32 — 1.0 where a real (non-pad, active) token
-    was written; multiplied into the attention bias so null/stale
-    positions read as masked, not as garbage.
+    valid (cache.validity): [P, G] float32 — 1.0 where a real (non-pad,
+    active) token was written; multiplied into the attention bias so
+    null/stale positions read as masked, not as garbage.
 
-    kv_dtype="int8" stores k/v as int8 with per-page SYMMETRIC scales
-    (the PR-7 EFInt8 convention: scale = amax/127, value = q * scale)
-    in k_scale/v_scale [L, P] float32 sidecars. The sidecars exist in
-    both modes (all-zero and inert under "f32") so the decode/prefill
-    step signatures — and therefore the two-compile pin — are identical
-    across kv dtypes. Scales ride every page lifecycle event with their
-    page: copy-on-write duplicates them in the same dispatch, prefix
-    hits share them (the page id indexes both slab and sidecar), and
-    eviction/drop_generation need no device work — a reused page's
-    first write (offset 0) resets its scale on device.
+    kv_dtype="int8" (cache.sidecars only; a family without them is
+    refused by name) stores the planes as int8 with per-page SYMMETRIC
+    scales (the PR-7 EFInt8 convention: scale = amax/127, value =
+    q * scale) in [L, P] float32 sidecars, one per plane. The sidecars
+    exist in both modes (all-zero and inert under "f32") so the
+    decode/prefill step signatures — and therefore the two-compile pin
+    — are identical across kv dtypes. Scales ride every page lifecycle
+    event with their page: copy-on-write duplicates them in the same
+    dispatch, prefix hits share them (the page id indexes both slab and
+    sidecar), and eviction/drop_generation need no device work — a
+    reused page's first write (offset 0) resets its scale on device.
+
+    `state` is all of it as the programs take and return it (donated):
+    the planes, then the sidecars, then the validity plane. k, v,
+    k_scale, v_scale and valid name its parts for a two-plane cache.
     """
 
-    def __init__(self, geom: PageGeometry, layers: int, heads: int,
-                 head_dim: int, dtype=jnp.bfloat16, kv_dtype: str = "f32"):
+    def __init__(self, geom: PageGeometry, cache=None, heads: int = 0,
+                 head_dim: int = 0, dtype=jnp.bfloat16,
+                 kv_dtype: str = "f32", layers: int = 0):
+        if not isinstance(cache, CacheSpec):
+            # the per-head K/V cache by its sizes (layers, heads,
+            # head_dim, dtype): what this class took before families
+            # declared their caches
+            cache = CacheSpec(layers=int(cache or layers), planes=2,
+                              lanes=heads * head_dim, dtype=dtype,
+                              sidecars=True, validity=True)
         if kv_dtype not in KV_DTYPES:
             raise ValueError(
                 f"serve kv_dtype must be one of {KV_DTYPES}, "
                 f"got {kv_dtype!r}")
         self.geom = geom
+        self.cache = cache
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype == "int8"
-        self.heads = heads
-        self.head_dim = head_dim
-        shape = (layers, geom.pages, geom.page, heads * head_dim)
-        store = jnp.int8 if self.quantized else dtype
-        self.k = jnp.zeros(shape, store)
-        self.v = jnp.zeros(shape, store)
-        self.k_scale = jnp.zeros((layers, geom.pages), jnp.float32)
-        self.v_scale = jnp.zeros((layers, geom.pages), jnp.float32)
-        self.valid = jnp.zeros((geom.pages, geom.page), jnp.float32)
+        if self.quantized and not cache.sidecars:
+            raise ValueError(
+                "this model family's cache declares no int8 scale "
+                "sidecars: kv_dtype 'int8' cannot be served for it")
+        shape = (cache.layers, geom.pages, geom.page, cache.width)
+        store = jnp.int8 if self.quantized else cache.dtype
+        state = [jnp.zeros(shape, store) for _ in range(cache.planes)]
+        if cache.sidecars:
+            state += [jnp.zeros((cache.layers, geom.pages), jnp.float32)
+                      for _ in range(cache.planes)]
+        if cache.validity:
+            state.append(jnp.zeros((geom.pages, geom.page), jnp.float32))
+        self.state = tuple(state)
+
+    # the parts of a two-plane cache with sidecars and validity (GPT's)
+    k = property(lambda self: self.state[0])
+    v = property(lambda self: self.state[1])
+    k_scale = property(lambda self: self.state[2])
+    v_scale = property(lambda self: self.state[3])
+    valid = property(lambda self: self.state[4])
 
     @property
     def device_bytes(self) -> int:
-        return int(self.k.nbytes + self.v.nbytes + self.valid.nbytes
-                   + self.k_scale.nbytes + self.v_scale.nbytes)
+        return int(sum(a.nbytes for a in self.state))
 
     @property
     def decode_bytes_per_token(self) -> int:
@@ -190,23 +224,22 @@ class KVPageSlab:
         on the CPU tier, with no chip attached).
 
         One decode dispatch row reads the slot's whole context through
-        the page table (K and V, every layer), writes one token row
+        the page table (every plane, every layer), writes one token row
         back, and in int8 mode additionally moves the per-page scale
-        sidecars — so per decoded token:
+        sidecars — so per decoded token, for a cache of N planes:
 
-            L * (2*(C+1)*H*Dh*itemsize  [context read + row write]
-                 + int8? 2*4*(Pmax+1))  [scale reads + scale write]
+            L * (N*(C+1)*lanes*itemsize  [context read + row write]
+                 + int8? N*4*(Pmax+1))   [scale reads + scale write]
 
         The int8/f32 ratio is ~itemsize(f32)/1 (~4x for f32 models,
         the bench arm's >= 3.5x self-assert).
         """
-        L = self.k.shape[0]
-        per_layer = 2 * (self.geom.context + 1) * self.heads \
-            * self.head_dim \
-            * self.k.dtype.itemsize
+        cache = self.cache
+        per_layer = cache.planes * (self.geom.context + 1) * cache.lanes \
+            * self.state[0].dtype.itemsize
         if self.quantized:
-            per_layer += 2 * 4 * (self.geom.pages_per_slot + 1)
-        return int(L * per_layer)
+            per_layer += cache.planes * 4 * (self.geom.pages_per_slot + 1)
+        return int(cache.layers * per_layer)
 
 
 class PageAllocator:

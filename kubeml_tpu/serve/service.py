@@ -422,6 +422,8 @@ class ServeService:
             deadline = time.monotonic() + 5.0
             while self._stepping and time.monotonic() < deadline:
                 self._cv.wait(0.05)
+            # what the in-flight step emitted after abandon() flushed
+            engine.flush_events()
             harvested = []
             for s in range(engine.slot_count):
                 slot = engine._slots[s]
@@ -528,6 +530,10 @@ class ServeService:
                     self._admit(engine)
                     self._stepping = True
             if idle:
+                # the engine holds a step's token events back until its
+                # next decode program is enqueued (engine.py
+                # _emit_token); none is coming, so hand them over
+                engine.flush_events()
                 self._publish()
                 with phase("serve.loop.wait", model=model,
                            step=engine._step_count + 1), self._cv:
@@ -582,6 +588,7 @@ class ServeService:
                     req = slot.req
                     engine.release(s, "error", msg)
                     self._terminal(req, None)
+        engine.flush_events()
         self._publish()
 
     def _idle(self, engine: DecodeEngine) -> bool:

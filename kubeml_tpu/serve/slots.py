@@ -135,8 +135,15 @@ class GenerateRequest:
                 return
 
     # ------------------------------------------------------------ engine side
-    def emit_token(self, token: int) -> None:
+    def emit_token(self, token: int, hold: bool = False) -> None:
+        """The token joins `tokens`; its event goes to the stream now,
+        or with `hold` when the engine calls post_token (engine.py
+        _emit_token)."""
         self.tokens.append(int(token))
+        if not hold:
+            self.post_token(token)
+
+    def post_token(self, token: int) -> None:
         self.events.put({"token": int(token)})
 
     def finish(self, outcome: str, error: Optional[str] = None) -> None:

@@ -10,7 +10,11 @@ prescribes.
 
 Format: one .npz of flattened variable leaves keyed by '/'-joined tree
 paths + a manifest.json (model name, dataset, dtypes). Self-describing —
-restore needs no template pytree.
+restore needs no template pytree. A bfloat16 leaf is written as its 16
+bits (uint16) and named in the manifest's `bfloat16_leaves`: numpy's
+.npy format has no bfloat16 and would write a void type that comes back
+as neither, and a model served in bfloat16 (models/deepseek_v2.py) must
+reach the engine in it, bit for bit.
 """
 
 from __future__ import annotations
@@ -67,8 +71,15 @@ def save_checkpoint(job_id: str, variables: PyTree, manifest: dict,
     if os.path.isdir(tmp):
         shutil.rmtree(tmp)
     os.makedirs(tmp)
-    np.savez(os.path.join(tmp, "weights.npz"), **_flatten(variables))
+    flat = _flatten(variables)
+    bf16 = sorted(k for k, a in flat.items() if a.dtype == jnp.bfloat16)
+    for k in bf16:
+        flat[k] = flat[k].view(np.uint16)
+    np.savez(os.path.join(tmp, "weights.npz"), **flat)
+    del flat
     manifest = dict(manifest, job_id=job_id, saved_at=time.time())
+    if bf16:
+        manifest["bfloat16_leaves"] = bf16
     with open(os.path.join(tmp, "manifest.json"), "w") as f:
         json.dump(manifest, f)
     # crash-safe publish: at EVERY instant either the current dir or
@@ -135,8 +146,11 @@ def load_checkpoint(job_id: str, root: Optional[str] = None
         try:
             with open(os.path.join(d, "manifest.json")) as f:
                 manifest = json.load(f)
+            bf16 = set(manifest.get("bfloat16_leaves", ()))
             with np.load(os.path.join(d, "weights.npz")) as z:
-                variables = _unflatten({k: z[k] for k in z.files})
+                variables = _unflatten(
+                    {k: z[k].view(jnp.bfloat16) if k in bf16 else z[k]
+                     for k in z.files})
             return variables, manifest
         except (OSError, ValueError) as e:
             if attempt:

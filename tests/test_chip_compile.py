@@ -260,6 +260,119 @@ def test_serve_program_keeps_the_slab_in_place_on_v5e(one_chip, name,
     assert temp < temp_bound, temp
 
 
+# ------------------------------------------- DeepSeek-V2 (latent pages)
+
+# (slots, heads, row lanes, value lanes, page, max_pages): the cell
+# deepseek-v2-ep4-serve.decode-heavy (64 slots, 128 heads against one
+# shared row of 576 lanes padded to 640, 256 pages of 16 a slot) and the
+# tiny preset the CPU tests run
+MLA_GEOMETRIES = {
+    "cell": (64, 128, 640, 512, 16, 256),
+    "tiny": (4, 4, 256, 128, 16, 16),
+}
+_DS_CELL = dict(slots=64, page=16, pages_per_slot=256, chunk=512)
+
+
+@pytest.mark.parametrize("name", sorted(MLA_GEOMETRIES))
+def test_mla_paged_attention_compiles_for_v5e(one_chip, name):
+    import jax.numpy as jnp
+
+    from kubeml_tpu.ops.pallas.mla_paged_attention import (
+        mla_paged_attention, mla_paged_eligible)
+    S, H, lanes, value_lanes, G, Pmax = MLA_GEOMETRIES[name]
+    assert mla_paged_eligible(heads=H, row_lanes=lanes,
+                              value_lanes=value_lanes, page=G,
+                              max_pages=Pmax, dtype=jnp.bfloat16)
+    L, P = 2, S * Pmax + 1
+    hlo = _compile(
+        functools.partial(mla_paged_attention, layer=L - 1,
+                          value_lanes=value_lanes, scale=0.1,
+                          impl="pallas"),
+        one_chip, ((S, H, lanes), jnp.bfloat16),
+        ((L, P, G, lanes), jnp.bfloat16), ((S, Pmax), jnp.int32),
+        ((S,), jnp.int32))
+    assert "tpu_custom_call" in hlo and "mla_paged_attention" in hlo
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_deepseek_v2_program_compiles_for_v5e_at_the_cells_sizes(
+        one_chip, which):
+    """Both programs of models/deepseek_v2.py at the PUBLISHED widths
+    and the cell's geometry (5 layers, 40 of 160 experts, 64 slots, 256
+    pages a slot, prefill chunk 512), bfloat16 parameters: the chip's
+    compiler accepts them, they hold their kernel (the latent-page
+    kernel in decode, the grouped expert product in prefill), the slab
+    keeps its one layout, and arguments and temporaries together fit
+    the chip's 16.9 GB. Read (sandbox compile, PR 27): decode 12.01 GB
+    of arguments + 13 MB of temporaries, prefill 9.47 GB (the last
+    layer's feed-forward and the head are dead code in a program that
+    returns pages only) + 152 MB."""
+    import importlib.util
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "models",
+        "deepseek_v2_ep4.py")
+    spec = importlib.util.spec_from_file_location("ds_ep4_model", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    m = mod.DeepSeekV2EP4().module
+    assert (m.hidden, m.heads, m.kv_lora_rank, m.n_held_experts) \
+        == (5120, 128, 512, 40)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0)))["params"])
+    c = _DS_CELL
+    S, G, Pmax, C = c["slots"], c["page"], c["pages_per_slot"], c["chunk"]
+    rows = (m.layers, S * Pmax + 1, G, m.row_lanes)
+    i32, f32 = jnp.int32, jnp.float32
+    family = m.serve_family()
+    if which == "decode":
+        fn = family.decode_step("f32", "pallas", False)
+        rest = [sds((S,), i32), sds((S,), i32), sds((S, Pmax), i32),
+                sds((S,), i32), sds((S,), i32), sds((S,), f32),
+                sds((S,), f32), sds((S, 2), jnp.uint32), sds((S,), i32),
+                sds((S,), i32), sds((S,), f32)]
+    else:
+        fn = family.prefill_step(C, "f32", "pallas", False)
+        rest = [sds((C,), i32), sds((C,), i32), sds((Pmax,), i32),
+                sds((C,), i32), sds((C,), i32), sds((C,), f32)]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, sds(rows, jnp.bfloat16), *rest).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert ("mla_paged_attention" in hlo) == (which == "decode")
+    shapes = [x for x in _result_shapes(hlo) if x[1] == "bf16"]
+    entry = {lay for _, _, dims, lay, op in shapes
+             if op == "parameter" and dims == rows}
+    assert len(entry) == 1 and next(iter(entry)).startswith("3,2,1,0"), entry
+    relaid = [(n, lay, op) for n, _, dims, lay, op in shapes
+              if dims == rows and lay not in entry]
+    assert not relaid, relaid
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 400e6, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.9e9
+    if which == "prefill":
+        # the attention loop's float32 scores [heads, chunk, keys] stay
+        # in VMEM (memory space 1) at PREFILL_KEY_BLOCK keys a step; at
+        # 512 keys they went through HBM three times a step and the loop
+        # took twice as long on the chip (PERF.md, PR 27)
+        from kubeml_tpu.models.deepseek_v2 import PREFILL_KEY_BLOCK
+        import re
+        shape = re.compile(
+            rf"f32\[{m.heads},{C},{PREFILL_KEY_BLOCK}\]\{{[^}}]*\}}")
+        scores = [x for line in hlo.splitlines() if " fusion(" in line
+                  for x in shape.findall(line.split(" fusion(")[0])]
+        assert len(scores) == m.layers - 1 and \
+            all("S(1)" in x for x in scores), scores
+
+
 # ---------------------------------------------------- flash attention
 
 FLASH_SHAPES = {

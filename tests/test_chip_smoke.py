@@ -88,6 +88,27 @@ def test_rehearsal_serve_phase_lines(rehearsal):
     assert all(d < 1e-4 for d in cases.values())
 
 
+def test_rehearsal_serves_the_latent_page_family(rehearsal):
+    """The serve phase also drives the DeepSeek-V2 family at a small
+    size through the engine (latent pages, one share's experts), and
+    holds its kernel against its plain path."""
+    _proc, lines = rehearsal
+    fam = [ln for ln in lines if ln.get("family") == "deepseek_v2"]
+    run = next(ln for ln in fam if "moe_assignments" in ln)
+    assert run["outcomes"] == ["ok", "ok", "ok"]
+    assert run["compiles"] == {"decode": 1, "prefill": 1}
+    assert run["dispatches"]["prefill"] >= 2
+    assert run["attn_impl_decode"] == "gather"     # 'auto' on the CPU
+    assert run["row_lanes"] % 128 == 0 > -run["latent_lanes"]
+    assert 0 < run["moe_local_assignments"] < run["moe_assignments"]
+    assert 0 < run["moe_experts_touched"]
+    diff = next(ln for ln in fam
+                if "latent_kernel_vs_plain_max_abs_diff" in ln)
+    assert diff["kernel_mode"] == "interpret"
+    assert diff["latent_kernel_vs_plain_max_abs_diff"] \
+        <= diff["latent_kernel_vs_plain_bound"]
+
+
 def test_rehearsal_reports_the_placed_compile_cache(rehearsal):
     proc, lines = rehearsal
     start, end = lines[0], lines[-2]

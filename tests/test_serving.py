@@ -170,6 +170,51 @@ def test_pages_free_on_eos_and_return_to_pool():
     assert engine.kv_utilization() == 0.0
 
 
+def _drain_events(req):
+    out = []
+    while not req.events.empty():
+        out.append(req.events.get_nowait())
+    return out
+
+
+def test_deferred_token_events_wait_for_the_next_decode_enqueue():
+    """A step's tokens join `req.tokens` in its emit phase and reach the
+    stream once the NEXT step has enqueued its decode program, so the
+    handler threads wake while the device works; a request's own events
+    are handed over before its terminal event, in order; flush_events()
+    and abandon() hand over the rest."""
+    from kubeml_tpu.serve.engine import DecodeEngine
+    from kubeml_tpu.serve.slots import GenerateRequest
+
+    _model, module, variables = _nano()
+    engine = DecodeEngine(module, variables, slots=2, page=4,
+                          prefill_chunk=0)
+    short = GenerateRequest([5, 6, 7], max_new_tokens=2)
+    long = GenerateRequest([9, 8, 7], max_new_tokens=5)
+    engine.attach(short)
+    engine.attach(long)
+    delivered, trail = [], []
+    while short.outcome is None:
+        before = len(long.tokens)
+        engine.step()
+        delivered += _drain_events(long)
+        trail.append((before, len(delivered), len(long.tokens)))
+    # a step delivers what was emitted before it, and holds its own
+    assert all(got == before for before, got, _after in trail), trail
+    assert trail[-1][2] == trail[-1][1] + 1
+    assert delivered == [{"token": t} for t in long.tokens[:-1]]
+    # the finished request: its tokens, then its terminal event
+    assert _drain_events(short) == \
+        [{"token": t} for t in short.tokens] \
+        + [{"done": True, "tokens": short.tokens}]
+    engine.flush_events()
+    assert _drain_events(long) == [{"token": long.tokens[-1]}]
+    engine.step()
+    assert _drain_events(long) == []
+    engine.abandon()
+    assert _drain_events(long) == [{"token": long.tokens[-1]}]
+
+
 def test_kv_exhaustion_sheds_newest_stream():
     """With every runnable slot stalled on an empty page pool, the
     NEWEST stream is shed with an error and the oldest finishes."""
