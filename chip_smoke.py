@@ -271,7 +271,10 @@ def kernel_vs_gather(module, page, slots, chunk, seed, interpret):
     """Max abs difference between the Pallas paged kernel and the gather
     path on random operands at the engine's decode and prefill
     geometries (the only op in which the two serve programs differ), for
-    the model's own page dtype and for the f32 and int8 page modes.
+    the model's own page dtype and for the f32 and int8 page modes, on
+    full tables, and for the model's own dtype on tables a fifth live
+    with null tails, as a serving slab mostly is (the kernel walks live
+    entries only, and only occupied slots' rows are compared).
     Returns {case: (max_abs_diff, max_abs_reference)}."""
     import functools
 
@@ -284,10 +287,13 @@ def kernel_vs_gather(module, page, slots, chunk, seed, interpret):
     Pmax = module.max_len // page
     C = Pmax * page
     out = {}
-    modes = ((np.dtype(module.dtype).name, module.dtype, False),
-             ("float32", jnp.float32, False), ("int8", module.dtype, True))
+    own = np.dtype(module.dtype).name
+    modes = ((own, module.dtype, False, Pmax),
+             ("float32", jnp.float32, False, Pmax),
+             ("int8", module.dtype, True, Pmax),
+             (f"{own}-sparse", module.dtype, False, -(-Pmax // 5)))
     for program, S, T in (("decode", slots, 1), ("prefill", 1, chunk)):
-        for mode, dtype, quantized in modes:
+        for mode, dtype, quantized, n_live in modes:
             ks = jax.random.split(jax.random.PRNGKey(seed), 5)
             P = S * Pmax + 1
             q = jax.random.normal(ks[0], (S, T, H, D),
@@ -308,8 +314,10 @@ def kernel_vs_gather(module, page, slots, chunk, seed, interpret):
                 kscale = vscale = jnp.zeros((2, P), jnp.float32)
             tables = 1 + np.arange(S * Pmax,
                                    dtype=np.int32).reshape(S, Pmax)
+            tables[:, n_live:] = 0
             # each slot sees a different context length; rest is masked
-            n_valid = np.minimum(C, (np.arange(S) + 1) * (C // S))
+            live = n_live * page
+            n_valid = np.minimum(live, (np.arange(S) + 1) * (live // S))
             keep = np.arange(C)[None, :] < n_valid[:, None]
             bias = np.broadcast_to(
                 ((1.0 - keep) * NEG_INF)[:, None, None, :],

@@ -569,7 +569,7 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
     v_pages are the slab as serve/pager.py KVPageSlab holds it,
     [layers, pages, page, H*Dh]: every write here is a token row at
     [layer, page, offset], every read a page through the table, and the
-    kernel gets the slab whole with the layer as a static index, so the
+    kernel gets the slab whole with the layer as an index, so the
     compiled program never relays the slab out or copies a layer's
     plane (tests/test_chip_compile.py).
 

@@ -338,6 +338,9 @@ class DecodeEngine:
             1: jax.device_put(variables["params"])}
         S, Pmax = self.geom.slots, self.geom.pages_per_slot
         self._tables = np.zeros((S, Pmax), np.int32)
+        # non-null entries of each slot's table row, kept where an entry
+        # is set or cleared (stats live_page_entries_sum)
+        self._live_entries = np.zeros(S, np.int64)
         self._slots: List[Optional[_Slot]] = [None] * S
         self._seq = 0
         self.compile_tracker = JitCompileTracker()
@@ -395,6 +398,7 @@ class DecodeEngine:
         # accelerator programs have their own compile lanes below.
         self.stats: Dict[str, object] = {
             "dispatches": 0, "generated_tokens": 0, "occupancy_sum": 0,
+            "live_page_entries_sum": 0, "page_entries_sum": 0,
             "stalls": 0, "compiles": 0,
             "prefill_dispatches": 0, "prefill_tokens": 0,
             "prefill_compiles": 0, "decode_tokens": 0,
@@ -664,6 +668,7 @@ class DecodeEngine:
                 self.stats["prefix_misses"] += 1
                 break
             self._tables[s, k] = pid
+            self._live_entries[s] += 1
             chain = digest
             k += 1
             self.stats["prefix_hits"] += 1
@@ -706,6 +711,7 @@ class DecodeEngine:
         if held:
             self.pager.free(held)
         self._tables[s] = 0
+        self._live_entries[s] = 0
         self._slots[s] = None
         slot.req.finished_at = self.clock()
         # terminal instant: finish (ok, error, or deadline expiry —
@@ -747,6 +753,7 @@ class DecodeEngine:
         if held:
             self.pager.free(held)
         self._tables[s] = 0
+        self._live_entries[s] = 0
         self._slots[s] = None
         self._maybe_retire(slot.gen)
         self.check_pager()
@@ -799,6 +806,7 @@ class DecodeEngine:
                     end = min(end, pi * G)
                     break
                 self._tables[s, pi] = pid
+                self._live_entries[s] += 1
                 granted += 1
         n = end - start
         if n <= 0:
@@ -846,6 +854,17 @@ class DecodeEngine:
         to the prefill program. With chunking off every position rides
         decode, so no slot is ever 'in prefill'."""
         return self._prefill is not None and slot.pos < slot.n_prompt - 1
+
+    def _count_page_walk(self, members: List[int]) -> None:
+        """Beside `occupancy_sum`, per decode-lane dispatch: the table
+        entries of its occupied slots and how many of them point at a
+        live page (`_live_entries`, one integer a slot). Their ratio is
+        the share of its table a paged kernel has to walk under this
+        traffic (docs/observability.md)."""
+        self.stats["live_page_entries_sum"] += int(
+            self._live_entries[members].sum())
+        self.stats["page_entries_sum"] += \
+            len(members) * self.geom.pages_per_slot
 
     # ------------------------------------------------------------ supervisor
     def abandon(self) -> None:
@@ -1043,6 +1062,7 @@ class DecodeEngine:
                     self._ungrant(s, granted)
                     return None
                 self._tables[s, pi] = pid
+                self._live_entries[s] += 1
                 granted.append(pi)
         return granted
 
@@ -1050,6 +1070,7 @@ class DecodeEngine:
         for pi in granted:
             self.pager.free([int(self._tables[s, pi])])
             self._tables[s, pi] = 0
+            self._live_entries[s] -= 1
 
     def _walk_emitted(self, s: int, toks, bads, k_max: int,
                       t0: float, t1: float, finished) -> None:
@@ -1167,6 +1188,7 @@ class DecodeEngine:
             self.stats["multi_step_dispatches"] += 1
             self.stats["multi_step_compiles"] += int(compiled)
             self.stats["occupancy_sum"] += len(members)
+            self._count_page_walk(members)
             self.flush_events()
         with phase("serve.step.readback", step=step):
             toks_host = np.asarray(toks)
@@ -1258,6 +1280,7 @@ class DecodeEngine:
             self.stats["verify_dispatches"] += 1
             self.stats["verify_compiles"] += int(compiled)
             self.stats["occupancy_sum"] += len(members)
+            self._count_page_walk(members)
             self.flush_events()
         with phase("serve.step.readback", step=step):
             picks_host = np.asarray(picks)
@@ -1286,6 +1309,7 @@ class DecodeEngine:
                     if pid:
                         self.pager.free([pid])
                         self._tables[s, pi] = 0
+                        self._live_entries[s] -= 1
             self.ledger.note_dispatch(
                 "serve.spec_verify",
                 tokens=self.stats["generated_tokens"] - gen_before_walk)
@@ -1391,6 +1415,7 @@ class DecodeEngine:
                         stalled.append(s)   # no page: sit this round out
                         continue
                     self._tables[s, pi] = pid
+                    self._live_entries[s] += 1
                 elif not self.pager.writable(pid):
                     # shared or cache-registered page: copy-on-write split
                     # inside this dispatch (copies run before any write)
@@ -1508,6 +1533,7 @@ class DecodeEngine:
                 self.stats["dispatches"] += 1
                 self.stats["compiles"] += int(compiled)
                 self.stats["occupancy_sum"] += len(members)
+                self._count_page_walk(members)
                 self.stats["decode_tokens"] += len(members)
                 # decode-bandwidth proxy: every decode-phase lane reads its
                 # whole paged context once per layer (geometry x dtype —

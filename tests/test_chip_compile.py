@@ -72,12 +72,38 @@ PAGED_GEOMETRIES = {
     "prefill-f32": (1, 16, 4, 64, 16, 32, "float32", False),
     "prefill-int8": (1, 16, 4, 64, 16, 32, "bfloat16", True),
     "wide-decode-bf16": (8, 1, 16, 128, 16, 64, "bfloat16", False),
-    # the largest context the VMEM gate still sends to the kernel at the
-    # wide geometry (bound 39.4 of the 40 MiB budget): what
-    # paged_eligible admits, the compiler must accept
-    "wide-budget-edge-bf16": (2, 1, 16, 128, 16, 272, "bfloat16", False),
+    # the kernel lands a softmax block at a time, so its VMEM does not
+    # grow with the context but for the bias rows: 4,352 tokens (the
+    # gate's edge while a slot's whole context landed at once) and
+    # 32,768
+    "wide-context-bf16": (2, 1, 16, 128, 16, 272, "bfloat16", False),
+    "wide-long-context-bf16": (2, 1, 16, 128, 16, 2048, "bfloat16",
+                               False),
+    # the most query rows the VMEM gate still sends to the kernel at
+    # the wide geometry, a prefill chunk of 80 (bound 38.7 of the 40 MiB
+    # budget): what paged_eligible admits, the compiler must accept
+    "wide-budget-edge-bf16": (1, 80, 16, 128, 16, 272, "bfloat16", False),
+    # the benchmark's serve cell (GPT-2 large: 20 heads of 64; 8 slots
+    # x 64 pages of 16, bf16 pages), its decode and prefill programs,
+    # and the 32 slots the slab has room for
+    "cell-decode-bf16": (8, 1, 20, 64, 16, 64, "bfloat16", False),
+    "cell-prefill-bf16": (1, 16, 20, 64, 16, 64, "bfloat16", False),
+    "cell-decode-32-slots-bf16": (32, 1, 20, 64, 16, 64, "bfloat16",
+                                  False),
 }
 _PAGED_LAYERS = 2
+
+
+def _paged_shapes(name):
+    import jax.numpy as jnp
+    S, T, H, D, G, Pmax, dtype_name, quantized = PAGED_GEOMETRIES[name]
+    dtype = getattr(jnp, dtype_name)
+    L, P = _PAGED_LAYERS, S * Pmax + 1
+    page_dtype = jnp.int8 if quantized else dtype
+    return (((S, T, H, D), dtype), ((L, P, G, H * D), page_dtype),
+            ((L, P, G, H * D), page_dtype), ((L, P), jnp.float32),
+            ((L, P), jnp.float32), ((S, Pmax), jnp.int32),
+            ((S, 1, T, Pmax * G), jnp.float32))
 
 
 @pytest.mark.parametrize("name", sorted(PAGED_GEOMETRIES))
@@ -91,17 +117,42 @@ def test_paged_attention_compiles_for_v5e(one_chip, name):
     # the kernel 'auto' would pick on the chip is the one compiled here
     assert paged_eligible(G, q_len=T, heads=H, head_dim=D,
                           max_pages=Pmax, dtype=dtype, quantized=quantized)
-    L, P = _PAGED_LAYERS, S * Pmax + 1
-    page_dtype = jnp.int8 if quantized else dtype
     hlo = _compile(
-        functools.partial(paged_attention, layer=L - 1,
+        functools.partial(paged_attention, layer=_PAGED_LAYERS - 1,
                           quantized=quantized, compute_dtype=dtype,
                           impl="pallas"),
-        one_chip,
-        ((S, T, H, D), dtype), ((L, P, G, H * D), page_dtype),
-        ((L, P, G, H * D), page_dtype), ((L, P), jnp.float32),
-        ((L, P), jnp.float32), ((S, Pmax), jnp.int32),
-        ((S, 1, T, Pmax * G), jnp.float32))
+        one_chip, *_paged_shapes(name))
+    assert "tpu_custom_call" in hlo and "paged_attention" in hlo
+
+
+@pytest.mark.parametrize("name", sorted(PAGED_GEOMETRIES))
+def test_paged_vmem_bound_covers_the_compilers_figure(one_chip, name):
+    """`paged_vmem_bytes` is what `paged_eligible` gates on and what the
+    call raises its scoped-VMEM limit to, so it may not be under what
+    the compiler allocates. The compiled call says both: the limit it
+    was given (`scoped_memory_configs`) and what Mosaic used of it
+    (`used_scoped_memory_configs`). The bound reads 1.2-2.2 times the
+    compiler's figure over these geometries (sandbox compile, PR 28)."""
+    import re
+
+    import jax.numpy as jnp
+
+    from kubeml_tpu.ops.pallas.paged_attention import (paged_attention,
+                                                       paged_vmem_bytes)
+    S, T, H, D, G, Pmax, dtype_name, quantized = PAGED_GEOMETRIES[name]
+    dtype = getattr(jnp, dtype_name)
+    bound = paged_vmem_bytes(T, H, D, G, Pmax, dtype, quantized)
+    hlo = _compile(
+        functools.partial(paged_attention, layer=_PAGED_LAYERS - 1,
+                          quantized=quantized, compute_dtype=dtype,
+                          impl="pallas"),
+        one_chip, *_paged_shapes(name))
+    call, = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    limit, used = (
+        int(re.search(key + r'":\[\{[^}]*"size":"(\d+)"', call).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
+    assert 0 < used <= bound <= limit
     assert "tpu_custom_call" in hlo
 
 

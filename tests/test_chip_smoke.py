@@ -84,8 +84,16 @@ def test_rehearsal_serve_phase_lines(rehearsal):
     cases = diff["kernel_vs_gather_max_abs_diff"]
     assert {c.split("-")[0] for c in cases} == {"decode", "prefill"}
     assert {c.split("-")[1] for c in cases} >= {"float32", "int8"}
-    # interpret mode on CPU: bf16 bit-identical, f32 within a few ulps
-    assert all(d < 1e-4 for d in cases.values())
+    # the tables a fifth live, null tails: the path the cell takes
+    assert sum(c.endswith("-sparse") for c in cases) == 2
+    # interpret mode on CPU: float32 to rounding; bf16 and int8 pages
+    # (bf16 compute) within one bf16 rounding of the output, which the
+    # smoke's own bound (3% of the largest magnitude) holds three times
+    # over — the kernel's probabilities are cast before the division by
+    # their sum since PR 28, so bf16 is no longer bit-identical
+    assert all(d < 1e-4 for c, d in cases.items() if "float32" in c)
+    bound = diff["kernel_vs_gather_bound"]
+    assert all(d <= bound[c] / 3 for c, d in cases.items())
 
 
 def test_rehearsal_serves_the_latent_page_family(rehearsal):

@@ -46,6 +46,9 @@ def _drive(engine, limit=10_000):
     finished = []
     while engine.active():
         finished.extend(engine.step())
+        # the per-slot count behind stats live_page_entries_sum
+        np.testing.assert_array_equal(
+            engine._live_entries, np.count_nonzero(engine._tables, axis=1))
         limit -= 1
         assert limit > 0, "engine failed to drain"
     return finished
