@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import lax
 
 PyTree = Any
 
@@ -133,6 +134,71 @@ class ServeFamily:
         take for this geometry ('off' where there is no prefill
         program): what the engine prints as `attn_impl_*`."""
         raise NotImplementedError
+
+
+# ---- what every family's paged programs share (called from inside
+# their jax.named_scope blocks "cow_split" and "sample")
+
+def cow_split_pages(pages, copy_src, copy_dst):
+    """Copy-on-write lane of a decode program over one slab plane
+    [L, P, G, row_lanes]: page copy_src[s] -> page copy_dst[s], every
+    layer, for each slot s. All S source pages are read BEFORE any is
+    written (the functional gather-before-scatter semantics of
+    `pages.at[:, dst].set(pages[:, src])`, which this replaces), then
+    written one slot at a time, in place. Real splits land on freshly
+    allocated pages, so their destinations are distinct; the 0 -> 0
+    lanes of slots with nothing to split rewrite the null page with its
+    own bytes, whatever the order.
+
+    Why S dynamic slices and not one gather: at 36 layers a gather whose
+    slice is [L, 1, G, H*Dh] is compiled (v5e, PR 26) as four gathers
+    over lane chunks of the slab, each fed by a copy of that chunk of
+    the WHOLE slab — a relayout by another name, 1.5 GB a step (on the
+    chip 2.0 ms a step for each of K and V, PERF.md). A dynamic slice
+    moves the page and nothing else; the barrier keeps the reads from
+    being fused into the writes, which would hold the unwritten slab
+    alive beside the written one."""
+    srcs = lax.optimization_barrier(
+        [lax.dynamic_slice_in_dim(pages, copy_src[s], 1, axis=1)
+         for s in range(copy_src.shape[0])])
+    for s, src in enumerate(srcs):
+        pages = lax.dynamic_update_slice_in_dim(pages, src, copy_dst[s],
+                                                axis=1)
+    return pages
+
+
+def sample_tokens(logits, active, temps, key_data, poison, pad_id):
+    """The last block of a decode program: from logits [S, V] (float32)
+    to (next_tokens[S] int32, bad[S] float32), every lane on its own.
+
+    poison[S] is the fault-injection lane (faults.py serve_nan_logits):
+    a raised row goes non-finite HERE, before the guard, so injection
+    and a genuinely poisoned checkpoint trip the same path
+    (where-select, never 0*NaN: that would stay NaN). bad[S] is the
+    on-device non-finite guard: 1.0 for an active row whose logits are
+    not all finite. It runs BEFORE the never-emit-PAD mask (which puts
+    a legitimate -inf into every row); flagged rows are where-selected
+    to zeros so argmax / categorical stay well-defined, and their pick
+    is forced to 0 (the host discards it and ends that stream alone).
+    A lane picks greedily at temps <= 0, else categorically over
+    logits / temp under its own key_data[s]: per-(request, position)
+    keys, so a draw never depends on which other requests share the
+    batch."""
+    logits = jnp.where(poison[:, None] > 0, jnp.nan, logits)
+    bad = active * (1.0 - jnp.all(
+        jnp.isfinite(logits), axis=-1).astype(jnp.float32))
+    logits = jnp.where(bad[:, None] > 0, jnp.zeros_like(logits), logits)
+    logits = logits.at[:, pad_id].set(-jnp.inf)
+
+    def pick_one(kd, lg, t):
+        greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        safe_t = jnp.where(t > 0, t, 1.0)
+        sampled = jax.random.categorical(
+            jax.random.wrap_key_data(kd), lg / safe_t).astype(jnp.int32)
+        return jnp.where(t > 0, sampled, greedy)
+
+    nxt = jax.vmap(pick_one)(key_data, logits, temps)
+    return jnp.where(bad > 0, 0, nxt), bad
 
 
 class KubeModel(abc.ABC):

@@ -65,8 +65,8 @@ import numpy as np
 from jax import lax
 
 from kubeml_tpu.models.base import (CacheSpec, InferenceInputError,
-                                    KubeModel, ServeFamily)
-from kubeml_tpu.models.gpt import _cow_split_pages
+                                    KubeModel, ServeFamily, cow_split_pages,
+                                    sample_tokens)
 from kubeml_tpu.ops.pallas import mla_paged_attention as mla
 
 PAD_ID = 0
@@ -403,7 +403,7 @@ def build_decode_logits(m: DeepSeekV2Module, attn_impl: str = "auto",
     def logits_of(params, c_pages, tokens, pos, page_tables, write_page,
                   write_off, active, copy_src, copy_dst):
         with jax.named_scope("cow_split"):
-            c_pages = _cow_split_pages(c_pages, copy_src, copy_dst)
+            c_pages = cow_split_pages(c_pages, copy_src, copy_dst)
         with jax.named_scope("embed"):
             h = params["embed"]["embedding"][tokens].astype(F32)
             cos, sin = _angles(m, pos)
@@ -456,10 +456,10 @@ def build_decode_step(m: DeepSeekV2Module, attn_impl: str = "auto",
     the engine's decode contract (models/base.py ServeFamily) for a
     cache of one plane: c_pages [layers, pages, page, row_lanes], rows
     written at [layer, page, offset], read through the page table by
-    the kernel with the layer static. Sampling, the non-finite guard,
-    the poison lane and the copy-on-write lane are the GPT step's
-    (models/gpt.py build_paged_decode_step), slots are rows, and every
-    per-request quantity is data. The three counts of STEP_COUNTERS,
+    the kernel with the layer static. Sampling with its non-finite guard
+    and poison lane, and the copy-on-write lane, are every family's
+    (models/base.py sample_tokens, cow_split_pages), slots are rows, and
+    every per-request quantity is data. The three counts of STEP_COUNTERS,
     summed over the expert layers, ride behind the S picks."""
     logits_of = build_decode_logits(m, attn_impl, attn_interpret)
 
@@ -470,24 +470,8 @@ def build_decode_step(m: DeepSeekV2Module, attn_impl: str = "auto",
             params, c_pages, tokens, pos, page_tables, write_page,
             write_off, active, copy_src, copy_dst)
         with jax.named_scope("sample"):
-            # the GPT step's guard and sampling, line for line
-            logits = jnp.where(poison[:, None] > 0, jnp.nan, logits)
-            bad = active * (1.0 - jnp.all(
-                jnp.isfinite(logits), axis=-1).astype(F32))
-            logits = jnp.where(bad[:, None] > 0,
-                               jnp.zeros_like(logits), logits)
-            logits = logits.at[:, PAD_ID].set(-jnp.inf)  # never emit PAD
-
-            def pick_one(kd, lg, t):
-                greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                safe_t = jnp.where(t > 0, t, 1.0)
-                sampled = jax.random.categorical(
-                    jax.random.wrap_key_data(kd),
-                    lg / safe_t).astype(jnp.int32)
-                return jnp.where(t > 0, sampled, greedy)
-
-            nxt = jax.vmap(pick_one)(key_data, logits, temps)
-            nxt = jnp.where(bad > 0, 0, nxt)
+            nxt, bad = sample_tokens(logits, active, temps, key_data,
+                                     poison, PAD_ID)
         return jnp.concatenate([nxt, counts]), bad, c_pages
 
     return step

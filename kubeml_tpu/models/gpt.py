@@ -38,7 +38,8 @@ from jax import lax
 
 from kubeml_tpu.models import register_model
 from kubeml_tpu.models.base import (CacheSpec, InferenceInputError,
-                                    KubeModel, ServeFamily)
+                                    KubeModel, ServeFamily, cow_split_pages,
+                                    sample_tokens)
 from kubeml_tpu.parallel.tp import TRANSFORMER_TP_RULES
 from kubeml_tpu.ops.attention import masked_attention
 
@@ -443,34 +444,6 @@ PAGED_SCOPES = ("cow_split", "embed", "mask", "qkv", "kv_write", "attn",
                 "proj", "mlp", "head", "sample")
 
 
-def _cow_split_pages(pages, copy_src, copy_dst):
-    """Copy-on-write lane of the decode program over one slab
-    [L, P, G, H*Dh]: page copy_src[s] -> page copy_dst[s], every layer,
-    for each slot s. All S source pages are read BEFORE any is written
-    (the functional gather-before-scatter semantics of
-    `pages.at[:, dst].set(pages[:, src])`, which this replaces), then
-    written one slot at a time, in place. Real splits land on freshly
-    allocated pages, so their destinations are distinct; the 0 -> 0
-    lanes of slots with nothing to split rewrite the null page with its
-    own bytes, whatever the order.
-
-    Why S dynamic slices and not one gather: at 36 layers a gather whose
-    slice is [L, 1, G, H*Dh] is compiled (v5e, PR 26) as four gathers
-    over lane chunks of the slab, each fed by a copy of that chunk of
-    the WHOLE slab — a relayout by another name, 1.5 GB a step (on the
-    chip 2.0 ms a step for each of K and V, PERF.md). A dynamic slice
-    moves the page and nothing else; the barrier keeps the reads from
-    being fused into the writes, which would hold the unwritten slab
-    alive beside the written one."""
-    srcs = lax.optimization_barrier(
-        [lax.dynamic_slice_in_dim(pages, copy_src[s], 1, axis=1)
-         for s in range(copy_src.shape[0])])
-    for s, src in enumerate(srcs):
-        pages = lax.dynamic_update_slice_in_dim(pages, src, copy_dst[s],
-                                                axis=1)
-    return pages
-
-
 def _int8_write_decode(pages, scales, layer, rows, write_page, write_off):
     """Quantize-on-write for one layer's decode rows [S, H*Dh] (f32,
     the slab's lane-dense token rows — serve/pager.py KVPageSlab) into
@@ -534,6 +507,112 @@ def _int8_write_prefill(pages, scales, layer, rows, write_pages,
     return pages, scales
 
 
+def _paged_trunk(module: GPTModule, kv_dtype: str, attn_impl: str,
+                 attn_interpret: bool, chunked: bool):
+    """What the decode and the prefill program share: the checks of the
+    module variant and kv_dtype, ONE construction of the trunk's flax
+    submodules (the kinds GPTModule applies, over the same parameter
+    subtrees), and the bodies built from them, under the scope names of
+    PAGED_SCOPES. `chunked` is everything that differs between the two:
+    the decode step's tokens are [S, 1] (a row a slot, each with its own
+    page table), a prefill chunk's [1, C] (C rows of ONE slot, one
+    table), and the int8 write helper is the one for that shape.
+    Returns (embed, layers, head):
+
+      embed(params, tokens[N], pos[N]) -> h
+      layers(params, h, k_pages, v_pages, k_scales, v_scales, tables,
+             bias, *where) -> (h, k_pages, v_pages, k_scales, v_scales)
+      head(params, h) -> logits[S, V] float32   (decode only)
+
+    `where` is (write_page[S], write_off[S]) in decode and
+    (write_pages[C], write_offs[C], in_chunk[C]) in prefill: each
+    layer's K and V rows land there BEFORE that layer's attention reads
+    its context through `tables`."""
+    what = "prefill" if chunked else "decode"
+    if module.n_experts or module.seq_axis is not None \
+            or module.tp_axis is not None:
+        raise ValueError(
+            f"paged {what} serves dense GPT modules only (no MoE, "
+            "sequence-parallel, or manual-TP variants)")
+    if kv_dtype not in _KV_DTYPES:
+        raise ValueError(
+            f"kv_dtype must be one of {_KV_DTYPES}, got {kv_dtype!r}")
+    quantized = kv_dtype == "int8"
+    int8_write = _int8_write_prefill if chunked else _int8_write_decode
+    heads, hidden = module.heads, module.hidden
+    head_dim = hidden // heads
+    dtype = module.dtype
+    from kubeml_tpu.ops.pallas.paged_attention import paged_attention
+    tok_embed = nn.Embed(module.vocab_size, hidden, dtype=dtype)
+    pos_embed = nn.Embed(module.max_len, hidden, dtype=dtype)
+    ln = nn.LayerNorm(dtype=jnp.float32)
+    qkv = nn.DenseGeneral((heads, head_dim), dtype=dtype)
+    out_proj = nn.DenseGeneral(hidden, axis=(-2, -1), dtype=dtype)
+    ffn_in = nn.Dense(module.ffn, dtype=dtype)
+    ffn_out = nn.Dense(hidden, dtype=dtype)
+
+    def batched(a):         # [S] -> [S, 1], or [C] -> [1, C]
+        return a[None, :] if chunked else a[:, None]
+
+    def rows_of(a):         # [S, 1, H, Dh] or [1, C, H, Dh] -> [N, H*Dh]
+        return (a[0] if chunked else a[:, 0]).reshape(-1, hidden)
+
+    def embed(params, tokens, pos):
+        h = tok_embed.apply({"params": params["tok_embed"]},
+                            batched(tokens))
+        return h + pos_embed.apply({"params": params["pos_embed"]},
+                                   batched(pos))
+
+    def layers(params, h, k_pages, v_pages, k_scales, v_scales, tables,
+               bias, *where):
+        for i in range(module.layers):
+            p = params[f"layer_{i}"]
+            with jax.named_scope(f"layer_{i}/qkv"):
+                x = ln.apply({"params": p["LayerNorm_0"]}, h)
+                q = qkv.apply({"params": p["q"]}, x)
+                k = qkv.apply({"params": p["k"]}, x)
+                v = qkv.apply({"params": p["v"]}, x)
+            with jax.named_scope(f"layer_{i}/kv_write"):
+                # a token's K (and V) is ONE lane-dense row of the
+                # slab, heads side by side (serve/pager.py KVPageSlab)
+                k_rows, v_rows = rows_of(k), rows_of(v)
+                if quantized:
+                    k_pages, k_scales = int8_write(
+                        k_pages, k_scales, i, k_rows.astype(jnp.float32),
+                        *where)
+                    v_pages, v_scales = int8_write(
+                        v_pages, v_scales, i, v_rows.astype(jnp.float32),
+                        *where)
+                else:
+                    at = (i, where[0], where[1])
+                    k_pages = k_pages.at[at].set(k_rows.astype(dtype))
+                    v_pages = v_pages.at[at].set(v_rows.astype(dtype))
+            with jax.named_scope(f"layer_{i}/attn"):
+                attn = paged_attention(
+                    q, k_pages, v_pages, k_scales, v_scales,
+                    tables[None] if chunked else tables, bias, layer=i,
+                    quantized=quantized, compute_dtype=dtype,
+                    impl=attn_impl, interpret=attn_interpret)
+            with jax.named_scope(f"layer_{i}/proj"):
+                attn = out_proj.apply({"params": p["out"]}, attn)
+                h = h + attn
+            with jax.named_scope(f"layer_{i}/mlp"):
+                x = ln.apply({"params": p["LayerNorm_1"]}, h)
+                x = ffn_in.apply({"params": p["Dense_0"]}, x)
+                x = nn.gelu(x)
+                x = ffn_out.apply({"params": p["Dense_1"]}, x)
+                h = h + x
+        return h, k_pages, v_pages, k_scales, v_scales
+
+    def head(params, h):
+        h = ln.apply({"params": params["LayerNorm_0"]}, h)
+        return tok_embed.apply(
+            {"params": params["tok_embed"]}, h.astype(dtype),
+            method=tok_embed.attend).astype(jnp.float32)[:, 0]
+
+    return embed, layers, head
+
+
 def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
                             attn_impl: str = "auto",
                             attn_interpret: bool = False):
@@ -546,7 +625,8 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
     requests join and leave continuously. This builder re-expresses the
     SAME math (identical flax submodule kinds applied to the same
     parameter subtrees, the same NEG_INF bias convention, the same
-    f32-softmax attention primitive) as a single fixed-shape step:
+    f32-softmax attention primitive: _paged_trunk) as a single
+    fixed-shape step:
 
       step(params, k_pages, v_pages, k_scales, v_scales, valid_pages,
            tokens[S], pos[S], page_tables[S, Pmax],
@@ -579,11 +659,12 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
     with validity 0 — written but never attended. Each active slot
     consumes its token at position pos (prompt tokens one per step
     during its prefill phase, then its own previous output) and the
-    returned row is its next-token pick: greedy at temps<=0, else
-    categorical over logits/temp keyed by that slot's own key_data —
-    per-(request, position) keys, so sampling is independent of which
-    other requests happen to share the batch (bit-identity under
-    continuous batching, proven in tests/test_serving.py).
+    returned row is its next-token pick, with bad[S] the on-device
+    non-finite guard and poison[S] the fault lane that drives it
+    (models/base.py sample_tokens, shared by every family: per-lane, so
+    one poisoned stream never perturbs its neighbours' math, and keyed
+    per (request, position) — bit-identity under continuous batching,
+    proven in tests/test_serving.py).
 
     copy_src/copy_dst are the prefix cache's COPY-ON-WRITE lane: before
     anything else, page copy_src[s] is duplicated into page copy_dst[s]
@@ -593,44 +674,15 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
     programs and the compile count stays pinned at two (prefill +
     decode). Slots with nothing to split pass 0 -> 0, a no-op through
     the null page. The pages move as whole [layers, 1, page, H*Dh]
-    slices of the slab, in place (_cow_split_pages).
-
-    bad[S] is the ON-DEVICE NON-FINITE GUARD (the kavg merge guard's
-    serving twin): 1.0 for an active row whose logits went non-finite
-    this step. The check runs BEFORE the never-emit-PAD mask (which
-    puts a legitimate -inf in every row) and flagged rows are
-    where-selected to zeros before sampling — per LANE, so one
-    poisoned stream never perturbs its neighbours' math and the host
-    can terminate just that slot. poison[S] is the fault-injection
-    lane driving it deterministically (faults.py serve_nan_logits): a
-    raised lane forces that row non-finite on device, through the same
-    guard a genuinely poisoned checkpoint would trip.
+    slices of the slab, in place (models/base.py cow_split_pages).
 
     Slots are rows: no cross-slot reduction exists anywhere in the
     step, which is what makes concurrent decode bit-identical to
     running the same requests one at a time.
     """
-    if module.n_experts or module.seq_axis is not None \
-            or module.tp_axis is not None:
-        raise ValueError(
-            "paged decode serves dense GPT modules only (no MoE, "
-            "sequence-parallel, or manual-TP variants)")
-    if kv_dtype not in _KV_DTYPES:
-        raise ValueError(
-            f"kv_dtype must be one of {_KV_DTYPES}, got {kv_dtype!r}")
-    quantized = kv_dtype == "int8"
-    heads, hidden = module.heads, module.hidden
-    head_dim = hidden // heads
-    dtype = module.dtype
+    embed, layers, head = _paged_trunk(module, kv_dtype, attn_impl,
+                                       attn_interpret, chunked=False)
     from kubeml_tpu.ops.attention import NEG_INF
-    from kubeml_tpu.ops.pallas.paged_attention import paged_attention
-    tok_embed = nn.Embed(module.vocab_size, hidden, dtype=dtype)
-    pos_embed = nn.Embed(module.max_len, hidden, dtype=dtype)
-    ln = nn.LayerNorm(dtype=jnp.float32)
-    qkv = nn.DenseGeneral((heads, head_dim), dtype=dtype)
-    out_proj = nn.DenseGeneral(hidden, axis=(-2, -1), dtype=dtype)
-    ffn_in = nn.Dense(module.ffn, dtype=dtype)
-    ffn_out = nn.Dense(hidden, dtype=dtype)
 
     def step(params, k_pages, v_pages, k_scales, v_scales, valid_pages,
              tokens, pos, page_tables, write_page, write_off, active,
@@ -649,17 +701,14 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
         # safe in the same step. 0 -> 0 rows are null-page no-ops.
         # Scales are page metadata and split with their page.
         with jax.named_scope("cow_split"):
-            k_pages = _cow_split_pages(k_pages, copy_src, copy_dst)
-            v_pages = _cow_split_pages(v_pages, copy_src, copy_dst)
+            k_pages = cow_split_pages(k_pages, copy_src, copy_dst)
+            v_pages = cow_split_pages(v_pages, copy_src, copy_dst)
             k_scales = k_scales.at[:, copy_dst].set(k_scales[:, copy_src])
             v_scales = v_scales.at[:, copy_dst].set(v_scales[:, copy_src])
             valid_pages = valid_pages.at[copy_dst].set(
                 valid_pages[copy_src])
         with jax.named_scope("embed"):
-            h = tok_embed.apply({"params": params["tok_embed"]},
-                                tokens[:, None])
-            h = h + pos_embed.apply({"params": params["pos_embed"]},
-                                    pos[:, None])
+            h = embed(params, tokens, pos)
         # this token's validity, written BEFORE the gather so a slot's
         # first token attends to itself (offset-0 decode semantics of
         # the contiguous path). Inactive slots write 0 to the null page.
@@ -671,77 +720,14 @@ def build_paged_decode_step(module: GPTModule, kv_dtype: str = "f32",
             causal = (jnp.arange(C)[None, :] <= pos[:, None]) \
                 .astype(jnp.float32)
             bias = (1.0 - ctx_valid * causal)[:, None, None, :] * NEG_INF
-        for i in range(module.layers):
-            p = params[f"layer_{i}"]
-            with jax.named_scope(f"layer_{i}/qkv"):
-                x = ln.apply({"params": p["LayerNorm_0"]}, h)
-                q = qkv.apply({"params": p["q"]}, x)
-                k = qkv.apply({"params": p["k"]}, x)
-                v = qkv.apply({"params": p["v"]}, x)
-            with jax.named_scope(f"layer_{i}/kv_write"):
-                # a token's K (and V) is ONE lane-dense row of the
-                # slab, heads side by side (serve/pager.py KVPageSlab)
-                k_row = k[:, 0].reshape(S, hidden)
-                v_row = v[:, 0].reshape(S, hidden)
-                if quantized:
-                    k_pages, k_scales = _int8_write_decode(
-                        k_pages, k_scales, i, k_row.astype(jnp.float32),
-                        write_page, write_off)
-                    v_pages, v_scales = _int8_write_decode(
-                        v_pages, v_scales, i, v_row.astype(jnp.float32),
-                        write_page, write_off)
-                else:
-                    k_pages = k_pages.at[i, write_page, write_off].set(
-                        k_row.astype(dtype))
-                    v_pages = v_pages.at[i, write_page, write_off].set(
-                        v_row.astype(dtype))
-            with jax.named_scope(f"layer_{i}/attn"):
-                attn = paged_attention(
-                    q, k_pages, v_pages, k_scales, v_scales,
-                    page_tables, bias, layer=i, quantized=quantized,
-                    compute_dtype=dtype, impl=attn_impl,
-                    interpret=attn_interpret)
-            with jax.named_scope(f"layer_{i}/proj"):
-                attn = out_proj.apply({"params": p["out"]}, attn)
-                h = h + attn
-            with jax.named_scope(f"layer_{i}/mlp"):
-                x = ln.apply({"params": p["LayerNorm_1"]}, h)
-                x = ffn_in.apply({"params": p["Dense_0"]}, x)
-                x = nn.gelu(x)
-                x = ffn_out.apply({"params": p["Dense_1"]}, x)
-                h = h + x
+        h, k_pages, v_pages, k_scales, v_scales = layers(
+            params, h, k_pages, v_pages, k_scales, v_scales, page_tables,
+            bias, write_page, write_off)
         with jax.named_scope("head"):
-            h = ln.apply({"params": params["LayerNorm_0"]}, h)
-            logits = tok_embed.apply(
-                {"params": params["tok_embed"]}, h.astype(dtype),
-                method=tok_embed.attend).astype(jnp.float32)[:, 0]
+            logits = head(params, h)
         with jax.named_scope("sample"):
-            # fault lane: a raised poison row goes non-finite here,
-            # BEFORE the guard — injection and genuine weight poison
-            # trip the same path (where-select, never 0*NaN: that would
-            # stay NaN)
-            logits = jnp.where(poison[:, None] > 0, jnp.nan, logits)
-            # non-finite guard, per lane. Must run BEFORE the PAD mask
-            # below writes a legitimate -inf into every row; flagged
-            # rows are sanitized to zeros so argmax/categorical stay
-            # well-defined (their pick is discarded by the host and
-            # forced to 0 anyway).
-            bad = active * (1.0 - jnp.all(
-                jnp.isfinite(logits), axis=-1).astype(jnp.float32))
-            logits = jnp.where(bad[:, None] > 0,
-                               jnp.zeros_like(logits), logits)
-            logits = logits.at[:, PAD_ID].set(-jnp.inf)  # never emit PAD
-
-            def pick_one(kd, lg, t):
-                greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                safe_t = jnp.where(t > 0, t, 1.0)
-                sampled = jax.random.categorical(
-                    jax.random.wrap_key_data(kd),
-                    lg / safe_t).astype(jnp.int32)
-                return jnp.where(t > 0, sampled, greedy)
-
-            nxt = jax.vmap(pick_one)(key_data, logits, temps)
-            nxt = jnp.where(bad > 0, 0, nxt)
+            nxt, bad = sample_tokens(logits, active, temps, key_data,
+                                     poison, PAD_ID)
         return nxt, bad, k_pages, v_pages, k_scales, v_scales, valid_pages
 
     return step
@@ -765,11 +751,10 @@ def build_paged_prefill_step(module: GPTModule, chunk: int,
               write_pages[C], write_offs[C], in_chunk[C])
         -> (k_pages, v_pages, k_scales, v_scales, valid_pages)
 
-    kv_dtype / attn_impl / attn_interpret mirror
-    build_paged_decode_step: "int8" quantizes chunk rows on write
-    (_int8_write_prefill) and the paged-attention context read
-    dequantizes them; "f32" leaves the scale lanes inert and the
-    program IEEE-identical to the pre-scale one.
+    kv_dtype / attn_impl / attn_interpret mean what they mean to
+    build_paged_decode_step, and the layers are that step's
+    (_paged_trunk): "int8" quantizes chunk rows on write
+    (_int8_write_prefill).
 
     The chunk size C is static (one compile, amortized forever); real
     chunk length is DATA — prompts shorter than C pad the tail with
@@ -792,27 +777,9 @@ def build_paged_prefill_step(module: GPTModule, chunk: int,
     """
     if chunk < 1:
         raise ValueError(f"prefill chunk must be >= 1, got {chunk}")
-    if module.n_experts or module.seq_axis is not None \
-            or module.tp_axis is not None:
-        raise ValueError(
-            "paged prefill serves dense GPT modules only (no MoE, "
-            "sequence-parallel, or manual-TP variants)")
-    if kv_dtype not in _KV_DTYPES:
-        raise ValueError(
-            f"kv_dtype must be one of {_KV_DTYPES}, got {kv_dtype!r}")
-    quantized = kv_dtype == "int8"
-    heads, hidden = module.heads, module.hidden
-    head_dim = hidden // heads
-    dtype = module.dtype
+    embed, layers, _ = _paged_trunk(module, kv_dtype, attn_impl,
+                                    attn_interpret, chunked=True)
     from kubeml_tpu.ops.attention import NEG_INF
-    from kubeml_tpu.ops.pallas.paged_attention import paged_attention
-    tok_embed = nn.Embed(module.vocab_size, hidden, dtype=dtype)
-    pos_embed = nn.Embed(module.max_len, hidden, dtype=dtype)
-    ln = nn.LayerNorm(dtype=jnp.float32)
-    qkv = nn.DenseGeneral((heads, head_dim), dtype=dtype)
-    out_proj = nn.DenseGeneral(hidden, axis=(-2, -1), dtype=dtype)
-    ffn_in = nn.Dense(module.ffn, dtype=dtype)
-    ffn_out = nn.Dense(hidden, dtype=dtype)
 
     def prefill(params, k_pages, v_pages, k_scales, v_scales,
                 valid_pages, tokens, pos, page_table, write_pages,
@@ -820,10 +787,7 @@ def build_paged_prefill_step(module: GPTModule, chunk: int,
         G = valid_pages.shape[1]
         C = page_table.shape[0] * G
         with jax.named_scope("embed"):
-            h = tok_embed.apply({"params": params["tok_embed"]},
-                                tokens[None, :])
-            h = h + pos_embed.apply({"params": params["pos_embed"]},
-                                    pos[None, :])
+            h = embed(params, tokens, pos)
         # chunk validity lands before the gather (write-then-attend,
         # like the decode step); pad-tail rows write 0 to the null page
         with jax.named_scope("mask"):
@@ -835,43 +799,9 @@ def build_paged_prefill_step(module: GPTModule, chunk: int,
                 .astype(jnp.float32)                      # [chunk, C]
             bias = (1.0 - ctx_valid[None, :] * causal)[None, None] \
                 * NEG_INF
-        for i in range(module.layers):
-            p = params[f"layer_{i}"]
-            with jax.named_scope(f"layer_{i}/qkv"):
-                x = ln.apply({"params": p["LayerNorm_0"]}, h)
-                q = qkv.apply({"params": p["q"]}, x)
-                k = qkv.apply({"params": p["k"]}, x)
-                v = qkv.apply({"params": p["v"]}, x)
-            with jax.named_scope(f"layer_{i}/kv_write"):
-                k_rows = k[0].reshape(-1, hidden)     # [chunk, H*Dh]
-                v_rows = v[0].reshape(-1, hidden)
-                if quantized:
-                    k_pages, k_scales = _int8_write_prefill(
-                        k_pages, k_scales, i, k_rows.astype(jnp.float32),
-                        write_pages, write_offs, in_chunk)
-                    v_pages, v_scales = _int8_write_prefill(
-                        v_pages, v_scales, i, v_rows.astype(jnp.float32),
-                        write_pages, write_offs, in_chunk)
-                else:
-                    k_pages = k_pages.at[i, write_pages, write_offs].set(
-                        k_rows.astype(dtype))
-                    v_pages = v_pages.at[i, write_pages, write_offs].set(
-                        v_rows.astype(dtype))
-            with jax.named_scope(f"layer_{i}/attn"):
-                attn = paged_attention(
-                    q, k_pages, v_pages, k_scales, v_scales,
-                    page_table[None], bias, layer=i, quantized=quantized,
-                    compute_dtype=dtype, impl=attn_impl,
-                    interpret=attn_interpret)
-            with jax.named_scope(f"layer_{i}/proj"):
-                attn = out_proj.apply({"params": p["out"]}, attn)
-                h = h + attn
-            with jax.named_scope(f"layer_{i}/mlp"):
-                x = ln.apply({"params": p["LayerNorm_1"]}, h)
-                x = ffn_in.apply({"params": p["Dense_0"]}, x)
-                x = nn.gelu(x)
-                x = ffn_out.apply({"params": p["Dense_1"]}, x)
-                h = h + x
+        _, k_pages, v_pages, k_scales, v_scales = layers(
+            params, h, k_pages, v_pages, k_scales, v_scales, page_table,
+            bias, write_pages, write_offs, in_chunk)
         return k_pages, v_pages, k_scales, v_scales, valid_pages
 
     return prefill

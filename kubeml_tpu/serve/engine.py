@@ -38,6 +38,15 @@ or hot-swap drain falls back to the single-step decode program — the
 accelerated programs only ever see the steady state they were compiled
 for, so the inventory above is exhaustive and recompilation-free.
 
+All four are dispatched through ONE sequence, DecodeEngine._dispatched
+(cost record, clock pair, call, slab state, compile note, counters,
+and for the decode lane the shared counters, the hand-over of held-back
+token events, the readback and the enqueue / readback / emit phase
+records), and the three decode-lane programs' picks are emitted by ONE
+walk, DecodeEngine._walk_emitted (the single-step program is its
+one-row case). A dispatcher keeps what is its own: granting pages,
+packing its lanes, reading its outputs.
+
 A token-budget scheduler in step() interleaves the two: each engine
 step spends at most `prefill_budget` prompt tokens on prefill chunks
 (FIFO over admission order; a dispatch is charged as a whole chunk,
@@ -67,6 +76,8 @@ or cache miss.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import logging
 import threading
 import time
@@ -176,6 +187,35 @@ SERVE_PHASE_KINDS = (
     "serve.step.emit",      # per-slot advance, prefix registration,
                             # emit_token, release
 )
+
+
+# The four programs of the inventory above, by dispatch kind: the name
+# the compile tracker and the cost ledger know it by, the attribute that
+# holds its jitted function, and its own dispatch and compile counters
+# in `stats` (the single-step program's dispatches are the decode
+# lane's shared "dispatches", and "compiles" keeps its PR-6 meaning).
+_PROGRAMS = {
+    "decode": ("serve.decode", "_step", None, "compiles"),
+    "multi": ("serve.multi_step", "_multi", "multi_step_dispatches",
+              "multi_step_compiles"),
+    "verify": ("serve.spec_verify", "_verify", "verify_dispatches",
+               "verify_compiles"),
+    "prefill": ("serve.prefill", "_prefill", "prefill_dispatches",
+                "prefill_compiles"),
+}
+
+# what DecodeEngine._dispatched hands the code that reads one dispatch:
+# the clock pair around the call, whether it compiled, the args of the
+# open serve.step.emit record, and the program's outputs ahead of the
+# slab state as host arrays
+_Dispatched = collections.namedtuple("_Dispatched",
+                                     "t0 t1 compiled span out")
+
+
+def _no_phase(name, **args):
+    """Stands in for `phase` where a dispatch leaves no record of its
+    own: a prefill chunk lies inside its one serve.step.prefill."""
+    return contextlib.nullcontext(args)
 
 
 def _serve_family(module) -> ServeFamily:
@@ -823,30 +863,19 @@ class DecodeEngine:
             write_pages[j] = self._tables[s, p // G]
             write_offs[j] = p % G
             in_chunk[j] = 1.0
-        args = (self._params_by_gen[slot.gen], *self.slab.state,
+        args = [self._params_by_gen[slot.gen], *self.slab.state,
                 jnp.asarray(tokens), jnp.asarray(pos),
                 jnp.asarray(self._tables[s]), jnp.asarray(write_pages),
-                jnp.asarray(write_offs), jnp.asarray(in_chunk))
-        self._ledger_capture("serve.prefill", self._prefill, args)
-        before = self._prefill._cache_size()
-        t0 = self.clock()
-        self.slab.state = tuple(self._prefill(*args))
-        compiled = self._prefill._cache_size() > before
-        t1 = self.clock()
-        self.compile_tracker.note(compiled, t1 - t0,
-                                  program="serve.prefill")
-        self.ledger.note_dispatch("serve.prefill")
-        self.stats["prefill_dispatches"] += 1
-        self.stats["prefill_compiles"] += int(compiled)
-        self.stats["prefill_tokens"] += n
-        slot.prefill_s += t1 - t0
-        self._dispatch_wall_s += t1 - t0
-        self._span("prefill_chunk", t0, t1, slot.req, tokens=n,
-                   pages_granted=granted, start_pos=start,
-                   compiled=int(compiled))
-        slot.pos = end
-        if self.prefix_cache:
-            self._register_full_pages(s, slot)
+                jnp.asarray(write_offs), jnp.asarray(in_chunk)]
+        with self._dispatched("prefill", args) as d:
+            self.stats["prefill_tokens"] += n
+            slot.prefill_s += d.t1 - d.t0
+            self._span("prefill_chunk", d.t0, d.t1, slot.req, tokens=n,
+                       pages_granted=granted, start_pos=start,
+                       compiled=int(d.compiled))
+            slot.pos = end
+            if self.prefix_cache:
+                self._register_full_pages(s, slot)
         return n
 
     def _in_prefill(self, slot: _Slot) -> bool:
@@ -1072,37 +1101,115 @@ class DecodeEngine:
             self._tables[s, pi] = 0
             self._live_entries[s] -= 1
 
+    @contextlib.contextmanager
+    def _dispatched(self, kind: str, args: list,
+                    members: Optional[List[int]] = None, steps: int = 1):
+        """THE dispatch sequence, from packed arguments to host arrays
+        and on to the ledger's note, for all four programs (`kind` keys
+        _PROGRAMS): the cost record's capture at the program's first
+        dispatch, the clock pair around the jitted call, the slab's new
+        state, the compile noted (tracker, the kind's own counters) and
+        the wall time; the body of the `with` then reads the dispatch (a
+        _Dispatched), and the ledger's dispatch note, with the tokens
+        that body emitted, closes it.
+
+        With `members` (the occupied lanes: a decode-lane dispatch of
+        any of the three kinds) the sequence also moves the lane's
+        shared counters, hands over the last step's token events once
+        the program is enqueued (the handler threads then run while the
+        device does), reads the outputs back, and leaves the
+        serve.step.enqueue / readback / emit records, the body running
+        inside emit. A prefill dispatch does none of that and reads
+        nothing back: benchmark/metrics/serve_loop_phases.py takes an
+        iteration with an enqueue record for a decode iteration.
+
+        `args` is a LIST, emptied before emit closes: the argument and
+        result buffers are dropped inside a phase (left to a function's
+        return, their release, a millisecond on the CPU backend, is
+        host time under no name)."""
+        program, attr, n_key, c_key = _PROGRAMS[kind]
+        jitfn = getattr(self, attr)
+        step = self._step_count
+        lane = _no_phase if members is None else phase
+        with lane("serve.step.enqueue", step=step) as span:
+            self._ledger_capture(program, jitfn, args, steps)
+            before = jitfn._cache_size()
+            t0 = self.clock()
+            out = jitfn(*args)
+            n_out = len(out) - len(self.slab.state)
+            self.slab.state = tuple(out[n_out:])
+            compiled = jitfn._cache_size() > before
+            t1 = self.clock()
+            self.compile_tracker.note(compiled, t1 - t0, program=program)
+            self._dispatch_wall_s += t1 - t0
+            if n_key:
+                self.stats[n_key] += 1
+            self.stats[c_key] += int(compiled)
+            if members is not None:
+                span["compiled"] = int(compiled)
+                self.stats["dispatches"] += 1
+                self.stats["occupancy_sum"] += len(members)
+                self._count_page_walk(members)
+                self.flush_events()
+        with lane("serve.step.readback", step=step):
+            host = [np.asarray(o) for o in out[:n_out]]
+        with lane("serve.step.emit", step=step) as span:
+            g0 = self.stats["generated_tokens"]
+            d0 = self.stats["decode_tokens"]
+            yield _Dispatched(t0, t1, compiled, span, host)
+            # decode-bandwidth proxy: every lane-step the walk retained
+            # read its whole paged context once per layer, so kv_bytes
+            # stays exactly decode_tokens x decode_bytes_per_token
+            # across every program (geometry x dtype, no timers)
+            self.stats["kv_bytes"] += (self.stats["decode_tokens"] - d0) \
+                * self.slab.decode_bytes_per_token
+            self.ledger.note_dispatch(
+                program, tokens=self.stats["generated_tokens"] - g0)
+            args.clear()
+            del out
+
     def _walk_emitted(self, s: int, toks, bads, k_max: int,
-                      t0: float, t1: float, finished) -> None:
-        """Host-side mirror of the device's per-lane early exit: emit
-        this lane's picks row by row until its own terminal condition
-        (non-finite guard, EOS, token budget), advancing pos exactly as
-        k_max single-step dispatches would have. toks/bads are the
-        lane's [k_max] device outputs; rows past the break are
-        garbage-by-design, like an inactive slot's pick."""
+                      t0: float, t1: float, finished, cow=()) -> None:
+        """THE emit walk, host-side mirror of the device's per-lane
+        early exit: emit lane s's picks row by row until its own
+        terminal condition (non-finite guard, EOS, token budget),
+        advancing pos exactly as k_max single-step dispatches would
+        have. toks/bads are a dispatch's [k_max, lanes] outputs; rows
+        past the break are garbage-by-design, like an inactive slot's
+        pick. The single-step program is the k_max = 1 case, and the
+        only one that sees a position before the prompt's last
+        (token-by-token prefill) or copy-on-write splits (`cow`, the
+        lanes that split a page in this dispatch)."""
         slot = self._slots[s]
         live_steps = 0
-        released = False
         for k in range(k_max):
             p = slot.pos
             slot.pos = p + 1
             live_steps += 1
-            if bads[k] > 0:
+            if bads[k, s] > 0:
+                # on-device non-finite guard fired for this lane:
+                # terminate ONLY this stream. Checked before the
+                # prefix-cache registration below so a poisoned stream
+                # never publishes its (suspect) KV pages.
                 req = slot.req
                 self.stats["poisoned"] += 1
                 self.release(s, "error",
                              "non-finite logits at position "
                              f"{p}; request poisoned and isolated")
                 finished.append(req)
-                released = True
                 break
             if p <= slot.n_prompt - 1:
-                # the first fused step computed prompt context (the
-                # first-token step) — TTFT prefill-compute term
+                # this dispatch computed prompt context for the slot
+                # (token-by-token prefill, or the first-token step) —
+                # it belongs to the TTFT prefill-compute term
                 slot.prefill_s += t1 - t0
             if self.prefix_cache:
+                # a prompt whose length is a page multiple completes
+                # its final page on this very advance — publish it
                 self._register_full_pages(s, slot)
-            tok = int(toks[k])
+            if p < slot.n_prompt - 1:
+                continue  # token-by-token prefill: output discarded
+            tok = int(toks[k, s])
             if slot.req.first_token_at is None:
                 slot.req.first_token_at = t1
                 self._note_first_token(slot, t1)
@@ -1111,20 +1218,20 @@ class DecodeEngine:
             n_out = len(slot.req.tokens)
             if self.tracer is not None and n_out > 1 \
                     and n_out % self.decode_span_every == 0:
+                # sampled: one decode span every Nth output token (the
+                # first token has its own instant) — enough to see
+                # cadence without drowning the timeline
                 self._span("decode", t0, t1, slot.req, pos=p,
-                           token_index=n_out, cow=0)
+                           token_index=n_out, cow=int(s in cow))
             if (slot.req.eos_id is not None
                     and tok == slot.req.eos_id) \
                     or len(slot.req.tokens) >= slot.req.max_new_tokens:
                 self.release(s, "ok")
                 finished.append(slot.req)
-                released = True
                 break
-        # retained decode work only: kv_bytes stays exactly
-        # decode_tokens x decode_bytes_per_token across every program
+        # retained decode work only (kv_bytes follows it, once a
+        # dispatch: _dispatched)
         self.stats["decode_tokens"] += live_steps
-        self.stats["kv_bytes"] += \
-            live_steps * self.slab.decode_bytes_per_token
 
     def _dispatch_multi(self, members: List[int], finished) -> bool:
         """One multi-step dispatch covering every ready slot: K fused
@@ -1165,43 +1272,16 @@ class DecodeEngine:
                 if slot.req.eos_id is not None:
                     eos_ids[s] = slot.req.eos_id
                 budgets[s] = slot.req.max_new_tokens - len(slot.req.tokens)
-            args = (self._params_by_gen[self.weight_generation],
+            args = [self._params_by_gen[self.weight_generation],
                     *self.slab.state,
                     jnp.asarray(tokens), jnp.asarray(pos),
                     jnp.asarray(self._tables), jnp.asarray(live),
                     jnp.asarray(temps), jnp.asarray(seeds),
-                    jnp.asarray(eos_ids), jnp.asarray(budgets))
-            self._ledger_capture("serve.multi_step", self._multi, args,
-                                 steps=K)
-        with phase("serve.step.enqueue", step=step) as span:
-            before = self._multi._cache_size()
-            t0 = self.clock()
-            toks, bads, *state = self._multi(*args)
-            self.slab.state = tuple(state)
-            compiled = self._multi._cache_size() > before
-            t1 = self.clock()
-            span["compiled"] = int(compiled)
-            self.compile_tracker.note(compiled, t1 - t0,
-                                      program="serve.multi_step")
-            self._dispatch_wall_s += t1 - t0
-            self.stats["dispatches"] += 1
-            self.stats["multi_step_dispatches"] += 1
-            self.stats["multi_step_compiles"] += int(compiled)
-            self.stats["occupancy_sum"] += len(members)
-            self._count_page_walk(members)
-            self.flush_events()
-        with phase("serve.step.readback", step=step):
-            toks_host = np.asarray(toks)
-            bads_host = np.asarray(bads)
-        with phase("serve.step.emit", step=step):
-            g0 = self.stats["generated_tokens"]
+                    jnp.asarray(eos_ids), jnp.asarray(budgets)]
+        with self._dispatched("multi", args, members, steps=K) as d:
+            toks, bads = d.out
             for s in members:
-                self._walk_emitted(s, toks_host[:, s], bads_host[:, s], K,
-                                   t0, t1, finished)
-            self.ledger.note_dispatch(
-                "serve.multi_step",
-                tokens=self.stats["generated_tokens"] - g0)
-            del args, toks, bads     # released inside a phase
+                self._walk_emitted(s, toks, bads, K, d.t0, d.t1, finished)
         return True
 
     def _dispatch_spec(self, members: List[int], finished) -> bool:
@@ -1257,48 +1337,24 @@ class DecodeEngine:
                 temps[s] = slot.req.temperature
                 seeds[s] = np.uint32(slot.req.seed & 0xFFFFFFFF)
                 wlen_arr[s] = wlens[s]
-            args = (self._params_by_gen[self.weight_generation],
+            args = [self._params_by_gen[self.weight_generation],
                     self._draft_params, *self.slab.state,
                     jnp.asarray(window), jnp.asarray(pos),
                     jnp.asarray(self._tables), jnp.asarray(live),
                     jnp.asarray(temps), jnp.asarray(seeds),
-                    jnp.asarray(wlen_arr))
-            self._ledger_capture("serve.spec_verify", self._verify, args,
-                                 steps=K + 1)
-        with phase("serve.step.enqueue", step=step) as span:
-            before = self._verify._cache_size()
-            t0 = self.clock()
-            picks, bads, acc, *state = self._verify(*args)
-            self.slab.state = tuple(state)
-            compiled = self._verify._cache_size() > before
-            t1 = self.clock()
-            span["compiled"] = int(compiled)
-            self.compile_tracker.note(compiled, t1 - t0,
-                                      program="serve.spec_verify")
-            self._dispatch_wall_s += t1 - t0
-            self.stats["dispatches"] += 1
-            self.stats["verify_dispatches"] += 1
-            self.stats["verify_compiles"] += int(compiled)
-            self.stats["occupancy_sum"] += len(members)
-            self._count_page_walk(members)
-            self.flush_events()
-        with phase("serve.step.readback", step=step):
-            picks_host = np.asarray(picks)
-            bads_host = np.asarray(bads)
-            acc_host = np.asarray(acc)
-        with phase("serve.step.emit", step=step):
-            gen_before_walk = self.stats["generated_tokens"]
+                    jnp.asarray(wlen_arr)]
+        with self._dispatched("verify", args, members, steps=K + 1) as d:
+            picks, bads, acc = d.out
             for s in members:
                 slot = self._slots[s]
-                a = int(acc_host[s])
+                a = int(acc[s])
                 p_start = slot.pos
                 self.stats["draft_tokens"] += K
                 # accepted prefix + the bonus pick (what the verifier kept;
                 # emission may still stop earlier at EOS)
                 self.stats["accepted_tokens"] += a + 1
                 self.stats["rejected_tokens"] += K - a
-                self._walk_emitted(s, picks_host[:a + 1, s],
-                                   bads_host[:a + 1, s], a + 1, t0, t1,
+                self._walk_emitted(s, picks, bads, a + 1, d.t0, d.t1,
                                    finished)
                 if self._slots[s] is None:
                     continue   # released: its pages were freed wholesale
@@ -1310,10 +1366,6 @@ class DecodeEngine:
                         self.pager.free([pid])
                         self._tables[s, pi] = 0
                         self._live_entries[s] -= 1
-            self.ledger.note_dispatch(
-                "serve.spec_verify",
-                tokens=self.stats["generated_tokens"] - gen_before_walk)
-            del args, picks, bads, acc   # released inside a phase
         return True
 
     def _step_inner(self, exclude: frozenset = frozenset()
@@ -1510,103 +1562,25 @@ class DecodeEngine:
                     if s in cow:
                         copy_src[s], copy_dst[s] = cow[s]
 
-                step_args = (
+                args = [
                     self._params_by_gen[gen], *self.slab.state,
                     jnp.asarray(tokens), jnp.asarray(pos),
                     jnp.asarray(self._tables), jnp.asarray(write_page),
                     jnp.asarray(write_off), jnp.asarray(active),
                     jnp.asarray(temps), jnp.asarray(key_data),
                     jnp.asarray(copy_src), jnp.asarray(copy_dst),
-                    jnp.asarray(poison))
-                self._ledger_capture("serve.decode", self._step, step_args)
-            with phase("serve.step.enqueue", step=step) as span:
-                before = self._step._cache_size()
-                t0 = self.clock()
-                nxt, bad, *state = self._step(*step_args)
-                self.slab.state = tuple(state)
-                compiled = self._step._cache_size() > before
-                t1 = self.clock()
-                span["compiled"] = int(compiled)
-                self.compile_tracker.note(compiled, t1 - t0,
-                                          program="serve.decode")
-                self._dispatch_wall_s += t1 - t0
-                self.stats["dispatches"] += 1
-                self.stats["compiles"] += int(compiled)
-                self.stats["occupancy_sum"] += len(members)
-                self._count_page_walk(members)
-                self.stats["decode_tokens"] += len(members)
-                # decode-bandwidth proxy: every decode-phase lane reads its
-                # whole paged context once per layer (geometry x dtype —
-                # deterministic, no timers)
-                self.stats["kv_bytes"] += \
-                    len(members) * self.slab.decode_bytes_per_token
-                self.flush_events()
-            with phase("serve.step.readback", step=step):
-                nxt_host = np.asarray(nxt)
-                bad_host = np.asarray(bad)
-
-            with phase("serve.step.emit", step=step) as emitted:
+                    jnp.asarray(poison)]
+            with self._dispatched("decode", args, members) as d:
+                nxt, bad = d.out
                 # the family's own counts ride behind the S picks, in
-                # the transfer that brought them; the step's go on this
-                # phase record, where a reader reaches them after the
-                # deployment has stopped (utils/trace.py phases())
-                for name, n in zip(self.family.step_counters,
-                                   nxt_host[S:]):
+                # the transfer that brought them; the step's go on the
+                # emit phase record, where a reader reaches them after
+                # the deployment has stopped (utils/trace.py phases())
+                for name, n in zip(self.family.step_counters, nxt[S:]):
                     self.stats[name] += int(n)
-                    emitted[name] = int(n)
-                gen_before_emit = self.stats["generated_tokens"]
+                    d.span[name] = int(n)
+                toks, bads = nxt[None], bad[None]
                 for s in members:
-                    slot = self._slots[s]
-                    p = slot.pos
-                    slot.pos = p + 1
-                    if bad_host[s] > 0:
-                        # on-device non-finite guard fired for this lane:
-                        # terminate ONLY this stream. Checked before the
-                        # prefix-cache registration below so a poisoned
-                        # stream never publishes its (suspect) KV pages.
-                        req = slot.req
-                        self.stats["poisoned"] += 1
-                        self.release(s, "error",
-                                     "non-finite logits at position "
-                                     f"{p}; request poisoned and isolated")
-                        finished.append(req)
-                        continue
-                    if p <= slot.n_prompt - 1:
-                        # this dispatch computed prompt context for the slot
-                        # (token-by-token prefill, or the first-token step)
-                        # — it belongs to the TTFT prefill-compute term
-                        slot.prefill_s += t1 - t0
-                    if self.prefix_cache:
-                        # a prompt whose length is a page multiple completes
-                        # its final page on this very advance — publish it
-                        self._register_full_pages(s, slot)
-                    if p < slot.n_prompt - 1:
-                        continue  # token-by-token prefill: output discarded
-                    tok = int(nxt_host[s])
-                    if slot.req.first_token_at is None:
-                        slot.req.first_token_at = t1
-                        self._note_first_token(slot, t1)
-                    self._emit_token(slot.req, tok)
-                    self.stats["generated_tokens"] += 1
-                    n_out = len(slot.req.tokens)
-                    if self.tracer is not None and n_out > 1 \
-                            and n_out % self.decode_span_every == 0:
-                        # sampled: one decode span every Nth output token
-                        # (the first token has its own instant) — enough to
-                        # see cadence without drowning the timeline
-                        self._span("decode", t0, t1, slot.req, pos=p,
-                                   token_index=n_out, cow=int(s in cow))
-                    if (slot.req.eos_id is not None
-                            and tok == slot.req.eos_id) \
-                            or len(slot.req.tokens) >= slot.req.max_new_tokens:
-                        self.release(s, "ok")
-                        finished.append(slot.req)
-                self.ledger.note_dispatch(
-                    "serve.decode",
-                    tokens=self.stats["generated_tokens"] - gen_before_emit)
-                # drop the dispatch's argument and result buffers inside
-                # a phase: left to the function's return, their release
-                # (a millisecond on the CPU backend) is host time under
-                # no name
-                del step_args, nxt, bad
+                    self._walk_emitted(s, toks, bads, 1, d.t0, d.t1,
+                                       finished, cow)
         return finished

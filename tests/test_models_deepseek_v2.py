@@ -544,3 +544,62 @@ def test_gpt_programs_are_the_builders_unchanged_behind_the_seam():
     with open(os.path.join(REPO, "kubeml_tpu", "serve", "engine.py")) as f:
         source = f.read()
     assert "models.gpt" not in source and "build_paged" not in source
+
+
+def test_both_families_decode_steps_end_in_the_one_sampling_function(
+        monkeypatch):
+    """GPT's and DeepSeek-V2's decode programs reach the SAME function
+    of models/base.py for the poison lane, the non-finite guard, the
+    PAD mask and the pick: traced with it wrapped by a recorder, each
+    program calls it once, with its [S, V] float32 logits, the step's
+    own per-lane arguments and its family's pad id. models/deepseek_v2.py
+    takes nothing from models/gpt.py."""
+    from kubeml_tpu.models import base, gpt
+    assert gpt.sample_tokens is base.sample_tokens is ds.sample_tokens
+    assert gpt.cow_split_pages is base.cow_split_pages is ds.cow_split_pages
+    with open(ds.__file__) as f:
+        assert "models.gpt" not in f.read()     # no import of it
+    calls = []
+
+    def recorder(logits, active, temps, key_data, poison, pad_id):
+        calls.append((logits.shape, logits.dtype, active.shape,
+                      temps.shape, key_data.shape, poison.shape, pad_id))
+        return base.sample_tokens(logits, active, temps, key_data,
+                                  poison, pad_id)
+
+    monkeypatch.setattr(gpt, "sample_tokens", recorder)
+    monkeypatch.setattr(ds, "sample_tokens", recorder)
+    S, G, pmax = 3, 8, 4
+    i32, f32 = jnp.int32, jnp.float32
+    sds = jax.ShapeDtypeStruct
+    lanes = [sds((S,), i32), sds((S,), i32), sds((S, pmax), i32),
+             sds((S,), i32), sds((S,), i32), sds((S,), f32),
+             sds((S,), f32), sds((S, 2), jnp.uint32), sds((S,), i32),
+             sds((S,), i32), sds((S,), f32)]
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    P = S * pmax + 1
+    nano = gpt.GPTNano().module
+    rows = sds((nano.layers, P, G, nano.hidden), nano.dtype)
+    scales = sds((nano.layers, P), f32)
+    gpt_params = abstract(jax.eval_shape(lambda: nano.init(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), i32))["params"]))
+    tiny = ds.DeepSeekV2Module()
+    ds_params = abstract(jax.eval_shape(
+        lambda: tiny.init(jax.random.PRNGKey(0)))["params"])
+    for family, args, vocab in (
+            (nano.serve_family(),
+             [gpt_params, rows, rows, scales, scales, sds((P, G), f32)],
+             nano.vocab_size),
+            (tiny.serve_family(),
+             [ds_params, sds((tiny.layers, P, G, tiny.row_lanes),
+                             tiny.dtype)], tiny.vocab_size)):
+        del calls[:]
+        out = jax.eval_shape(family.decode_step("f32", "gather", False),
+                             *args, *lanes)
+        assert calls == [((S, vocab), f32, (S,), (S,), (S, 2), (S,),
+                          family.pad_id)], family.name
+        assert out[0].shape == (S + len(family.step_counters),)
+        assert out[1].shape == (S,) and out[1].dtype == f32

@@ -639,6 +639,101 @@ def test_phases_answer_after_the_service_stopped():
         for r in phases(t0=t_after + 1.0))
 
 
+# One dispatch sequence for the four programs (DecodeEngine._dispatched):
+# kind -> (engine knobs, prompt, the program's name in the tracker and
+# the cost ledger, its own dispatch counter, its compile counter). The
+# decode-lane kinds start from 1-token prompts, so every step is an
+# all-decode steady state and the accelerated programs engage at once;
+# the prefill kind's 20-token prompt owes three chunks of 8.
+DISPATCH_KINDS = {
+    "single_step": (dict(prefill_chunk=0), [5], "serve.decode",
+                    "dispatches", "compiles"),
+    "multi_step": (dict(decode_steps=4), [5], "serve.multi_step",
+                   "multi_step_dispatches", "multi_step_compiles"),
+    "verify": (dict(draft=True), [5], "serve.spec_verify",
+               "verify_dispatches", "verify_compiles"),
+    "prefill": (dict(prefill_chunk=8), list(range(2, 22)), "serve.prefill",
+                "prefill_dispatches", "prefill_compiles"),
+}
+LANE_COUNTERS = ("dispatches", "occupancy_sum", "live_page_entries_sum",
+                 "page_entries_sum")
+
+
+@pytest.mark.parametrize("kind", sorted(DISPATCH_KINDS))
+def test_one_dispatch_leaves_the_same_records_whatever_the_program(kind):
+    """Two dispatches of each program through the one sequence: the
+    phase records a dispatch leaves (a decode-lane dispatch of any kind:
+    pages, pack, enqueue, readback, emit, in that order; a prefill
+    dispatch its one serve.step.prefill and NO enqueue or readback,
+    which is how benchmark/metrics/serve_loop_phases.py tells a decode
+    iteration), the decode lane's shared counters moved alike by the
+    three kinds and not at all by a prefill dispatch, the kind's own
+    dispatch counter up by one a dispatch, and exactly one compile."""
+    from kubeml_tpu.serve.engine import DecodeEngine
+    from kubeml_tpu.serve.slots import GenerateRequest
+    from kubeml_tpu.utils.trace import phases
+
+    knobs, prompt, program, n_key, c_key = DISPATCH_KINDS[kind]
+    knobs = dict(knobs)
+    _model, module, variables = _nano()
+    if knobs.pop("draft", False):
+        knobs.update(draft_module=module, draft_variables=variables)
+    engine = DecodeEngine(module, variables, slots=4, page=4, **knobs)
+    n_reqs = 1 if kind == "prefill" else 2
+    for seed in range(n_reqs):
+        engine.attach(GenerateRequest(list(prompt), max_new_tokens=12,
+                                      temperature=0.0, seed=seed))
+    pages = ["serve.step.pages"] * (1 if kind == "single_step" else 2)
+    lane = ["serve.step.reap", *pages, "serve.step.pack",
+            "serve.step.enqueue", "serve.step.readback", "serve.step.emit"]
+    tid = threading.get_ident()
+    for nth in (1, 2):
+        before = dict(engine.stats)
+        t0 = time.monotonic()
+        engine.step()
+        recs = [r for r in phases(t0=t0) if r.tid == tid
+                and r.name.startswith("serve.step.")
+                and r.args.get("step") == engine._step_count]
+        names = [r.name for r in recs]
+        moved = {k: engine.stats[k] - before[k] for k in LANE_COUNTERS}
+        assert engine.stats[n_key] - before[n_key] == 1
+        if kind == "prefill":
+            assert names == ["serve.step.reap", "serve.step.prefill",
+                             "serve.step.pages"]
+            assert recs[1].args["tokens"] == 8
+            assert moved == dict.fromkeys(LANE_COUNTERS, 0)
+            assert engine.stats["prefill_tokens"] == 8 * nth
+            assert engine.stats["generated_tokens"] == 0
+            continue
+        assert names == lane
+        enqueue = recs[names.index("serve.step.enqueue")]
+        assert enqueue.args["compiled"] == (1 if nth == 1 else 0)
+        assert all(b.t0 >= a.t1 for a, b in zip(recs, recs[1:]))
+        assert moved["dispatches"] == 1
+        assert moved["occupancy_sum"] == n_reqs
+        assert moved["page_entries_sum"] == \
+            n_reqs * engine.geom.pages_per_slot
+        assert n_reqs <= moved["live_page_entries_sum"] \
+            <= moved["page_entries_sum"]
+        assert engine.stats["generated_tokens"] \
+            > before["generated_tokens"]
+        assert engine.stats["kv_bytes"] == \
+            engine.stats["decode_tokens"] * engine.kv_bytes_per_token
+    assert engine.stats[c_key] == 1
+    # nobody else compiled, or dispatched under this kind's name
+    for other, (*_, o_c) in DISPATCH_KINDS.items():
+        if other != kind and o_c != c_key:
+            assert engine.stats[o_c] == 0, (other, o_c)
+    # the tracker and the cost ledger heard of both, under one name
+    assert list(engine.compile_tracker._recent) == [program]
+    snap = engine.compile_tracker.snapshot()
+    assert (snap["jit_dispatches"], snap["jit_compiles"]) == (2, 1)
+    totals = engine.ledger.totals(program)
+    assert totals["dispatches"] == 2
+    assert totals["tokens"] == engine.stats["generated_tokens"]
+    engine.flush_events()
+
+
 def _profiled_decode(tmp_path, profile: bool):
     """Three requests through a bare engine, with a profiler session
     around the steps or without; returns (tokens, trace dir)."""
