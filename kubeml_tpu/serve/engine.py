@@ -38,14 +38,15 @@ or hot-swap drain falls back to the single-step decode program — the
 accelerated programs only ever see the steady state they were compiled
 for, so the inventory above is exhaustive and recompilation-free.
 
-All four are dispatched through ONE sequence, DecodeEngine._dispatched
-(cost record, clock pair, call, slab state, compile note, counters,
-and for the decode lane the shared counters, the hand-over of held-back
-token events, the readback and the enqueue / readback / emit phase
-records), and the three decode-lane programs' picks are emitted by ONE
-walk, DecodeEngine._walk_emitted (the single-step program is its
-one-row case). A dispatcher keeps what is its own: granting pages,
-packing its lanes, reading its outputs.
+All four are dispatched through ONE sequence in two halves,
+DecodeEngine._enqueue (cost record, clock pair, call, slab state,
+compile note, counters, and for the decode lane the shared counters,
+the hand-over of held-back token events and the enqueue phase record)
+and DecodeEngine._read (the readback and the readback / emit phase
+records); _dispatched runs one behind the other. The three decode-lane
+programs' picks are emitted by ONE walk, DecodeEngine._walk_emitted
+(the single-step program is its one-row case). A dispatcher keeps what
+is its own: granting pages, packing its lanes, reading its outputs.
 
 A token-budget scheduler in step() interleaves the two: each engine
 step spends at most `prefill_budget` prompt tokens on prefill chunks
@@ -54,6 +55,34 @@ so by default a step runs one), then runs one decode dispatch for the
 streams that are past their prompt — so in-flight streams' inter-token
 latency stays bounded while new prompts load, instead of every stream
 stalling behind a 512-token prompt fed one token per dispatch.
+
+ONE DECODE DISPATCH AHEAD. Of what the single-step decode program
+takes, only `tokens` depends on the dispatch before it; positions,
+pages, copy-on-write pairs, sampling keys and who leaves by its token
+budget are the host's own bookkeeping. So where the host knows all of
+that (_runs_ahead: no masked lane, no fault plan, one weight
+generation, no accelerated program, pages to spare), a step packs and
+enqueues the NEXT decode dispatch before it reads the last one back:
+
+  reap -> prefill lane -> pages / pack / enqueue D(n+1)
+       -> readback D(n) -> emit D(n)
+
+and returns with D(n+1) unread (`_unread`). A continuing lane's input
+token is D(n)'s pick, taken on the device (the jitted entry selects it
+from the unread dispatch's output row where the lane's `from_prev` is
+set); a lane joining from prefill brings its prompt token as before.
+Device order is what it was: D(n), chunk, D(n+1), chunk. A lane whose
+end only the result shows (EOS, the non-finite guard) has one lane-step
+computed too many: its row is dropped at the walk
+(stats["overrun_lane_steps"]), its write went to a page it still held.
+A dispatch remembers the slot objects it was packed for, so a row never
+reaches a request that took the slot later. Every other step reads the
+unread dispatch FIRST and runs the serial sequence it always ran, and
+whatever takes state out from under the engine settles the unread
+dispatch before it does: drain() (install_weights, evacuate,
+spawn_recovered, the service before it parks or stops) reads and emits
+it, abandon() drops it unread (a resumed stream decodes those tokens
+again, bit-identically).
 
 Prefix caching rides the same page tables: at attach, the engine walks
 the prompt's full pages through the allocator's content-hash index
@@ -115,6 +144,8 @@ SERVE_PATH_VARIANTS = (
     "spec_verify",              # speculative accept path vs generate
     "spec_rollback",            # rejected tokens: pager state == never-
                                 # proposed run: cursors, free list, scales
+    "one_ahead",                # next decode dispatch enqueued before the
+                                # last is read vs the serial sequence
 )
 
 # Every hot-swap path variant MUST have a quoted-name test in tests/
@@ -181,11 +212,13 @@ SERVE_PHASE_KINDS = (
     "serve.step.pages",     # decode-lane page grants, copy-on-write
     "serve.step.pack",      # numpy arrays and jnp.asarray transfers
     "serve.step.enqueue",   # the jitted call until it returns; args:
-                            # compiled
+                            # compiled, ahead
     "serve.step.readback",  # np.asarray of the picks: the host blocked
-                            # on the device
+                            # on the device, the next dispatch queued
+                            # behind the one awaited where the step
+                            # runs ahead
     "serve.step.emit",      # per-slot advance, prefix registration,
-                            # emit_token, release
+                            # emit_token, release; args: overrun
 )
 
 
@@ -204,12 +237,35 @@ _PROGRAMS = {
                 "prefill_compiles"),
 }
 
-# what DecodeEngine._dispatched hands the code that reads one dispatch:
-# the clock pair around the call, whether it compiled, the args of the
-# open serve.step.emit record, and the program's outputs ahead of the
-# slab state as host arrays
+# what DecodeEngine._read hands the code that reads one dispatch: the
+# clock pair around the call, whether it compiled, the args of the open
+# serve.step.emit record, and the program's outputs ahead of the slab
+# state as host arrays
 _Dispatched = collections.namedtuple("_Dispatched",
                                      "t0 t1 compiled span out")
+
+
+class _Enqueued:
+    """One dispatch between DecodeEngine._enqueue and ._read: the
+    program's ledger name, the clock pair around the call, whether it
+    compiled, its argument list (kept until the read: see _read) and
+    its outputs ahead of the slab state, still on the device. A
+    single-step decode dispatch also remembers whom it was
+    packed for, `lanes` (lane -> the _Slot object it held then), and the
+    lanes that split a page in it, `cow`: it may be read a step later."""
+
+    __slots__ = ("program", "t0", "t1", "compiled", "args", "out",
+                 "lanes", "cow")
+
+    def __init__(self, program, t0, t1, compiled, args, out):
+        self.program = program
+        self.t0 = t0
+        self.t1 = t1
+        self.compiled = compiled
+        self.args = args
+        self.out = out
+        self.lanes: Dict[int, "_Slot"] = {}
+        self.cow: Dict[int, tuple] = {}
 
 
 def _no_phase(name, **args):
@@ -228,6 +284,25 @@ def _serve_family(module) -> ServeFamily:
             f"is served when its serve_family() returns its cache "
             f"declaration and paged programs (models/base.py ServeFamily)")
     return make()
+
+
+def _decode_entry(step_fn, n_state: int, slots: int):
+    """The engine's jitted single-step decode entry, the same for every
+    family: `step_fn` (family.decode_step's function, untouched) behind
+    one select. `prev` is the token row of the dispatch before this one
+    (picks, then the family's counters), still on the device, and a lane
+    whose `from_prev` is set takes its input token from there instead of
+    from the host's `tokens`: the next step's tokens never cross the
+    host. Named `step` like the function it wraps, so the compiled
+    module keeps the name a trace knows it by."""
+
+    def step(params, *rest):
+        state = rest[:n_state]
+        prev, from_prev, tokens, *lanes = rest[n_state:]
+        tokens = jnp.where(from_prev > 0, prev[:slots], tokens)
+        return step_fn(params, *state, tokens, *lanes)
+
+    return step
 
 
 class _Slot:
@@ -319,7 +394,29 @@ class DecodeEngine:
         n_state = len(self.slab.state)
         donate = () if jax.default_backend() == "cpu" \
             else tuple(range(1, 1 + n_state))
-        self._step = jax.jit(self._step_raw, donate_argnums=donate)
+        self._step = jax.jit(
+            _decode_entry(self._step_raw, n_state, self.geom.slots),
+            donate_argnums=donate)
+        # what a decode dispatch with no dispatch before it is handed
+        # as `prev`: the same shape and dtype, so the entry compiles once
+        self._no_prev = jnp.zeros(
+            self.geom.slots + len(family.step_counters), jnp.int32)
+        # an all-zero per-lane argument is not sent again (_lane)
+        self._zero_lanes = {
+            np.dtype(dt): jnp.zeros(self.geom.slots, dt)
+            for dt in (np.int32, np.float32)}
+        # the single-step decode dispatch that is enqueued and not yet
+        # read (module docstring, ONE DECODE DISPATCH AHEAD), and the
+        # requests a drain() outside a step finished, which the next
+        # step returns
+        self._unread: Optional[_Enqueued] = None
+        self._carry: List[GenerateRequest] = []
+        # the most pages one step can take: a page a decode lane, and
+        # what each prefill chunk of the step's budget can span
+        self._step_pages = self.geom.slots
+        if prefill_chunk > 0:
+            self._step_pages += -(-self.prefill_budget // prefill_chunk) \
+                * (prefill_chunk // self.geom.page + 2)
         self._prefill = None
         if prefill_chunk > 0:
             self._prefill = jax.jit(
@@ -450,6 +547,11 @@ class DecodeEngine:
             "verify_dispatches": 0, "verify_compiles": 0,
             "draft_tokens": 0, "accepted_tokens": 0,
             "rejected_tokens": 0,
+            # decode dispatches enqueued while the one before was still
+            # unread (its share of "dispatches" is how often the engine
+            # ran one ahead), and lane-steps whose row was dropped at
+            # the walk because the lane's request had gone by then
+            "ahead_dispatches": 0, "overrun_lane_steps": 0,
         }
         # counts the family's decode program appends to its token row
         # (ServeFamily.step_counters), summed over decode dispatches
@@ -572,7 +674,9 @@ class DecodeEngine:
         stay resident); every LATER attach pins to the new generation.
         Returns the new generation number. Serving-loop thread only,
         like attach/step — the ServeService marshals installs into the
-        loop via its pending-install hook."""
+        loop via its pending-install hook. An unread dispatch is
+        settled first: it was packed while one generation was resident."""
+        self._carry.extend(self.drain())
         self.weight_generation += 1
         self._params_by_gen[self.weight_generation] = jax.device_put(
             variables["params"])
@@ -785,7 +889,10 @@ class DecodeEngine:
         prompt + emitted tokens for a bit-identical continuation. The
         pager audit runs like any release: a refcount that does not
         balance on forced teardown is a real leak, attributable here
-        rather than archaeology at the next restart."""
+        rather than archaeology at the next restart. An unread dispatch
+        is read and emitted first, so the request leaves with every
+        token computed for it (and may end there: None then)."""
+        self._carry.extend(self.drain())
         slot = self._slots[s]
         if slot is None:
             return None
@@ -795,6 +902,9 @@ class DecodeEngine:
         self._tables[s] = 0
         self._live_entries[s] = 0
         self._slots[s] = None
+        # the request leaves with its token events handed over, in
+        # order before whatever its next engine emits
+        self.flush_events(only=slot.req)
         self._maybe_retire(slot.gen)
         self.check_pager()
         return slot.req
@@ -884,6 +994,17 @@ class DecodeEngine:
         decode, so no slot is ever 'in prefill'."""
         return self._prefill is not None and slot.pos < slot.n_prompt - 1
 
+    def _lane(self, host: np.ndarray) -> jax.Array:
+        """A per-lane [S] argument of the decode program, on the device.
+        One that is all zeros (no copy-on-write pair, no poison, greedy
+        temperatures, no host token where every lane takes the last
+        dispatch's pick) is the zeros already there: with a dispatch
+        always queued the host's step is what the device waits for, and
+        a transfer is a quarter of a millisecond of it."""
+        if host.any():
+            return jnp.asarray(host)
+        return self._zero_lanes[host.dtype]
+
     def _count_page_walk(self, members: List[int]) -> None:
         """Beside `occupancy_sum`, per decode-lane dispatch: the table
         entries of its occupied slots and how many of them point at a
@@ -903,8 +1024,13 @@ class DecodeEngine:
         at any time without double-emitting tokens the new engine is
         re-decoding; it also unblocks ServeFaultPlan.maybe_wedge. Token
         events still held back go out now: their tokens are in
-        `req.tokens`, which a resumed stream never emits again."""
+        `req.tokens`, which a resumed stream never emits again. An
+        unread dispatch is DROPPED, not read: the caller may hold the
+        service's lock and the device may be what went wrong, and a
+        resumed stream decodes those tokens again, bit-identically (a
+        step still running when this lands drops what it leaves: step)."""
         self._abandoned = True
+        self._unread = None
         self.flush_events()
 
     # ------------------------------------------------- deferred events
@@ -958,7 +1084,11 @@ class DecodeEngine:
         replacement ADOPTS every resident weight generation, so resumed
         streams re-attach pinned to the params they started under; the
         prefix cache starts cold (its KV bytes lived in the dead slab)
-        and re-fills as resumed prompts re-prefill."""
+        and re-fills as resumed prompts re-prefill. A dispatch this
+        engine still held unread is read and emitted first (none after
+        abandon())."""
+        self._carry.extend(self.drain())
+        self.flush_events()
         eng = DecodeEngine(
             self.module,
             {"params": self._params_by_gen[self.weight_generation]},
@@ -996,6 +1126,13 @@ class DecodeEngine:
         step-exception bisection retries a failed step with suspect
         lanes masked to isolate the poisoning request).
 
+        Where the engine runs one decode dispatch ahead (module
+        docstring) the round's decode dispatch is still unread when this
+        returns and the requests returned are those the dispatch BEFORE
+        it finished: a caller that steps the engine itself and stops
+        mid-stream calls drain() for the last dispatch's tokens, as it
+        calls flush_events() for their events.
+
         Every step — including idle and stalled ones — leaves one record
         in the flight recorder; the mark/record pair brackets the whole
         round so the deltas cover every return path."""
@@ -1014,6 +1151,8 @@ class DecodeEngine:
         try:
             return self._step_inner(exclude)
         finally:
+            if self._abandoned:
+                self._unread = None     # abandon() landed mid-step
             if self._outbox_stale:
                 self.flush_events()
             if mark is not None:
@@ -1101,37 +1240,30 @@ class DecodeEngine:
             self._tables[s, pi] = 0
             self._live_entries[s] -= 1
 
-    @contextlib.contextmanager
-    def _dispatched(self, kind: str, args: list,
-                    members: Optional[List[int]] = None, steps: int = 1):
-        """THE dispatch sequence, from packed arguments to host arrays
-        and on to the ledger's note, for all four programs (`kind` keys
-        _PROGRAMS): the cost record's capture at the program's first
-        dispatch, the clock pair around the jitted call, the slab's new
-        state, the compile noted (tracker, the kind's own counters) and
-        the wall time; the body of the `with` then reads the dispatch (a
-        _Dispatched), and the ledger's dispatch note, with the tokens
-        that body emitted, closes it.
+    def _enqueue(self, kind: str, args: list,
+                 members: Optional[List[int]] = None,
+                 steps: int = 1) -> _Enqueued:
+        """THE dispatch sequence, first half, for all four programs
+        (`kind` keys _PROGRAMS): the cost record's capture at the
+        program's first dispatch, the clock pair around the jitted call,
+        the slab's new state, the compile noted (tracker, the kind's own
+        counters) and the wall time. Returns the dispatch, unread.
 
         With `members` (the occupied lanes: a decode-lane dispatch of
-        any of the three kinds) the sequence also moves the lane's
-        shared counters, hands over the last step's token events once
-        the program is enqueued (the handler threads then run while the
-        device does), reads the outputs back, and leaves the
-        serve.step.enqueue / readback / emit records, the body running
-        inside emit. A prefill dispatch does none of that and reads
-        nothing back: benchmark/metrics/serve_loop_phases.py takes an
-        iteration with an enqueue record for a decode iteration.
+        any of the three kinds) it also moves the lane's shared
+        counters, hands over the last step's token events once the
+        program is enqueued (the handler threads then run while the
+        device does) and leaves the serve.step.enqueue record, `ahead`
+        on it where the dispatch before this one is still unread. A
+        prefill dispatch does none of that:
+        benchmark/metrics/serve_loop_phases.py takes an iteration with
+        an enqueue record for a decode iteration.
 
-        `args` is a LIST, emptied before emit closes: the argument and
-        result buffers are dropped inside a phase (left to a function's
-        return, their release, a millisecond on the CPU backend, is
-        host time under no name)."""
+        `args` is a LIST that the read empties (_read)."""
         program, attr, n_key, c_key = _PROGRAMS[kind]
         jitfn = getattr(self, attr)
-        step = self._step_count
         lane = _no_phase if members is None else phase
-        with lane("serve.step.enqueue", step=step) as span:
+        with lane("serve.step.enqueue", step=self._step_count) as span:
             self._ledger_capture(program, jitfn, args, steps)
             before = jitfn._cache_size()
             t0 = self.clock()
@@ -1146,17 +1278,35 @@ class DecodeEngine:
                 self.stats[n_key] += 1
             self.stats[c_key] += int(compiled)
             if members is not None:
+                ahead = int(self._unread is not None)
                 span["compiled"] = int(compiled)
+                span["ahead"] = ahead
+                self.stats["ahead_dispatches"] += ahead
                 self.stats["dispatches"] += 1
                 self.stats["occupancy_sum"] += len(members)
                 self._count_page_walk(members)
                 self.flush_events()
+            return _Enqueued(program, t0, t1, compiled, args,
+                             list(out[:n_out]))
+
+    @contextlib.contextmanager
+    def _read(self, rec: _Enqueued, lane=phase):
+        """THE dispatch sequence, second half: the outputs read back
+        (serve.step.readback: the host blocked on the device, with the
+        next dispatch queued behind the one awaited where the engine
+        runs ahead), then, inside serve.step.emit, the body of the
+        `with`, which reads the dispatch (a _Dispatched), and the
+        ledger's dispatch note with the tokens that body emitted.
+        `lane` is `phase` for a decode-lane dispatch read inside a step
+        and _no_phase where no record is left: a prefill chunk, a
+        drain() outside a step."""
+        step = self._step_count
         with lane("serve.step.readback", step=step):
-            host = [np.asarray(o) for o in out[:n_out]]
+            host = [np.asarray(o) for o in rec.out]
         with lane("serve.step.emit", step=step) as span:
             g0 = self.stats["generated_tokens"]
             d0 = self.stats["decode_tokens"]
-            yield _Dispatched(t0, t1, compiled, span, host)
+            yield _Dispatched(rec.t0, rec.t1, rec.compiled, span, host)
             # decode-bandwidth proxy: every lane-step the walk retained
             # read its whole paged context once per layer, so kv_bytes
             # stays exactly decode_tokens x decode_bytes_per_token
@@ -1164,9 +1314,83 @@ class DecodeEngine:
             self.stats["kv_bytes"] += (self.stats["decode_tokens"] - d0) \
                 * self.slab.decode_bytes_per_token
             self.ledger.note_dispatch(
-                program, tokens=self.stats["generated_tokens"] - g0)
-            args.clear()
-            del out
+                rec.program, tokens=self.stats["generated_tokens"] - g0)
+            # the argument and result buffers are dropped here, inside
+            # a phase and once the program has run (left to a function's
+            # return, their release, a millisecond on the CPU backend,
+            # is host time under no name; dropped at the enqueue, with
+            # the program and its transfers still pending, it cost the
+            # enqueue phase a millisecond on the chip)
+            rec.args.clear()
+            rec.out = None
+
+    @contextlib.contextmanager
+    def _dispatched(self, kind: str, args: list,
+                    members: Optional[List[int]] = None, steps: int = 1):
+        """A dispatch enqueued and read at once, its body inside the
+        emit half: a prefill chunk (which reads nothing back), the
+        multi-step and verify programs. The single-step decode program
+        takes the two halves apart where it runs ahead (_step_inner)."""
+        rec = self._enqueue(kind, args, members, steps)
+        with self._read(rec, _no_phase if members is None else phase) as d:
+            yield d
+
+    def _settle(self, rec: Optional[_Enqueued], finished,
+                lane=phase) -> None:
+        """Read one single-step decode dispatch back and emit it: the
+        family's counts, then every lane's pick through the walk. A row
+        goes only to the request its lane held at pack time: a slot
+        released since (cancel, deadline, an end that only the dispatch
+        before this one showed) or given to another request drops it,
+        counted in overrun_lane_steps. `rec` None is the step that
+        starts running ahead: nothing to read yet, and the two records
+        every decode iteration has are left all the same."""
+        if rec is None:
+            with lane("serve.step.readback", step=self._step_count):
+                pass
+            with lane("serve.step.emit", step=self._step_count) as span:
+                span["overrun"] = 0
+            return
+        S = self.geom.slots
+        with self._read(rec, lane) as d:
+            nxt, bad = d.out
+            # the family's own counts ride behind the S picks, in the
+            # transfer that brought them; the dispatch's go on the emit
+            # phase record of the step that walked it, where a reader
+            # reaches them after the deployment has stopped
+            # (utils/trace.py phases())
+            for name, n in zip(self.family.step_counters, nxt[S:]):
+                self.stats[name] += int(n)
+                d.span[name] = int(n)
+            toks, bads = nxt[None], bad[None]
+            overrun = 0
+            for s, slot in rec.lanes.items():
+                if self._slots[s] is not slot:
+                    overrun += 1
+                    continue
+                self._walk_emitted(s, toks, bads, 1, d.t0, d.t1,
+                                   finished, rec.cow)
+            self.stats["overrun_lane_steps"] += overrun
+            d.span["overrun"] = overrun
+
+    def _take_unread(self, finished, lane=phase) -> None:
+        """Settle the unread dispatch. It is forgotten before it is
+        read: a readback that raises is not tried again, and its lanes
+        then stand where their last emitted token left them."""
+        rec, self._unread = self._unread, None
+        self._settle(rec, finished, lane)
+
+    def drain(self) -> List[GenerateRequest]:
+        """Read and emit the dispatch that is still unread, if there is
+        one, outside a step (so no serve.step.* record): what a caller
+        that steps the engine itself calls when it stops mid-stream, and
+        what everything that takes state out from under the engine calls
+        first. Returns the requests it finished. On an abandoned engine
+        there is nothing to read: abandon() dropped it."""
+        finished: List[GenerateRequest] = []
+        if self._unread is not None and not self._abandoned:
+            self._take_unread(finished, _no_phase)
+        return finished
 
     def _walk_emitted(self, s: int, toks, bads, k_max: int,
                       t0: float, t1: float, finished, cow=()) -> None:
@@ -1230,7 +1454,7 @@ class DecodeEngine:
                 finished.append(slot.req)
                 break
         # retained decode work only (kv_bytes follows it, once a
-        # dispatch: _dispatched)
+        # dispatch: _read)
         self.stats["decode_tokens"] += live_steps
 
     def _dispatch_multi(self, members: List[int], finished) -> bool:
@@ -1374,12 +1598,21 @@ class DecodeEngine:
         G = self.geom.page
         stalled: List[int] = []
         step = self._step_count
+        # what drains outside a step finished since the last one
+        finished, self._carry = self._carry, []
+        ahead = self._runs_ahead(exclude)
+        # the dispatch still unread: where this step cannot run ahead it
+        # is read FIRST, and the step is the serial sequence it always
+        # was. One name for the whole step: abandon() may take the
+        # attribute away under it.
+        if self._unread is not None and not ahead:
+            self._take_unread(finished)
+        unread = self._unread
 
         with phase("serve.step.reap", step=step):
             # reap cancellations FIRST: a cancelled slot's pages go back to
             # the pool before this round's tables are snapshotted, so the
             # device never writes through a freed page
-            finished: List[GenerateRequest] = []
             for s, slot in enumerate(self._slots):
                 if slot is not None and slot.req.cancelled:
                     req = slot.req
@@ -1452,14 +1685,28 @@ class DecodeEngine:
         # and copy pair appear only in its own generation's dispatch
         # (other dispatches see 0 there, landing writes in the null
         # page), so generations never clobber each other's KV.
+        #
+        # A lane of the unread dispatch stands one position past its
+        # cursor (the walk that advances `pos` has not run yet), takes
+        # its token from that dispatch's picks on the device, and is no
+        # member here if that dispatch spends the last of its budget:
+        # the host knows all three.
         with phase("serve.step.pages", step=step):
             ready: List[int] = []
             cow: Dict[int, tuple] = {}
+            pos_of: Dict[int, int] = {}
             for s, slot in enumerate(self._slots):
                 if slot is None or self._in_prefill(slot) \
                         or slot.req.rid in exclude:
                     continue
-                pi = slot.pos // G
+                p = slot.pos
+                if unread is not None and unread.lanes.get(s) is slot:
+                    if p >= slot.n_prompt - 1 and \
+                            len(slot.req.tokens) + 1 \
+                            >= slot.req.max_new_tokens:
+                        continue    # its budget ends in the unread one
+                    p += 1
+                pi = p // G
                 pid = int(self._tables[s, pi])
                 if pid == 0:
                     pid = self.pager.alloc()
@@ -1480,30 +1727,34 @@ class DecodeEngine:
                     self.pager.free([pid])  # drop this slot's share
                     self.stats["cow_splits"] += 1
                 ready.append(s)
+                pos_of[s] = p
 
-            if not ready:
-                if stalled:
-                    self.stats["stalls"] += len(stalled)
-                    if not progressed:
-                        # every runnable slot is out of pages and nothing
-                        # moved this round: shed the NEWEST stream (oldest
-                        # is closest to finishing and freeing)
-                        victim = max(stalled, key=lambda s: self._slots[s].seq)
-                        req = self._slots[victim].req
-                        logger.warning("KV slab exhausted with all slots "
-                                       "stalled; shedding newest stream")
-                        self._shed_count += 1
-                        self.release(victim, "error",
-                                     "KV cache pages exhausted; request shed")
-                        finished.append(req)
-                return finished
             if stalled:
                 self.stats["stalls"] += len(stalled)
+            if not ready and stalled and not progressed:
+                # every runnable slot is out of pages and nothing
+                # moved this round: shed the NEWEST stream (oldest
+                # is closest to finishing and freeing)
+                victim = max(stalled, key=lambda s: self._slots[s].seq)
+                req = self._slots[victim].req
+                logger.warning("KV slab exhausted with all slots "
+                               "stalled; shedding newest stream")
+                self._shed_count += 1
+                self.release(victim, "error",
+                             "KV cache pages exhausted; request shed")
+                finished.append(req)
 
             # snapshot each ready slot's generation up front: an earlier
             # generation's dispatch may finish-and-release its members, and
             # re-reading self._slots for the next generation would hit None
             gen_of = {s: self._slots[s].gen for s in ready}
+
+        if not ready:
+            # nothing to enqueue: what is still unread ends here (every
+            # lane of it left by its budget, or was released)
+            if unread is not None:
+                self._take_unread(finished)
+            return finished
 
         # all-decode steady state: every ready slot is past its prompt,
         # nothing prefilled/stalled/CoW-split this round, no fault
@@ -1512,7 +1763,8 @@ class DecodeEngine:
         # compiled for. Speculative verify gets first claim, then the
         # multi-step scan; any ineligibility (including a failed page
         # grant, rolled back inside the dispatch method) falls through
-        # to the single-step loop below.
+        # to the single-step loop below. (An engine that has them never
+        # runs ahead, so nothing is unread here.)
         if (not exclude and not stalled and not cow and not progressed
                 and not finished and self.fault_plan is None
                 and (self._verify is not None or self._multi is not None)
@@ -1541,46 +1793,75 @@ class DecodeEngine:
                 copy_src = np.zeros(S, np.int32)
                 copy_dst = np.zeros(S, np.int32)
                 poison = np.zeros(S, np.float32)
+                from_prev = np.zeros(S, np.int32)
                 if self.fault_plan is not None:
                     for s in self.fault_plan.nan_hits(self._step_count,
                                                       members):
                         poison[s] = 1.0
                 for s in members:
                     slot = self._slots[s]
+                    p = pos_of[s]
                     active[s] = 1.0
-                    tokens[s] = slot.prompt[slot.pos] \
-                        if slot.pos < slot.n_prompt else slot.req.tokens[-1]
-                    pos[s] = slot.pos
-                    write_page[s] = int(self._tables[s, slot.pos // G])
-                    write_off[s] = slot.pos % G
+                    if p < slot.n_prompt:
+                        tokens[s] = slot.prompt[p]
+                    elif p > slot.pos:
+                        # the unread dispatch's pick, on the device
+                        from_prev[s] = 1
+                    else:
+                        tokens[s] = slot.req.tokens[-1]
+                    pos[s] = p
+                    write_page[s] = int(self._tables[s, p // G])
+                    write_off[s] = p % G
                     temps[s] = slot.req.temperature
                     # per-(request, position) key: sampling is independent
                     # of co-resident streams — the sampled-path
                     # bit-identity hinge
                     key_data[s] = (np.uint32(slot.req.seed & 0xFFFFFFFF),
-                                   np.uint32(slot.pos))
+                                   np.uint32(p))
                     if s in cow:
                         copy_src[s], copy_dst[s] = cow[s]
 
                 args = [
                     self._params_by_gen[gen], *self.slab.state,
-                    jnp.asarray(tokens), jnp.asarray(pos),
-                    jnp.asarray(self._tables), jnp.asarray(write_page),
-                    jnp.asarray(write_off), jnp.asarray(active),
-                    jnp.asarray(temps), jnp.asarray(key_data),
-                    jnp.asarray(copy_src), jnp.asarray(copy_dst),
-                    jnp.asarray(poison)]
-            with self._dispatched("decode", args, members) as d:
-                nxt, bad = d.out
-                # the family's own counts ride behind the S picks, in
-                # the transfer that brought them; the step's go on the
-                # emit phase record, where a reader reaches them after
-                # the deployment has stopped (utils/trace.py phases())
-                for name, n in zip(self.family.step_counters, nxt[S:]):
-                    self.stats[name] += int(n)
-                    d.span[name] = int(n)
-                toks, bads = nxt[None], bad[None]
-                for s in members:
-                    self._walk_emitted(s, toks, bads, 1, d.t0, d.t1,
-                                       finished, cow)
+                    self._no_prev if unread is None else unread.out[0],
+                    self._lane(from_prev),
+                    self._lane(tokens), self._lane(pos),
+                    jnp.asarray(self._tables), self._lane(write_page),
+                    self._lane(write_off), self._lane(active),
+                    self._lane(temps), jnp.asarray(key_data),
+                    self._lane(copy_src), self._lane(copy_dst),
+                    self._lane(poison)]
+            rec = self._enqueue("decode", args, members)
+            rec.lanes = {s: self._slots[s] for s in members}
+            rec.cow = cow
+            if not ahead:
+                self._settle(rec, finished)
+                continue
+            # one ahead: this dispatch stays unread, and the one before
+            # it is read with this one queued behind it. If that read
+            # fails, this one goes with it: it was packed on its word.
+            self._unread = rec
+            try:
+                self._settle(unread, finished)
+            except BaseException:
+                self._unread = None
+                raise
         return finished
+
+    def _runs_ahead(self, exclude: frozenset) -> bool:
+        """Whether this step may enqueue its decode dispatch before the
+        last one is read, and leave it unread in turn: decided by what
+        the engine can see, not by a setting. The host has to know
+        everything of the dispatch but the continuing lanes' tokens: no
+        masked lane (the service's bisection), no fault plan (its hooks
+        name the step a token was computed in), one resident weight
+        generation (one dispatch a step), none of the accelerated
+        programs (they are chosen by what the last dispatch left), and
+        pages enough that no grant of this step can fail that the unread
+        dispatch's releases would have covered: a lane takes one page at
+        most, the prefill lane what its budget's chunks span."""
+        return (not exclude and self.fault_plan is None
+                and self._multi is None and self._verify is None
+                and len(self._params_by_gen) == 1
+                and self.pager.free_pages + self.pager.evictable_pages
+                >= self._step_pages)

@@ -530,9 +530,18 @@ class ServeService:
                     self._admit(engine)
                     self._stepping = True
             if idle:
-                # the engine holds a step's token events back until its
-                # next decode program is enqueued (engine.py
-                # _emit_token); none is coming, so hand them over
+                # no slot is occupied, so a dispatch the engine still
+                # holds unread has nobody to emit to (its lanes were
+                # cancelled or ended a dispatch earlier): it is read and
+                # dropped before the loop parks. The engine also holds a
+                # step's token events back until its next decode program
+                # is enqueued (engine.py _emit_token); none is coming,
+                # so hand them over
+                drained = engine.drain()
+                if drained:
+                    with self._cv:
+                        for req in drained:
+                            self._terminal(req, None)
                 engine.flush_events()
                 self._publish()
                 with phase("serve.loop.wait", model=model,
@@ -579,7 +588,12 @@ class ServeService:
         # the grace budget — say so, rather than the generic message.
         msg = "drained: grace budget exhausted" if self._draining \
             else "serving loop stopped"
+        # tokens already computed reach their streams first (a stream
+        # may end there, in order)
+        drained = engine.drain()
         with self._cv:
+            for req in drained:
+                self._terminal(req, None)
             while self._pending:
                 self._terminal(self._pending.popleft(), "error", msg)
             for s in range(engine.slot_count):

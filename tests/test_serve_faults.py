@@ -193,6 +193,7 @@ def test_deadline_reaps_slot_and_restores_free_list():
     assert engine.pager.in_use == 0
     engine.attach(req)
     engine.step()
+    engine.step()       # the first step's dispatch is read by the second
     assert req.outcome is None and len(req.tokens) >= 1
     clk["t"] = 0.2
     finished = engine.step()

@@ -639,7 +639,8 @@ def test_phases_answer_after_the_service_stopped():
         for r in phases(t0=t_after + 1.0))
 
 
-# One dispatch sequence for the four programs (DecodeEngine._dispatched):
+# One dispatch sequence for the four programs (DecodeEngine._enqueue and
+# ._read):
 # kind -> (engine knobs, prompt, the program's name in the tracker and
 # the cost ledger, its own dispatch counter, its compile counter). The
 # decode-lane kinds start from 1-token prompts, so every step is an
@@ -708,6 +709,12 @@ def test_one_dispatch_leaves_the_same_records_whatever_the_program(kind):
         assert names == lane
         enqueue = recs[names.index("serve.step.enqueue")]
         assert enqueue.args["compiled"] == (1 if nth == 1 else 0)
+        # the single-step program runs one dispatch ahead: the first
+        # step's dispatch stays unread (its readback and emit records
+        # are empty), the second enqueues with it unread and reads it
+        ahead = kind == "single_step"
+        assert enqueue.args["ahead"] == int(ahead and nth == 2)
+        assert recs[-1].args.get("overrun", 0) == 0
         assert all(b.t0 >= a.t1 for a, b in zip(recs, recs[1:]))
         assert moved["dispatches"] == 1
         assert moved["occupancy_sum"] == n_reqs
@@ -715,11 +722,14 @@ def test_one_dispatch_leaves_the_same_records_whatever_the_program(kind):
             n_reqs * engine.geom.pages_per_slot
         assert n_reqs <= moved["live_page_entries_sum"] \
             <= moved["page_entries_sum"]
-        assert engine.stats["generated_tokens"] \
-            > before["generated_tokens"]
+        assert (engine.stats["generated_tokens"]
+                > before["generated_tokens"]) == (not ahead or nth == 2)
         assert engine.stats["kv_bytes"] == \
             engine.stats["decode_tokens"] * engine.kv_bytes_per_token
     assert engine.stats[c_key] == 1
+    assert (engine._unread is not None) == (kind == "single_step")
+    engine.drain()
+    assert engine.stats["ahead_dispatches"] == int(kind == "single_step")
     # nobody else compiled, or dispatched under this kind's name
     for other, (*_, o_c) in DISPATCH_KINDS.items():
         if other != kind and o_c != c_key:
@@ -790,7 +800,9 @@ def test_profiler_session_finds_the_phases_in_its_file(tmp_path):
     names = [e.name for p in host for line in p.lines
              for e in line.events]
     assert names.count("serve.step.readback") >= 3
-    assert names.count("serve.step.enqueue") == \
+    # one of each a decode iteration, and the step that reads the last
+    # dispatch enqueues none
+    assert names.count("serve.step.enqueue") + 1 == \
         names.count("serve.step.readback")
 
 
