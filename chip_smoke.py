@@ -438,6 +438,119 @@ def phase_serve_latent(args, on_tpu):
     assert diff <= bound, (diff, bound)
 
 
+def phase_serve_state(args, on_tpu):
+    """The Jamba family (models/jamba.py) at a small size through the
+    engine itself: K/V pages in its attention layers, per-slot
+    recurrent and convolution state in its Mamba layers, the selective
+    scan under both programs and grouped-query paged attention, both
+    programs compiled once; every served token against the module's own
+    whole-sequence forward (no cache, the recurrence as a lax.scan),
+    then the scan kernel against its plain path."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubeml_tpu.models.jamba import JambaModule
+    from kubeml_tpu.ops.pallas.selective_scan import selective_scan
+    from kubeml_tpu.serve.engine import DecodeEngine
+    from kubeml_tpu.serve.slots import GenerateRequest
+    module = JambaModule() if args.tiny else JambaModule(
+        vocab_size=4096, max_len=512, hidden=512, layers=6, attn_period=3,
+        attn_offset=1, heads=4, kv_heads=1, intermediate_size=1024,
+        dt_rank=32)
+    variables = module.init(jax.random.PRNGKey(args.seed))
+    chunk = 32 if args.tiny else 128
+    eng = DecodeEngine(module, variables, slots=8, page=16,
+                       prefill_chunk=chunk)
+    rng = np.random.RandomState(args.seed)
+    n_new = 6 if args.tiny else 16
+    reqs = [GenerateRequest(rng.randint(1, module.vocab_size, n).tolist(),
+                            max_new_tokens=n_new, temperature=0.0, seed=0)
+            for n in (5, chunk + 9, 1, 2 * chunk + 1)]
+    for r in reqs:
+        eng.attach(r)
+    while eng.active():
+        eng.step()
+    eng.drain()
+    stats = eng.stats
+    scan_impls = eng.family.scan_impls(eng.geom.slots, chunk, "auto", False)
+    # each served token against the module's own forward of everything
+    # before it: the gap by which its logit lies below the forward's best
+    forward = jax.jit(module.apply)
+    widest = 0.0
+    for r in reqs:
+        ids = np.zeros(module.max_len, np.int32)
+        seq = list(r.prompt) + list(r.tokens)
+        ids[:len(seq)] = seq
+        logits = np.array(forward(variables, jnp.asarray(ids)), np.float32)
+        logits[:, 0] = -np.inf
+        rows = np.arange(len(r.prompt) - 1, len(seq) - 1)
+        gaps = logits[rows].max(-1) - logits[rows, np.asarray(r.tokens)]
+        widest = max(widest, float(gaps.max()))
+    emit(phase="serve", family="jamba", layers=module.layers,
+         attn_layers=list(module.attn_layers), hidden=module.hidden,
+         heads=module.heads, kv_heads=module.kv_heads,
+         d_inner=module.d_inner, d_state=module.d_state,
+         outcomes=[r.outcome for r in reqs],
+         new_tokens=[len(r.tokens) for r in reqs],
+         attn_impl_decode=stats["attn_impl_decode"],
+         scan_impl_decode=scan_impls[0], scan_impl_prefill=scan_impls[1],
+         compiles={"decode": int(stats["compiles"]),
+                   "prefill": int(stats["prefill_compiles"])},
+         dispatches={"decode": int(stats["dispatches"]),
+                     "prefill": int(stats["prefill_dispatches"])},
+         ahead_dispatches=int(stats["ahead_dispatches"]),
+         ssm_lane_updates=int(stats["ssm_lane_updates"]),
+         slot_state_bytes=int(stats["slot_state_bytes"]),
+         prefix_hits=int(stats["prefix_hits"]),
+         widest_gap_to_own_forward=widest, gap_bound=KERNEL_RTOL)
+    assert all(r.outcome == "ok" and len(r.tokens) == n_new for r in reqs)
+    assert stats["compiles"] == 1 and stats["prefill_compiles"] == 1
+    assert stats["prefill_dispatches"] >= 4 and stats["prefix_hits"] == 0
+    assert stats["ssm_lane_updates"] == stats["occupancy_sum"] > 0
+    want = "pallas" if on_tpu else "gather"
+    got = (stats["attn_impl_decode"],) + tuple(scan_impls)
+    # tiny's one KV head of 128 lanes and d_inner 512 are eligible too
+    assert got == (want,) * 3, got
+    # bfloat16 programs against a bfloat16 forward in another order of
+    # sums: a served token lies within a bf16 rounding of the best
+    assert widest <= KERNEL_RTOL, widest
+    # the scan kernel against its plain path on random operands, at the
+    # decode and the prefill geometry
+    diffs = {}
+    for name, (batch, steps, slot0) in {
+            "decode": (eng.geom.slots, 1, 0), "prefill": (1, chunk, 3)}.items():
+        ks = jax.random.split(jax.random.PRNGKey(args.seed + steps), 7)
+        n, di = module.d_state, module.d_inner
+        operands = (
+            jax.random.normal(ks[0], (2, eng.geom.slots, n, di)),
+            jax.random.normal(ks[1], (batch, steps, di)),
+            jax.nn.softplus(jax.random.normal(ks[2], (batch, steps, di)) - 3),
+            jax.random.normal(ks[3], (batch, steps, n)),
+            jax.random.normal(ks[4], (batch, steps, n)),
+            -jnp.exp(jax.random.normal(ks[5], (n, di))),
+            jax.random.normal(ks[6], (di,)),
+            jnp.ones((batch, steps)).at[:, steps - steps // 4:].set(
+                0.0 if steps > 1 else 1.0),
+            (jnp.arange(batch) % 3 == 1).astype(jnp.int32))
+        kw = dict(layer=jnp.int32(1), slot0=jnp.int32(slot0))
+        ker = jax.jit(functools.partial(
+            selective_scan, impl="pallas", interpret=not on_tpu,
+            **kw))(*operands)
+        ref = jax.jit(functools.partial(selective_scan, impl="gather",
+                                        **kw))(*operands)
+        live = np.asarray(operands[7])[:, :, None]
+        diffs[name] = max(
+            float(np.abs(np.asarray(ker[0]) - np.asarray(ref[0])).max()),
+            float(np.abs((np.asarray(ker[1]) - np.asarray(ref[1]))
+                         * live).max()))
+    emit(phase="serve", family="jamba", scan_kernel_vs_plain_max_abs_diff=diffs,
+         kernel_mode="mosaic" if on_tpu else "interpret")
+    # float32 on both sides; exp and the order of the sum over d_state
+    assert all(d <= 1e-3 for d in diffs.values()), diffs
+
+
 def phase_serve(args, dep, on_tpu):
     import jax
 
@@ -542,6 +655,7 @@ def phase_serve(args, dep, on_tpu):
     assert all(d <= bound[c] for c, (d, _r) in diffs.items()), \
         (diffs, bound)
     phase_serve_latent(args, on_tpu)
+    phase_serve_state(args, on_tpu)
 
 
 # ------------------------------------------------------------- four chips

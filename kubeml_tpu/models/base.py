@@ -49,6 +49,28 @@ class InferenceInputError(ValueError):
 
 
 @dataclasses.dataclass(frozen=True)
+class SlotState:
+    """One per-slot state array of a family's cache: `[layers, slots,
+    *shape]` in `dtype`, indexed by SLOT, not by page (a recurrence's
+    running state, a convolution's last inputs). It has no per-token
+    rows, so it cannot be shared by reference or split on write: a
+    family that declares any takes no prefix-cache hit (serve/engine.py).
+    `layers` counts the layers that keep it, which need not be the
+    layers that keep pages. Keep the minor dimension lane-dense (a
+    multiple of 128): 16 lanes pad eightfold on the chip."""
+
+    name: str
+    layers: int
+    shape: Tuple[int, ...]
+    dtype: Any
+
+    def slot_bytes(self) -> int:
+        """Bytes one slot holds of it, all layers."""
+        return int(self.layers * np.prod(self.shape)
+                   * np.dtype(self.dtype).itemsize)
+
+
+@dataclasses.dataclass(frozen=True)
 class CacheSpec:
     """What a model family keeps per token in the serving plane's paged
     cache (serve/pager.py KVPageSlab builds its arrays from this and
@@ -61,7 +83,9 @@ class CacheSpec:
     per-page int8 scales ([layers, pages] float32, one per plane), so
     kv_dtype "int8" can be served. `validity`: they carry the shared
     [pages, page_tokens] float32 validity plane (padding tokens masked
-    out of attention); a family without it masks by position alone."""
+    out of attention); a family without it masks by position alone.
+    `slot_state`: per-slot arrays beside the pages (SlotState), empty
+    for a family whose whole context lives in pages."""
 
     layers: int
     planes: int
@@ -70,10 +94,16 @@ class CacheSpec:
     row_lanes: int = 0
     sidecars: bool = False
     validity: bool = False
+    slot_state: Tuple[SlotState, ...] = ()
 
     @property
     def width(self) -> int:
         return self.row_lanes or self.lanes
+
+    @property
+    def slot_state_bytes(self) -> int:
+        """Bytes of per-slot state one slot holds."""
+        return sum(st.slot_bytes() for st in self.slot_state)
 
 
 class ServeFamily:
@@ -83,8 +113,9 @@ class ServeFamily:
     reads no other field of a module.
 
     Program signatures, `state` being the slab's arrays in KVPageSlab's
-    order (the planes, then the sidecars, then the validity plane, each
-    only where the cache declares it), donated and returned in place:
+    order (the planes, then the sidecars, then the validity plane, then
+    the per-slot state arrays, each only where the cache declares it),
+    donated and returned in place:
 
       decode_step(...)  -> step(params, *state, tokens[S], pos[S],
           page_tables[S, Pmax], write_page[S], write_off[S], active[S],
@@ -93,6 +124,14 @@ class ServeFamily:
       prefill_step(chunk, ...) -> prefill(params, *state, tokens[C],
           pos[C], page_table[Pmax], write_pages[C], write_offs[C],
           in_chunk[C]) -> state
+          (a family that declares slot state takes one more scalar,
+          `slot`, after in_chunk: whose state the chunk advances)
+
+    Per-slot state follows one rule in both programs: a lane or chunk
+    whose first position is 0 starts from the zero state, decided in
+    the program from `pos`, so admission, slot reuse and a resumed
+    stream's re-prefill need no host-side zeroing; an inactive lane and
+    a chunk's padded tail leave the state as it is.
 
     `step_counters` names int32 counts the decode program appends to
     its token row (read back in the same transfer); the engine sums
@@ -165,6 +204,27 @@ def cow_split_pages(pages, copy_src, copy_dst):
         pages = lax.dynamic_update_slice_in_dim(pages, src, copy_dst[s],
                                                 axis=1)
     return pages
+
+
+def rms_norm(x, scale, eps):
+    """RMSNorm in float32; the caller casts."""
+    x = x.astype(jnp.float32)
+    return scale.astype(jnp.float32) * x * lax.rsqrt(
+        jnp.mean(x * x, -1, keepdims=True) + eps)
+
+
+def dot_f32(x, w):
+    """x @ w, operands in the parameter dtype, float32 accumulation."""
+    return jnp.dot(x.astype(w.dtype), w,
+                   preferred_element_type=jnp.float32)
+
+
+def gated_mlp(x, p):
+    """W_down(silu(W_gate x) * W_up x), x already normed; p holds the
+    three `kernel` leaves under gate, up, down."""
+    a = jax.nn.silu(dot_f32(x, p["gate"]["kernel"])) \
+        * dot_f32(x, p["up"]["kernel"])
+    return dot_f32(a, p["down"]["kernel"])
 
 
 def sample_tokens(logits, active, temps, key_data, poison, pad_id):

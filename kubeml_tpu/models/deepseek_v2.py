@@ -67,6 +67,9 @@ from jax import lax
 from kubeml_tpu.models.base import (CacheSpec, InferenceInputError,
                                     KubeModel, ServeFamily, cow_split_pages,
                                     sample_tokens)
+from kubeml_tpu.models.base import dot_f32 as _dot
+from kubeml_tpu.models.base import gated_mlp as _gated
+from kubeml_tpu.models.base import rms_norm as _rms
 from kubeml_tpu.ops.pallas import mla_paged_attention as mla
 
 PAD_ID = 0
@@ -243,13 +246,6 @@ def softmax_scale(m: DeepSeekV2Module) -> float:
     return (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5 * ms * ms
 
 
-def _rms(x, scale, eps):
-    """RMSNorm in float32; the caller casts."""
-    x = x.astype(F32)
-    return scale.astype(F32) * x * lax.rsqrt(
-        jnp.mean(x * x, -1, keepdims=True) + eps)
-
-
 def _rope(x, cos, sin):
     """x [N, ..., rope] rotated by cos/sin [N, rope/2] (float32):
     dimension i pairs with i + rope/2."""
@@ -258,18 +254,6 @@ def _rope(x, cos, sin):
     cos, sin = cos.reshape(shape), sin.reshape(shape)
     a, b = x[..., :half].astype(F32), x[..., half:].astype(F32)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
-
-
-def _dot(x, w):
-    """x @ w, operands in the parameter dtype, float32 accumulation."""
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=F32)
-
-
-def _gated(x, p):
-    """W_down(silu(W_gate x) * W_up x), x already normed."""
-    a = jax.nn.silu(_dot(x, p["gate"]["kernel"])) \
-        * _dot(x, p["up"]["kernel"])
-    return _dot(a, p["down"]["kernel"])
 
 
 def route(m: DeepSeekV2Module, logits):

@@ -63,6 +63,18 @@ the buffer first. Nothing else needs zeroing: every row a product reads
 was copied or zeroed for this block, so no row is ever uninitialized or
 another slot's.
 
+Grouped queries (PR 31). The slab's row is `kv_heads * D` lanes, read
+off the slab, and H query heads a multiple of it: query head h attends
+KV head h // (H / kv_heads). Row (h, t) of the block-diagonal q then
+takes the lanes of head h's KV HEAD, so the one product over the row's
+lanes is unchanged; with one KV head (multi-query) q is simply [H*T, D]
+against the block's [block, D] rows. That q is built beside the call
+([S, H*T, kv_heads*D], a few hundred KB), the kernel hands back every
+row's accumulator whole and the caller keeps the lanes of each row's own
+KV head. With kv_heads = H nothing of this runs: the call traces and
+lowers to the program it always was (GPT's jaxprs are pinned). A cache
+without int8 sidecars passes no scales (`k_scale=None`).
+
 Math contract: f32-accumulated scores, the reference's `1/sqrt(D)`
 scale expression and additive-bias convention, float32 softmax
 statistics, probabilities cast to the compute dtype for the second
@@ -161,8 +173,11 @@ def block_pages(page: int, max_pages: int) -> int:
 
 
 def paged_vmem_bytes(q_len: int, heads: int, head_dim: int, page: int,
-                     max_pages: int, dtype, quantized: bool) -> int:
-    """Upper bound on the kernel's scoped VMEM, from shapes alone.
+                     max_pages: int, dtype, quantized: bool,
+                     kv_heads: int = 0) -> int:
+    """Upper bound on the kernel's scoped VMEM, from shapes alone
+    (`kv_heads` 0: as many as `heads`; grouped queries keep the H*T
+    query rows and narrow every row to kv_heads*D lanes).
 
     Every buffer is counted at its TILED size (last dim padded to 128
     lanes, second-to-last to the dtype's sublane tile): the double
@@ -185,12 +200,14 @@ def paged_vmem_bytes(q_len: int, heads: int, head_dim: int, page: int,
     C = page * max_pages
     bp = block_pages(page, max_pages)
     block = page * bp
-    row = _pad(heads * head_dim, LANES)
+    row = _pad((kv_heads or heads) * head_dim, LANES)
     rows = heads * q_len
     landing = 2 * 2 * bp * sub(page, page_item) * row * page_item
     dequantized = 2 * block * row * item \
         + 2 * sub(page, 4) * row * (4 + item) if quantized else 0
-    q_out = 2 * 2 * sub(q_len, item) * row * item
+    # grouped queries hand the kernel the H*T rows themselves
+    q_out = 2 * 2 * sub(rows if kv_heads not in (0, heads) else q_len,
+                        item) * row * item
     bias = 2 * (C // block) * sub(q_len, 4) * _pad(block, LANES) * 4
     kv_block = 2 * sub(block, item) * row * item
     q_rows = sub(rows, item) * row * item + 2 * sub(rows, 4) * row * 4
@@ -199,17 +216,20 @@ def paged_vmem_bytes(q_len: int, heads: int, head_dim: int, page: int,
 
 
 def paged_eligible(page: int, *, q_len: int, heads: int, head_dim: int,
-                   max_pages: int, dtype, quantized: bool = False) -> bool:
+                   max_pages: int, dtype, quantized: bool = False,
+                   kv_heads: int = 0) -> bool:
     """Geometry gate for the Mosaic kernel, from shapes and dtype only:
     a page is the sublane extent of a landing buffer's entry, so it must
-    be sublane-aligned; a token row of H*D lanes must be a whole number
-    of 128-lane tiles — the slab is lane-dense and unpadded only then,
-    which is the point of the kernel's operand layout; and the computed
-    VMEM bound must fit the budget. Ineligible geometries fall back to
-    the gather path under 'auto'."""
-    return page % SUBLANES == 0 and (heads * head_dim) % LANES == 0 \
+    be sublane-aligned; a token row of kv_heads*D lanes (H*D without
+    grouped queries) must be a whole number of 128-lane tiles — the
+    slab is lane-dense and unpadded only then, which is the point of
+    the kernel's operand layout; and the computed VMEM bound must fit
+    the budget. Ineligible geometries fall back to the gather path
+    under 'auto'."""
+    return page % SUBLANES == 0 \
+        and ((kv_heads or heads) * head_dim) % LANES == 0 \
         and paged_vmem_bytes(q_len, heads, head_dim, page, max_pages,
-                             dtype, quantized) <= VMEM_BUDGET
+                             dtype, quantized, kv_heads) <= VMEM_BUDGET
 
 
 def resolve_impl(impl: str, interpret: bool, **geometry) -> str:
@@ -227,7 +247,7 @@ def resolve_impl(impl: str, interpret: bool, **geometry) -> str:
 
 def _pa_kernel(tables_ref, live_ref, first_ref, layer_ref, kscale_ref,
                vscale_ref, q_ref, k_hbm, v_hbm, bias_ref, out_ref, k_buf,
-               v_buf, sems, *dequantized, heads: int):
+               v_buf, sems, *dequantized, heads: int, kv_heads: int):
     """One slot.
 
     tables_ref [S, Pmax], live_ref [S] (table entries up to the slot's
@@ -238,14 +258,18 @@ def _pa_kernel(tables_ref, live_ref, first_ref, layer_ref, kscale_ref,
     bias_ref [1, C/block, T, block]; k_buf/v_buf [2, block/G, G, H*D],
     the landing buffers, one half a block; sems DMA [2, 2] (plane,
     half); for int8 pages `dequantized` is a [block/G, G, H*D] pair in
-    the compute dtype.
+    the compute dtype. With grouped queries (kv_heads < heads) the
+    lanes are kv_heads*D, q_ref is the block-diagonal [1, H*T, lanes]
+    already (row (h, t) in the lanes of head h's KV head, built beside
+    the call) and out_ref takes every row's accumulator whole.
     """
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
     layer = layer_ref[0]
     _, bp, page, lanes = k_buf.shape
-    q_len = q_ref.shape[1]
-    d = lanes // heads
+    grouped = kv_heads != heads
+    q_len = q_ref.shape[1] // heads if grouped else q_ref.shape[1]
+    d = lanes // kv_heads
     block = bp * page
 
     def blocks_of(slot):
@@ -283,9 +307,12 @@ def _pa_kernel(tables_ref, live_ref, first_ref, layer_ref, kscale_ref,
     # h's scores in row (h, t)
     q = q_ref[0]                                          # [T, H*D]
     rows = heads * q_len
-    own = lax.broadcasted_iota(jnp.int32, (rows, lanes), 0) // q_len \
+    own = None if grouped else \
+        lax.broadcasted_iota(jnp.int32, (rows, lanes), 0) // q_len \
         == lax.broadcasted_iota(jnp.int32, (rows, lanes), 1) // d
-    if q_len == 1:
+    if grouped:
+        pass                    # [H*T, kv_heads*D], block-diagonal already
+    elif q_len == 1:
         # the one row times a 0/1 plane (exact): Mosaic has no relayout
         # for a select whose operand is a row replicated over sublanes
         q = (q.astype(jnp.float32) * own.astype(jnp.float32)
@@ -362,6 +389,10 @@ def _pa_kernel(tables_ref, live_ref, first_ref, layer_ref, kscale_ref,
          jnp.zeros((rows, 1), jnp.float32),
          jnp.zeros((rows, lanes), jnp.float32)))
 
+    if grouped:
+        # every row whole: the caller keeps the lanes of its KV head
+        out_ref[0] = (acc / jnp.where(l > 0, l, 1.0)).astype(out_ref.dtype)
+        return
     # row (h, t) keeps its own head's lanes; the rows of one t then add
     # up to the token's lane-dense output row
     acc = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
@@ -383,11 +414,23 @@ def _pa_pallas(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
     the cell's set-up, PERF.md PR 28)."""
     S, T, H, D = q.shape
     _, _, G, HD = k_pages.shape
+    KVH = HD // D
     Pmax = page_tables.shape[1]
     C = Pmax * G
     bp = block_pages(G, Pmax)
     block = G * bp
     vma = gate.out_vma(q, k_pages, v_pages, page_tables, bias)
+    if KVH != H:
+        # grouped queries: row (h, t) of the block-diagonal q takes the
+        # lanes of head h's KV head (with one KV head, q as it is)
+        mine = jnp.arange(H)[:, None] // (H // KVH) == jnp.arange(KVH)
+        q_rows = (q.transpose(0, 2, 1, 3)[:, :, :, None, :]
+                  * mine[None, :, None, :, None].astype(q.dtype)
+                  ).reshape(S, H * T, HD)
+    n_rows = T if KVH == H else H * T
+    if k_scale is None:
+        # a cache without int8 sidecars: nothing reads the scales
+        k_scale = v_scale = jnp.zeros((k_pages.shape[0], 1), jnp.float32)
     # entries up to a slot's last live one: what the kernel walks; and
     # the blocks the slots before it walk, whose parity is the half of
     # the landing buffers its first block takes
@@ -396,7 +439,7 @@ def _pa_pallas(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
                    axis=1)
     n_blocks = (live + bp - 1) // bp
     first = jnp.cumsum(n_blocks) - n_blocks
-    q_spec = pl.BlockSpec((1, T, HD), lambda s, *_: (s, 0, 0),
+    q_spec = pl.BlockSpec((1, n_rows, HD), lambda s, *_: (s, 0, 0),
                           memory_space=pltpu.VMEM)
     scratch = [pltpu.VMEM((2, bp, G, HD), k_pages.dtype),
                pltpu.VMEM((2, bp, G, HD), v_pages.dtype),
@@ -418,24 +461,31 @@ def _pa_pallas(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
-        functools.partial(_pa_kernel, heads=H),
+        functools.partial(_pa_kernel, heads=H, kv_heads=KVH),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, T, HD), q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((S, n_rows, HD), q.dtype, vma=vma),
         compiler_params=pltpu.CompilerParams(
             # a block's copies are started a block ahead, a slot's first
             # ones by the slot before it: the steps run in order
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=max(
                 paged_vmem_bytes(T, H, D, G, Pmax, compute_dtype,
-                                 quantized),
+                                 quantized, KVH),
                 _DEFAULT_SCOPED_VMEM)),
         name="paged_attention",
         interpret=pltpu.InterpretParams() if interpret else False,
     )(page_tables, live, first.astype(jnp.int32),
       jnp.reshape(layer, (1,)).astype(jnp.int32),
-      k_scale[layer], v_scale[layer], q.reshape(S, T, HD), k_pages, v_pages,
+      k_scale[layer], v_scale[layer],
+      q.reshape(S, T, HD) if KVH == H else q_rows, k_pages, v_pages,
       jnp.broadcast_to(bias, (S, 1, T, C)).reshape(
           S, T, C // block, block).transpose(0, 2, 1, 3))
+    if KVH != H:
+        # row (h, t) keeps the lanes of its own KV head
+        out = out.reshape(S, H, T, KVH, D)
+        out = jnp.sum(out * mine[None, :, None, :, None].astype(out.dtype),
+                      axis=3) if KVH > 1 else out[:, :, :, 0]
+        return out.transpose(0, 2, 1, 3)
     return out.reshape(S, T, H, D)
 
 
@@ -454,8 +504,12 @@ def _pa_gather(q, k_pages, v_pages, k_scale, v_scale, page_tables, bias,
     if quantized:
         k_pages = _dequant(k_pages, k_scale[layer], compute_dtype)
         v_pages = _dequant(v_pages, v_scale[layer], compute_dtype)
-    ck = k_pages[page_tables].reshape(S, C, H, D)
-    cv = v_pages[page_tables].reshape(S, C, H, D)
+    KVH = k_pages.shape[-1] // D
+    ck = k_pages[page_tables].reshape(S, C, KVH, D)
+    cv = v_pages[page_tables].reshape(S, C, KVH, D)
+    if KVH != H:
+        # grouped queries: each KV head under its H / KVH query heads
+        ck, cv = (jnp.repeat(c, H // KVH, axis=2) for c in (ck, cv))
     return multi_head_attention(q, ck, cv, bias)
 
 
@@ -470,10 +524,14 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Attention of [S, T, H, D] queries over paged KV, through the
     page table — one layer's context read of the serving programs.
 
-    k_pages/v_pages: the WHOLE slab, [L, P, G, H*D] (compute dtype, or
-    int8 with quantized=True), in the one layout serve/pager.py
+    k_pages/v_pages: the WHOLE slab, [L, P, G, KVH*D] (compute dtype,
+    or int8 with quantized=True), in the one layout serve/pager.py
     KVPageSlab holds it in: a token's K or V is one lane-dense row,
-    head h in lanes [h*D, (h+1)*D). `layer` (an int) picks the plane
+    KV head g in lanes [g*D, (g+1)*D). KVH is read off the row: H of
+    them is multi-head attention, fewer are GROUPED queries, query head
+    h attending KV head h // (H / KVH) (one KV head: multi-query; the
+    scales may then be None, for a cache without int8 sidecars).
+    `layer` (an int) picks the plane
     inside the kernel, where the page copies index the slab with it, so
     no per-layer copy of a plane is ever made for the call; H and D
     come from q.
@@ -495,22 +553,28 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """
     S, T, H, D = q.shape
     G = k_pages.shape[2]
-    if k_pages.shape[3] != H * D:
+    KVH = k_pages.shape[3] // D
+    if k_pages.shape[3] != KVH * D or KVH < 1 or H % KVH:
         raise ValueError(
             f"slab rows hold {k_pages.shape[3]} lanes, q has "
-            f"{H} heads of {D}")
+            f"{H} heads of {D}: a row is kv_heads * {D} lanes, and the "
+            f"query heads a multiple of kv_heads")
+    if quantized and k_scale is None:
+        raise ValueError("int8 pages need their per-page scales")
     if compute_dtype is None:
         compute_dtype = q.dtype
     geometry = dict(page=G, q_len=T, heads=H, head_dim=D,
                     max_pages=page_tables.shape[1], dtype=compute_dtype,
                     quantized=quantized)
+    if KVH != H:
+        geometry["kv_heads"] = KVH
     if resolve_impl(impl, interpret, **geometry) == "pallas":
         # a forced kernel is refused only for what it cannot run at
         # all; an unaligned token row (paged_eligible's third clause)
         # costs padding, not correctness
         if G % SUBLANES or paged_vmem_bytes(
                 T, H, D, G, page_tables.shape[1], compute_dtype,
-                quantized) > VMEM_BUDGET:
+                quantized, KVH) > VMEM_BUDGET:
             raise ValueError(
                 f"page size {G} is not sublane-aligned ({SUBLANES}) or "
                 f"the kernel's VMEM bound exceeds {VMEM_BUDGET} B for "

@@ -3,11 +3,22 @@
 The engine serves any model FAMILY that declares its cache and hands
 over its paged programs (models/base.py ServeFamily, reached through
 `module.serve_family()`): the GPT trunk (models/gpt.py: per-head K/V
-pages, all four programs below) and DeepSeek-V2 (models/deepseek_v2.py:
-one plane of latent pages, decode and prefill). Slots, page tables,
-allocation, copy-on-write, the prefix cache and the step loop below are
-the same for every family; a family that lacks an optional program is
-refused by name when a deployment asks for it.
+pages, all four programs below), DeepSeek-V2 (models/deepseek_v2.py:
+one plane of latent pages, decode and prefill) and Jamba
+(models/jamba.py: K/V pages for its few attention layers and a
+per-slot recurrent state for the others, decode and prefill). Slots,
+page tables, allocation, copy-on-write, the prefix cache and the step
+loop below are the same for every family; a family that lacks an
+optional program is refused by name when a deployment asks for it.
+
+Per-slot state (models/base.py SlotState) rides in the slab's `state`
+behind the pages, donated and returned by every program like them, so
+it follows from dispatch to dispatch on the device, one ahead or not.
+The engine hands the prefill program of such a family the slot index
+and never zeroes anything: a program starts a slot's state from zeros
+where the stream's position is 0. A family that declares any takes no
+prefix-cache hit: pages restore K and V, not the state at their
+boundary.
 
 An EXACT, documented inventory of jitted programs serves every stream
 (compile count pinned by tests/test_serving.py and
@@ -381,7 +392,11 @@ class DecodeEngine:
             slots=slots, page=page, max_len=family.max_len)
         self.clock = clock
         self.prefill_chunk = prefill_chunk
-        self.prefix_cache = bool(prefix_cache)
+        # per-slot state has no per-token rows: pages alone do not
+        # restore a prefix of such a family, so it registers and matches
+        # none (decided from the declaration; the option stays accepted)
+        self._slot_state = bool(family.cache.slot_state)
+        self.prefix_cache = bool(prefix_cache) and not self._slot_state
         self.prefill_budget = int(prefill_budget) if prefill_budget \
             else max(prefill_chunk, 1)
         if self.prefill_budget < 1:
@@ -542,7 +557,9 @@ class DecodeEngine:
             "prefix_hits": 0, "prefix_misses": 0, "cow_splits": 0,
             "weight_swaps": 0, "generations_retired": 0,
             "poisoned": 0, "deadline_expired": 0, "page_leaks": 0,
-            "kv_bytes": 0,
+            # per-slot state a decode-lane dispatch moves: occupied
+            # lanes x the declaration's bytes a slot, read and written
+            "kv_bytes": 0, "slot_state_bytes": 0,
             "multi_step_dispatches": 0, "multi_step_compiles": 0,
             "verify_dispatches": 0, "verify_compiles": 0,
             "draft_tokens": 0, "accepted_tokens": 0,
@@ -557,6 +574,13 @@ class DecodeEngine:
         # (ServeFamily.step_counters), summed over decode dispatches
         for name in family.step_counters:
             self.stats[name] = 0
+        self._slot_state_step_bytes = 2 * family.cache.slot_state_bytes
+        # the position each slot's cache stands at on the device, after
+        # everything enqueued. A page write is idempotent, a recurrence
+        # is not: a lane whose per-slot state a failed dispatch left one
+        # step ahead of its stream's cursor is ended, never advanced
+        # twice (_in_step)
+        self._state_pos = np.zeros(self.geom.slots, np.int64)
         # which implementation each attention call site takes, resolved
         # by the family with the SAME rule its kernel's dispatch applies
         # at trace time — so a silent fallback to the gather path shows
@@ -879,7 +903,7 @@ class DecodeEngine:
         # every release path audits page conservation: a leak caught at
         # the releasing request is attributable; one caught at restart
         # is archaeology
-        self.check_pager()
+        self.check_pager(quick=True)
 
     def evacuate(self, s: int) -> Optional[GenerateRequest]:
         """Forced-teardown detach: free slot ``s``'s page references and
@@ -906,15 +930,23 @@ class DecodeEngine:
         # order before whatever its next engine emits
         self.flush_events(only=slot.req)
         self._maybe_retire(slot.gen)
-        self.check_pager()
+        self.check_pager(quick=True)
         return slot.req
 
-    def check_pager(self) -> None:
+    def check_pager(self, quick: bool = False) -> None:
         """Run the allocator's invariant audit (pager.check_invariants).
         Violations raise in strict mode; in production they count into
         stats["page_leaks"] (published as
         kubeml_serve_page_leaks_total) and serving continues — a leak
-        degrades capacity, it does not justify failing live streams."""
+        degrades capacity, it does not justify failing live streams.
+        `quick` (the release paths, which run on the loop thread with
+        every stream waiting): in production the audit is run only
+        where the constant-time conservation count fails; the whole
+        audit walks every page of the slab, 4 ms at 128 slots of 384
+        pages, three or four times a second (PERF.md, PR 31). Strict
+        mode and every other caller audit in full."""
+        if quick and not self.strict_pager and self.pager.conserved():
+            return
         problems = self.pager.check_invariants()
         if not problems:
             return
@@ -977,6 +1009,9 @@ class DecodeEngine:
                 jnp.asarray(tokens), jnp.asarray(pos),
                 jnp.asarray(self._tables[s]), jnp.asarray(write_pages),
                 jnp.asarray(write_offs), jnp.asarray(in_chunk)]
+        if self._slot_state:
+            # whose per-slot state the chunk advances
+            args.append(jnp.asarray(s, jnp.int32))
         with self._dispatched("prefill", args) as d:
             self.stats["prefill_tokens"] += n
             slot.prefill_s += d.t1 - d.t0
@@ -984,9 +1019,27 @@ class DecodeEngine:
                        pages_granted=granted, start_pos=start,
                        compiled=int(d.compiled))
             slot.pos = end
+            self._state_pos[s] = end
             if self.prefix_cache:
                 self._register_full_pages(s, slot)
         return n
+
+    def _in_step(self, s: int, p: int, finished) -> bool:
+        """Whether slot s's per-slot state stands where its stream's
+        next position `p` needs it (always, for a family that keeps
+        none, and at position 0, where a program starts from zeros).
+        Where it does not, a dispatch advanced the state and raised
+        before its walk advanced the cursor: the stream is ended with
+        an error, since its state cannot be stepped back."""
+        if not self._slot_state or p == 0 or self._state_pos[s] == p:
+            return True
+        req = self._slots[s].req
+        self.release(s, "error",
+                     f"per-slot state stands at position "
+                     f"{int(self._state_pos[s])}, the stream at {p}: a "
+                     f"dispatch failed between its enqueue and its walk")
+        finished.append(req)
+        return False
 
     def _in_prefill(self, slot: _Slot) -> bool:
         """Chunked-prefill phase: positions [pos, n_prompt-1) still owed
@@ -1284,6 +1337,8 @@ class DecodeEngine:
                 self.stats["ahead_dispatches"] += ahead
                 self.stats["dispatches"] += 1
                 self.stats["occupancy_sum"] += len(members)
+                self.stats["slot_state_bytes"] += \
+                    len(members) * self._slot_state_step_bytes
                 self._count_page_walk(members)
                 self.flush_events()
             return _Enqueued(program, t0, t1, compiled, args,
@@ -1362,6 +1417,9 @@ class DecodeEngine:
             for name, n in zip(self.family.step_counters, nxt[S:]):
                 self.stats[name] += int(n)
                 d.span[name] = int(n)
+            if self._slot_state:
+                d.span["slot_state_bytes"] = \
+                    len(rec.lanes) * self._slot_state_step_bytes
             toks, bads = nxt[None], bad[None]
             overrun = 0
             for s, slot in rec.lanes.items():
@@ -1657,7 +1715,8 @@ class DecodeEngine:
                 key=lambda s: self._slots[s].seq)
             for s in order:
                 slot = self._slots[s]
-                while budget > 0 and slot.pos < slot.n_prompt - 1:
+                while budget > 0 and slot.pos < slot.n_prompt - 1 \
+                        and self._in_step(s, slot.pos, finished):
                     n = self._dispatch_prefill(s, slot)
                     if n == 0:
                         stalled.append(s)
@@ -1706,6 +1765,8 @@ class DecodeEngine:
                             >= slot.req.max_new_tokens:
                         continue    # its budget ends in the unread one
                     p += 1
+                if not self._in_step(s, p, finished):
+                    continue
                 pi = p // G
                 pid = int(self._tables[s, pi])
                 if pid == 0:
@@ -1810,6 +1871,7 @@ class DecodeEngine:
                     else:
                         tokens[s] = slot.req.tokens[-1]
                     pos[s] = p
+                    self._state_pos[s] = p + 1
                     write_page[s] = int(self._tables[s, p // G])
                     write_off[s] = p % G
                     temps[s] = slot.req.temperature

@@ -168,9 +168,15 @@ class KVPageSlab:
     sidecar), and eviction/drop_generation need no device work — a
     reused page's first write (offset 0) resets its scale on device.
 
+    Per-slot state (cache.slot_state): one array `[layers, slots,
+    *shape]` for each declaration, after everything paged. It belongs
+    to a slot, not to a page: the allocator, the tables, copy-on-write
+    and the prefix cache never see it.
+
     `state` is all of it as the programs take and return it (donated):
-    the planes, then the sidecars, then the validity plane. k, v,
-    k_scale, v_scale and valid name its parts for a two-plane cache.
+    the planes, then the sidecars, then the validity plane, then the
+    per-slot state arrays; `state_names` names them in that order. k,
+    v, k_scale, v_scale and valid name the parts of a two-plane cache.
     """
 
     def __init__(self, geom: PageGeometry, cache=None, heads: int = 0,
@@ -203,7 +209,18 @@ class KVPageSlab:
                       for _ in range(cache.planes)]
         if cache.validity:
             state.append(jnp.zeros((geom.pages, geom.page), jnp.float32))
+        # per-slot state, indexed by slot and never by page: zeroed here
+        # for a defined start only, a program zeroes a slot's own where
+        # its stream begins (position 0)
+        state += [jnp.zeros((st.layers, geom.slots) + tuple(st.shape),
+                            st.dtype) for st in cache.slot_state]
         self.state = tuple(state)
+        self.state_names = tuple(
+            [f"plane_{i}" for i in range(cache.planes)]
+            + ([f"scale_{i}" for i in range(cache.planes)]
+               if cache.sidecars else [])
+            + (["valid"] if cache.validity else [])
+            + [st.name for st in cache.slot_state])
 
     # the parts of a two-plane cache with sidecars and validity (GPT's)
     k = property(lambda self: self.state[0])
@@ -388,6 +405,16 @@ class PageAllocator:
             self._by_hash.pop(digest, None)
 
     # ------------------------------------------------------------ accounting
+    def conserved(self) -> bool:
+        """Page conservation by the lists' LENGTHS alone, in constant
+        time: null + free + referenced + parked == every page, and the
+        hash index as long as its inverse. What a leaked release path
+        breaks; check_invariants() says what else is wrong, in time
+        proportional to the slab (4 ms at 49,153 pages)."""
+        return (1 + len(self._free) + len(self._refs) + len(self._lru)
+                == self.geom.pages
+                and len(self._by_hash) == len(self._hash_of))
+
     def check_invariants(self) -> List[str]:
         """Audit the three-state pool; returns human-readable violation
         strings (empty = healthy). The load-bearing identity is page

@@ -54,6 +54,12 @@ SKETCH_SUBWINDOWS = 6
 # flush-per-publish turns the loop thread into an O(n^2) JSON writer
 # under sustained traffic. Forced flushes (stop, eject, flight
 # snapshots, explicit flush_trace()) always write immediately.
+# A batch of a FIXED size is quadratic all the same once the file is
+# large: at 128 slots (440 events a second) a write of 20,000 events
+# took the loop thread 0.45 s every 0.6 s of events, a sixth of a traced
+# window with the device idle behind it (PERF.md, PR 31). So the batch
+# also grows with what is already written (half of it): the writes of
+# a run add up to a few times its last one.
 TRACE_FLUSH_EVERY = 256
 
 # Retry-After sizing for the prefill backlog: a conservative host-tier
@@ -867,7 +873,8 @@ class ServeService:
         if self.trace_sink is None or self.tracer is None:
             return
         n = self.tracer.event_count()
-        if not force and n - self._events_flushed < TRACE_FLUSH_EVERY:
+        if not force and n - self._events_flushed < max(
+                TRACE_FLUSH_EVERY, self._events_flushed // 2):
             return
         with phase("serve.trace.flush", model=self.model_id,
                    step=self.engine._step_count, events=n) as args:

@@ -424,6 +424,172 @@ def test_deepseek_v2_program_compiles_for_v5e_at_the_cells_sizes(
             all("S(1)" in x for x in scores), scores
 
 
+# ------------------------------- Jamba (per-slot state, grouped queries)
+
+# the cell jamba2-3b-serve.decode-heavy: 128 slots, 384 pages of 16 a
+# slot, prefill chunk 512, 26 Mamba layers of d_inner 5120 x d_state 16
+_JAMBA_CELL = dict(slots=128, page=16, pages_per_slot=384, chunk=512)
+
+# (batch, steps): every slot one step (the decode program), one slot a
+# chunk (the prefill program), and the tiny preset's chunk
+SCAN_GEOMETRIES = {
+    "cell-decode": (128, 1, 5120, 26),
+    "cell-prefill": (1, 512, 5120, 26),
+    "tiny-prefill": (1, 16, 512, 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SCAN_GEOMETRIES))
+def test_selective_scan_compiles_for_v5e_in_place(one_chip, name):
+    """The selective-scan kernel at the cell's two geometries: the
+    chip's compiler accepts it, the state array is read and written in
+    place (the result aliases the argument and the program holds no
+    temporary: a copy of the array would be 1.09 GB of it), and no
+    instruction yields a copy of the array."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeml_tpu.ops.pallas.selective_scan import (scan_eligible,
+                                                      selective_scan)
+    batch, steps, d, layers = SCAN_GEOMETRIES[name]
+    S, n = _JAMBA_CELL["slots"], 16
+    assert scan_eligible(batch=batch, steps=steps, d_inner=d, d_state=n)
+    f32, i32 = jnp.float32, jnp.int32
+
+    def fn(state, x, dt, b, c, a, dd, valid, fresh, layer, slot0):
+        return selective_scan(state, x, dt, b, c, a, dd, valid, fresh,
+                              layer=layer, slot0=slot0, impl="pallas")
+
+    shapes = (((layers, S, n, d), f32), ((batch, steps, d), f32),
+              ((batch, steps, d), f32), ((batch, steps, n), f32),
+              ((batch, steps, n), f32), ((n, d), f32), ((d,), f32),
+              ((batch, steps), f32), ((batch,), i32), ((), i32), ((), i32))
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "selective_scan" in hlo
+    mem = compiled.memory_analysis()
+    state_bytes = layers * S * n * d * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 8
+    whole = [x for x in _result_shapes(hlo)
+             if x[2] == (layers, S, n, d) and x[4] in ("copy", "copy-start")]
+    assert not whole, whole
+
+
+@pytest.mark.parametrize("kv_heads", [1, 2])
+def test_paged_attention_grouped_queries_compile_for_v5e(one_chip,
+                                                         kv_heads):
+    """The cell's decode attention (20 query heads over ONE KV head of
+    128, 128 slots, 384 pages a slot, no scale sidecars) and a two-KV-
+    head point: eligible, accepted by the chip's compiler, and inside
+    the VMEM bound the call is given."""
+    import re
+
+    import jax.numpy as jnp
+
+    from kubeml_tpu.ops.pallas.paged_attention import (paged_attention,
+                                                       paged_eligible,
+                                                       paged_vmem_bytes)
+    c = _JAMBA_CELL
+    S, G, Pmax, H, D = c["slots"], c["page"], c["pages_per_slot"], 20, 128
+    bf = jnp.bfloat16
+    assert paged_eligible(G, q_len=1, heads=H, head_dim=D, max_pages=Pmax,
+                          dtype=bf, kv_heads=kv_heads)
+    bound = paged_vmem_bytes(1, H, D, G, Pmax, bf, False, kv_heads)
+    rows = (2, S * Pmax + 1, G, kv_heads * D)
+
+    def fn(q, k, v, tables, bias):
+        return paged_attention(q, k, v, None, None, tables, bias, layer=1,
+                               impl="pallas")
+
+    hlo = _compile(fn, one_chip, ((S, 1, H, D), bf), (rows, bf), (rows, bf),
+                   ((S, Pmax), jnp.int32), ((S, 1, 1, Pmax * G), jnp.float32))
+    assert "tpu_custom_call" in hlo and "paged_attention" in hlo
+    call, = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    limit, used = (
+        int(re.search(key + r'":\[\{[^}]*"size":"(\d+)"', call).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
+    assert 0 < used <= bound <= limit
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_jamba_serve_programs_compile_at_published_widths_for_v5e(
+        one_chip, which):
+    """Both programs of models/jamba.py at the PUBLISHED widths and the
+    cell's geometry (28 layers, 128 slots, 384 pages a slot, prefill
+    chunk 512), bfloat16 parameters: the chip's compiler accepts them,
+    they hold the selective-scan kernel (and the decode program the
+    paged-attention kernel), NO instruction yields a copy of either
+    per-slot state array or of a page plane (a gather of pages once
+    compiled to a copy of the whole slab: PERF.md, PR 26; here a
+    reshape of the convolution's tail did, both ways, until its taps
+    became lane slices), and arguments and temporaries together fit the
+    chip's 16.9 GB. Read (sandbox compile, PR 31): decode 8.06 GB of
+    arguments + 44 MB of temporaries, prefill 7.90 GB + 73 MB."""
+    import importlib.util
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "models", "jamba2_3b.py")
+    spec = importlib.util.spec_from_file_location("jamba2_3b_model", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    m = mod.Jamba2_3B().module
+    assert (m.hidden, m.layers, m.attn_layers, m.d_inner, m.kv_heads) \
+        == (2560, 28, (7, 21), 5120, 1)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0)))["params"])
+    c = _JAMBA_CELL
+    S, G, Pmax, C = c["slots"], c["page"], c["pages_per_slot"], c["chunk"]
+    family = m.serve_family()
+    cache = family.cache
+    plane = (cache.layers, S * Pmax + 1, G, cache.width)
+    slot_arrays = [(st.layers, S) + tuple(st.shape)
+                   for st in cache.slot_state]
+    state = [sds(plane, cache.dtype)] * cache.planes + [
+        sds(shape, st.dtype)
+        for shape, st in zip(slot_arrays, cache.slot_state)]
+    i32, f32 = jnp.int32, jnp.float32
+    if which == "decode":
+        fn = family.decode_step("f32", "pallas", False)
+        rest = [sds((S,), i32), sds((S,), i32), sds((S, Pmax), i32),
+                sds((S,), i32), sds((S,), i32), sds((S,), f32),
+                sds((S,), f32), sds((S, 2), jnp.uint32), sds((S,), i32),
+                sds((S,), i32), sds((S,), f32)]
+    else:
+        fn = family.prefill_step(C, "f32", "pallas", False)
+        rest = [sds((C,), i32), sds((C,), i32), sds((Pmax,), i32),
+                sds((C,), i32), sds((C,), i32), sds((C,), f32),
+                sds((), i32)]
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
+        params, *state, *rest).compile()
+    hlo = compiled.as_text()
+    # the kernels by their instructions' names (file names may appear
+    # in a module's metadata whatever it calls)
+    assert "%selective_scan" in hlo
+    assert ("%paged_attention" in hlo) == (which == "decode")
+    copies = [(n, dims, op) for n, _, dims, _, op in _result_shapes(hlo)
+              if dims in [plane] + slot_arrays
+              and op in ("copy", "copy-start", "gather")]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 200e6, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.9e9
+    assert mem.alias_size_in_bytes >= sum(
+        int(jnp.dtype(a.dtype).itemsize) * int(__import__("math").prod(
+            a.shape)) for a in state)
+
+
 # ---------------------------------------------------- flash attention
 
 FLASH_SHAPES = {

@@ -451,6 +451,19 @@ def test_pager_invariant_audit_strict_and_production_postures():
     del prod.pager._refs[pid]
     prod.check_pager()                         # logs + counts, no raise
     assert prod.stats["page_leaks"] == 1
+    # the release paths' quick form: a healthy production pool is
+    # passed on its constant-time count alone, a broken count gets the
+    # whole audit; strict mode always audits in full
+    assert not prod.pager.conserved()
+    prod.check_pager(quick=True)
+    assert prod.stats["page_leaks"] == 2
+    healthy = DecodeEngine(module, variables, slots=2, page=8,
+                           strict_pager=False)
+    assert healthy.pager.conserved()
+    healthy.pager.check_invariants = None      # must not be called
+    healthy.check_pager(quick=True)
+    with pytest.raises(AssertionError, match="pager invariants"):
+        strict.check_pager(quick=True)
 
 
 # ----------------------------------------------------------- observability
