@@ -112,7 +112,8 @@ class ServeFamily:
     it has a `serve_family()` method returning one of these; the engine
     reads no other field of a module.
 
-    Program signatures, `state` being the slab's arrays in KVPageSlab's
+    Program signatures, `params` being the tree `serve_params` returns
+    and `state` the slab's arrays in KVPageSlab's
     order (the planes, then the sidecars, then the validity plane, then
     the per-slot state arrays, each only where the cache declares it),
     donated and returned in place:
@@ -165,6 +166,15 @@ class ServeFamily:
         raise ValueError(
             f"serve family {self.name!r} provides no speculative verify "
             f"program (no draft model can be configured)")
+
+    def serve_params(self, params):
+        """The parameter tree as this family's paged programs read it:
+        what the engine puts on the device, for every weight generation
+        and for a draft's tree alike. A pure function of the tree and of
+        the module's own fields, and idempotent (a rebuilt engine is
+        handed the resident tree). The identity unless a family's
+        programs read a leaf only ever through a cast."""
+        return params
 
     def attn_impls(self, page: int, max_pages: int, prefill_chunk: int,
                    kv_dtype: str, attn_impl: str,

@@ -507,6 +507,11 @@ def _int8_write_prefill(pages, scales, layer, rows, write_pages,
     return pages, scales
 
 
+# the subtrees of a layer that _paged_trunk applies through a
+# module.dtype Dense or DenseGeneral (GPTServeFamily.serve_params)
+_PAGED_MATMULS = ("q", "k", "v", "out", "Dense_0", "Dense_1")
+
+
 def _paged_trunk(module: GPTModule, kv_dtype: str, attn_impl: str,
                  attn_interpret: bool, chunked: bool):
     """What the decode and the prefill program share: the checks of the
@@ -1102,6 +1107,27 @@ class GPTServeFamily(ServeFamily):
         return build_paged_spec_verify_step(
             self.module, draft.module, steps, window, kv_dtype, attn_impl,
             attn_interpret)
+
+    def serve_params(self, params):
+        """Every leaf the paged trunk reads only through flax's cast to
+        `module.dtype` (_paged_trunk: the two embedding tables, and each
+        layer's q, k, v, out, Dense_0, Dense_1 kernels and biases), held
+        in that dtype already, so no program converts it again: the same
+        bits, since the cast is a function of the value alone. The
+        LayerNorm subtrees stay as they are: nn.LayerNorm(dtype=float32)
+        reads them in float32. Chosen by the leaf's path, not its rank
+        (the attention kernels are rank 3); with a float32 module, or on
+        a tree already in this form, every leaf comes back untouched."""
+        dtype = self.module.dtype
+
+        def held(path, leaf):
+            top, *rest = (str(k.key) for k in path)
+            cast = top in ("tok_embed", "pos_embed") or (
+                top.startswith("layer_") and rest[0] in _PAGED_MATMULS)
+            return leaf.astype(dtype) if cast and leaf.dtype != dtype \
+                else leaf
+
+        return jax.tree_util.tree_map_with_path(held, params)
 
     def attn_impls(self, page, max_pages, prefill_chunk, kv_dtype,
                    attn_impl, attn_interpret):

@@ -297,6 +297,24 @@ def _serve_family(module) -> ServeFamily:
     return make()
 
 
+def _put_params(family: ServeFamily, params):
+    """Every parameter tree an engine holds comes onto the device here,
+    in the form the family's paged programs read it
+    (ServeFamily.serve_params): generation 1, each installed generation
+    and a draft's tree alike, so all generations have equal shapes and
+    dtypes and a hot swap reuses the compiled programs. A host tree (a
+    checkpoint's) is cast on the host, leaf by leaf, and only the held
+    form crosses to the device: no float32 copy is ever resident there.
+    Returns (tree of device arrays, leaves whose dtype the family
+    changed, the tree's bytes)."""
+    held = family.serve_params(params)
+    leaves = jax.tree_util.tree_leaves
+    cast = sum(a.dtype != b.dtype
+               for a, b in zip(leaves(params), leaves(held)))
+    held = jax.device_put(held)
+    return held, cast, sum(int(a.nbytes) for a in leaves(held))
+
+
 def _decode_entry(step_fn, n_state: int, slots: int):
     """The engine's jitted single-step decode entry, the same for every
     family: `step_fn` (family.decode_step's function, untouched) behind
@@ -478,16 +496,19 @@ class DecodeEngine:
                     draft_family, self.spec_steps, self.spec_window,
                     kv_dtype, attn_impl, self.attn_interpret),
                 donate_argnums=verify_donate)
-            self._draft_params = jax.device_put(draft_variables["params"])
+            self._draft_params = _put_params(
+                draft_family, draft_variables["params"])[0]
         # weight generations: params are per-slot DATA, not program
         # state — every generation's params pytree has identical
-        # shapes/dtypes, so dispatching different generations reuses the
-        # same two compiled programs (the compile-count pin survives
-        # hot-swaps). New attaches pin to weight_generation; old
-        # generations retire when their last slot releases.
+        # shapes/dtypes (_put_params), so dispatching different
+        # generations reuses the same two compiled programs (the
+        # compile-count pin survives hot-swaps). New attaches pin to
+        # weight_generation; old generations retire when their last
+        # slot releases.
         self.weight_generation = 1
-        self._params_by_gen: Dict[int, object] = {
-            1: jax.device_put(variables["params"])}
+        held, param_leaves_cast, param_bytes = _put_params(
+            family, variables["params"])
+        self._params_by_gen: Dict[int, object] = {1: held}
         S, Pmax = self.geom.slots, self.geom.pages_per_slot
         self._tables = np.zeros((S, Pmax), np.int32)
         # non-null entries of each slot's table row, kept where an entry
@@ -569,6 +590,11 @@ class DecodeEngine:
             # ran one ahead), and lane-steps whose row was dropped at
             # the walk because the lane's request had gone by then
             "ahead_dispatches": 0, "overrun_lane_steps": 0,
+            # the current generation's tree as held on the device, and
+            # how many of its leaves the family's serve_params holds in
+            # another dtype than they were handed over in
+            "param_bytes": param_bytes,
+            "param_leaves_cast": param_leaves_cast,
         }
         # counts the family's decode program appends to its token row
         # (ServeFamily.step_counters), summed over decode dispatches
@@ -640,12 +666,13 @@ class DecodeEngine:
         rule of thumb) over params read once plus each lane's paged KV
         traffic. A coarse stand-in — budgets treat fallback-sourced
         fields with the same tolerance as XLA fields."""
-        params = self._params_by_gen.get(self.weight_generation)
-        nbytes = sum(int(getattr(a, "nbytes", 0))
-                     for a in jax.tree_util.tree_leaves(params))
+        leaves = jax.tree_util.tree_leaves(
+            self._params_by_gen.get(self.weight_generation))
+        weights = sum(int(a.size) for a in leaves)
+        nbytes = sum(int(a.nbytes) for a in leaves)
         S = self.geom.slots
         return {
-            "flops": 2.0 * (nbytes / 4.0) * S * steps,
+            "flops": 2.0 * weights * S * steps,
             "hbm_bytes": float(
                 nbytes + S * steps * self.slab.decode_bytes_per_token),
         }
@@ -702,8 +729,10 @@ class DecodeEngine:
         settled first: it was packed while one generation was resident."""
         self._carry.extend(self.drain())
         self.weight_generation += 1
-        self._params_by_gen[self.weight_generation] = jax.device_put(
-            variables["params"])
+        (self._params_by_gen[self.weight_generation],
+         self.stats["param_leaves_cast"],
+         self.stats["param_bytes"]) = _put_params(
+            self.family, variables["params"])
         self.stats["weight_swaps"] += 1
         # generations nobody reads anymore free immediately (an idle
         # engine holds exactly one generation after a swap)

@@ -169,36 +169,51 @@ def test_paged_vmem_bound_covers_the_compilers_figure(one_chip, name):
 _SERVE = dict(heads=20, head_dim=64, ffn=5120, vocab=50257, max_len=1024,
               slots=8, page=16, chunk=16, steps=4, window=128)
 # name -> (builder, layers, bound in bytes on the temporaries the
-# program may ask for beside its arguments). Read (sandbox compile,
-# PR 26), bf16 / int8 pages: decode 133 / 133 MB, prefill 134 / 136,
+# program may ask for beside its arguments, whether the parameters come
+# in the form the engine holds them in). The engine hands a program
+# ServeFamily.serve_params' tree (PR 32: every matmul and embedding leaf
+# bfloat16, the norms float32): the "-held" cases, the cell's programs
+# as they run. The others take the float32 tree and cast it at every
+# use, as the train plane's forward does and every engine did until
+# PR 32; they guard the slab the same way and say what the held form
+# saves. Read (sandbox compile, PR 26 and again PR 32), bf16 / int8
+# pages, float32 tree: decode 133 / 133 MB, prefill 134 / 136,
 # multi-step 141 / 140, verify 430 / 355 (at 2 layers the compiler also
-# stages the 42 MB bf16 slab in VMEM), the cell's own 36-layer decode
-# program 183. 129 MB of each is the tied head's bf16 copy of
-# the float32 embedding [50257, 1280]; the verify program adds the
-# draft's float32 logits over its window (206 MB) and, by design, a
-# second copy of K and V (it scans twice from the input slab). The 5-D
-# slab read 363 MB for decode at 2 layers and 8.65 GB at 36: a relayout
-# of either slab is at least 42 MB here (int8, 2 layers), 756 MB at 36.
-# The deep case is there because depth changes what the compiler does:
-# at 24 layers and more it served the copy-on-write gather of whole
-# pages by copying the slab in lane chunks ([36, 513, 16, 384] x 3 and
-# [.., 128]), which no 2-layer compile shows.
+# stages the 42 MB bf16 slab in VMEM), the cell's 36-layer decode
+# program 183.8: 129 MB of each is the bf16 copy of the float32
+# embedding [50257, 1280] that the lookup and the tied head read; the
+# verify program adds the draft's float32 logits over its window
+# (206 MB) and, by design, a second copy of K and V (it scans twice
+# from the input slab). Held form (sandbox compile, PR 32): decode
+# 4.35 / 4.39 MB, prefill 5.68 / 0, the 36-layer decode program 73.3,
+# each bound a quarter above its reading; arguments 3.06 GB at 36
+# layers where the float32 tree's are 4.61. The 5-D slab read 363 MB
+# for decode at 2 layers and 8.65 GB at 36: a relayout of either slab
+# is at least 42 MB here (int8, 2 layers), 756 MB at 36. The deep cases
+# are there because depth changes what the compiler does: at 24 layers
+# and more it served the copy-on-write gather of whole pages by copying
+# the slab in lane chunks ([36, 513, 16, 384] x 3 and [.., 128]), which
+# no 2-layer compile shows.
 SERVE_PROGRAMS = {
-    "decode": ("decode", 2, 160e6),
-    "prefill": ("prefill", 4, 160e6),
-    "multi": ("multi", 3, 165e6),
-    "verify": ("verify", 2, 470e6),
-    "decode-36-layers": ("decode", 36, 400e6),
+    "decode": ("decode", 2, 160e6, False),
+    "prefill": ("prefill", 4, 160e6, False),
+    "multi": ("multi", 3, 165e6, False),
+    "verify": ("verify", 2, 470e6, False),
+    "decode-36-layers": ("decode", 36, 400e6, False),
+    "decode-held": ("decode", 2, 5.5e6, True),
+    "prefill-held": ("prefill", 4, 7.1e6, True),
+    "decode-36-layers-held": ("decode", 36, 92e6, True),
 }
 SERVE_CASES = [(name, kv) for name in SERVE_PROGRAMS
                for kv in ("f32", "int8")
-               if (name, kv) != ("decode-36-layers", "int8")]
+               if not (name.startswith("decode-36-layers") and kv == "int8")]
 
 
-def _serve_program(which, L, kv_dtype, sds):
+def _serve_program(which, L, kv_dtype, sds, held=False):
     """(fn, args, donate_argnums, slab shape) of one serve program at
     the cell's widths and L layers, arguments as shapes on the
-    described chip."""
+    described chip; `held`: the parameter trees as the engine puts them
+    there (the family's serve_params), else float32 as initialised."""
     import jax
     import jax.numpy as jnp
 
@@ -212,6 +227,9 @@ def _serve_program(which, L, kv_dtype, sds):
             dtype=jnp.bfloat16)
         params = jax.eval_shape(lambda: module.init(
             jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))["params"])
+        if held:
+            params = jax.eval_shape(module.serve_family().serve_params,
+                                    params)
         return module, jax.tree_util.tree_map(
             lambda a: sds(a.shape, a.dtype), params)
 
@@ -286,8 +304,10 @@ def test_serve_program_keeps_the_slab_in_place_on_v5e(one_chip, name,
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    which, layers, temp_bound = SERVE_PROGRAMS[name]
-    fn, args, donate, rows = _serve_program(which, layers, kv_dtype, sds)
+    c = _SERVE
+    which, layers, temp_bound, held = SERVE_PROGRAMS[name]
+    fn, args, donate, rows = _serve_program(which, layers, kv_dtype, sds,
+                                            held)
     compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
@@ -309,6 +329,20 @@ def test_serve_program_keeps_the_slab_in_place_on_v5e(one_chip, name,
     assert not chunks, chunks
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < temp_bound, temp
+    # (d) handed the held form, a program converts no parameter again:
+    # nothing in it (an entry parameter, a fusion's) is a float32 array
+    # of an embedding's or a kernel's whole shape, and no convert yields
+    # a bfloat16 one. The float32 tree's programs do both, for every
+    # such leaf: the check can fail.
+    whole = {a.shape for path, a
+             in jax.tree_util.tree_leaves_with_path(args[0])
+             if path[-1].key in ("kernel", "embedding")}
+    assert (c["vocab"], c["heads"] * c["head_dim"]) in whole \
+        and len(whole) == 6, whole
+    converted = [(n, t, dims, op) for n, t, dims, _, op
+                 in _result_shapes(hlo) if dims in whole
+                 and (t == "f32" or (t == "bf16" and op == "convert"))]
+    assert bool(converted) != held, converted[:8]
 
 
 # ------------------------------------------- DeepSeek-V2 (latent pages)

@@ -204,6 +204,46 @@ def test_decode_engine_kv_record_reconciles_exactly(monkeypatch):
     assert att["serve"]["bytes_per_token"] > 0.0
 
 
+def test_engine_cost_fallback_counts_weights_not_bytes():
+    """The closed-form stand-in (backends without XLA cost analysis)
+    reckons 2 FLOPs a weight a lane-step from the tree's ELEMENTS and
+    its parameter traffic from the tree's BYTES: the tree held in
+    bfloat16 (the engine's own form of a bfloat16 module's float32
+    tree) and the same tree held in float32 do the same arithmetic and
+    move half the parameter bytes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kubeml_tpu.models import get_builtin
+    from kubeml_tpu.serve.engine import DecodeEngine
+
+    model = get_builtin("gpt-nano")()
+    module = model.module
+    variables = model.init_variables(
+        jax.random.PRNGKey(0),
+        {"x": np.ones((1, module.max_len), np.int32)})
+    leaves = jax.tree_util.tree_leaves(variables["params"])
+    weights = sum(int(a.size) for a in leaves)
+    engine = DecodeEngine(module, variables, slots=4, page=4)
+    kv = 4 * engine.slab.decode_bytes_per_token
+    held = engine._cost_fallback()
+    assert engine.stats["param_bytes"] < 4 * weights
+    assert held == {"flops": 2.0 * weights * 4,
+                    "hbm_bytes": float(engine.stats["param_bytes"] + kv)}
+    assert engine._cost_fallback(steps=3) == {
+        "flops": 3 * held["flops"],
+        "hbm_bytes": float(engine.stats["param_bytes"] + 3 * kv)}
+    # the float32-held tree and an all-bfloat16 one, in the same engine
+    as_held = engine._params_by_gen[1]
+    for dtype, itemsize in ((jnp.float32, 4), (jnp.bfloat16, 2)):
+        engine._params_by_gen[1] = jax.tree_util.tree_map(
+            lambda a: a.astype(dtype), as_held)
+        assert engine._cost_fallback() == {
+            "flops": held["flops"],
+            "hbm_bytes": float(itemsize * weights + kv)}
+
+
 # ---------------------------------------------------------- budget gate
 
 def test_budget_gate_passes_committed_and_fails_perturbed():

@@ -4,7 +4,10 @@ of a decode program (poison lane -> non-finite guard -> never-emit-PAD
 -> greedy or categorical under the lane's own key), and
 `cow_split_pages`, the copy-on-write lane over one slab plane. GPT's
 and DeepSeek-V2's decode steps both end in the first and begin with the
-second (tests/test_models_deepseek_v2.py holds them to it)."""
+second (tests/test_models_deepseek_v2.py holds them to it). And the
+form a family's programs read their parameters in
+(`ServeFamily.serve_params`): GPT's four programs give the same bits on
+the tree held in the module's dtype as on the float32 one."""
 
 import jax
 import jax.numpy as jnp
@@ -121,3 +124,190 @@ def test_cow_split_reads_every_source_before_any_write():
     want = np.asarray(pages.at[:, dst].set(pages[:, src]))
     np.testing.assert_array_equal(out, want)
     np.testing.assert_array_equal(out[:, 5], np.asarray(pages[:, 4]))
+
+
+# ------------------------- ServeFamily.serve_params: the form a family's
+# programs read their parameters in
+
+GS, GG, GPMAX, GC, GW = 3, 8, 4, 8, 24     # slots, page, pages a slot,
+GPAGES = 1 + GS * GPMAX                     # chunk, verify window
+
+
+def _gpt(dtype, seed=0):
+    from kubeml_tpu.models.gpt import GPTModule
+    module = GPTModule(vocab_size=512, max_len=64, hidden=32, layers=2,
+                       heads=2, ffn=64, dropout=0.0, dtype=dtype)
+    params = module.init(jax.random.PRNGKey(seed),
+                         np.ones((1, 8), np.int32))["params"]
+    # biases and scales off their constant initial values, so a cast of
+    # them would show
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 1000))
+    params = jax.tree_util.tree_map(
+        lambda a: a + 0.1 * jax.random.normal(next(keys), a.shape), params)
+    return module, params
+
+
+def _gpt_state(module, kv_dtype, rng):
+    """A slab part full: slot s holds 5 + 6 s tokens in its own pages."""
+    L, H = module.layers, module.hidden
+    if kv_dtype == "int8":
+        planes = [jnp.asarray(rng.integers(-127, 128, (L, GPAGES, GG, H)),
+                              jnp.int8) for _ in range(2)]
+        scales = [jnp.asarray(rng.uniform(0.01, 0.05, (L, GPAGES)),
+                              jnp.float32) for _ in range(2)]
+    else:
+        planes = [jnp.asarray(rng.normal(size=(L, GPAGES, GG, H)),
+                              module.dtype) for _ in range(2)]
+        scales = [jnp.ones((L, GPAGES), jnp.float32) for _ in range(2)]
+    tables = 1 + np.arange(GS * GPMAX, dtype=np.int32).reshape(GS, GPMAX)
+    pos = np.asarray([5 + 6 * s for s in range(GS)], np.int32)
+    valid = np.zeros((GPAGES, GG), np.float32)
+    for s in range(GS):
+        for t in range(pos[s]):
+            valid[tables[s, t // GG], t % GG] = 1.0
+    return [*planes, *scales, jnp.asarray(valid)], tables, pos
+
+
+def _gpt_program(program, kv_dtype, module, draft_module):
+    """(jitted program, its arguments after the parameter trees)."""
+    from kubeml_tpu.models import gpt
+    rng = np.random.default_rng(7)
+    state, tables, pos = _gpt_state(module, kv_dtype, rng)
+    i32, f32 = jnp.int32, jnp.float32
+    tokens = jnp.asarray(rng.integers(1, 512, GS), i32)
+    temps = jnp.asarray([0.0, 0.8, 1.3], f32)
+    live = jnp.ones(GS, i32)
+    seeds = jnp.asarray([11, 12, 13], jnp.uint32)
+    build = dict(kv_dtype=kv_dtype, attn_impl="gather")
+    if program == "decode":
+        fn = gpt.build_paged_decode_step(module, **build)
+        args = [tokens, jnp.asarray(pos), jnp.asarray(tables),
+                jnp.asarray(tables[np.arange(GS), pos // GG]),
+                jnp.asarray(pos % GG), jnp.ones(GS, f32), temps,
+                jnp.asarray(rng.integers(0, 2**31, (GS, 2)), jnp.uint32),
+                jnp.zeros(GS, i32), jnp.zeros(GS, i32), jnp.zeros(GS, f32)]
+    elif program == "prefill":
+        fn = gpt.build_paged_prefill_step(module, GC, **build)
+        at = pos[1] + np.arange(GC)          # slot 1's next chunk
+        args = [jnp.asarray(rng.integers(1, 512, GC), i32),
+                jnp.asarray(at, i32), jnp.asarray(tables[1]),
+                jnp.asarray(tables[1, at // GG]), jnp.asarray(at % GG, i32),
+                jnp.asarray([1.0] * (GC - 2) + [0.0] * 2, f32)]
+    elif program == "multi_step":
+        fn = gpt.build_paged_multi_step_decode(module, 3, **build)
+        args = [tokens, jnp.asarray(pos), jnp.asarray(tables), live, temps,
+                seeds, jnp.full(GS, -1, i32), jnp.full(GS, 3, i32)]
+    else:
+        fn = gpt.build_paged_spec_verify_step(module, draft_module, 2, GW,
+                                              **build)
+        window = np.zeros((GS, GW), np.int32)
+        for s in range(GS):
+            window[s, :pos[s] + 1] = rng.integers(1, 512, pos[s] + 1)
+        args = [jnp.asarray(window), jnp.asarray(pos), jnp.asarray(tables),
+                live, temps, seeds, jnp.full(GS, 3, i32)]
+    return jax.jit(fn), [*state, *args]
+
+
+def _bits(tree):
+    return [np.asarray(a).view(np.uint8).tolist() if a.dtype == jnp.bfloat16
+            else np.asarray(a).tolist()
+            for a in jax.tree_util.tree_leaves(tree)]
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "multi_step",
+                                     "verify"])
+def test_gpt_program_gives_the_same_bits_on_the_held_form(program,
+                                                           kv_dtype):
+    """Next tokens, `bad`, accepted counts and every returned state
+    array: equal bit for bit whether the program casts the float32
+    leaves itself or is handed them cast (a draft's tree too); the
+    held form is what the docstring of serve_params says, and is a
+    fixed point; a float32 module's is its input, so its jaxpr is the
+    string it was."""
+    module, params = _gpt(jnp.bfloat16)
+    draft, draft_params = _gpt(jnp.bfloat16, seed=5)
+    family = module.serve_family()
+    held = family.serve_params(params)
+    flat = {jax.tree_util.keystr(k): a for k, a in
+            jax.tree_util.tree_leaves_with_path(held)}
+    assert {k: str(a.dtype) for k, a in flat.items()} == {
+        k: "float32" if "LayerNorm" in k else "bfloat16" for k in flat}
+    assert sum("LayerNorm" in k for k in flat) == 2 * (2 * 2 + 1)
+    again = family.serve_params(held)
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(held),
+                                      jax.tree_util.tree_leaves(again)))
+    # a host tree (a checkpoint's) is cast on the host: the same bits
+    host = family.serve_params(jax.tree_util.tree_map(np.asarray, params))
+    assert all(isinstance(a, np.ndarray)
+               for a in jax.tree_util.tree_leaves(host))
+    assert _bits(host) == _bits(held)
+
+    fn, args = _gpt_program(program, kv_dtype, module, draft)
+    trees = [[params], [held]] if program != "verify" else [
+        [params, draft_params],
+        [held, draft.serve_family().serve_params(draft_params)]]
+    cast_inside, handed_cast = (fn(*p, *args) for p in trees)
+    assert [a.dtype for a in jax.tree_util.tree_leaves(cast_inside)] == [
+        a.dtype for a in jax.tree_util.tree_leaves(handed_cast)]
+    assert _bits(cast_inside) == _bits(handed_cast)
+
+    module32, params32 = _gpt(jnp.float32)
+    same = module32.serve_family().serve_params(params32)
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(params32),
+                                      jax.tree_util.tree_leaves(same)))
+    fn32, args32 = _gpt_program(program, kv_dtype, module32, module32)
+    trees32 = [[params32], [same]] if program != "verify" else [
+        [params32, params32], [same, same]]
+    assert str(jax.make_jaxpr(fn32)(*trees32[0], *args32)) == str(
+        jax.make_jaxpr(fn32)(*trees32[1], *args32))
+
+
+@pytest.mark.parametrize("chunked", [False, True])
+def test_gpt_head_logits_are_the_same_bits_on_the_held_form(chunked):
+    """The trunk's float32 logits themselves (a program returns only
+    the pick made from them), after the embedding and both layers: a
+    decode step's [S, V] and, through the same head, a chunk's."""
+    from kubeml_tpu.models import gpt
+    from kubeml_tpu.ops.attention import NEG_INF
+    module, params = _gpt(jnp.bfloat16)
+    held = module.serve_family().serve_params(params)
+    embed, layers, head = gpt._paged_trunk(module, "f32", "gather", False,
+                                           chunked=chunked)
+    rng = np.random.default_rng(3)
+    state, tables, pos = _gpt_state(module, "f32", rng)
+    if chunked:      # slot 1's next chunk: rows of one slot, one table
+        at, own = pos[1] + np.arange(GC, dtype=np.int32), tables[1]
+        where = (own[at // GG], at % GG, np.ones(GC, np.float32))
+    else:            # a row a slot, each with its own table
+        at, own = pos, tables
+        where = (own[np.arange(GS), at // GG], at % GG)
+    tokens = rng.integers(1, 512, len(at)).astype(np.int32)
+    seen = np.arange(GPMAX * GG)[None, :] <= at[:, None]
+    bias = np.where(seen, 0.0, NEG_INF).astype(np.float32)
+    bias = bias[None, None] if chunked else bias[:, None, None, :]
+
+    @jax.jit
+    def logits(p):
+        h = embed(p, tokens, at)
+        h = layers(p, h, *state[:4], own, bias, *where)[0]
+        return head(p, jnp.swapaxes(h, 0, 1) if chunked else h)
+
+    a, b = logits(params), logits(held)
+    assert a.dtype == jnp.float32 and a.shape == (len(at), 512)
+    assert np.isfinite(np.asarray(a)).all() and np.asarray(a).std() > 0
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("name", ["deepseek_v2", "jamba"])
+def test_other_families_hand_back_the_very_tree(name):
+    """Their leaves are bfloat16 from the checkpoint on and their
+    float32 ones are read in float32: nothing to hold otherwise."""
+    import importlib
+    mod = importlib.import_module(f"kubeml_tpu.models.{name}")
+    family = next(v for v in vars(mod).values() if isinstance(v, type)
+                  and issubclass(v, mod.ServeFamily)
+                  and v is not mod.ServeFamily)
+    assert "serve_params" not in vars(family)
+    tree = {"a": {"kernel": np.ones((2, 2), np.float32)}}
+    assert mod.ServeFamily.serve_params(object(), tree) is tree

@@ -144,6 +144,116 @@ def test_join_leave_never_recompiles():
         engine.stats["dispatches"] + engine.stats["prefill_dispatches"]
 
 
+def _leaf_types(tree):
+    import jax
+    return {jax.tree_util.keystr(k): (a.shape, str(a.dtype))
+            for k, a in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def test_every_generation_is_held_in_the_serve_form():
+    """An engine built from a float32 tree holds it as its family's
+    programs read it (models/gpt.py serve_params: the norms float32,
+    every other leaf in the module's bfloat16), says so in its stats,
+    and holds an installed generation and a rebuilt engine's tree in
+    exactly that form: a hot swap compiles nothing."""
+    import jax
+
+    from kubeml_tpu.serve.engine import DecodeEngine
+    from kubeml_tpu.serve.slots import GenerateRequest
+
+    model, module, variables = _nano()
+    other = model.init_variables(
+        jax.random.PRNGKey(1), {"x": np.ones((1, module.max_len), np.int32)})
+    handed = _leaf_types(variables["params"])
+    assert {d for _s, d in handed.values()} == {"float32"}
+    engine = DecodeEngine(module, variables, slots=2, page=4,
+                          prefill_chunk=4)
+    types = _leaf_types(engine._params_by_gen[1])
+    assert types == {k: (s, "float32" if "LayerNorm" in k else "bfloat16")
+                     for k, (s, _d) in handed.items()}
+    norms = sum(4 * int(np.prod(s)) for k, (s, _d) in handed.items()
+                if "LayerNorm" in k)
+    whole = sum(4 * int(np.prod(s)) for s, _d in handed.values())
+    held = {"param_bytes": norms + (whole - norms) // 2,
+            # the two tables, and a layer's six kernels and six biases
+            "param_leaves_cast": 2 + 12 * module.layers}
+    assert {k: engine.stats[k] for k in held} == held
+    assert held["param_leaves_cast"] == sum(
+        "LayerNorm" not in k for k in types)
+
+    a = GenerateRequest([5, 6, 7, 8, 9, 10], max_new_tokens=8)
+    engine.attach(a)
+    for _ in range(4):
+        engine.step()
+    compiled = (engine.stats["compiles"], engine.stats["prefill_compiles"])
+    assert compiled == (1, 1)
+    assert engine.install_weights(other) == 2
+    assert engine.active_generations() == [1, 2]
+    assert _leaf_types(engine._params_by_gen[2]) == types
+    b = GenerateRequest([5, 6, 7, 8, 9, 10], max_new_tokens=8)
+    engine.attach(b)
+    _drive(engine)
+    assert a.outcome == "ok" and b.outcome == "ok" and a.tokens != b.tokens
+    assert (engine.stats["compiles"],
+            engine.stats["prefill_compiles"]) == compiled
+    assert engine.compile_tracker.compiles == 2
+    assert {k: engine.stats[k] for k in held} == held
+
+    rebuilt = engine.spawn_recovered()
+    assert rebuilt.active_generations() == [2]
+    resident = jax.tree_util.tree_leaves(engine._params_by_gen[2])
+    assert all(x is y for x, y in zip(resident, jax.tree_util.tree_leaves(
+        rebuilt._params_by_gen[2])))
+    # handed the held form, the family's function changes nothing
+    assert {k: rebuilt.stats[k] for k in held} == {
+        **held, "param_leaves_cast": 0}
+    c = GenerateRequest([5, 6, 7, 8, 9, 10], max_new_tokens=8)
+    rebuilt.attach(c)
+    _drive(rebuilt)
+    assert c.tokens == b.tokens
+
+
+@pytest.mark.parametrize("how", ["solo", "batched"])
+def test_streams_equal_the_float32_held_forward(how):
+    """What an engine built from a float32 tree serves is, token for
+    token, what the module gives on that float32 tree by its plain
+    forward (the cast at each use: every engine's way before the tree
+    was held cast), alone in the engine and among neighbours."""
+    import jax
+
+    from kubeml_tpu.serve.engine import DecodeEngine
+    from kubeml_tpu.serve.slots import GenerateRequest
+
+    _model, module, variables = _nano()
+    forward = jax.jit(lambda p, x: module.apply({"params": p}, x))
+    prompts = [[5, 6, 7], [9, 10, 11, 12, 13, 14, 15, 16, 17],
+               list(range(40, 60))]
+    n_new = 10
+
+    def reference(prompt):
+        toks = list(prompt)
+        for _ in range(n_new):
+            x = np.zeros((1, module.max_len), np.int32)
+            x[0, :len(toks)] = toks
+            logits = np.array(forward(variables["params"], x))
+            row = logits[0, len(toks) - 1]
+            row[module.serve_family().pad_id] = -np.inf
+            toks.append(int(row.argmax()))
+        return toks[len(prompt):]
+
+    engine = DecodeEngine(module, variables, slots=4, page=8,
+                          prefill_chunk=8)
+    assert engine.stats["param_leaves_cast"] > 0
+    reqs = [GenerateRequest(list(p), max_new_tokens=n_new) for p in prompts]
+    for r in reqs:
+        engine.attach(r)
+        if how == "solo":
+            _drive(engine)
+    _drive(engine)
+    assert [r.outcome for r in reqs] == ["ok"] * 3
+    assert [r.tokens for r in reqs] == [reference(p) for p in prompts]
+
+
 def test_pages_free_on_eos_and_return_to_pool():
     """EOS finishes the stream early, its pages free, and the pool
     drains back to zero in-use after every stream completes."""
