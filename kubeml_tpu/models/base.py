@@ -38,6 +38,8 @@ import numpy as np
 import optax
 from jax import lax
 
+from kubeml_tpu.ops.attention import NEG_INF
+
 PyTree = Any
 
 
@@ -51,13 +53,17 @@ class InferenceInputError(ValueError):
 @dataclasses.dataclass(frozen=True)
 class SlotState:
     """One per-slot state array of a family's cache: `[layers, slots,
-    *shape]` in `dtype`, indexed by SLOT, not by page (a recurrence's
-    running state, a convolution's last inputs). It has no per-token
-    rows, so it cannot be shared by reference or split on write: a
-    family that declares any takes no prefix-cache hit (serve/engine.py).
-    `layers` counts the layers that keep it, which need not be the
-    layers that keep pages. Keep the minor dimension lane-dense (a
-    multiple of 128): 16 lanes pad eightfold on the chip."""
+    *shape]` in `dtype`, indexed by SLOT, not by page: a recurrence's
+    running state, a convolution's last inputs, or attention rows that
+    need no page because only the last few are ever read (a window
+    layer's ring of K or V rows, row = position % window). It has no
+    rows a page table reaches, so it cannot be shared by reference or
+    split on write: a family that declares any takes no prefix-cache
+    hit (serve/engine.py). `layers` counts the layers that keep it,
+    which need not be the layers that keep pages (EXAONE-MoE: pages for
+    its global layers, rings for its window layers). Keep the minor
+    dimension lane-dense (a multiple of 128): 16 lanes pad eightfold on
+    the chip."""
 
     name: str
     layers: int
@@ -84,8 +90,10 @@ class CacheSpec:
     kv_dtype "int8" can be served. `validity`: they carry the shared
     [pages, page_tokens] float32 validity plane (padding tokens masked
     out of attention); a family without it masks by position alone.
-    `slot_state`: per-slot arrays beside the pages (SlotState), empty
-    for a family whose whole context lives in pages."""
+    `slot_state`: per-slot arrays beside the pages (SlotState: a
+    recurrence's state, or the attention rows of layers that read a
+    bounded window), empty for a family whose whole context lives in
+    pages. `layers` counts the layers that keep PAGES."""
 
     layers: int
     planes: int
@@ -129,10 +137,11 @@ class ServeFamily:
           `slot`, after in_chunk: whose state the chunk advances)
 
     Per-slot state follows one rule in both programs: a lane or chunk
-    whose first position is 0 starts from the zero state, decided in
-    the program from `pos`, so admission, slot reuse and a resumed
-    stream's re-prefill need no host-side zeroing; an inactive lane and
-    a chunk's padded tail leave the state as it is.
+    whose first position is 0 starts from the zero state (a ring: from
+    no valid row), decided in the program from `pos`, so admission,
+    slot reuse and a resumed stream's re-prefill need no host-side
+    zeroing; an inactive lane and a chunk's padded tail leave the state
+    as it is.
 
     `step_counters` names int32 counts the decode program appends to
     its token row (read back in the same transfer); the engine sums
@@ -235,6 +244,141 @@ def gated_mlp(x, p):
     a = jax.nn.silu(dot_f32(x, p["gate"]["kernel"])) \
         * dot_f32(x, p["up"]["kernel"])
     return dot_f32(a, p["down"]["kernel"])
+
+
+def attend_pages_in_blocks(q, k_pages, v_pages, row, page_table, pos,
+                           n_blocks, per_block, *, kv_heads: int, dtype):
+    """Prefill attention of ONE slot's chunk over its K and V pages, in
+    plain JAX: q [C, H, D] (grouped queries: H a multiple of `kv_heads`)
+    over the rows of plane `row` that `page_table` [Pmax] names,
+    `per_block` pages at a time gathered through the table, a running
+    float32 softmax over `n_blocks` blocks (as many as the chunk's last
+    position needs; a traced count), key j visible to the query at
+    pos[t] iff j <= pos[t]. The chunk's own rows are in the pages
+    already. Returns [C, H * D] float32. What a family without a
+    prefill kernel for its geometry runs (Jamba, EXAONE-MoE's global
+    layers)."""
+    G = k_pages.shape[2]
+    block = per_block * G
+    C, H, D = q.shape
+    group = H // kv_heads
+    scale = 1.0 / np.sqrt(D)
+    q = q.reshape(C, kv_heads, group, D)
+
+    def one_block(b, carry):
+        mx, den, acc = carry
+        ids = lax.dynamic_slice_in_dim(page_table, b * per_block,
+                                       per_block)
+        k = k_pages[row, ids].reshape(block, kv_heads, D)
+        v = v_pages[row, ids].reshape(block, kv_heads, D)
+        sc = jnp.einsum("qgrd,kgd->grqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+        seen = (b * block + jnp.arange(block)[None, :]
+                <= pos[:, None])[None, None]
+        sc = jnp.where(seen, sc, NEG_INF)
+        mx_new = jnp.maximum(mx, sc.max(-1))
+        w = jnp.where(seen, jnp.exp(sc - mx_new[..., None]), 0.0)
+        alpha = jnp.exp(mx - mx_new)
+        den = alpha * den + w.sum(-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "grqk,kgd->grqd", w.astype(dtype), v,
+            preferred_element_type=jnp.float32)
+        return mx_new, den, acc
+
+    _, den, acc = lax.fori_loop(
+        0, n_blocks, one_block,
+        (jnp.full((kv_heads, group, C), NEG_INF, jnp.float32),
+         jnp.zeros((kv_heads, group, C), jnp.float32),
+         jnp.zeros((kv_heads, group, C, D), jnp.float32)))
+    o = acc / jnp.where(den > 0, den, 1.0)[..., None]
+    return o.transpose(2, 0, 1, 3).reshape(C, -1)
+
+
+def pages_per_block(key_block: int, page: int, n_pages: int) -> int:
+    """Pages a block of `attend_pages_in_blocks` takes: `key_block` keys'
+    worth where that divides the table, else the whole table."""
+    per_block = max(1, key_block // page)
+    return n_pages if n_pages % per_block else per_block
+
+
+# up to this many tokens held_expert_layer's callers run every held
+# expert over every token (a decode batch); above it (a prefill chunk),
+# ragged_dot over token-expert pairs sorted by expert
+DENSE_MOE_TOKENS = 64
+
+
+def held_expert_layer(x, p, live, route, *, held: int, rank: int,
+                       scaling: float, dtype, dense: bool):
+    """Shared experts + ONE SHARE's routed experts over normed tokens
+    x [N, d] (float32): the dropless expert layer of every family whose
+    deployment is expert-parallel (DeepSeek-V2, EXAONE-MoE). `p` holds
+    `router/kernel` [d, E] (E the router's whole width), the `shared`
+    gated MLP and the `experts` stacks [held, d, width] of the experts
+    this share HOLDS, [rank * held, (rank + 1) * held). `route(logits
+    [N, E] float32) -> (experts [N, k], scores [N, k])` is the family's
+    own choice; a chosen expert that lives here weighs `scores *
+    scaling`, one that lives on another chip is left out. `live` [N]
+    marks real tokens (an idle slot's or a chunk's padding row routes
+    nowhere and counts nowhere). Nothing is dropped: with `dense` (a
+    decode batch) every held expert runs over every token under the
+    routing's mask, S * held tiny matmuls that cost a fraction of
+    reading the experts' weights, which a step reads anyway; without
+    it (a prefill chunk) the token-expert pairs are sorted by expert
+    and `jax.lax.ragged_dot` runs over the groups (the dense form would
+    be `held` times the FLOPs). Scopes `router`, `experts`,
+    `shared_expert`. Returns (output [N, d] float32, counts int32[3]:
+    token-expert pairs chosen, those that chose a held expert, held
+    experts with at least one token)."""
+    n = x.shape[0]
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, p["router"]["kernel"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        experts, scores = route(logits)
+        k = experts.shape[1]
+        local = experts - held * rank
+        here = (local >= 0) & (local < held) & (live[:, None] > 0)
+        weight = jnp.where(here, scores * scaling, 0.0)
+        local = jnp.where(here, local, held)        # held: nowhere
+        per_expert = jnp.zeros((n, held + 1), jnp.float32).at[
+            jnp.arange(n)[:, None], local].add(weight)[:, :held]
+        tokens_of = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
+        counts = jnp.stack([
+            jnp.sum(live > 0).astype(jnp.int32) * k,
+            jnp.sum(here).astype(jnp.int32),
+            jnp.sum(tokens_of > 0).astype(jnp.int32)])
+    xb = x.astype(dtype)
+    e = p["experts"]
+    with jax.named_scope("experts"):
+        if dense:
+            # every held expert over every token, the routing a mask
+            g = jnp.einsum("nd,edf->enf", xb, e["gate"]["kernel"],
+                           preferred_element_type=jnp.float32)
+            u = jnp.einsum("nd,edf->enf", xb, e["up"]["kernel"],
+                           preferred_element_type=jnp.float32)
+            a = (jax.nn.silu(g) * u * per_expert.T[:, :, None]
+                 ).astype(dtype)
+            routed = jnp.einsum("enf,efd->nd", a, e["down"]["kernel"],
+                                preferred_element_type=jnp.float32)
+        else:
+            # token-expert pairs sorted by held expert, absent ones last
+            flat = local.reshape(n * k)
+            order = jnp.argsort(flat, stable=True)
+            rows = xb[order // k]
+            g = lax.ragged_dot(rows, e["gate"]["kernel"], tokens_of,
+                               preferred_element_type=jnp.float32)
+            u = lax.ragged_dot(rows, e["up"]["kernel"], tokens_of,
+                               preferred_element_type=jnp.float32)
+            a = (jax.nn.silu(g) * u).astype(dtype)
+            y = lax.ragged_dot(a, e["down"]["kernel"], tokens_of,
+                               preferred_element_type=jnp.float32)
+            # rows past the last group belong to no expert: whatever
+            # the product left there is selected away, not multiplied
+            y = jnp.where((jnp.arange(n * k) < tokens_of.sum())[:, None],
+                          y * weight.reshape(n * k)[order][:, None], 0.0)
+            routed = y[jnp.argsort(order)].reshape(n, k, -1).sum(1)
+    with jax.named_scope("shared_expert"):
+        shared = gated_mlp(xb, p["shared"])
+    return shared + routed, counts
 
 
 def sample_tokens(logits, active, temps, key_data, poison, pad_id):

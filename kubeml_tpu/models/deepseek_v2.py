@@ -27,12 +27,11 @@ published top-k; the layer adds the shared experts and its own
 experts' terms, what the absent experts would add is left out, and
 that partial sum goes on to the next layer. No code stands in for the
 absent chips. The expert layer DROPS NOTHING (no capacity factor; this
-is not models/gpt.py MoEFFN): a decode batch runs every held expert
-over every token under the routing's mask (S * held tiny matmuls that
-cost a fraction of reading the experts' weights, which a step reads
-anyway), a prefill chunk sorts its token-expert pairs by expert and
-runs `jax.lax.ragged_dot` over the groups (the dense form would be
-`held` times the FLOPs).
+is not models/gpt.py MoEFFN) and is written once for every
+expert-parallel family, in models/base.py `held_expert_layer` (the
+dense-mask form for a decode batch, `jax.lax.ragged_dot` over sorted
+token-expert pairs for a prefill chunk, the three step counts): this
+file keeps the family's own `route` and hands it in.
 
 The cache (models/base.py CacheSpec) is ONE plane per layer of
 `[c_kv | k_pe]` rows, 576 lanes at the published widths, after the norm
@@ -64,16 +63,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from kubeml_tpu.models.base import (CacheSpec, InferenceInputError,
-                                    KubeModel, ServeFamily, cow_split_pages,
-                                    sample_tokens)
+from kubeml_tpu.models.base import (DENSE_MOE_TOKENS, CacheSpec,
+                                    InferenceInputError, KubeModel,
+                                    ServeFamily, cow_split_pages,
+                                    held_expert_layer, sample_tokens)
 from kubeml_tpu.models.base import dot_f32 as _dot
 from kubeml_tpu.models.base import gated_mlp as _gated
 from kubeml_tpu.models.base import rms_norm as _rms
 from kubeml_tpu.ops.pallas import mla_paged_attention as mla
 
 PAD_ID = 0
-HI = lax.Precision.HIGHEST
 F32 = jnp.float32
 
 # jax.named_scope names inside the two programs, in program order; the
@@ -83,13 +82,11 @@ F32 = jnp.float32
 PAGED_SCOPES = ("cow_split", "embed", "mla_q", "mla_kv_write", "mla_attn",
                 "mla_out", "mlp", "router", "experts", "shared_expert",
                 "head", "sample")
-# what the decode program counts, appended to its token row
+# what the decode program counts, appended to its token row: the three
+# counts of the shared expert layer (models/base.py held_expert_layer),
+# summed over the expert layers
 STEP_COUNTERS = ("moe_assignments", "moe_local_assignments",
                  "moe_experts_touched")
-# up to this many tokens a program runs every held expert over every
-# token (a decode batch); above it (a prefill chunk), ragged_dot over
-# token-expert pairs sorted by expert
-DENSE_MOE_TOKENS = 64
 # keys a step of the prefill attention loop takes. At the published
 # widths and a chunk of 512 the float32 scores of a step are [128, 512,
 # keys]: at 256 keys (67 MB) the v5e compiler keeps them in VMEM, at 512
@@ -272,57 +269,14 @@ def route(m: DeepSeekV2Module, logits):
 
 def _moe(m: DeepSeekV2Module, x, p, live, dense: bool):
     """Shared experts + this share's routed experts over normed tokens
-    x [N, d] (float32). `live` [N] marks real tokens (an idle slot's or
-    a chunk's padding row routes nowhere and counts nowhere). Returns
-    (output [N, d] float32, the three counts of STEP_COUNTERS)."""
-    n, k, held = x.shape[0], m.experts_per_tok, m.n_held_experts
-    with jax.named_scope("router"):
-        logits = jnp.dot(x, p["router"]["kernel"].astype(F32), precision=HI)
-        experts, scores = route(m, logits)
-        local = experts - held * m.ep_rank
-        here = (local >= 0) & (local < held) & (live[:, None] > 0)
-        weight = jnp.where(here, scores * m.routed_scaling_factor, 0.0)
-        local = jnp.where(here, local, held)        # held: nowhere
-        per_expert = jnp.zeros((n, held + 1), F32).at[
-            jnp.arange(n)[:, None], local].add(weight)[:, :held]
-        tokens_of = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
-        counts = jnp.stack([
-            jnp.sum(live > 0).astype(jnp.int32) * k,
-            jnp.sum(here).astype(jnp.int32),
-            jnp.sum(tokens_of > 0).astype(jnp.int32)])
-    xb = x.astype(m.dtype)
-    e = p["experts"]
-    with jax.named_scope("experts"):
-        if dense:
-            # every held expert over every token, the routing a mask
-            g = jnp.einsum("nd,edf->enf", xb, e["gate"]["kernel"],
-                           preferred_element_type=F32)
-            u = jnp.einsum("nd,edf->enf", xb, e["up"]["kernel"],
-                           preferred_element_type=F32)
-            a = (jax.nn.silu(g) * u * per_expert.T[:, :, None]
-                 ).astype(m.dtype)
-            routed = jnp.einsum("enf,efd->nd", a, e["down"]["kernel"],
-                                preferred_element_type=F32)
-        else:
-            # token-expert pairs sorted by held expert, absent ones last
-            flat = local.reshape(n * k)
-            order = jnp.argsort(flat, stable=True)
-            rows = xb[order // k]
-            g = lax.ragged_dot(rows, e["gate"]["kernel"], tokens_of,
-                               preferred_element_type=F32)
-            u = lax.ragged_dot(rows, e["up"]["kernel"], tokens_of,
-                               preferred_element_type=F32)
-            a = (jax.nn.silu(g) * u).astype(m.dtype)
-            y = lax.ragged_dot(a, e["down"]["kernel"], tokens_of,
-                               preferred_element_type=F32)
-            # rows past the last group belong to no expert: whatever
-            # the product left there is selected away, not multiplied
-            y = jnp.where((jnp.arange(n * k) < tokens_of.sum())[:, None],
-                          y * weight.reshape(n * k)[order][:, None], 0.0)
-            routed = y[jnp.argsort(order)].reshape(n, k, -1).sum(1)
-    with jax.named_scope("shared_expert"):
-        shared = _gated(xb, p["shared"])
-    return shared + routed, counts
+    x [N, d] (float32): the expert layer every expert-parallel family
+    shares (models/base.py held_expert_layer) under this family's own
+    `route`. Returns (output [N, d] float32, the three counts of
+    STEP_COUNTERS)."""
+    return held_expert_layer(
+        x, p, live, lambda logits: route(m, logits),
+        held=m.n_held_experts, rank=m.ep_rank,
+        scaling=m.routed_scaling_factor, dtype=m.dtype, dense=dense)
 
 
 def _ffn(m, i, h, p, live):

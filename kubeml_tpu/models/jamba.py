@@ -65,7 +65,8 @@ from jax import lax
 
 from kubeml_tpu.models.base import (CacheSpec, InferenceInputError,
                                     KubeModel, ServeFamily, SlotState,
-                                    cow_split_pages, dot_f32, gated_mlp,
+                                    attend_pages_in_blocks, cow_split_pages,
+                                    dot_f32, gated_mlp, pages_per_block,
                                     rms_norm, sample_tokens)
 from kubeml_tpu.ops.attention import NEG_INF, multi_head_attention
 from kubeml_tpu.ops.pallas import paged_attention as pa
@@ -441,51 +442,11 @@ def build_prefill_step(m: JambaModule, chunk: int, attn_impl: str = "auto",
     prompt token goes through the decode step."""
     if chunk < 1:
         raise ValueError(f"prefill chunk must be >= 1, got {chunk}")
-    group = m.heads // m.kv_heads
-    scale = 1.0 / np.sqrt(m.head_dim)
-
-    def attend(q, k_pages, v_pages, row, page_table, pos, n_blocks,
-               per_block):
-        G = k_pages.shape[2]
-        block = per_block * G
-        C = q.shape[0]
-        q = q.reshape(C, m.kv_heads, group, m.head_dim)
-
-        def one_block(b, carry):
-            mx, den, acc = carry
-            ids = lax.dynamic_slice_in_dim(page_table, b * per_block,
-                                           per_block)
-            k = k_pages[row, ids].reshape(block, m.kv_heads, m.head_dim)
-            v = v_pages[row, ids].reshape(block, m.kv_heads, m.head_dim)
-            sc = jnp.einsum("qgrd,kgd->grqk", q, k,
-                            preferred_element_type=F32) * scale
-            seen = (b * block + jnp.arange(block)[None, :]
-                    <= pos[:, None])[None, None]
-            sc = jnp.where(seen, sc, NEG_INF)
-            mx_new = jnp.maximum(mx, sc.max(-1))
-            w = jnp.where(seen, jnp.exp(sc - mx_new[..., None]), 0.0)
-            alpha = jnp.exp(mx - mx_new)
-            den = alpha * den + w.sum(-1)
-            acc = alpha[..., None] * acc + jnp.einsum(
-                "grqk,kgd->grqd", w.astype(m.dtype), v,
-                preferred_element_type=F32)
-            return mx_new, den, acc
-
-        _, den, acc = lax.fori_loop(
-            0, n_blocks, one_block,
-            (jnp.full((m.kv_heads, group, C), NEG_INF, F32),
-             jnp.zeros((m.kv_heads, group, C), F32),
-             jnp.zeros((m.kv_heads, group, C, m.head_dim), F32)))
-        o = acc / jnp.where(den > 0, den, 1.0)[..., None]
-        return o.transpose(2, 0, 1, 3).reshape(C, -1)
-
     def prefill(params, k_pages, v_pages, ssm, conv, tokens, pos,
                 page_table, write_pages, write_offs, in_chunk, slot):
         G = k_pages.shape[2]
-        n_pages = page_table.shape[0]
-        per_block = max(1, PREFILL_KEY_BLOCK // G)
-        if n_pages % per_block:
-            per_block = n_pages        # one block: the whole table
+        per_block = pages_per_block(PREFILL_KEY_BLOCK, G,
+                                    page_table.shape[0])
         with jax.named_scope("embed"):
             h = params["embed"]["embedding"][tokens].astype(F32)
             fresh = ((pos[0] == 0) & (in_chunk[0] > 0)).astype(jnp.int32)
@@ -500,8 +461,9 @@ def build_prefill_step(m: JambaModule, chunk: int, attn_impl: str = "auto",
                     k_pages = k_pages.at[row, write_pages, write_offs].set(k)
                     v_pages = v_pages.at[row, write_pages, write_offs].set(v)
                 with jax.named_scope(f"layer_{i}/attn"):
-                    o = attend(q, k_pages, v_pages, row, page_table, pos,
-                               n_blocks, per_block)
+                    o = attend_pages_in_blocks(
+                        q, k_pages, v_pages, row, page_table, pos, n_blocks,
+                        per_block, kv_heads=m.kv_heads, dtype=m.dtype)
                 with jax.named_scope(f"layer_{i}/proj"):
                     h = h + dot_f32(o, p["o"]["kernel"])
             else:

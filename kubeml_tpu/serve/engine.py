@@ -4,9 +4,12 @@ The engine serves any model FAMILY that declares its cache and hands
 over its paged programs (models/base.py ServeFamily, reached through
 `module.serve_family()`): the GPT trunk (models/gpt.py: per-head K/V
 pages, all four programs below), DeepSeek-V2 (models/deepseek_v2.py:
-one plane of latent pages, decode and prefill) and Jamba
+one plane of latent pages, decode and prefill), Jamba
 (models/jamba.py: K/V pages for its few attention layers and a
-per-slot recurrent state for the others, decode and prefill). Slots,
+per-slot recurrent state for the others, decode and prefill) and
+EXAONE-MoE (models/exaone_moe.py: K/V pages for its global attention
+layers and a per-slot ring of the last `window` rows for its window
+layers, decode and prefill). Slots,
 page tables, allocation, copy-on-write, the prefix cache and the step
 loop below are the same for every family; a family that lacks an
 optional program is refused by name when a deployment asks for it.
@@ -18,7 +21,8 @@ The engine hands the prefill program of such a family the slot index
 and never zeroes anything: a program starts a slot's state from zeros
 where the stream's position is 0. A family that declares any takes no
 prefix-cache hit: pages restore K and V, not the state at their
-boundary.
+boundary (a recurrence's running state, or a window layer's last
+rows).
 
 An EXACT, documented inventory of jitted programs serves every stream
 (compile count pinned by tests/test_serving.py and

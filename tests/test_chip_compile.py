@@ -624,6 +624,88 @@ def test_jamba_serve_programs_compile_at_published_widths_for_v5e(
             a.shape)) for a in state)
 
 
+_EXAONE_CELL = dict(slots=64, page=16, pages_per_slot=1152, chunk=512)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_exaone_moe_serve_programs_compile_at_published_widths_for_v5e(
+        one_chip, which):
+    """Both programs of models/exaone_moe.py at the PUBLISHED widths and
+    the cell's geometry (5 layers L L L G L, 16 of 128 experts held, 64
+    slots, 1,152 pages a slot, prefill chunk 512), bfloat16 parameters:
+    the chip's compiler accepts them, the decode program holds the
+    paged-attention kernel for its one global layer (64 query heads
+    over 8 KV heads of 128), NO instruction yields a copy of either
+    ring array or of a page plane (the decode lanes' row writes are a
+    scatter in place, the chunk's a dynamic-update-slice of one slot's
+    ring), and arguments and temporaries together fit the chip's
+    16.9 GB beside every slot's pages at its cap. Read (sandbox compile,
+    PR 33): decode 12.39 GB of arguments + 32 MB of temporaries,
+    prefill 10.67 GB + 126 MB (the last layer's feed-forward and the
+    head feed no state the chunk returns, so the compiler drops them
+    and their parameters)."""
+    import importlib.util
+    import math
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "models",
+        "k_exaone_ep8.py")
+    spec = importlib.util.spec_from_file_location("k_exaone_ep8_model", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    m = mod.KExaoneEP8().module
+    assert (m.hidden, m.layers, m.window_layers, m.global_layers,
+            m.kv_lanes, m.n_held_experts) \
+        == (6144, 5, (0, 1, 2, 4), (3,), 1024, 16)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0)))["params"])
+    c = _EXAONE_CELL
+    S, G, Pmax, C = c["slots"], c["page"], c["pages_per_slot"], c["chunk"]
+    family = m.serve_family()
+    cache = family.cache
+    plane = (cache.layers, S * Pmax + 1, G, cache.width)
+    rings = [(st.layers, S) + tuple(st.shape) for st in cache.slot_state]
+    assert rings == [(4, 64, 128, 1024)] * 2
+    state = [sds(plane, cache.dtype)] * cache.planes + [
+        sds(shape, st.dtype) for shape, st in zip(rings, cache.slot_state)]
+    i32, f32 = jnp.int32, jnp.float32
+    if which == "decode":
+        assert family.attn_impls(G, Pmax, C, "f32", "pallas", False) \
+            == ("pallas", "gather")
+        fn = family.decode_step("f32", "pallas", False)
+        rest = [sds((S,), i32), sds((S,), i32), sds((S, Pmax), i32),
+                sds((S,), i32), sds((S,), i32), sds((S,), f32),
+                sds((S,), f32), sds((S, 2), jnp.uint32), sds((S,), i32),
+                sds((S,), i32), sds((S,), f32)]
+    else:
+        fn = family.prefill_step(C, "f32", "pallas", False)
+        rest = [sds((C,), i32), sds((C,), i32), sds((Pmax,), i32),
+                sds((C,), i32), sds((C,), i32), sds((C,), f32),
+                sds((), i32)]
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
+        params, *state, *rest).compile()
+    hlo = compiled.as_text()
+    assert ("%paged_attention" in hlo) == (which == "decode")
+    copies = [(n, dims, op) for n, _, dims, _, op in _result_shapes(hlo)
+              if dims in [plane] + rings
+              and op in ("copy", "copy-start", "gather")]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 300e6, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.7e9
+    assert mem.alias_size_in_bytes >= sum(
+        int(jnp.dtype(a.dtype).itemsize) * math.prod(a.shape)
+        for a in state)
+
+
 # ---------------------------------------------------- flash attention
 
 FLASH_SHAPES = {

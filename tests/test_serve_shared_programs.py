@@ -7,7 +7,10 @@ and DeepSeek-V2's decode steps both end in the first and begin with the
 second (tests/test_models_deepseek_v2.py holds them to it). And the
 form a family's programs read their parameters in
 (`ServeFamily.serve_params`): GPT's four programs give the same bits on
-the tree held in the module's dtype as on the float32 one."""
+the tree held in the module's dtype as on the float32 one. And the
+expert layer every expert-parallel family shares (`held_expert_layer`):
+under DeepSeek-V2's route it is the layer that family had to itself,
+jaxpr for jaxpr, and no other family's program reaches it."""
 
 import jax
 import jax.numpy as jnp
@@ -299,7 +302,7 @@ def test_gpt_head_logits_are_the_same_bits_on_the_held_form(chunked):
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("name", ["deepseek_v2", "jamba"])
+@pytest.mark.parametrize("name", ["deepseek_v2", "jamba", "exaone_moe"])
 def test_other_families_hand_back_the_very_tree(name):
     """Their leaves are bfloat16 from the checkpoint on and their
     float32 ones are read in float32: nothing to hold otherwise."""
@@ -311,3 +314,137 @@ def test_other_families_hand_back_the_very_tree(name):
     assert "serve_params" not in vars(family)
     tree = {"a": {"kernel": np.ones((2, 2), np.float32)}}
     assert mod.ServeFamily.serve_params(object(), tree) is tree
+
+
+# ------------------------------------------------- the shared expert layer
+
+def _parents_moe(m, x, p, live, dense: bool):
+    """models/deepseek_v2.py `_moe` as it stood before its body moved to
+    models/base.py `held_expert_layer` (PR 27's text, verbatim)."""
+    from jax import lax
+
+    from kubeml_tpu.models import deepseek_v2 as ds
+    from kubeml_tpu.models.base import gated_mlp as _gated
+    F32, HI, route = jnp.float32, lax.Precision.HIGHEST, ds.route
+    n, k, held = x.shape[0], m.experts_per_tok, m.n_held_experts
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, p["router"]["kernel"].astype(F32), precision=HI)
+        experts, scores = route(m, logits)
+        local = experts - held * m.ep_rank
+        here = (local >= 0) & (local < held) & (live[:, None] > 0)
+        weight = jnp.where(here, scores * m.routed_scaling_factor, 0.0)
+        local = jnp.where(here, local, held)        # held: nowhere
+        per_expert = jnp.zeros((n, held + 1), F32).at[
+            jnp.arange(n)[:, None], local].add(weight)[:, :held]
+        tokens_of = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
+        counts = jnp.stack([
+            jnp.sum(live > 0).astype(jnp.int32) * k,
+            jnp.sum(here).astype(jnp.int32),
+            jnp.sum(tokens_of > 0).astype(jnp.int32)])
+    xb = x.astype(m.dtype)
+    e = p["experts"]
+    with jax.named_scope("experts"):
+        if dense:
+            g = jnp.einsum("nd,edf->enf", xb, e["gate"]["kernel"],
+                           preferred_element_type=F32)
+            u = jnp.einsum("nd,edf->enf", xb, e["up"]["kernel"],
+                           preferred_element_type=F32)
+            a = (jax.nn.silu(g) * u * per_expert.T[:, :, None]
+                 ).astype(m.dtype)
+            routed = jnp.einsum("enf,efd->nd", a, e["down"]["kernel"],
+                                preferred_element_type=F32)
+        else:
+            flat = local.reshape(n * k)
+            order = jnp.argsort(flat, stable=True)
+            rows = xb[order // k]
+            g = lax.ragged_dot(rows, e["gate"]["kernel"], tokens_of,
+                               preferred_element_type=F32)
+            u = lax.ragged_dot(rows, e["up"]["kernel"], tokens_of,
+                               preferred_element_type=F32)
+            a = (jax.nn.silu(g) * u).astype(m.dtype)
+            y = lax.ragged_dot(a, e["down"]["kernel"], tokens_of,
+                               preferred_element_type=F32)
+            y = jnp.where((jnp.arange(n * k) < tokens_of.sum())[:, None],
+                          y * weight.reshape(n * k)[order][:, None], 0.0)
+            routed = y[jnp.argsort(order)].reshape(n, k, -1).sum(1)
+    with jax.named_scope("shared_expert"):
+        shared = _gated(xb, p["shared"])
+    return shared + routed, counts
+
+
+def _family_programs(module, chunk, slots=2, page=16):
+    """(decode jaxpr, prefill jaxpr) of a family's two programs at a
+    small geometry, traced from shapes alone."""
+    family = module.serve_family()
+    pmax = module.max_len // page
+    sds = jax.ShapeDtypeStruct
+    i32, f32 = jnp.int32, jnp.float32
+    cache = family.cache
+    state = [sds((cache.layers, slots * pmax + 1, page, cache.width),
+                 cache.dtype)] * cache.planes
+    if cache.sidecars:
+        state += [sds((cache.layers, slots * pmax + 1), f32)] * cache.planes
+    if cache.validity:
+        state.append(sds((slots * pmax + 1, page), f32))
+    state += [sds((st.layers, slots) + tuple(st.shape), st.dtype)
+              for st in cache.slot_state]
+    params = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0)))["params"]
+    S, C = slots, chunk
+    decode = (params, *state, sds((S,), i32), sds((S,), i32),
+              sds((S, pmax), i32), sds((S,), i32), sds((S,), i32),
+              sds((S,), f32), sds((S,), f32), sds((S, 2), jnp.uint32),
+              sds((S,), i32), sds((S,), i32), sds((S,), f32))
+    prefill = [params, *state, sds((C,), i32), sds((C,), i32),
+               sds((pmax,), i32), sds((C,), i32), sds((C,), i32),
+               sds((C,), f32)]
+    if cache.slot_state:
+        prefill.append(sds((), i32))
+    return (str(jax.make_jaxpr(family.decode_step("f32", "gather", False))(
+        *decode)), str(jax.make_jaxpr(family.prefill_step(
+            C, "f32", "gather", False))(*prefill)))
+
+
+@pytest.mark.parametrize("chunk", [32, 80], ids=["dense", "ragged"])
+def test_lifted_expert_layer_gives_deepseek_the_parents_jaxprs(chunk,
+                                                               monkeypatch):
+    """DeepSeek-V2's decode and prefill programs trace to the same text
+    with `held_expert_layer` under its route as with the body `_moe`
+    had before the lift (a prefill chunk of 80 takes the ragged_dot
+    form, the decode batch and a chunk of 32 the dense mask): the
+    order of traced operations is part of that text."""
+    from kubeml_tpu.models import deepseek_v2 as ds
+    module = ds.DeepSeekV2Module()
+    assert (chunk > ds.DENSE_MOE_TOKENS) == (chunk == 80)
+    lifted = _family_programs(module, chunk)
+    monkeypatch.setattr(ds, "_moe", _parents_moe)
+    parents = _family_programs(module, chunk)
+    assert lifted[0] == parents[0] and lifted[1] == parents[1]
+    assert "ragged_dot" in lifted[1] or chunk == 32
+
+
+@pytest.mark.parametrize("name", ["gpt", "jamba"])
+def test_families_without_experts_never_reach_the_expert_layer(name,
+                                                               monkeypatch):
+    """GPT's and Jamba's programs trace with the shared expert layer
+    taken away: nothing of it can have moved their jaxprs."""
+    from kubeml_tpu.models import base, deepseek_v2, exaone_moe, jamba
+
+    def gone(*a, **kw):
+        raise AssertionError("a family without experts reached the "
+                             "expert layer")
+
+    for mod in (base, deepseek_v2, exaone_moe):
+        monkeypatch.setattr(mod, "held_expert_layer", gone)
+    if name == "gpt":
+        module, params = _gpt(jnp.float32)
+        decode, prefill = (
+            str(jax.make_jaxpr(fn)(params, *args)) for fn, args in (
+                _gpt_program(which, "f32", module, None)
+                for which in ("decode", "prefill")))
+    else:
+        decode, prefill = _family_programs(jamba.JambaModule(), 16)
+    assert "dot_general" in decode and "dot_general" in prefill
+    with pytest.raises(AssertionError, match="reached the expert layer"):
+        _family_programs(deepseek_v2.DeepSeekV2Module(), 32)
+
