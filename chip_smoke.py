@@ -376,6 +376,43 @@ def latent_kernel_vs_plain(module, page, slots, seed, interpret):
     return float(np.abs(ker - ref).max()), float(np.abs(ref).max())
 
 
+def grouped_kernel_vs_ragged(module, chunk, seed, interpret):
+    """The expert MLP of one chunk's token-expert rows through the
+    grouped-matmul kernel and through `lax.ragged_dot`, a third of the
+    rows real and one expert idle: (max |difference| over the real
+    rows, the bound: bfloat16 agreement relative to the largest
+    magnitude)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubeml_tpu.ops.pallas.grouped_matmul import grouped_mlp
+    held, d, f = (module.n_held_experts, module.hidden,
+                  module.moe_intermediate_size)
+    rows = chunk * module.experts_per_tok
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    sizes = np.random.RandomState(seed).multinomial(
+        rows // 3, np.ones(held - 1) / (held - 1))
+    sizes = jnp.asarray(np.insert(sizes, held // 2, 0), jnp.int32)
+    x = jax.random.normal(ks[0], (rows, d)).astype(module.dtype)
+    gate, up = (
+        (jax.random.normal(k, (held, d, f)) * d ** -0.5).astype(module.dtype)
+        for k in ks[1:3])
+    down = (jax.random.normal(ks[3], (held, f, d)) * f ** -0.5
+            ).astype(module.dtype)
+    # read before the other path is dispatched: under the interpreter
+    # the kernel's callbacks run JAX operations of their own
+    ker = jax.block_until_ready(grouped_mlp(
+        x, gate, up, down, sizes, impl="pallas", interpret=interpret))
+    ref = jax.jit(functools.partial(grouped_mlp, impl="gather"))(
+        x, gate, up, down, sizes)
+    real = int(sizes.sum())
+    ker, ref = np.asarray(ker)[:real], np.asarray(ref)[:real]
+    return (float(np.abs(ker - ref).max()),
+            KERNEL_RTOL * max(1.0, float(np.abs(ref).max())))
+
+
 def phase_serve_latent(args, on_tpu):
     """The DeepSeek-V2 family (models/deepseek_v2.py) at a small size
     through the engine itself: latent pages, the absorbed decode kernel,
@@ -414,6 +451,7 @@ def phase_serve_latent(args, on_tpu):
          outcomes=[r.outcome for r in reqs],
          new_tokens=[len(r.tokens) for r in reqs],
          attn_impl_decode=stats["attn_impl_decode"],
+         moe_impl_prefill=stats["moe_impl_prefill"],
          compiles={"decode": int(stats["compiles"]),
                    "prefill": int(stats["prefill_compiles"])},
          dispatches={"decode": int(stats["dispatches"]),
@@ -427,6 +465,18 @@ def phase_serve_latent(args, on_tpu):
     assert 0 < stats["moe_local_assignments"] < stats["moe_assignments"]
     want = "pallas" if on_tpu else "gather"
     assert stats["attn_impl_decode"] == want, stats["attn_impl_decode"]
+    # a chunk of more than 64 tokens sorts its token-expert pairs and
+    # runs the grouped-matmul kernel on the chip; the tiny chunk takes
+    # the dense mask form
+    want = "dense" if args.tiny else want
+    assert stats["moe_impl_prefill"] == want, stats["moe_impl_prefill"]
+    diff, bound = grouped_kernel_vs_ragged(module, chunk, args.seed,
+                                           interpret=not on_tpu)
+    emit(phase="serve", family="deepseek_v2",
+         grouped_kernel_vs_ragged_max_abs_diff=diff,
+         grouped_kernel_vs_ragged_bound=bound,
+         kernel_mode="mosaic" if on_tpu else "interpret")
+    assert diff <= bound, (diff, bound)
     diff, ref = latent_kernel_vs_plain(module, eng.geom.page,
                                        eng.geom.slots, args.seed,
                                        interpret=not on_tpu)

@@ -39,6 +39,8 @@ import optax
 from jax import lax
 
 from kubeml_tpu.ops.attention import NEG_INF
+from kubeml_tpu.ops.pallas.grouped_matmul import (grouped_mlp,
+                                                   resolve_mlp_impl)
 
 PyTree = Any
 
@@ -193,6 +195,13 @@ class ServeFamily:
         program): what the engine prints as `attn_impl_*`."""
         raise NotImplementedError
 
+    def moe_impl(self, prefill_chunk: int, attn_impl: str,
+                 attn_interpret: bool) -> str:
+        """Which form a prefill chunk's expert layers take: 'off' for a
+        family without experts or a deployment without a prefill
+        program; what the engine prints as `moe_impl_prefill`."""
+        return "off"
+
 
 # ---- what every family's paged programs share (called from inside
 # their jax.named_scope blocks "cow_split" and "sample")
@@ -303,12 +312,28 @@ def pages_per_block(key_block: int, page: int, n_pages: int) -> int:
 
 # up to this many tokens held_expert_layer's callers run every held
 # expert over every token (a decode batch); above it (a prefill chunk),
-# ragged_dot over token-expert pairs sorted by expert
+# the grouped product over token-expert pairs sorted by expert
 DENSE_MOE_TOKENS = 64
 
 
+def held_expert_impl(tokens: int, top_k: int, d: int, f: int, dtype,
+                     impl: str, interpret: bool) -> str:
+    """Which form held_expert_layer takes for `tokens` tokens that each
+    choose `top_k` experts of width f over hidden size d: 'off' (no
+    tokens), 'dense' (the mask form), else what the grouped product
+    resolves to with the SAME rule its dispatch applies at trace time
+    ('pallas' or 'gather'): a family's `moe_impl`."""
+    if tokens <= 0:
+        return "off"
+    if tokens <= DENSE_MOE_TOKENS:
+        return "dense"
+    return resolve_mlp_impl(impl, interpret, rows=tokens * top_k, d=d, f=f,
+                            itemsize=jnp.dtype(dtype).itemsize)
+
+
 def held_expert_layer(x, p, live, route, *, held: int, rank: int,
-                       scaling: float, dtype, dense: bool):
+                       scaling: float, dtype, dense: bool,
+                       impl: str = "auto", interpret: bool = False):
     """Shared experts + ONE SHARE's routed experts over normed tokens
     x [N, d] (float32): the dropless expert layer of every family whose
     deployment is expert-parallel (DeepSeek-V2, EXAONE-MoE). `p` holds
@@ -324,11 +349,15 @@ def held_expert_layer(x, p, live, route, *, held: int, rank: int,
     routing's mask, S * held tiny matmuls that cost a fraction of
     reading the experts' weights, which a step reads anyway; without
     it (a prefill chunk) the token-expert pairs are sorted by expert
-    and `jax.lax.ragged_dot` runs over the groups (the dense form would
-    be `held` times the FLOPs). Scopes `router`, `experts`,
-    `shared_expert`. Returns (output [N, d] float32, counts int32[3]:
-    token-expert pairs chosen, those that chose a held expert, held
-    experts with at least one token)."""
+    and the grouped product runs over the groups (the dense form would
+    be `held` times the FLOPs): ops/pallas/grouped_matmul.py
+    `grouped_mlp`, the kernel that streams each touched expert's
+    weights once where `impl` / `interpret` (the deployment's kernel
+    choice, as for attention) and the shapes allow it, `lax.ragged_dot`
+    elsewhere. Scopes `router`, `experts`, `shared_expert`. Returns
+    (output [N, d] float32, counts int32[3]: token-expert pairs chosen,
+    those that chose a held expert, held experts with at least one
+    token)."""
     n = x.shape[0]
     with jax.named_scope("router"):
         logits = jnp.dot(x, p["router"]["kernel"].astype(jnp.float32),
@@ -364,15 +393,12 @@ def held_expert_layer(x, p, live, route, *, held: int, rank: int,
             flat = local.reshape(n * k)
             order = jnp.argsort(flat, stable=True)
             rows = xb[order // k]
-            g = lax.ragged_dot(rows, e["gate"]["kernel"], tokens_of,
-                               preferred_element_type=jnp.float32)
-            u = lax.ragged_dot(rows, e["up"]["kernel"], tokens_of,
-                               preferred_element_type=jnp.float32)
-            a = (jax.nn.silu(g) * u).astype(dtype)
-            y = lax.ragged_dot(a, e["down"]["kernel"], tokens_of,
-                               preferred_element_type=jnp.float32)
+            y = grouped_mlp(rows, e["gate"]["kernel"], e["up"]["kernel"],
+                            e["down"]["kernel"], tokens_of, impl=impl,
+                            interpret=interpret)
             # rows past the last group belong to no expert: whatever
-            # the product left there is selected away, not multiplied
+            # the product left there (the kernel writes nothing) is
+            # selected away, not multiplied
             y = jnp.where((jnp.arange(n * k) < tokens_of.sum())[:, None],
                           y * weight.reshape(n * k)[order][:, None], 0.0)
             routed = y[jnp.argsort(order)].reshape(n, k, -1).sum(1)

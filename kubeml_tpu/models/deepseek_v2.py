@@ -66,7 +66,8 @@ from jax import lax
 from kubeml_tpu.models.base import (DENSE_MOE_TOKENS, CacheSpec,
                                     InferenceInputError, KubeModel,
                                     ServeFamily, cow_split_pages,
-                                    held_expert_layer, sample_tokens)
+                                    held_expert_impl, held_expert_layer,
+                                    sample_tokens)
 from kubeml_tpu.models.base import dot_f32 as _dot
 from kubeml_tpu.models.base import gated_mlp as _gated
 from kubeml_tpu.models.base import rms_norm as _rms
@@ -267,7 +268,8 @@ def route(m: DeepSeekV2Module, logits):
     return experts, scores
 
 
-def _moe(m: DeepSeekV2Module, x, p, live, dense: bool):
+def _moe(m: DeepSeekV2Module, x, p, live, dense: bool, impl: str = "auto",
+         interpret: bool = False):
     """Shared experts + this share's routed experts over normed tokens
     x [N, d] (float32): the expert layer every expert-parallel family
     shares (models/base.py held_expert_layer) under this family's own
@@ -276,16 +278,20 @@ def _moe(m: DeepSeekV2Module, x, p, live, dense: bool):
     return held_expert_layer(
         x, p, live, lambda logits: route(m, logits),
         held=m.n_held_experts, rank=m.ep_rank,
-        scaling=m.routed_scaling_factor, dtype=m.dtype, dense=dense)
+        scaling=m.routed_scaling_factor, dtype=m.dtype, dense=dense,
+        impl=impl, interpret=interpret)
 
 
-def _ffn(m, i, h, p, live):
-    """x' = h + FFN(RMSNorm(h)) of layer i (float32), and its counts."""
+def _ffn(m, i, h, p, live, impl: str = "auto", interpret: bool = False):
+    """x' = h + FFN(RMSNorm(h)) of layer i (float32), and its counts;
+    `impl` / `interpret` are the deployment's kernel choice, for the
+    grouped product of more than DENSE_MOE_TOKENS tokens."""
     x = _rms(h, p["ffn_norm"]["scale"], m.rms_eps)
     if i < m.first_dense:
         with jax.named_scope("mlp"):
             return h + _gated(x, p["mlp"]), jnp.zeros(3, jnp.int32)
-    y, counts = _moe(m, x, p, live, dense=h.shape[0] <= DENSE_MOE_TOKENS)
+    y, counts = _moe(m, x, p, live, h.shape[0] <= DENSE_MOE_TOKENS,
+                     impl=impl, interpret=interpret)
     return h + y, counts
 
 
@@ -372,7 +378,7 @@ def build_decode_logits(m: DeepSeekV2Module, attn_impl: str = "auto",
                 o = o.reshape(o.shape[0], -1)
                 h = h + _dot(o, p["o"]["kernel"])
             with jax.named_scope(f"layer_{i}"):
-                h, c = _ffn(m, i, h, p, active)
+                h, c = _ffn(m, i, h, p, active, attn_impl, attn_interpret)
                 counts = counts + c
         with jax.named_scope("head"):
             x = _rms(h, params["final_norm"]["scale"], m.rms_eps)
@@ -415,7 +421,8 @@ def build_decode_step(m: DeepSeekV2Module, attn_impl: str = "auto",
     return step
 
 
-def build_prefill_step(m: DeepSeekV2Module, chunk: int):
+def build_prefill_step(m: DeepSeekV2Module, chunk: int,
+                       attn_impl: str = "auto", attn_interpret: bool = False):
     """Chunked prefill of ONE slot over the paged latent cache:
 
       prefill(params, c_pages, tokens[C], pos[C], page_table[Pmax],
@@ -426,8 +433,10 @@ def build_prefill_step(m: DeepSeekV2Module, chunk: int):
     route to no expert. Attention is the up-projected form over the
     slot's pages PREFILL_KEY_BLOCK keys at a time (gathered through the
     table, up-projected, a running float32 softmax), as many blocks as
-    the chunk's last position needs. No logits: the last prompt token goes
-    through the decode step."""
+    the chunk's last position needs; `attn_impl` / `attn_interpret`
+    reach the expert layers' grouped product (models/base.py
+    held_expert_layer), the one kernel under this program. No logits:
+    the last prompt token goes through the decode step."""
     if chunk < 1:
         raise ValueError(f"prefill chunk must be >= 1, got {chunk}")
     scale = softmax_scale(m)
@@ -501,7 +510,8 @@ def build_prefill_step(m: DeepSeekV2Module, chunk: int):
                 o = o.transpose(1, 0, 2).reshape(C, -1)
                 h = h + _dot(o, p["o"]["kernel"])
             with jax.named_scope(f"layer_{i}"):
-                h, _ = _ffn(m, i, h, p, in_chunk)
+                h, _ = _ffn(m, i, h, p, in_chunk, attn_impl,
+                            attn_interpret)
         return (c_pages,)
 
     return prefill
@@ -540,7 +550,8 @@ class DeepSeekV2ServeFamily(ServeFamily):
 
     def prefill_step(self, chunk, kv_dtype, attn_impl, attn_interpret):
         self._check(kv_dtype)
-        return build_prefill_step(self.module, chunk)
+        return build_prefill_step(self.module, chunk, attn_impl,
+                                  attn_interpret)
 
     def _geometry(self, page, max_pages):
         m = self.module
@@ -554,6 +565,12 @@ class DeepSeekV2ServeFamily(ServeFamily):
         return (mla.resolve_impl(attn_impl, attn_interpret,
                                  **self._geometry(page, max_pages)),
                 "gather" if prefill_chunk > 0 else "off")
+
+    def moe_impl(self, prefill_chunk, attn_impl, attn_interpret):
+        m = self.module
+        return held_expert_impl(
+            prefill_chunk, m.experts_per_tok, m.hidden,
+            m.moe_intermediate_size, m.dtype, attn_impl, attn_interpret)
 
 
 class DeepSeekV2(KubeModel):

@@ -82,7 +82,8 @@ from kubeml_tpu.models.base import (DENSE_MOE_TOKENS, CacheSpec,
                                     InferenceInputError, KubeModel,
                                     ServeFamily, SlotState,
                                     attend_pages_in_blocks, cow_split_pages,
-                                    dot_f32, gated_mlp, held_expert_layer,
+                                    dot_f32, gated_mlp, held_expert_impl,
+                                    held_expert_layer,
                                     pages_per_block, rms_norm, sample_tokens)
 from kubeml_tpu.ops.attention import NEG_INF
 from kubeml_tpu.ops.pallas import paged_attention as pa
@@ -261,9 +262,12 @@ def route(m: ExaoneMoEModule, logits, bias):
     return experts, chosen / jnp.sum(chosen, axis=-1, keepdims=True)
 
 
-def _ffn(m: ExaoneMoEModule, i: int, h, p, live):
+def _ffn(m: ExaoneMoEModule, i: int, h, p, live, impl: str = "auto",
+         interpret: bool = False):
     """h + FFN(RMSNorm(h)) of layer i (float32), and the expert layer's
-    three counts (zeros for a dense layer)."""
+    three counts (zeros for a dense layer); `impl` / `interpret` are
+    the deployment's kernel choice, for the grouped product of more
+    than DENSE_MOE_TOKENS tokens."""
     x = rms_norm(h, p["ffn_norm"]["scale"], m.rms_eps)
     if i < m.first_dense:
         with jax.named_scope("mlp"):
@@ -272,7 +276,8 @@ def _ffn(m: ExaoneMoEModule, i: int, h, p, live):
         x, p, live, lambda logits: route(m, logits, p["router"]["bias"]),
         held=m.n_held_experts, rank=m.ep_rank,
         scaling=m.routed_scaling_factor, dtype=m.dtype,
-        dense=h.shape[0] <= DENSE_MOE_TOKENS)
+        dense=h.shape[0] <= DENSE_MOE_TOKENS, impl=impl,
+        interpret=interpret)
     return h + y, counts
 
 
@@ -381,7 +386,7 @@ def build_decode_logits(m: ExaoneMoEModule, attn_impl: str = "auto",
             with jax.named_scope(f"layer_{i}/proj"):
                 h = h + dot_f32(o, p["o"]["kernel"])
             with jax.named_scope(f"layer_{i}"):
-                h, c = _ffn(m, i, h, p, active)
+                h, c = _ffn(m, i, h, p, active, attn_impl, attn_interpret)
                 counts = counts + c
         with jax.named_scope("head"):
             x = rms_norm(h, params["final_norm"]["scale"], m.rms_eps)
@@ -423,7 +428,8 @@ def build_decode_step(m: ExaoneMoEModule, attn_impl: str = "auto",
     return step
 
 
-def build_prefill_step(m: ExaoneMoEModule, chunk: int):
+def build_prefill_step(m: ExaoneMoEModule, chunk: int,
+                       attn_impl: str = "auto", attn_interpret: bool = False):
     """Chunked prefill of ONE slot:
 
       prefill(params, k_pages, v_pages, win_k, win_v, tokens[C], pos[C],
@@ -522,7 +528,8 @@ def build_prefill_step(m: ExaoneMoEModule, chunk: int):
             with jax.named_scope(f"layer_{i}/proj"):
                 h = h + dot_f32(o, p["o"]["kernel"])
             with jax.named_scope(f"layer_{i}"):
-                h, _ = _ffn(m, i, h, p, in_chunk)
+                h, _ = _ffn(m, i, h, p, in_chunk, attn_impl,
+                            attn_interpret)
         return k_pages, v_pages, win_k, win_v
 
     return prefill
@@ -564,7 +571,8 @@ class ExaoneMoEServeFamily(ServeFamily):
 
     def prefill_step(self, chunk, kv_dtype, attn_impl, attn_interpret):
         self._check(kv_dtype, attn_impl)
-        return build_prefill_step(self.module, chunk)
+        return build_prefill_step(self.module, chunk, attn_impl,
+                                  attn_interpret)
 
     def attn_impls(self, page, max_pages, prefill_chunk, kv_dtype,
                    attn_impl, attn_interpret):
@@ -576,6 +584,12 @@ class ExaoneMoEServeFamily(ServeFamily):
             head_dim=m.head_dim, max_pages=max_pages, dtype=m.dtype,
             kv_heads=m.kv_heads),
             "gather" if prefill_chunk > 0 else "off")
+
+    def moe_impl(self, prefill_chunk, attn_impl, attn_interpret):
+        m = self.module
+        return held_expert_impl(
+            prefill_chunk, m.experts_per_tok, m.hidden,
+            m.moe_intermediate_size, m.dtype, attn_impl, attn_interpret)
 
 
 class ExaoneMoE(KubeModel):

@@ -1,5 +1,5 @@
 """Shared TPU auto-gate for the pallas kernels (fused_merge,
-flash_attention, paged_attention).
+flash_attention, paged_attention, selective_scan, grouped_matmul).
 
 Every kernel in this package follows the same dispatch contract:
 
@@ -30,6 +30,14 @@ from jax.sharding import AxisType, get_abstract_mesh
 # TPU-native tiling constants shared by the kernels' layouts.
 LANES = 128     # vector lane width (f32 native lane tiling)
 SUBLANES = 8    # f32 sublane minimum
+
+
+def largest_divisor(n: int, cap: int, multiple: int = 1) -> int:
+    """The largest divisor of n up to `cap` that is a multiple of
+    `multiple` (a block size that tiles n in whole lane or sublane
+    tiles); n itself where there is none."""
+    return max((k for k in range(multiple, min(n, cap) + 1, multiple)
+                if n % k == 0), default=n)
 
 
 def use_pallas(interpret: Optional[bool]) -> bool:

@@ -72,18 +72,13 @@ STEP_SLOTS = 8
 F32 = jnp.float32
 
 
-def _largest_divisor(n: int, cap: int, multiple: int = 1) -> int:
-    return max((k for k in range(multiple, min(n, cap) + 1, multiple)
-                if n % k == 0), default=n)
-
-
 def geometry(batch: int, steps: int, d_inner: int) -> tuple:
     """(slots a grid step, lanes a grid step, time block) for a call's
     shapes."""
     if steps == 1:
-        return (_largest_divisor(batch, STEP_SLOTS),
-                _largest_divisor(d_inner, STEP_BLOCK, LANES), 1)
-    return 1, _largest_divisor(d_inner, SEQ_BLOCK, LANES), TIME_BLOCK
+        return (gate.largest_divisor(batch, STEP_SLOTS),
+                gate.largest_divisor(d_inner, STEP_BLOCK, LANES), 1)
+    return 1, gate.largest_divisor(d_inner, SEQ_BLOCK, LANES), TIME_BLOCK
 
 
 def scan_eligible(*, batch: int, steps: int, d_inner: int,

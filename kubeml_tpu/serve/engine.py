@@ -620,6 +620,10 @@ class DecodeEngine:
          self.stats["attn_impl_prefill"]) = family.attn_impls(
             self.geom.page, self.geom.pages_per_slot, prefill_chunk,
             kv_dtype, attn_impl, self.attn_interpret)
+        # and which form a prefill chunk's expert layers take ('off'
+        # for a family without experts), by the same rule
+        self.stats["moe_impl_prefill"] = family.moe_impl(
+            prefill_chunk, attn_impl, self.attn_interpret)
 
     # ------------------------------------------------------------- capacity
     @property

@@ -444,6 +444,12 @@ def test_deepseek_v2_program_compiles_for_v5e_at_the_cells_sizes(
     assert mem.temp_size_in_bytes < 400e6, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.9e9
     if which == "prefill":
+        # the expert layers' grouped products are the repo's kernel
+        # (gate and up in one call, then down: PR 34), and no
+        # `ragged-dot` call of the compiler's is left beside it
+        assert family.moe_impl(C, "pallas", False) == "pallas"
+        assert "grouped_matmul_gated" in hlo
+        assert "ragged-dot" not in hlo and "ragged_dot" not in hlo
         # the attention loop's float32 scores [heads, chunk, keys] stay
         # in VMEM (memory space 1) at PREFILL_KEY_BLOCK keys a step; at
         # 512 keys they went through HBM three times a step and the loop
@@ -456,6 +462,55 @@ def test_deepseek_v2_program_compiles_for_v5e_at_the_cells_sizes(
                   for x in shape.findall(line.split(" fusion(")[0])]
         assert len(scores) == m.layers - 1 and \
             all("S(1)" in x for x in scores), scores
+
+
+# ------------------------- the prefill expert layer's grouped products
+
+# (token-expert rows of a chunk of 512, hidden, expert width, held
+# experts) of the two MoE cells
+GROUPED_GEOMETRIES = {
+    "deepseek-v2-ep4": (3072, 5120, 1536, 40),
+    "k-exaone-ep8": (4096, 6144, 2048, 16),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GROUPED_GEOMETRIES))
+def test_grouped_matmul_compiles_for_v5e_within_the_vmem_it_declares(
+        one_chip, name):
+    """The expert MLP of a prefill chunk (ops/pallas/grouped_matmul.py:
+    gate and up in one kernel, down in a second, one visit plan) at the
+    cell's shapes: the chip's compiler accepts both calls, each is
+    given the scoped-VMEM limit `grouped_vmem_bytes` computes (what
+    `grouped_eligible` gates on) and uses no more than that. Read
+    (sandbox compile, PR 34): 36.1-57.1 MB used of 40.2-71.3 MB declared."""
+    import re
+
+    import jax.numpy as jnp
+
+    from kubeml_tpu.ops.pallas import grouped_matmul as gm
+    rows, d, f, held = GROUPED_GEOMETRIES[name]
+    bf16 = jnp.bfloat16
+    assert gm.resolve_mlp_impl("pallas", False, rows=rows, d=d, f=f) \
+        == "pallas"
+    hlo = _compile(
+        functools.partial(gm.grouped_mlp, impl="pallas"), one_chip,
+        ((rows, d), bf16), ((held, d, f), bf16), ((held, d, f), bf16),
+        ((held, f, d), bf16), ((held,), jnp.int32))
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and "ragged-dot" not in hlo
+    bounds = {"grouped_matmul_gated": gm.grouped_vmem_bytes(
+                  rows, d, f, stacks=2),
+              "grouped_matmul": gm.grouped_vmem_bytes(rows, f, d)}
+    for call in calls:
+        limit, used = (
+            int(re.search(key + r'":\[\{[^}]*"size":"(\d+)"', call).group(1))
+            for key in ("scoped_memory_configs",
+                        "used_scoped_memory_configs"))
+        kernel = re.search(r"%(grouped_matmul\w*?)(?:\.\d+)? =", call)
+        assert kernel, call[:200]
+        assert 0 < used <= limit == bounds[kernel.group(1)] \
+            <= gm.VMEM_BUDGET
 
 
 # ------------------------------- Jamba (per-slot state, grouped queries)
@@ -694,6 +749,11 @@ def test_exaone_moe_serve_programs_compile_at_published_widths_for_v5e(
         params, *state, *rest).compile()
     hlo = compiled.as_text()
     assert ("%paged_attention" in hlo) == (which == "decode")
+    # a chunk's expert layers run the grouped-matmul kernel (PR 34); a
+    # decode batch of 64 tokens takes the dense mask form
+    assert family.moe_impl(C, "pallas", False) == "pallas"
+    assert ("grouped_matmul_gated" in hlo) == (which == "prefill")
+    assert "ragged-dot" not in hlo and "ragged_dot" not in hlo
     copies = [(n, dims, op) for n, _, dims, _, op in _result_shapes(hlo)
               if dims in [plane] + rings
               and op in ("copy", "copy-start", "gather")]

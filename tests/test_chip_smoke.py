@@ -107,9 +107,15 @@ def test_rehearsal_serves_the_latent_page_family(rehearsal):
     assert run["compiles"] == {"decode": 1, "prefill": 1}
     assert run["dispatches"]["prefill"] >= 2
     assert run["attn_impl_decode"] == "gather"     # 'auto' on the CPU
+    assert run["moe_impl_prefill"] == "dense"      # the tiny chunk of 32
     assert run["row_lanes"] % 128 == 0 > -run["latent_lanes"]
     assert 0 < run["moe_local_assignments"] < run["moe_assignments"]
     assert 0 < run["moe_experts_touched"]
+    grouped = next(ln for ln in fam
+                   if "grouped_kernel_vs_ragged_max_abs_diff" in ln)
+    assert grouped["kernel_mode"] == "interpret"
+    assert grouped["grouped_kernel_vs_ragged_max_abs_diff"] \
+        <= grouped["grouped_kernel_vs_ragged_bound"]
     diff = next(ln for ln in fam
                 if "latent_kernel_vs_plain_max_abs_diff" in ln)
     assert diff["kernel_mode"] == "interpret"

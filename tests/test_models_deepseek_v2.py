@@ -207,6 +207,10 @@ def _drive(m, variables, prompts, n_new, attn_impl, interpret, chunk=32,
                 jnp.asarray(np.where(live > 0, tables[s][pos // page], 0)),
                 jnp.asarray(np.where(live > 0, pos % page, 0)),
                 jnp.asarray(live))
+            # a chunk's kernel through the interpreter runs JAX
+            # operations of its own in callbacks: nothing that waits
+            # for the chunk is dispatched behind it
+            jax.block_until_ready(slab)
     seqs = [list(p) for p in prompts]
     out_logits = [[] for _ in prompts]
     counts = np.zeros(3, np.int64)
@@ -230,18 +234,22 @@ def _drive(m, variables, prompts, n_new, attn_impl, interpret, chunk=32,
 
 
 @pytest.mark.parametrize("attn_impl,interpret,chunk", [
-    ("gather", False, 32), ("pallas", True, 32), ("gather", False, 80)])
+    ("gather", False, 32), ("pallas", True, 32), ("gather", False, 80),
+    ("pallas", True, 80)])
 def test_paged_prefill_then_decode_against_the_full_forward(
         ref, attn_impl, interpret, chunk):
     """Float32 throughout, so what differs is the order of the sums:
     absorbed against up-projected attention, a running softmax in
     blocks against one softmax, experts under a mask (or, in a prefill
-    chunk of more than 64 tokens, sorted into groups for ragged_dot)
+    chunk of more than 64 tokens, sorted into groups for ragged_dot
+    or, forced through the interpreter, for the grouped-matmul kernel)
     against the reference's loop. Logits are O(1) and those
     differences stay under 2e-4 absolute (measured: 3e-5); a misplaced
     page, a wrong position or a dropped expert moves them by 1e-1."""
     m = ds.DeepSeekV2Module(dtype=jnp.float32, ep_rank=1)
     assert (chunk > ds.DENSE_MOE_TOKENS) == (chunk == 80)
+    assert m.serve_family().moe_impl(chunk, attn_impl, interpret) == (
+        "dense" if chunk == 32 else attn_impl)
     variables = seeded(m)
     w, cfg = flat_weights(variables), cfg_of(m)
     rng = np.random.default_rng(11)

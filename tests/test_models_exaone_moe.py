@@ -513,12 +513,16 @@ def test_sigmoid_router(ref, case):
         assert np.abs(np.asarray(once)).max() > 0.1
 
 
-@pytest.mark.parametrize("tokens", [48, 80])
-def test_the_shares_sum_to_the_uncut_layer(ref, tokens):
+@pytest.mark.parametrize("tokens,impl,interpret", [
+    (48, "auto", False), (80, "auto", False), (80, "pallas", True)],
+    ids=["48", "80", "80-kernel"])
+def test_the_shares_sum_to_the_uncut_layer(ref, tokens, impl, interpret):
     """At a small size: the routed parts of all four shares plus the
     shared expert counted once are the uncut reference's layer output,
     and the program's layer on each share (the dense-mask form at 48
-    tokens, ragged_dot at 80) is the reference's on that share."""
+    tokens, ragged_dot at 80, and at 80 the grouped-matmul kernel
+    forced through the interpreter) is the reference's on that
+    share."""
     uncut = ex.ExaoneMoEModule(dtype=jnp.float32, n_held_experts=16)
     cfg_all = cfg_of(uncut)
     assert cfg_all["ep"]["size"] == 1
@@ -554,7 +558,14 @@ def test_the_shares_sum_to_the_uncut_layer(ref, tokens):
                         for n in ("gate", "up", "down")},
              "experts": {n: {"kernel": lw_r[f"experts/{n}/kernel"]}
                          for n in ("gate", "up", "down")}}
-        got, counts = ex._ffn(share, 1, h, p, jnp.ones(tokens))
+        # one program, read before anything else is dispatched: the
+        # interpreter's callbacks run JAX operations of their own
+        got, counts = jax.block_until_ready(jax.jit(
+            lambda h, p, live: ex._ffn(share, 1, h, p, live, impl,
+                                       interpret))(h, p, jnp.ones(tokens)))
+        assert share.serve_family().moe_impl(tokens, impl, interpret) == (
+            "dense" if tokens == 48 else
+            "pallas" if interpret else "gather")
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(h + shared + routed),
                                    atol=2e-5, rtol=0)
@@ -826,5 +837,6 @@ def test_engine_takes_the_kernel_in_interpret_mode(ref):
                          attn_interpret=True)
     assert eng.stats["attn_impl_decode"] == "pallas"
     assert eng.stats["attn_impl_prefill"] == "gather"
+    assert eng.stats["moe_impl_prefill"] == "dense"     # a chunk of 32
     for r, got in zip(reqs, served):
         _close(got, _reference_logits(ref, m, variables, r), F32_RTOL)

@@ -318,9 +318,12 @@ def test_other_families_hand_back_the_very_tree(name):
 
 # ------------------------------------------------- the shared expert layer
 
-def _parents_moe(m, x, p, live, dense: bool):
+def _parents_moe(m, x, p, live, dense: bool, impl="gather",
+                 interpret=False):
     """models/deepseek_v2.py `_moe` as it stood before its body moved to
-    models/base.py `held_expert_layer` (PR 27's text, verbatim)."""
+    models/base.py `held_expert_layer` (PR 27's text, verbatim; the
+    kernel choice it is handed since PR 34 it never had: its grouped
+    form IS the 'gather' path, three `lax.ragged_dot` calls)."""
     from jax import lax
 
     from kubeml_tpu.models import deepseek_v2 as ds
