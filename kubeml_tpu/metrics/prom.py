@@ -561,6 +561,17 @@ class MetricsRegistry:
             "kubeml_serve_rejected_tokens_total",
             "Draft proposals rejected by the verifier and rolled back "
             "as data, by served model", "model")
+        # dispatches enqueued when the host could see that the device
+        # had nothing left of the engine's to run: against
+        # kubeml_serve_decode_tokens_total it tells a host-bound
+        # replica, for which a faster chip or kernel buys nothing, from
+        # a device-bound one
+        self.serve_starved_dispatches_total = Counter(
+            "kubeml_serve_starved_dispatches_total",
+            "Device programs enqueued after the device had run out of "
+            "queued work and stood waiting for the host (a lower bound: "
+            "the host learns of a program's end some tenths of a "
+            "millisecond late), by served model", "model")
         # continual plane (PR 10): the weight generation new admissions
         # attach to (advances on every zero-downtime hot-swap), and the
         # continual job's data freshness — dataset generation trained
@@ -808,6 +819,7 @@ class MetricsRegistry:
                                 self.serve_draft_tokens_total,
                                 self.serve_accepted_tokens_total,
                                 self.serve_rejected_tokens_total,
+                                self.serve_starved_dispatches_total,
                                 self.serve_fleet_spills_total,
                                 self.serve_fleet_router_retries_total,
                                 self.serve_fleet_cold_starts_total,
@@ -1017,6 +1029,9 @@ class MetricsRegistry:
     def note_serve_rejected_tokens(self, model: str, n: int) -> None:
         self.serve_rejected_tokens_total.inc(model, n)
 
+    def note_serve_starved_dispatches(self, model: str, n: int) -> None:
+        self.serve_starved_dispatches_total.inc(model, n)
+
     def observe_serve_ttft_breakdown(self, model: str, queue: float,
                                      prefill: float,
                                      interleave: float) -> None:
@@ -1126,6 +1141,7 @@ class MetricsRegistry:
                   self.serve_draft_tokens_total,
                   self.serve_accepted_tokens_total,
                   self.serve_rejected_tokens_total,
+                  self.serve_starved_dispatches_total,
                   self.serve_fleet_spills_total,
                   self.serve_fleet_router_retries_total,
                   self.serve_fleet_cold_starts_total,

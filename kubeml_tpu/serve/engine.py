@@ -54,11 +54,12 @@ accelerated programs only ever see the steady state they were compiled
 for, so the inventory above is exhaustive and recompilation-free.
 
 All four are dispatched through ONE sequence in two halves,
-DecodeEngine._enqueue (cost record, clock pair, call, slab state,
-compile note, counters, and for the decode lane the shared counters,
-the hand-over of held-back token events and the enqueue phase record)
-and DecodeEngine._read (the readback and the readback / emit phase
-records); _dispatched runs one behind the other. The three decode-lane
+DecodeEngine._enqueue (cost record, whether the device had run dry,
+clock pair, call, slab state, compile note, counters, the enqueue phase
+record, and for the decode lane the shared counters and the hand-over
+of held-back token events) and DecodeEngine._read (the readback, with
+whether the result was waiting, and the readback / emit phase records);
+_dispatched runs one behind the other. The three decode-lane
 programs' picks are emitted by ONE walk, DecodeEngine._walk_emitted
 (the single-step program is its one-row case). A dispatcher keeps what
 is its own: granting pages, packing its lanes, reading its outputs.
@@ -75,7 +76,7 @@ ONE DECODE DISPATCH AHEAD. Of what the single-step decode program
 takes, only `tokens` depends on the dispatch before it; positions,
 pages, copy-on-write pairs, sampling keys and who leaves by its token
 budget are the host's own bookkeeping. So where the host knows all of
-that (_runs_ahead: no masked lane, no fault plan, one weight
+that (_why_serial: no masked lane, no fault plan, one weight
 generation, no accelerated program, pages to spare), a step packs and
 enqueues the NEXT decode dispatch before it reads the last one back:
 
@@ -207,12 +208,14 @@ SERVE_SPAN_KINDS = (
 # LOOP THREAD is doing, in the process-wide phase ring and, whenever a
 # profiler session is on, in its .xplane.pb. serve.loop.* tile one
 # iteration of ServeService._loop, serve.step.* tile serve.loop.step
-# (one DecodeEngine.step), serve.trace.flush is a child of
-# serve.loop.publish. Every record's args hold the engine `step`, which
-# joins an iteration's phases to each other and to the flight record of
-# that step. The benchmark's readers and dashboards key on the literal
-# names, so tools/check_serve_spans.py holds each one to a quoted
-# assertion in tests/, like the kinds above.
+# (one DecodeEngine.step), serve.chunk.* tile serve.step.prefill (their
+# own prefix: a reader that sums serve.step.* or takes a step with a
+# serve.step.enqueue record for a decode step reads what it read),
+# serve.trace.flush is a child of serve.loop.publish. Every record's
+# args hold the engine `step`, which joins an iteration's phases to each
+# other and to the flight record of that step. The benchmark's readers
+# and dashboards key on the literal names, so tools/check_serve_spans.py
+# holds each one to a quoted assertion in tests/, like the kinds above.
 SERVE_PHASE_KINDS = (
     "serve.loop.wait",      # parked on the condition, nothing to do
     "serve.loop.admit",     # lock, weight swap, deadline sweep, attach
@@ -222,18 +225,28 @@ SERVE_PHASE_KINDS = (
     "serve.loop.publish",   # health snapshot, Prometheus, trace flush
     "serve.trace.flush",    # the sink rewrite; args: events, bytes
     "serve.step.reap",      # cancellations, deadlines, fault hooks
-    "serve.step.prefill",   # one chunk dispatch: page grant, pack,
-                            # enqueue; args: tokens
+    "serve.step.prefill",   # one chunk dispatch, tiled by the four
+                            # serve.chunk.* below; args: tokens
     "serve.step.pages",     # decode-lane page grants, copy-on-write
-    "serve.step.pack",      # numpy arrays and jnp.asarray transfers
-    "serve.step.enqueue",   # the jitted call until it returns; args:
-                            # compiled, ahead
-    "serve.step.readback",  # np.asarray of the picks: the host blocked
-                            # on the device, the next dispatch queued
-                            # behind the one awaited where the step
-                            # runs ahead
+    "serve.step.pack",      # numpy arrays and their transfers; args:
+                            # transfers, h2d_bytes
+    "serve.step.enqueue",   # the jitted call until it returns, then
+                            # the token events handed over; args:
+                            # compiled, ahead, starved, call_s, and
+                            # serial where the step could not run ahead
+    "serve.step.readback",  # np.asarray of the picks; args: ready, 1
+                            # where the result was waiting and the
+                            # phase is the fetch's own cost, 0 where it
+                            # is the host blocked on the device
     "serve.step.emit",      # per-slot advance, prefix registration,
                             # emit_token, release; args: overrun
+    "serve.chunk.pages",    # the chunk's page grants
+    "serve.chunk.pack",     # its per-token loop and transfers; args:
+                            # transfers, h2d_bytes
+    "serve.chunk.enqueue",  # its jitted call; args: compiled, starved,
+                            # call_s
+    "serve.chunk.emit",     # cursor, prefix registration, the release
+                            # of the dispatch's buffers
 )
 
 
@@ -283,10 +296,20 @@ class _Enqueued:
         self.cow: Dict[int, tuple] = {}
 
 
-def _no_phase(name, **args):
-    """Stands in for `phase` where a dispatch leaves no record of its
-    own: a prefill chunk lies inside its one serve.step.prefill."""
-    return contextlib.nullcontext(args)
+# The names a dispatch's two halves leave their phase records under,
+# (enqueue, readback, emit), None for no record: a decode-lane dispatch
+# inside a step; a prefill chunk, whose halves are children of its
+# serve.step.prefill under a prefix of their own and which reads nothing
+# back; a drain() outside a step, which leaves none.
+_STEP_PHASES = ("serve.step.enqueue", "serve.step.readback",
+                "serve.step.emit")
+_CHUNK_PHASES = ("serve.chunk.enqueue", None, "serve.chunk.emit")
+_NO_PHASES = (None, None, None)
+
+
+def _phase(name: Optional[str], **args):
+    """`phase`, or no record where `name` is None."""
+    return phase(name, **args) if name else contextlib.nullcontext(args)
 
 
 def _serve_family(module) -> ServeFamily:
@@ -448,6 +471,11 @@ class DecodeEngine:
         # step returns
         self._unread: Optional[_Enqueued] = None
         self._carry: List[GenerateRequest] = []
+        # why the step under way runs the serial sequence, "" where it
+        # runs ahead (_why_serial; the enqueue record shows it)
+        self._serial = ""
+        # [transfers, bytes] of the dispatch being packed (_h2d)
+        self._h2d_pending = [0, 0]
         # the most pages one step can take: a page a decode lane, and
         # what each prefill chunk of the step's budget can span
         self._step_pages = self.geom.slots
@@ -594,6 +622,12 @@ class DecodeEngine:
             # ran one ahead), and lane-steps whose row was dropped at
             # the walk because the lane's request had gone by then
             "ahead_dispatches": 0, "overrun_lane_steps": 0,
+            # dispatches of any program enqueued when the host could
+            # see that everything this engine had queued before had
+            # run: the device had run dry and was waiting for the host
+            # (its share of all dispatches tells a host-bound replica
+            # from a device-bound one; a lower bound, _enqueue)
+            "starved_dispatches": 0,
             # the current generation's tree as held on the device, and
             # how many of its leaves the family's serve_params holds in
             # another dtype than they were handed over in
@@ -1011,44 +1045,52 @@ class DecodeEngine:
             return n
 
     def _prefill_chunk(self, s: int, slot: _Slot) -> int:
+        """The chunk, tiled by its four serve.chunk.* phases: pages and
+        pack here, enqueue and emit the two halves of the dispatch
+        sequence (_CHUNK_PHASES)."""
         G = self.geom.page
         C = self.prefill_chunk
+        step = self._step_count
         start = slot.pos
         end = min(start + C, slot.n_prompt - 1)
         granted = 0
-        for pi in range(start // G, (end - 1) // G + 1):
-            if self._tables[s, pi] == 0:
-                pid = self.pager.alloc()
-                if pid is None:
-                    # shrink the chunk to the pages we hold; a partial
-                    # chunk still makes progress, zero progress stalls
-                    end = min(end, pi * G)
-                    break
-                self._tables[s, pi] = pid
-                self._live_entries[s] += 1
-                granted += 1
+        with phase("serve.chunk.pages", step=step):
+            for pi in range(start // G, (end - 1) // G + 1):
+                if self._tables[s, pi] == 0:
+                    pid = self.pager.alloc()
+                    if pid is None:
+                        # shrink the chunk to the pages we hold; a
+                        # partial chunk still makes progress, zero
+                        # progress stalls
+                        end = min(end, pi * G)
+                        break
+                    self._tables[s, pi] = pid
+                    self._live_entries[s] += 1
+                    granted += 1
         n = end - start
         if n <= 0:
             return 0
-        tokens = np.zeros(C, np.int32)
-        pos = np.zeros(C, np.int32)
-        write_pages = np.zeros(C, np.int32)
-        write_offs = np.zeros(C, np.int32)
-        in_chunk = np.zeros(C, np.float32)
-        for j in range(n):
-            p = start + j
-            tokens[j] = slot.prompt[p]
-            pos[j] = p
-            write_pages[j] = self._tables[s, p // G]
-            write_offs[j] = p % G
-            in_chunk[j] = 1.0
-        args = [self._params_by_gen[slot.gen], *self.slab.state,
-                jnp.asarray(tokens), jnp.asarray(pos),
-                jnp.asarray(self._tables[s]), jnp.asarray(write_pages),
-                jnp.asarray(write_offs), jnp.asarray(in_chunk)]
-        if self._slot_state:
-            # whose per-slot state the chunk advances
-            args.append(jnp.asarray(s, jnp.int32))
+        with phase("serve.chunk.pack", step=step) as span:
+            tokens = np.zeros(C, np.int32)
+            pos = np.zeros(C, np.int32)
+            write_pages = np.zeros(C, np.int32)
+            write_offs = np.zeros(C, np.int32)
+            in_chunk = np.zeros(C, np.float32)
+            for j in range(n):
+                p = start + j
+                tokens[j] = slot.prompt[p]
+                pos[j] = p
+                write_pages[j] = self._tables[s, p // G]
+                write_offs[j] = p % G
+                in_chunk[j] = 1.0
+            args = [self._params_by_gen[slot.gen], *self.slab.state,
+                    self._h2d(tokens), self._h2d(pos),
+                    self._h2d(self._tables[s]), self._h2d(write_pages),
+                    self._h2d(write_offs), self._h2d(in_chunk)]
+            if self._slot_state:
+                # whose per-slot state the chunk advances
+                args.append(self._h2d(np.int32(s)))
+            self._packed(span)
         with self._dispatched("prefill", args) as d:
             self.stats["prefill_tokens"] += n
             slot.prefill_s += d.t1 - d.t0
@@ -1084,15 +1126,29 @@ class DecodeEngine:
         decode, so no slot is ever 'in prefill'."""
         return self._prefill is not None and slot.pos < slot.n_prompt - 1
 
+    def _h2d(self, host) -> jax.Array:
+        """Every host array of the dispatch being packed crosses to the
+        device here, counted for the pack's record (_packed)."""
+        self._h2d_pending[0] += 1
+        self._h2d_pending[1] += host.nbytes
+        return jnp.asarray(host)
+
+    def _packed(self, span: dict) -> None:
+        """What _h2d sent since the last pack, onto the open pack
+        record (`transfers`, `h2d_bytes`)."""
+        span["transfers"], span["h2d_bytes"] = self._h2d_pending
+        self._h2d_pending = [0, 0]
+
     def _lane(self, host: np.ndarray) -> jax.Array:
         """A per-lane [S] argument of the decode program, on the device.
         One that is all zeros (no copy-on-write pair, no poison, greedy
         temperatures, no host token where every lane takes the last
-        dispatch's pick) is the zeros already there: with a dispatch
-        always queued the host's step is what the device waits for, and
-        a transfer is a quarter of a millisecond of it."""
+        dispatch's pick) is the zeros already there and counts as no
+        transfer: with a dispatch always queued the host's step is what
+        the device waits for, and a transfer is a quarter of a
+        millisecond of it."""
         if host.any():
-            return jnp.asarray(host)
+            return self._h2d(host)
         return self._zero_lanes[host.dtype]
 
     def _count_page_walk(self, members: List[int]) -> None:
@@ -1335,27 +1391,41 @@ class DecodeEngine:
                  steps: int = 1) -> _Enqueued:
         """THE dispatch sequence, first half, for all four programs
         (`kind` keys _PROGRAMS): the cost record's capture at the
-        program's first dispatch, the clock pair around the jitted call,
-        the slab's new state, the compile noted (tracker, the kind's own
-        counters) and the wall time. Returns the dispatch, unread.
+        program's first dispatch, whether the device had run dry, the
+        clock pair around the jitted call, the slab's new state, the
+        compile noted (tracker, the kind's own counters) and the wall
+        time, and the enqueue record (serve.step.enqueue, or
+        serve.chunk.enqueue for a prefill chunk:
+        benchmark/metrics/serve_loop_phases.py takes an iteration with
+        a serve.step.enqueue record for a decode iteration) with
+        `compiled`, `starved` and `call_s`, the call alone of what the
+        phase holds. Returns the dispatch, unread.
+
+        `starved`: the slab's state is the output of the newest program
+        of ANY kind, so where the host sees it ready the device has
+        nothing left of this engine's to run, and the program enqueued
+        here starts only when the host has got it there. One
+        non-blocking question, no transfer, and a lower bound: the host
+        learns of a program's end late (0.6-0.7 ms after call plus
+        length on a TPU v5e, launch included), so for that long after
+        the device ran dry the answer is still 0, never 1 too early.
 
         With `members` (the occupied lanes: a decode-lane dispatch of
         any of the three kinds) it also moves the lane's shared
         counters, hands over the last step's token events once the
         program is enqueued (the handler threads then run while the
-        device does) and leaves the serve.step.enqueue record, `ahead`
-        on it where the dispatch before this one is still unread. A
-        prefill dispatch does none of that:
-        benchmark/metrics/serve_loop_phases.py takes an iteration with
-        an enqueue record for a decode iteration.
+        device does) and puts on the record `ahead`, where the dispatch
+        before this one is still unread, or `serial`, why the step could
+        not run ahead (neither on the step that opens the regime).
 
         `args` is a LIST that the read empties (_read)."""
         program, attr, n_key, c_key = _PROGRAMS[kind]
         jitfn = getattr(self, attr)
-        lane = _no_phase if members is None else phase
-        with lane("serve.step.enqueue", step=self._step_count) as span:
+        names = _CHUNK_PHASES if members is None else _STEP_PHASES
+        with phase(names[0], step=self._step_count) as span:
             self._ledger_capture(program, jitfn, args, steps)
             before = jitfn._cache_size()
+            starved = int(self.slab.state[0].is_ready())
             t0 = self.clock()
             out = jitfn(*args)
             n_out = len(out) - len(self.slab.state)
@@ -1367,10 +1437,15 @@ class DecodeEngine:
             if n_key:
                 self.stats[n_key] += 1
             self.stats[c_key] += int(compiled)
+            self.stats["starved_dispatches"] += starved
+            span["compiled"] = int(compiled)
+            span["starved"] = starved
+            span["call_s"] = t1 - t0
             if members is not None:
                 ahead = int(self._unread is not None)
-                span["compiled"] = int(compiled)
                 span["ahead"] = ahead
+                if self._serial:
+                    span["serial"] = self._serial
                 self.stats["ahead_dispatches"] += ahead
                 self.stats["dispatches"] += 1
                 self.stats["occupancy_sum"] += len(members)
@@ -1382,20 +1457,23 @@ class DecodeEngine:
                              list(out[:n_out]))
 
     @contextlib.contextmanager
-    def _read(self, rec: _Enqueued, lane=phase):
+    def _read(self, rec: _Enqueued, names=_STEP_PHASES):
         """THE dispatch sequence, second half: the outputs read back
-        (serve.step.readback: the host blocked on the device, with the
-        next dispatch queued behind the one awaited where the engine
-        runs ahead), then, inside serve.step.emit, the body of the
-        `with`, which reads the dispatch (a _Dispatched), and the
-        ledger's dispatch note with the tokens that body emitted.
-        `lane` is `phase` for a decode-lane dispatch read inside a step
-        and _no_phase where no record is left: a prefill chunk, a
-        drain() outside a step."""
+        (serve.step.readback, `ready` on it: 1 where the result was
+        waiting and the phase is the fetch's own cost, host work; 0
+        where it is the host blocked on the device, with the next
+        dispatch queued behind the one awaited where the engine runs
+        ahead), then, inside serve.step.emit, the body of the `with`,
+        which reads the dispatch (a _Dispatched), and the ledger's
+        dispatch note with the tokens that body emitted. `names` are
+        the records' (_STEP_PHASES, _CHUNK_PHASES for a prefill chunk,
+        whose body is its serve.chunk.emit, _NO_PHASES)."""
         step = self._step_count
-        with lane("serve.step.readback", step=step):
+        with _phase(names[1], step=step) as span:
+            if names[1]:
+                span["ready"] = int(rec.out[0].is_ready())
             host = [np.asarray(o) for o in rec.out]
-        with lane("serve.step.emit", step=step) as span:
+        with _phase(names[2], step=step) as span:
             g0 = self.stats["generated_tokens"]
             d0 = self.stats["decode_tokens"]
             yield _Dispatched(rec.t0, rec.t1, rec.compiled, span, host)
@@ -1424,11 +1502,12 @@ class DecodeEngine:
         multi-step and verify programs. The single-step decode program
         takes the two halves apart where it runs ahead (_step_inner)."""
         rec = self._enqueue(kind, args, members, steps)
-        with self._read(rec, _no_phase if members is None else phase) as d:
+        with self._read(rec, _CHUNK_PHASES if members is None
+                        else _STEP_PHASES) as d:
             yield d
 
     def _settle(self, rec: Optional[_Enqueued], finished,
-                lane=phase) -> None:
+                names=_STEP_PHASES) -> None:
         """Read one single-step decode dispatch back and emit it: the
         family's counts, then every lane's pick through the walk. A row
         goes only to the request its lane held at pack time: a slot
@@ -1438,13 +1517,13 @@ class DecodeEngine:
         starts running ahead: nothing to read yet, and the two records
         every decode iteration has are left all the same."""
         if rec is None:
-            with lane("serve.step.readback", step=self._step_count):
-                pass
-            with lane("serve.step.emit", step=self._step_count) as span:
+            with _phase(names[1], step=self._step_count) as span:
+                span["ready"] = 1       # nothing to wait for
+            with _phase(names[2], step=self._step_count) as span:
                 span["overrun"] = 0
             return
         S = self.geom.slots
-        with self._read(rec, lane) as d:
+        with self._read(rec, names) as d:
             nxt, bad = d.out
             # the family's own counts ride behind the S picks, in the
             # transfer that brought them; the dispatch's go on the emit
@@ -1468,12 +1547,12 @@ class DecodeEngine:
             self.stats["overrun_lane_steps"] += overrun
             d.span["overrun"] = overrun
 
-    def _take_unread(self, finished, lane=phase) -> None:
+    def _take_unread(self, finished, names=_STEP_PHASES) -> None:
         """Settle the unread dispatch. It is forgotten before it is
         read: a readback that raises is not tried again, and its lanes
         then stand where their last emitted token left them."""
         rec, self._unread = self._unread, None
-        self._settle(rec, finished, lane)
+        self._settle(rec, finished, names)
 
     def drain(self) -> List[GenerateRequest]:
         """Read and emit the dispatch that is still unread, if there is
@@ -1484,7 +1563,7 @@ class DecodeEngine:
         there is nothing to read: abandon() dropped it."""
         finished: List[GenerateRequest] = []
         if self._unread is not None and not self._abandoned:
-            self._take_unread(finished, _no_phase)
+            self._take_unread(finished, _NO_PHASES)
         return finished
 
     def _walk_emitted(self, s: int, toks, bads, k_max: int,
@@ -1572,7 +1651,7 @@ class DecodeEngine:
                         self._ungrant(gs, gl)
                     return False
                 grants[s] = g
-        with phase("serve.step.pack", step=step):
+        with phase("serve.step.pack", step=step) as span:
             tokens = np.zeros(S, np.int32)
             pos = np.zeros(S, np.int32)
             live = np.zeros(S, np.int32)
@@ -1593,10 +1672,11 @@ class DecodeEngine:
                 budgets[s] = slot.req.max_new_tokens - len(slot.req.tokens)
             args = [self._params_by_gen[self.weight_generation],
                     *self.slab.state,
-                    jnp.asarray(tokens), jnp.asarray(pos),
-                    jnp.asarray(self._tables), jnp.asarray(live),
-                    jnp.asarray(temps), jnp.asarray(seeds),
-                    jnp.asarray(eos_ids), jnp.asarray(budgets)]
+                    self._h2d(tokens), self._h2d(pos),
+                    self._h2d(self._tables), self._h2d(live),
+                    self._h2d(temps), self._h2d(seeds),
+                    self._h2d(eos_ids), self._h2d(budgets)]
+            self._packed(span)
         with self._dispatched("multi", args, members, steps=K) as d:
             toks, bads = d.out
             for s in members:
@@ -1638,7 +1718,7 @@ class DecodeEngine:
                         self._ungrant(gs, gl)
                     return False
                 grants[s] = g
-        with phase("serve.step.pack", step=step):
+        with phase("serve.step.pack", step=step) as span:
             window = np.zeros((S, W), np.int32)
             pos = np.zeros(S, np.int32)
             live = np.zeros(S, np.int32)
@@ -1658,10 +1738,11 @@ class DecodeEngine:
                 wlen_arr[s] = wlens[s]
             args = [self._params_by_gen[self.weight_generation],
                     self._draft_params, *self.slab.state,
-                    jnp.asarray(window), jnp.asarray(pos),
-                    jnp.asarray(self._tables), jnp.asarray(live),
-                    jnp.asarray(temps), jnp.asarray(seeds),
-                    jnp.asarray(wlen_arr)]
+                    self._h2d(window), self._h2d(pos),
+                    self._h2d(self._tables), self._h2d(live),
+                    self._h2d(temps), self._h2d(seeds),
+                    self._h2d(wlen_arr)]
+            self._packed(span)
         with self._dispatched("verify", args, members, steps=K + 1) as d:
             picks, bads, acc = d.out
             for s in members:
@@ -1695,7 +1776,8 @@ class DecodeEngine:
         step = self._step_count
         # what drains outside a step finished since the last one
         finished, self._carry = self._carry, []
-        ahead = self._runs_ahead(exclude)
+        self._serial = self._why_serial(exclude)
+        ahead = not self._serial
         # the dispatch still unread: where this step cannot run ahead it
         # is read FIRST, and the step is the serial sequence it always
         # was. One name for the whole step: abandon() may take the
@@ -1880,7 +1962,7 @@ class DecodeEngine:
 
         for gen in sorted(set(gen_of.values())):
             members = [s for s in ready if gen_of[s] == gen]
-            with phase("serve.step.pack", step=step):
+            with phase("serve.step.pack", step=step) as span:
                 tokens = np.zeros(S, np.int32)
                 pos = np.zeros(S, np.int32)
                 write_page = np.zeros(S, np.int32)
@@ -1925,11 +2007,12 @@ class DecodeEngine:
                     self._no_prev if unread is None else unread.out[0],
                     self._lane(from_prev),
                     self._lane(tokens), self._lane(pos),
-                    jnp.asarray(self._tables), self._lane(write_page),
+                    self._h2d(self._tables), self._lane(write_page),
                     self._lane(write_off), self._lane(active),
-                    self._lane(temps), jnp.asarray(key_data),
+                    self._lane(temps), self._h2d(key_data),
                     self._lane(copy_src), self._lane(copy_dst),
                     self._lane(poison)]
+                self._packed(span)
             rec = self._enqueue("decode", args, members)
             rec.lanes = {s: self._slots[s] for s in members}
             rec.cow = cow
@@ -1947,20 +2030,26 @@ class DecodeEngine:
                 raise
         return finished
 
-    def _runs_ahead(self, exclude: frozenset) -> bool:
-        """Whether this step may enqueue its decode dispatch before the
-        last one is read, and leave it unread in turn: decided by what
-        the engine can see, not by a setting. The host has to know
-        everything of the dispatch but the continuing lanes' tokens: no
-        masked lane (the service's bisection), no fault plan (its hooks
-        name the step a token was computed in), one resident weight
-        generation (one dispatch a step), none of the accelerated
-        programs (they are chosen by what the last dispatch left), and
-        pages enough that no grant of this step can fail that the unread
-        dispatch's releases would have covered: a lane takes one page at
-        most, the prefill lane what its budget's chunks span."""
-        return (not exclude and self.fault_plan is None
-                and self._multi is None and self._verify is None
-                and len(self._params_by_gen) == 1
-                and self.pager.free_pages + self.pager.evictable_pages
-                >= self._step_pages)
+    def _why_serial(self, exclude: frozenset) -> str:
+        """Why this step may NOT enqueue its decode dispatch before the
+        last one is read and leave it unread in turn, "" where it may:
+        decided by what the engine can see, not by a setting. The host
+        has to know everything of the dispatch but the continuing
+        lanes' tokens: no masked lane (the service's bisection), no
+        fault plan (its hooks name the step a token was computed in),
+        none of the accelerated programs (they are chosen by what the
+        last dispatch left), one resident weight generation (one
+        dispatch a step), and pages enough that no grant of this step
+        can fail that the unread dispatch's releases would have
+        covered: a lane takes one page at most, the prefill lane what
+        its budget's chunks span. The first of the five that says no is
+        the answer (`serial` on the step's enqueue record)."""
+        return (
+            "exclude" if exclude
+            else "fault_plan" if self.fault_plan is not None
+            else "accelerated" if self._multi is not None
+            or self._verify is not None
+            else "generations" if len(self._params_by_gen) != 1
+            else "pages" if self.pager.free_pages
+            + self.pager.evictable_pages < self._step_pages
+            else "")

@@ -1048,7 +1048,9 @@ class ServeService:
                     ("accepted_tokens",
                      self.metrics.note_serve_accepted_tokens),
                     ("rejected_tokens",
-                     self.metrics.note_serve_rejected_tokens)):
+                     self.metrics.note_serve_rejected_tokens),
+                    ("starved_dispatches",
+                     self.metrics.note_serve_starved_dispatches)):
                 cur = int(self.engine.stats[stat])
                 delta = cur - self._counters_seen.get(stat, 0)
                 if delta > 0:

@@ -31,7 +31,10 @@ Here tracing is structural, Dapper-style:
     ``.xplane.pb`` on the device trace's own clock, and it appends one
     record to a bounded process-wide ring (`PHASES`) that `phases()`
     reads back. There is no switch: with no session the annotation is a
-    flag test and the ring append is all that is left.
+    flag test (it is not even built) and the ring append is all that is
+    left. Where the args hold the engine's `step` the annotation carries
+    it too (an event stat in the .xplane.pb, the event's name unchanged),
+    so a host span of the profiler's file is joined to its ring record.
 
 All `Tracer` timing goes through an injectable ``clock`` which tests
 replace with a fake to assert exact span trees deterministically. The
@@ -272,16 +275,25 @@ Phase = collections.namedtuple("Phase", "name t0 t1 tid args")
 _annotation = None
 
 
+class _NoAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation`` where jax is not
+    importable: no session is ever on."""
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+
 def _resolve_annotation():
     """``jax.profiler.TraceAnnotation``, looked up once, at the first
     phase and not at import (importing this module must not import
-    jax); a no-op where jax is not importable, since the controller
+    jax); never built where jax is not importable, since the controller
     and the scheduler import this module too."""
     global _annotation
     try:
         from jax.profiler import TraceAnnotation
     except ImportError:
-        TraceAnnotation = contextlib.nullcontext
+        TraceAnnotation = _NoAnnotation
     _annotation = TraceAnnotation
     return TraceAnnotation
 
@@ -295,14 +307,23 @@ class _PhaseSpan:
         self._args = args
 
     def __enter__(self) -> dict:
-        self._ann = (_annotation or _resolve_annotation())(self._name)
-        self._ann.__enter__()
+        # the annotation lies INSIDE the clock pair: what it costs to
+        # build while a session is on is the phase's own time, not a
+        # hole between two phases that a reader of the tiling sees
         self._t0 = self._ring._clock()
+        annotation = _annotation or _resolve_annotation()
+        self._ann = None
+        if annotation.is_enabled():     # a profiler session is on
+            step = self._args.get("step")
+            self._ann = annotation(self._name) if step is None \
+                else annotation(self._name, step=step)
+            self._ann.__enter__()
         return self._args
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         t1 = self._ring._clock()
-        self._ann.__exit__(*exc)
         self._ring._records.append(
             (self._name, self._t0, t1, threading.get_ident(), self._args))
         return False
@@ -327,10 +348,12 @@ class PhaseRing:
 
     def phase(self, name: str, **args) -> _PhaseSpan:
         """Context manager around one phase of a loop thread. Enter and
-        exit bracket a ``jax.profiler.TraceAnnotation(name)`` and
-        append ``(name, t0, t1, thread id, args)`` to the ring, nothing
-        else. Yields the args dict, which is read at exit, so the body
-        can attach what it only learns while running."""
+        exit bracket a ``jax.profiler.TraceAnnotation(name)`` (with the
+        args' `step`, where they have one; built only while a profiler
+        session is on) and append ``(name, t0, t1, thread id, args)``
+        to the ring, nothing else. Yields the args dict, which is read
+        at exit, so the body can attach what it only learns while
+        running."""
         return _PhaseSpan(self, name, args)
 
     def phases(self, t0: Optional[float] = None,

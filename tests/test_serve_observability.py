@@ -826,6 +826,18 @@ def test_get_trace_shows_loop_phases_beside_request_trees(serve_ps):
                for e in spans if e["name"] == "serve.step.prefill")
     assert any(s.endswith(".phases.trace.json")
                for s in doc["metadata"]["sources"])
+    # the ring as it is: the chunk's children and every dispatch's own
+    # account of itself, with no code of the endpoint's for either
+    enqueues = [e for e in spans if e["name"] in (
+        "serve.step.enqueue", "serve.chunk.enqueue")]
+    assert {e["name"] for e in enqueues} == {
+        "serve.step.enqueue", "serve.chunk.enqueue"}
+    assert all(e["args"]["starved"] in (0, 1) and e["args"]["call_s"] >= 0
+               for e in enqueues)
+    assert all(e["args"]["transfers"] > 0 for e in spans
+               if e["name"] in ("serve.step.pack", "serve.chunk.pack"))
+    assert all(e["args"]["ready"] in (0, 1) for e in spans
+               if e["name"] == "serve.step.readback")
 
 
 def test_paged_programs_carry_named_scopes():
@@ -874,8 +886,8 @@ def test_serve_span_lint_passes_on_this_repo():
 
 
 def test_serve_phase_registry_is_linted():
-    """The thirteen loop-phase names are a registry the lint reads (the
-    benchmark's readers key on them), dotted names included."""
+    """The seventeen loop-phase names are a registry the lint reads
+    (the benchmark's readers key on them), dotted names included."""
     import os
 
     import tools.check_serve_spans as lint
@@ -884,9 +896,9 @@ def test_serve_phase_registry_is_linted():
     engine_py = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "kubeml_tpu", "serve", "engine.py")
     assert lint.phase_kinds(engine_py) == list(SERVE_PHASE_KINDS)
-    assert len(SERVE_PHASE_KINDS) == 13
+    assert len(SERVE_PHASE_KINDS) == 17
     assert {k.rsplit(".", 1)[0] for k in SERVE_PHASE_KINDS} == {
-        "serve.loop", "serve.step", "serve.trace"}
+        "serve.loop", "serve.step", "serve.chunk", "serve.trace"}
 
 
 def test_serve_span_lint_holds_phase_kinds(tmp_path):
