@@ -64,6 +64,16 @@ programs' picks are emitted by ONE walk, DecodeEngine._walk_emitted
 (the single-step program is its one-row case). A dispatcher keeps what
 is its own: granting pages, packing its lanes, reading its outputs.
 
+ONE TRANSFER A DISPATCH. Every host argument of a dispatch (its
+per-lane vectors, the page tables or the slot's row, the sampling key
+pairs, a chunk's token columns, a slot index) is written into ONE fresh
+int32 buffer (_Packing: floats and uint32 keys as their bit patterns,
+a layout fixed per kind at construction), which crosses to the device
+in one transfer (_h2d); the jitted entry (_packed_entry) slices and
+bitcasts it back into exactly the arrays the family's program takes
+and calls that program untouched. What is already on the device stays
+an argument of its own: the parameters, the slab's state and `prev`.
+
 A token-budget scheduler in step() interleaves the two: each engine
 step spends at most `prefill_budget` prompt tokens on prefill chunks
 (FIFO over admission order; a dispatch is charged as a whole chunk,
@@ -124,6 +134,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import logging
+import math
 import threading
 import time
 from typing import Dict, List, Optional
@@ -342,23 +353,106 @@ def _put_params(family: ServeFamily, params):
     return held, cast, sum(int(a.nbytes) for a in leaves(held))
 
 
-def _decode_entry(step_fn, n_state: int, slots: int):
-    """The engine's jitted single-step decode entry, the same for every
-    family: `step_fn` (family.decode_step's function, untouched) behind
-    one select. `prev` is the token row of the dispatch before this one
-    (picks, then the family's counters), still on the device, and a lane
-    whose `from_prev` is set takes its input token from there instead of
-    from the host's `tokens`: the next step's tokens never cross the
-    host. Named `step` like the function it wraps, so the compiled
-    module keeps the name a trace knows it by."""
+class _Packing:
+    """How one kind of dispatch lays its host arguments into ONE int32
+    buffer: `fields` are (name, shape, dtype) in the order the family's
+    program takes them after the slab state, each 32 bits wide. With
+    `rows` (the decode-lane kinds, whose every argument leads with the S
+    lanes) the buffer is [rows, columns] and a field is a range of
+    columns; without, it is one flat vector and a field a range of it."""
 
-    def step(params, *rest):
-        state = rest[:n_state]
-        prev, from_prev, tokens, *lanes = rest[n_state:]
-        tokens = jnp.where(from_prev > 0, prev[:slots], tokens)
-        return step_fn(params, *state, tokens, *lanes)
+    def __init__(self, fields, rows: int = 0):
+        self.fields = tuple((name, tuple(shape), np.dtype(dt))
+                            for name, shape, dt in fields)
+        self.spans = []
+        off = 0
+        for _, shape, _ in self.fields:
+            n = math.prod(shape) // (rows or 1)
+            self.spans.append((off, n))
+            off += n
+        self.shape = (rows, off) if rows else (off,)
 
-    return step
+    def host(self):
+        """A fresh zeroed buffer and a writable view of each field into
+        it, in the field's shape and dtype. Fresh for every dispatch:
+        the one before may still be reading its own (and on the CPU
+        backend the device array may be the numpy memory itself)."""
+        buf = np.zeros(self.shape, np.int32)
+        return buf, [buf[..., o:o + n].reshape(shape).view(dt)
+                     for (o, n), (_, shape, dt)
+                     in zip(self.spans, self.fields)]
+
+    def unpack(self, packed):
+        """The device side of host(): each field sliced out of `packed`
+        and bitcast back to its dtype, bit for bit."""
+        out = []
+        for (o, n), (_, shape, dt) in zip(self.spans, self.fields):
+            x = packed[..., o:o + n].reshape(shape)
+            out.append(x if dt == np.int32
+                       else jax.lax.bitcast_convert_type(x, dt))
+        return out
+
+
+def _packing(kind: str, family: ServeFamily, geom: PageGeometry,
+             width: int = 0) -> _Packing:
+    """One program kind's layout, from the declaration, the geometry
+    and the kind: the decode-lane kinds a row a lane, a prefill chunk
+    one vector (its slot index last, for a family with per-slot state).
+    `width` is a chunk's tokens (prefill) or the window (verify)."""
+    S, P = geom.slots, geom.pages_per_slot
+    i32, f32, u32 = np.int32, np.float32, np.uint32
+
+    def lanes(*names, dt=i32):
+        return [(n, (S,), dt) for n in names]
+
+    table = [("page_tables", (S, P), i32)]
+    if kind == "prefill":
+        C = width
+        return _Packing(
+            [("tokens", (C,), i32), ("pos", (C,), i32),
+             ("page_table", (P,), i32), ("write_pages", (C,), i32),
+             ("write_offs", (C,), i32), ("in_chunk", (C,), f32)]
+            + ([("slot", (), i32)] if family.cache.slot_state else []))
+    fields = {
+        # from_prev first: the entry takes it out before the call
+        "decode": lanes("from_prev", "tokens", "pos") + table
+        + lanes("write_page", "write_off") + lanes("active", "temps", dt=f32)
+        + [("key_data", (S, 2), u32)] + lanes("copy_src", "copy_dst")
+        + lanes("poison", dt=f32),
+        "multi": lanes("tokens", "pos") + table + lanes("live")
+        + lanes("temps", dt=f32) + lanes("seeds", dt=u32)
+        + lanes("eos_ids", "budgets"),
+        "verify": [("window", (S, width), i32)] + lanes("pos") + table
+        + lanes("live") + lanes("temps", dt=f32) + lanes("seeds", dt=u32)
+        + lanes("wlen"),
+    }[kind]
+    return _Packing(fields, rows=S)
+
+
+def _packed_entry(fn, packing: _Packing, prev_lanes: int = 0):
+    """The engine's jitted entry, the same for every kind and family:
+    `fn` (the family's program, untouched) called with the leading
+    device arguments (parameters, slab state) and the arrays `packing`
+    unpacks from the one buffer, the last argument. With `prev_lanes`
+    (the single-step decode program) the argument before the buffer is
+    `prev`, the token row of the dispatch before this one (picks, then
+    the family's counters) still on the device, and a lane whose
+    `from_prev` is set takes its input token from there instead of from
+    the host's `tokens`: the next step's tokens never cross the host.
+    Named like the function it wraps, so the compiled module keeps the
+    name a trace knows it by (`jit_step`, `jit_prefill`)."""
+
+    def entry(*args):
+        lead, host = list(args[:-1]), packing.unpack(args[-1])
+        if prev_lanes:
+            prev = lead.pop()
+            from_prev, tokens, *host = host
+            host = [jnp.where(from_prev > 0, prev[:prev_lanes], tokens),
+                    *host]
+        return fn(*lead, *host)
+
+    entry.__name__ = entry.__qualname__ = fn.__name__
+    return entry
 
 
 class _Slot:
@@ -454,17 +548,13 @@ class DecodeEngine:
         n_state = len(self.slab.state)
         donate = () if jax.default_backend() == "cpu" \
             else tuple(range(1, 1 + n_state))
-        self._step = jax.jit(
-            _decode_entry(self._step_raw, n_state, self.geom.slots),
-            donate_argnums=donate)
+        # each built program's layout of its one host buffer, by kind
+        self._packings: Dict[str, _Packing] = {}
+        self._step = self._jit("decode", self._step_raw, donate)
         # what a decode dispatch with no dispatch before it is handed
         # as `prev`: the same shape and dtype, so the entry compiles once
         self._no_prev = jnp.zeros(
             self.geom.slots + len(family.step_counters), jnp.int32)
-        # an all-zero per-lane argument is not sent again (_lane)
-        self._zero_lanes = {
-            np.dtype(dt): jnp.zeros(self.geom.slots, dt)
-            for dt in (np.int32, np.float32)}
         # the single-step decode dispatch that is enqueued and not yet
         # read (module docstring, ONE DECODE DISPATCH AHEAD), and the
         # requests a drain() outside a step finished, which the next
@@ -484,10 +574,10 @@ class DecodeEngine:
                 * (prefill_chunk // self.geom.page + 2)
         self._prefill = None
         if prefill_chunk > 0:
-            self._prefill = jax.jit(
-                family.prefill_step(prefill_chunk, kv_dtype, attn_impl,
-                                    self.attn_interpret),
-                donate_argnums=donate)
+            self._prefill = self._jit(
+                "prefill", family.prefill_step(
+                    prefill_chunk, kv_dtype, attn_impl, self.attn_interpret),
+                donate, prefill_chunk)
         # decode accelerators: the multi-step scan program and the
         # speculative verify program — OPTIONAL members of the exact
         # program inventory documented at the top of this module. The
@@ -500,10 +590,10 @@ class DecodeEngine:
         self.decode_steps = decode_steps
         self._multi = None
         if decode_steps > 1:
-            self._multi = jax.jit(
-                family.multi_step(decode_steps, kv_dtype, attn_impl,
-                                  self.attn_interpret),
-                donate_argnums=donate)
+            self._multi = self._jit(
+                "multi", family.multi_step(decode_steps, kv_dtype,
+                                           attn_impl, self.attn_interpret),
+                donate)
         # speculation depth K: decode_steps when raised past 1, else 4
         # proposals per dispatch; the verify window is the largest
         # context both trunks (and the page slab) can hold — slots
@@ -523,11 +613,11 @@ class DecodeEngine:
                                    self.geom.context)
             verify_donate = () if jax.default_backend() == "cpu" \
                 else tuple(range(2, 2 + n_state))
-            self._verify = jax.jit(
-                family.spec_verify(
+            self._verify = self._jit(
+                "verify", family.spec_verify(
                     draft_family, self.spec_steps, self.spec_window,
                     kv_dtype, attn_impl, self.attn_interpret),
-                donate_argnums=verify_donate)
+                verify_donate, self.spec_window)
             self._draft_params = _put_params(
                 draft_family, draft_variables["params"])[0]
         # weight generations: params are per-slot DATA, not program
@@ -658,6 +748,17 @@ class DecodeEngine:
         # for a family without experts), by the same rule
         self.stats["moe_impl_prefill"] = family.moe_impl(
             prefill_chunk, attn_impl, self.attn_interpret)
+
+    def _jit(self, kind: str, fn, donate, width: int = 0):
+        """`fn`, the family's program of this kind, jitted behind the
+        one packed entry; its layout is kept for the kind's pack site
+        (`_packings`)."""
+        packing = self._packings[kind] = _packing(
+            kind, self.family, self.geom, width)
+        return jax.jit(
+            _packed_entry(fn, packing,
+                          self.geom.slots if kind == "decode" else 0),
+            donate_argnums=donate)
 
     # ------------------------------------------------------------- capacity
     @property
@@ -1071,11 +1172,8 @@ class DecodeEngine:
         if n <= 0:
             return 0
         with phase("serve.chunk.pack", step=step) as span:
-            tokens = np.zeros(C, np.int32)
-            pos = np.zeros(C, np.int32)
-            write_pages = np.zeros(C, np.int32)
-            write_offs = np.zeros(C, np.int32)
-            in_chunk = np.zeros(C, np.float32)
+            buf, (tokens, pos, table, write_pages, write_offs, in_chunk,
+                  *slot_index) = self._packings["prefill"].host()
             for j in range(n):
                 p = start + j
                 tokens[j] = slot.prompt[p]
@@ -1083,13 +1181,12 @@ class DecodeEngine:
                 write_pages[j] = self._tables[s, p // G]
                 write_offs[j] = p % G
                 in_chunk[j] = 1.0
-            args = [self._params_by_gen[slot.gen], *self.slab.state,
-                    self._h2d(tokens), self._h2d(pos),
-                    self._h2d(self._tables[s]), self._h2d(write_pages),
-                    self._h2d(write_offs), self._h2d(in_chunk)]
+            table[:] = self._tables[s]
             if self._slot_state:
                 # whose per-slot state the chunk advances
-                args.append(self._h2d(np.int32(s)))
+                slot_index[0][()] = s
+            args = [self._params_by_gen[slot.gen], *self.slab.state,
+                    self._h2d(buf)]
             self._packed(span)
         with self._dispatched("prefill", args) as d:
             self.stats["prefill_tokens"] += n
@@ -1127,29 +1224,18 @@ class DecodeEngine:
         return self._prefill is not None and slot.pos < slot.n_prompt - 1
 
     def _h2d(self, host) -> jax.Array:
-        """Every host array of the dispatch being packed crosses to the
-        device here, counted for the pack's record (_packed)."""
+        """The one host buffer of the dispatch being packed
+        (_Packing.host) crosses to the device here, counted for the
+        pack's record (_packed)."""
         self._h2d_pending[0] += 1
         self._h2d_pending[1] += host.nbytes
         return jnp.asarray(host)
 
     def _packed(self, span: dict) -> None:
         """What _h2d sent since the last pack, onto the open pack
-        record (`transfers`, `h2d_bytes`)."""
+        record (`transfers`, `h2d_bytes`): one buffer a dispatch."""
         span["transfers"], span["h2d_bytes"] = self._h2d_pending
         self._h2d_pending = [0, 0]
-
-    def _lane(self, host: np.ndarray) -> jax.Array:
-        """A per-lane [S] argument of the decode program, on the device.
-        One that is all zeros (no copy-on-write pair, no poison, greedy
-        temperatures, no host token where every lane takes the last
-        dispatch's pick) is the zeros already there and counts as no
-        transfer: with a dispatch always queued the host's step is what
-        the device waits for, and a transfer is a quarter of a
-        millisecond of it."""
-        if host.any():
-            return self._h2d(host)
-        return self._zero_lanes[host.dtype]
 
     def _count_page_walk(self, members: List[int]) -> None:
         """Beside `occupancy_sum`, per decode-lane dispatch: the table
@@ -1638,7 +1724,6 @@ class DecodeEngine:
         when any slot cannot pre-grant its K-step page window — the
         caller falls through to the single-step path for this round."""
         K = self.decode_steps
-        S = self.geom.slots
         step = self._step_count
         with phase("serve.step.pages", step=step):
             grants: Dict[int, List[int]] = {}
@@ -1652,13 +1737,10 @@ class DecodeEngine:
                     return False
                 grants[s] = g
         with phase("serve.step.pack", step=step) as span:
-            tokens = np.zeros(S, np.int32)
-            pos = np.zeros(S, np.int32)
-            live = np.zeros(S, np.int32)
-            temps = np.zeros(S, np.float32)
-            seeds = np.zeros(S, np.uint32)
-            eos_ids = np.full(S, -1, np.int32)
-            budgets = np.zeros(S, np.int32)
+            buf, (tokens, pos, tables, live, temps, seeds, eos_ids,
+                  budgets) = self._packings["multi"].host()
+            tables[:] = self._tables
+            eos_ids[:] = -1
             for s in members:
                 slot = self._slots[s]
                 live[s] = 1
@@ -1671,11 +1753,7 @@ class DecodeEngine:
                     eos_ids[s] = slot.req.eos_id
                 budgets[s] = slot.req.max_new_tokens - len(slot.req.tokens)
             args = [self._params_by_gen[self.weight_generation],
-                    *self.slab.state,
-                    self._h2d(tokens), self._h2d(pos),
-                    self._h2d(self._tables), self._h2d(live),
-                    self._h2d(temps), self._h2d(seeds),
-                    self._h2d(eos_ids), self._h2d(budgets)]
+                    *self.slab.state, self._h2d(buf)]
             self._packed(span)
         with self._dispatched("multi", args, members, steps=K) as d:
             toks, bads = d.out
@@ -1698,7 +1776,6 @@ class DecodeEngine:
         K = self.spec_steps
         W = self.spec_window
         G = self.geom.page
-        S = self.geom.slots
         step = self._step_count
         with phase("serve.step.pages", step=step):
             wlens: Dict[int, int] = {}
@@ -1719,12 +1796,9 @@ class DecodeEngine:
                     return False
                 grants[s] = g
         with phase("serve.step.pack", step=step) as span:
-            window = np.zeros((S, W), np.int32)
-            pos = np.zeros(S, np.int32)
-            live = np.zeros(S, np.int32)
-            temps = np.zeros(S, np.float32)
-            seeds = np.zeros(S, np.uint32)
-            wlen_arr = np.zeros(S, np.int32)
+            buf, (window, pos, tables, live, temps, seeds,
+                  wlen_arr) = self._packings["verify"].host()
+            tables[:] = self._tables
             for s in members:
                 slot = self._slots[s]
                 # full context = prompt + emitted tokens; in the steady
@@ -1737,11 +1811,7 @@ class DecodeEngine:
                 seeds[s] = np.uint32(slot.req.seed & 0xFFFFFFFF)
                 wlen_arr[s] = wlens[s]
             args = [self._params_by_gen[self.weight_generation],
-                    self._draft_params, *self.slab.state,
-                    self._h2d(window), self._h2d(pos),
-                    self._h2d(self._tables), self._h2d(live),
-                    self._h2d(temps), self._h2d(seeds),
-                    self._h2d(wlen_arr)]
+                    self._draft_params, *self.slab.state, self._h2d(buf)]
             self._packed(span)
         with self._dispatched("verify", args, members, steps=K + 1) as d:
             picks, bads, acc = d.out
@@ -1770,7 +1840,6 @@ class DecodeEngine:
 
     def _step_inner(self, exclude: frozenset = frozenset()
                     ) -> List[GenerateRequest]:
-        S = self.geom.slots
         G = self.geom.page
         stalled: List[int] = []
         step = self._step_count
@@ -1963,17 +2032,10 @@ class DecodeEngine:
         for gen in sorted(set(gen_of.values())):
             members = [s for s in ready if gen_of[s] == gen]
             with phase("serve.step.pack", step=step) as span:
-                tokens = np.zeros(S, np.int32)
-                pos = np.zeros(S, np.int32)
-                write_page = np.zeros(S, np.int32)
-                write_off = np.zeros(S, np.int32)
-                active = np.zeros(S, np.float32)
-                temps = np.zeros(S, np.float32)
-                key_data = np.zeros((S, 2), np.uint32)
-                copy_src = np.zeros(S, np.int32)
-                copy_dst = np.zeros(S, np.int32)
-                poison = np.zeros(S, np.float32)
-                from_prev = np.zeros(S, np.int32)
+                buf, (from_prev, tokens, pos, tables, write_page,
+                      write_off, active, temps, key_data, copy_src,
+                      copy_dst, poison) = self._packings["decode"].host()
+                tables[:] = self._tables
                 if self.fault_plan is not None:
                     for s in self.fault_plan.nan_hits(self._step_count,
                                                       members):
@@ -2005,13 +2067,7 @@ class DecodeEngine:
                 args = [
                     self._params_by_gen[gen], *self.slab.state,
                     self._no_prev if unread is None else unread.out[0],
-                    self._lane(from_prev),
-                    self._lane(tokens), self._lane(pos),
-                    self._h2d(self._tables), self._lane(write_page),
-                    self._lane(write_off), self._lane(active),
-                    self._lane(temps), self._h2d(key_data),
-                    self._lane(copy_src), self._lane(copy_dst),
-                    self._lane(poison)]
+                    self._h2d(buf)]
                 self._packed(span)
             rec = self._enqueue("decode", args, members)
             rec.lanes = {s: self._slots[s] for s in members}

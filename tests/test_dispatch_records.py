@@ -9,6 +9,7 @@ read these records; benchmark/tests/test_dispatch_metrics.py (by hand)
 holds the readers to a hand-made ring.
 """
 
+import math
 import threading
 import time
 
@@ -213,41 +214,33 @@ def test_the_step_that_opens_the_regime_says_no_serial(nano):
 # ------------------------------------------------------------- transfers
 
 def test_transfers_count_what_the_pack_sent(nano):
-    """One greedy stream from a 1-token prompt, token by token: the
-    pack record's `transfers` is the page tables and the sampling keys
-    plus exactly the per-lane arguments that are not all zeros, and
-    `h2d_bytes` their bytes."""
+    """One greedy stream from a 1-token prompt, token by token: every
+    decode pack sends ONE buffer, whatever its lanes hold (the prompt
+    token at position 0, the unread dispatch's pick after it, a page's
+    first row, all-zero lanes alike), and `h2d_bytes` is the decode
+    layout's bytes: the 10 per-lane columns, the key pair and the page
+    table, a row a lane."""
     module, variables, _ = nano
     eng = DecodeEngine(module, variables, slots=2, page=PAGE,
                        prefill_chunk=0)
     S, P = eng.geom.slots, eng.geom.pages_per_slot
     sent = []
-    lane = eng._lane
-    eng._lane = lambda host: (sent.append(bool(host.any())), lane(host))[1]
+    h2d = eng._h2d
+    eng._h2d = lambda host: (sent.append(host.shape), h2d(host))[1]
     eng.attach(_req(1, 8))
     t0 = time.monotonic()
     _finish(eng)
     packs = _mine(t0, "serve.step.pack")
     assert len(packs) == 8
-    always = 4 * S * P + 4 * 2 * S      # int32 tables, uint32 key pairs
-    # position 0 opens the regime: the prompt token, its page, the
-    # active mask; pos, write_off, from_prev, temperatures, the
-    # copy-on-write pair and the poison lane are all zeros and not sent
-    assert packs[0].args["transfers"] == 2 + 3
-    assert packs[0].args["h2d_bytes"] == always + 3 * 4 * S
-    # position 1: from_prev, pos, write_page, write_off, active; the
-    # token is the unread dispatch's pick and never crosses the host
-    assert packs[1].args["transfers"] == 2 + 5
-    assert packs[1].args["h2d_bytes"] == always + 5 * 4 * S
-    # position 4 is a page's first row: write_off is all zeros again
-    assert packs[4].args["transfers"] == 2 + 4
-    # the general rule, from what _lane was handed
-    assert len(sent) == 10 * len(packs)
-    for i, r in enumerate(packs):
-        assert r.args["transfers"] == 2 + sum(sent[10 * i:10 * i + 10])
+    assert eng._packings["decode"].shape == (S, 10 + 2 + P)
+    for r in packs:
+        assert r.args["transfers"] == 1
+        assert r.args["h2d_bytes"] == 4 * S * (10 + 2 + P)
+    # what crossed: one [lanes, columns] buffer a dispatch, no other
+    assert sent == [(S, 10 + 2 + P)] * 8
 
 
-def test_a_chunks_pack_counts_its_seven_transfers(nano):
+def test_a_chunks_pack_is_one_transfer(nano):
     module, variables, _ = nano
     eng = DecodeEngine(module, variables, slots=2, page=PAGE,
                        prefill_chunk=8)
@@ -258,11 +251,46 @@ def test_a_chunks_pack_counts_its_seven_transfers(nano):
     assert len(packs) == 3
     P = eng.geom.pages_per_slot
     for r in packs:
-        # tokens, pos, the slot's table row, write pages, write
-        # offsets, the in-chunk mask (GPT keeps no per-slot state, so
-        # no slot index)
-        assert r.args["transfers"] == 6
+        # one vector: tokens, pos, write pages, write offsets and the
+        # in-chunk mask, 8 each, and the slot's table row (GPT keeps no
+        # per-slot state, so no slot index)
+        assert r.args["transfers"] == 1
         assert r.args["h2d_bytes"] == 5 * 4 * 8 + 4 * P
+        assert eng._packings["prefill"].shape == (5 * 8 + P,)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "multi", "verify"])
+def test_every_kinds_pack_is_one_transfer(nano, kind):
+    """Each of the four programs' packs sends one buffer of its own
+    layout's bytes: as many pack records of that size as the kind's
+    dispatches, and no pack record that sent more than one."""
+    module, variables, _ = nano
+    kw = {"multi": dict(decode_steps=2),
+          "verify": dict(draft_module=module, draft_variables=variables)
+          }.get(kind, {})
+    eng = DecodeEngine(module, variables, slots=2, page=PAGE,
+                       prefill_chunk=8, **kw)
+    sent = []
+    h2d = eng._h2d
+    eng._h2d = lambda host: (sent.append(host.shape), h2d(host))[1]
+    eng.attach(_req(11, 6))
+    eng.attach(_req(3, 5, start=30))
+    t0 = time.monotonic()
+    _finish(eng)
+    packs = _mine(t0, "serve.step.pack", "serve.chunk.pack")
+    assert len(packs) == len(sent)
+    assert all(r.args["transfers"] == 1 for r in packs)
+    layout = eng._packings[kind]
+    mine = [r for r, shape in zip(packs, sent) if shape == layout.shape]
+    assert len(mine) == {
+        "decode": eng.stats["dispatches"]
+        - eng.stats["multi_step_dispatches"]
+        - eng.stats["verify_dispatches"],
+        "prefill": eng.stats["prefill_dispatches"],
+        "multi": eng.stats["multi_step_dispatches"],
+        "verify": eng.stats["verify_dispatches"]}[kind] > 0
+    assert all(r.args["h2d_bytes"] == 4 * math.prod(layout.shape)
+               for r in mine)
 
 
 # ---------------------------------------------------------- the chunk tiled
@@ -319,7 +347,8 @@ def test_chunk_phases_tile_the_prefill_phase(nano, monkeypatch):
         assert all(r.args["step"] == o.args["step"] for r in kids)
         assert all(b.t0 >= a.t1 for a, b in zip(kids, kids[1:]))
         covered = sum(r.t1 - r.t0 for r in kids)
-        assert covered >= 0.99 * (o.t1 - o.t0) > 8.0
+        # a page grant, the one transfer and the registration at least
+        assert covered >= 0.99 * (o.t1 - o.t0) > 2.5
     for name in ("serve.chunk.pages", "serve.chunk.pack",
                  "serve.chunk.enqueue", "serve.chunk.emit"):
         assert name in SERVE_PHASE_KINDS

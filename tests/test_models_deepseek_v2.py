@@ -549,10 +549,13 @@ def test_gpt_programs_are_the_builders_unchanged_behind_the_seam():
     assert str(jax.make_jaxpr(served)(*prefill_args)) == str(
         jax.make_jaxpr(gpt.build_paged_prefill_step(module, C))(
             *prefill_args))
-    # the engine's own entry is that function behind one select: the
-    # dispatch before's token row and which lanes take their token there
-    out = eng._step(variables["params"], *state, jnp.zeros(S, i32),
-                    jnp.zeros(S, i32), *decode_args[1 + len(state):])
+    # the engine's own entry is that function behind one select (the
+    # dispatch before's token row, and which lanes take their token
+    # there), its host arguments unpacked from one buffer
+    buf, views = eng._packings["decode"].host()
+    assert [v.shape for v in views[1:]] == [
+        a.shape for a in decode_args[1 + len(state):]]
+    out = eng._step(variables["params"], *state, jnp.zeros(S, i32), buf)
     assert out[0].shape == (S,) and len(out) == 2 + len(state)
     # serve/engine.py names no builder of models/gpt.py
     with open(os.path.join(REPO, "kubeml_tpu", "serve", "engine.py")) as f:

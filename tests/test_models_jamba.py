@@ -757,7 +757,8 @@ def test_deepseek_programs_are_the_builders_unchanged_behind_the_seam():
     served = eng.family.prefill_step(C, "f32", "auto", False)
     assert str(jax.make_jaxpr(served)(*prefill_args)) == str(
         jax.make_jaxpr(ds.build_prefill_step(module, C))(*prefill_args))
-    # and the engine hands the prefill program exactly those arguments
+    # and the engine hands the prefill program exactly those arguments:
+    # the parameters, the state and one buffer that unpacks to the rest
     seen = []
     real = eng._prefill
     eng._prefill = lambda *a: (seen.append(len(a)), real(*a))[1]
@@ -766,4 +767,6 @@ def test_deepseek_programs_are_the_builders_unchanged_behind_the_seam():
                           temperature=0.0, seed=0)
     eng.attach(req)
     _finish(eng)
-    assert seen and set(seen) == {len(prefill_args)}
+    assert seen and set(seen) == {1 + len(state) + 1}
+    assert [(v.shape, v.dtype) for v in eng._packings["prefill"].host()[1]] \
+        == [(a.shape, a.dtype) for a in prefill_args[1 + len(state):]]
