@@ -184,8 +184,17 @@ class ServeFamily:
         and for a draft's tree alike. A pure function of the tree and of
         the module's own fields, and idempotent (a rebuilt engine is
         handed the resident tree). The identity unless a family's
-        programs read a leaf only ever through a cast."""
+        programs read a leaf only ever through a cast, or bind its
+        parameters in another layout than the module's (GPT stacks a
+        layer's norms and biases over the layers)."""
         return params
+
+    def module_params(self, held):
+        """A tree `serve_params` returned, in the module's own layout
+        (leaf for leaf what the module's init gives, in the held
+        dtypes): how the engine counts what `serve_params` cast. The
+        identity where the held form keeps the module's layout."""
+        return held
 
     def attn_impls(self, page: int, max_pages: int, prefill_chunk: int,
                    kv_dtype: str, attn_impl: str,

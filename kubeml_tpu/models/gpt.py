@@ -512,6 +512,35 @@ def _int8_write_prefill(pages, scales, layer, rows, write_pages,
 _PAGED_MATMULS = ("q", "k", "v", "out", "Dense_0", "Dense_1")
 
 
+def layer_params(params, i: int):
+    """Layer `i`'s subtree of a parameter tree in either form the paged
+    programs take: the module's own (`layer_<i>` whole), or the held
+    form (GPTServeFamily.serve_params), whose `layer_<i>` keeps the
+    layer's kernels and whose `layers` stacks every other leaf of a
+    layer over the layers, each then indexed at the static `i`. Chosen
+    by the tree's own structure."""
+    own = params[f"layer_{i}"]
+    stacked = params.get("layers")
+    if stacked is None:
+        return own
+    rest = jax.tree_util.tree_map(lambda a: a[i], stacked)
+    return {k: {**sub, **own.get(k, {})} for k, sub in rest.items()}
+
+
+def module_params(params, layers: int):
+    """The tree in the module's own layout (`layer_0` ... `layer_<L-1>`
+    whole beside the embeddings and the final norm): a held tree's
+    layers put back together by `layer_params`, any other tree as it
+    is."""
+    if "layers" not in params:
+        return params
+    out = {k: v for k, v in params.items()
+           if k != "layers" and not k.startswith("layer_")}
+    out.update({f"layer_{i}": layer_params(params, i)
+                for i in range(layers)})
+    return out
+
+
 def _paged_trunk(module: GPTModule, kv_dtype: str, attn_impl: str,
                  attn_interpret: bool, chunked: bool):
     """What the decode and the prefill program share: the checks of the
@@ -571,7 +600,7 @@ def _paged_trunk(module: GPTModule, kv_dtype: str, attn_impl: str,
     def layers(params, h, k_pages, v_pages, k_scales, v_scales, tables,
                bias, *where):
         for i in range(module.layers):
-            p = params[f"layer_{i}"]
+            p = layer_params(params, i)
             with jax.named_scope(f"layer_{i}/qkv"):
                 x = ln.apply({"params": p["LayerNorm_0"]}, h)
                 q = qkv.apply({"params": p["q"]}, x)
@@ -998,11 +1027,14 @@ def build_paged_spec_verify_step(module: GPTModule,
         zero_f = jnp.zeros(S, jnp.float32)
 
         # ---- draft proposes K tokens (greedy, full window re-forward
-        # per token — stateless, so rejection needs no draft rollback)
+        # per token — stateless, so rejection needs no draft rollback).
+        # The draft's flax module reads its own layout: a held tree is
+        # put back in it here, inside the program
+        draft_tree = module_params(draft_params, draft_module.layers)
         win = window_toks
         props = []
         for i in range(1, steps + 1):
-            lg = draft_module.apply({"params": draft_params}, win)
+            lg = draft_module.apply({"params": draft_tree}, win)
             lg = lg[rows, jnp.clip(pos + i - 1, 0, W - 1)]
             lg = lg.at[:, PAD_ID].set(-jnp.inf)
             d = jnp.argmax(lg, axis=-1).astype(jnp.int32)
@@ -1116,18 +1148,56 @@ class GPTServeFamily(ServeFamily):
         bits, since the cast is a function of the value alone. The
         LayerNorm subtrees stay as they are: nn.LayerNorm(dtype=float32)
         reads them in float32. Chosen by the leaf's path, not its rank
-        (the attention kernels are rank 3); with a float32 module, or on
-        a tree already in this form, every leaf comes back untouched."""
+        (the attention kernels are rank 3).
+
+        Then every leaf of a layer but its six kernels (the two norms'
+        scale and bias, the six biases) is stacked over the layers into
+        one array of its kind under `layers` (layer_params reads layer i
+        back at a static index), and `layer_<i>` keeps the kernels: a
+        jitted call binds 4 + 10 + 6 L parameter arrays, 230 for GPT-2
+        large where the module's own tree has 4 + 16 L, 580 (a call's
+        dispatch and launch cost one to two microseconds an argument).
+        The kernels stay an argument each because the chip's compiler
+        fetches a kernel into on-chip memory ahead of its product only
+        where it is a whole argument: stacked over the 36 layers, each
+        layer's kernel was read from HBM inside its product or copied
+        out first, and a decode step took 3.21 ms where it took 2.71
+        (TPU v5e). A host tree (a checkpoint's) is cast and stacked on
+        the host, so only the held form crosses to the device. On a
+        tree already in this form every leaf comes back untouched."""
         dtype = self.module.dtype
 
         def held(path, leaf):
             top, *rest = (str(k.key) for k in path)
             cast = top in ("tok_embed", "pos_embed") or (
-                top.startswith("layer_") and rest[0] in _PAGED_MATMULS)
+                (top == "layers" or top.startswith("layer_"))
+                and rest[0] in _PAGED_MATMULS)
             return leaf.astype(dtype) if cast and leaf.dtype != dtype \
                 else leaf
 
-        return jax.tree_util.tree_map_with_path(held, params)
+        params = jax.tree_util.tree_map_with_path(held, params)
+        if "layers" in params:
+            return params
+        per_layer = [params[f"layer_{i}"] for i in range(self.module.layers)]
+        host = all(isinstance(a, np.ndarray)
+                   for a in jax.tree_util.tree_leaves(per_layer))
+        stack = np.stack if host else jnp.stack
+
+        def part(layer, kernels):
+            return {name: {k: a for k, a in sub.items()
+                           if (k == "kernel") == kernels}
+                    for name, sub in layer.items()
+                    if (name in _PAGED_MATMULS) or not kernels}
+
+        out = {k: v for k, v in params.items() if not k.startswith("layer_")}
+        out["layers"] = jax.tree_util.tree_map(
+            lambda *a: stack(a), *(part(p, False) for p in per_layer))
+        out.update({f"layer_{i}": part(p, True)
+                    for i, p in enumerate(per_layer)})
+        return out
+
+    def module_params(self, held):
+        return module_params(held, self.module.layers)
 
     def attn_impls(self, page, max_pages, prefill_chunk, kv_dtype,
                    attn_impl, attn_interpret):

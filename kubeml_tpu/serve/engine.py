@@ -243,8 +243,9 @@ SERVE_PHASE_KINDS = (
                             # transfers, h2d_bytes
     "serve.step.enqueue",   # the jitted call until it returns, then
                             # the token events handed over; args:
-                            # compiled, ahead, starved, call_s, and
-                            # serial where the step could not run ahead
+                            # compiled, ahead, starved, call_s, args,
+                            # and serial where the step could not run
+                            # ahead
     "serve.step.readback",  # np.asarray of the picks; args: ready, 1
                             # where the result was waiting and the
                             # phase is the fetch's own cost, 0 where it
@@ -255,7 +256,7 @@ SERVE_PHASE_KINDS = (
     "serve.chunk.pack",     # its per-token loop and transfers; args:
                             # transfers, h2d_bytes
     "serve.chunk.enqueue",  # its jitted call; args: compiled, starved,
-                            # call_s
+                            # call_s, args
     "serve.chunk.emit",     # cursor, prefix registration, the release
                             # of the dispatch's buffers
 )
@@ -343,14 +344,22 @@ def _put_params(family: ServeFamily, params):
     dtypes and a hot swap reuses the compiled programs. A host tree (a
     checkpoint's) is cast on the host, leaf by leaf, and only the held
     form crosses to the device: no float32 copy is ever resident there.
-    Returns (tree of device arrays, leaves whose dtype the family
-    changed, the tree's bytes)."""
+    Returns (tree of device arrays, its stats: `param_leaves_cast`,
+    leaves whose dtype the family changed, counted in the module's own
+    layout whatever the held one; `param_leaves`, the held tree's
+    leaves, which is what a jitted call binds of it; `param_bytes`)."""
     held = family.serve_params(params)
     leaves = jax.tree_util.tree_leaves
+
+    def layout(tree):          # shapes and dtypes, no device work
+        return leaves(jax.eval_shape(family.module_params, tree))
+
     cast = sum(a.dtype != b.dtype
-               for a, b in zip(leaves(params), leaves(held)))
+               for a, b in zip(layout(params), layout(held)))
     held = jax.device_put(held)
-    return held, cast, sum(int(a.nbytes) for a in leaves(held))
+    return held, {"param_leaves_cast": cast,
+                  "param_leaves": len(leaves(held)),
+                  "param_bytes": sum(int(a.nbytes) for a in leaves(held))}
 
 
 class _Packing:
@@ -550,6 +559,9 @@ class DecodeEngine:
             else tuple(range(1, 1 + n_state))
         # each built program's layout of its one host buffer, by kind
         self._packings: Dict[str, _Packing] = {}
+        # the device arrays a call of each kind binds, counted at its
+        # first dispatch (the enqueue record's `args`)
+        self._bound: Dict[str, int] = {}
         self._step = self._jit("decode", self._step_raw, donate)
         # what a decode dispatch with no dispatch before it is handed
         # as `prev`: the same shape and dtype, so the entry compiles once
@@ -628,8 +640,7 @@ class DecodeEngine:
         # weight_generation; old generations retire when their last
         # slot releases.
         self.weight_generation = 1
-        held, param_leaves_cast, param_bytes = _put_params(
-            family, variables["params"])
+        held, param_stats = _put_params(family, variables["params"])
         self._params_by_gen: Dict[int, object] = {1: held}
         S, Pmax = self.geom.slots, self.geom.pages_per_slot
         self._tables = np.zeros((S, Pmax), np.int32)
@@ -718,11 +729,11 @@ class DecodeEngine:
             # (its share of all dispatches tells a host-bound replica
             # from a device-bound one; a lower bound, _enqueue)
             "starved_dispatches": 0,
-            # the current generation's tree as held on the device, and
-            # how many of its leaves the family's serve_params holds in
-            # another dtype than they were handed over in
-            "param_bytes": param_bytes,
-            "param_leaves_cast": param_leaves_cast,
+            # the current generation's tree as held on the device: its
+            # bytes, its leaves (what a call binds of it) and how many of
+            # the module's leaves the family's serve_params holds in
+            # another dtype than they were handed over in (_put_params)
+            **param_stats,
         }
         # counts the family's decode program appends to its token row
         # (ServeFamily.step_counters), summed over decode dispatches
@@ -872,10 +883,9 @@ class DecodeEngine:
         settled first: it was packed while one generation was resident."""
         self._carry.extend(self.drain())
         self.weight_generation += 1
-        (self._params_by_gen[self.weight_generation],
-         self.stats["param_leaves_cast"],
-         self.stats["param_bytes"]) = _put_params(
-            self.family, variables["params"])
+        held, param_stats = _put_params(self.family, variables["params"])
+        self._params_by_gen[self.weight_generation] = held
+        self.stats.update(param_stats)
         self.stats["weight_swaps"] += 1
         # generations nobody reads anymore free immediately (an idle
         # engine holds exactly one generation after a swap)
@@ -1484,8 +1494,11 @@ class DecodeEngine:
         serve.chunk.enqueue for a prefill chunk:
         benchmark/metrics/serve_loop_phases.py takes an iteration with
         a serve.step.enqueue record for a decode iteration) with
-        `compiled`, `starved` and `call_s`, the call alone of what the
-        phase holds. Returns the dispatch, unread.
+        `compiled`, `starved`, `call_s`, the call alone of what the
+        phase holds, and `args`, the device arrays the call binds (the
+        parameter tree's leaves, the slab state, `prev`, the packed
+        buffer: a call's dispatch and launch cost one to two
+        microseconds each). Returns the dispatch, unread.
 
         `starved`: the slab's state is the output of the newest program
         of ANY kind, so where the host sees it ready the device has
@@ -1527,6 +1540,11 @@ class DecodeEngine:
             span["compiled"] = int(compiled)
             span["starved"] = starved
             span["call_s"] = t1 - t0
+            bound = self._bound.get(kind)
+            if bound is None:
+                bound = self._bound[kind] = len(
+                    jax.tree_util.tree_leaves(args))
+            span["args"] = bound
             if members is not None:
                 ahead = int(self._unread is not None)
                 span["ahead"] = ahead

@@ -187,7 +187,12 @@ _SERVE = dict(heads=20, head_dim=64, ffn=5120, vocab=50257, max_len=1024,
 # from the input slab). Held form (sandbox compile, PR 32): decode
 # 4.35 / 4.39 MB, prefill 5.68 / 0, the 36-layer decode program 73.3,
 # each bound a quarter above its reading; arguments 3.06 GB at 36
-# layers where the float32 tree's are 4.61. The 5-D slab read 363 MB
+# layers where the float32 tree's are 4.61. The engine now holds that
+# tree with a layer's norms and biases stacked over the layers
+# (serve_params; the "-held-stacked" cases), the "-held" ones being the
+# same leaves in the module's layout: decode 4.13 MB, prefill 5.19, the
+# 36-layer decode program 63.0 compiled for the described chip, under
+# the same bounds. The 5-D slab read 363 MB
 # for decode at 2 layers and 8.65 GB at 36: a relayout of either slab
 # is at least 42 MB here (int8, 2 layers), 756 MB at 36. The deep cases
 # are there because depth changes what the compiler does: at 24 layers
@@ -195,25 +200,29 @@ _SERVE = dict(heads=20, head_dim=64, ffn=5120, vocab=50257, max_len=1024,
 # the slab in lane chunks ([36, 513, 16, 384] x 3 and [.., 128]), which
 # no 2-layer compile shows.
 SERVE_PROGRAMS = {
-    "decode": ("decode", 2, 160e6, False),
-    "prefill": ("prefill", 4, 160e6, False),
-    "multi": ("multi", 3, 165e6, False),
-    "verify": ("verify", 2, 470e6, False),
-    "decode-36-layers": ("decode", 36, 400e6, False),
-    "decode-held": ("decode", 2, 5.5e6, True),
-    "prefill-held": ("prefill", 4, 7.1e6, True),
-    "decode-36-layers-held": ("decode", 36, 92e6, True),
+    "decode": ("decode", 2, 160e6, None),
+    "prefill": ("prefill", 4, 160e6, None),
+    "multi": ("multi", 3, 165e6, None),
+    "verify": ("verify", 2, 470e6, None),
+    "decode-36-layers": ("decode", 36, 400e6, None),
+    "decode-held": ("decode", 2, 5.5e6, "leaves"),
+    "prefill-held": ("prefill", 4, 7.1e6, "leaves"),
+    "decode-36-layers-held": ("decode", 36, 92e6, "leaves"),
+    "decode-held-stacked": ("decode", 2, 5.5e6, "stacked"),
+    "prefill-held-stacked": ("prefill", 4, 7.1e6, "stacked"),
+    "decode-36-layers-held-stacked": ("decode", 36, 92e6, "stacked"),
 }
 SERVE_CASES = [(name, kv) for name in SERVE_PROGRAMS
                for kv in ("f32", "int8")
                if not (name.startswith("decode-36-layers") and kv == "int8")]
 
 
-def _serve_program(which, L, kv_dtype, sds, held=False):
+def _serve_program(which, L, kv_dtype, sds, held=None):
     """(fn, args, donate_argnums, slab shape) of one serve program at
     the cell's widths and L layers, arguments as shapes on the
-    described chip; `held`: the parameter trees as the engine puts them
-    there (the family's serve_params), else float32 as initialised."""
+    described chip; `held` "stacked": the parameter trees as the engine
+    puts them there (the family's serve_params), "leaves": the same
+    leaves in the module's layout, None: float32 as initialised."""
     import jax
     import jax.numpy as jnp
 
@@ -228,8 +237,10 @@ def _serve_program(which, L, kv_dtype, sds, held=False):
         params = jax.eval_shape(lambda: module.init(
             jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))["params"])
         if held:
-            params = jax.eval_shape(module.serve_family().serve_params,
-                                    params)
+            family = module.serve_family()
+            params = jax.eval_shape(family.serve_params, params)
+            if held == "leaves":
+                params = jax.eval_shape(family.module_params, params)
         return module, jax.tree_util.tree_map(
             lambda a: sds(a.shape, a.dtype), params)
 
@@ -342,10 +353,90 @@ def test_serve_program_keeps_the_slab_in_place_on_v5e(one_chip, name,
     converted = [(n, t, dims, op) for n, t, dims, _, op
                  in _result_shapes(hlo) if dims in whole
                  and (t == "f32" or (t == "bf16" and op == "convert"))]
-    assert bool(converted) != held, converted[:8]
+    assert bool(converted) != bool(held), converted[:8]
 
 
-@pytest.mark.parametrize("name", ["decode-held", "prefill-held"])
+def _entry_arrays(hlo):
+    """(opcode, dims, on chip) of every array an instruction of the
+    ENTRY computation yields, a tuple's elements each; on chip: in
+    memory space 1, the core's own memory, not HBM."""
+    import re
+    entry = re.search(r"^ENTRY .*?^}", hlo, re.M | re.S).group(0)
+    out = []
+    for types, op in re.findall(r"^\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(",
+                                entry, re.M):
+        for dims, lay in re.findall(r"\w+\[([0-9,]*)\]\{([^}]*)\}", types):
+            out.append((op, tuple(int(d) for d in dims.split(",") if d),
+                        lay.endswith("S(1)")))
+    return out
+
+
+@pytest.mark.parametrize("name", [n for n in SERVE_PROGRAMS
+                                  if n.endswith("-held-stacked")])
+def test_the_stacked_form_fetches_every_kernel_as_before_on_v5e(one_chip,
+                                                                name):
+    """The engine's held tree (a layer's norms and biases stacked over
+    the layers, each kernel an argument: serve_params) against the same
+    leaves in the module's layout, compiled for the described chip: no
+    instruction but the entry parameter yields a stacked leaf's whole
+    shape except into the core's own memory (the compiler fetches a
+    small stack whole, as it fetches a bias); no more instructions yield
+    one layer's kernel shape, and none a slice of one ([1, *kernel]:
+    what a kernel stacked over the layers was read through); and no
+    more temporaries. A kernel stacked over 36 layers was read inside
+    its product or copied out synchronously, and the decode step took
+    3.21 ms where it takes 2.71 per leaf (TPU v5e)."""
+    import collections
+
+    import jax
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    which, layers, temp_bound, _ = SERVE_PROGRAMS[name]
+    compiled, arrays, trees = {}, {}, {}
+    for held in ("leaves", "stacked"):
+        fn, args, donate, _ = _serve_program(which, layers, "f32", sds, held)
+        compiled[held] = jax.jit(fn, donate_argnums=donate).lower(
+            *args).compile()
+        arrays[held] = _entry_arrays(compiled[held].as_text())
+        trees[held] = args[0]
+    # one layer's kernel shapes, as the module's layout has them
+    kernels = {a.shape for path, a
+               in jax.tree_util.tree_leaves_with_path(trees["leaves"])
+               if path[-1].key == "kernel"}
+    assert len(kernels) == 4, kernels
+    stacks = {a.shape for a in jax.tree_util.tree_leaves(
+        trees["stacked"]["layers"])}
+    # (an async copy's start names its source among its results)
+    whole = [(op, dims) for op, dims, on_chip in arrays["stacked"]
+             if dims in stacks and not on_chip
+             and op not in ("parameter", "copy-start", "slice-start")]
+    assert not whole, whole
+    sliced = [(op, dims) for op, dims, _ in arrays["stacked"]
+              if dims[:1] == (1,) and dims[1:] in kernels]
+    assert not sliced, sliced[:8]
+
+    def kernel_shaped(held):
+        return collections.Counter(op for op, dims, _ in arrays[held]
+                                   if dims in kernels and op != "parameter")
+    assert sum(kernel_shaped("stacked").values()) \
+        <= sum(kernel_shaped("leaves").values()), (
+            kernel_shaped("stacked"), kernel_shaped("leaves"))
+    temps = {k: c.memory_analysis().temp_size_in_bytes
+             for k, c in compiled.items()}
+    assert temps["stacked"] <= temps["leaves"] < temp_bound, temps
+    # and the held tree is what serve_params' docstring says: ten kinds
+    # stacked over the layers, each kernel its own leaf
+    held = trees["stacked"]
+    assert len(jax.tree_util.tree_leaves(held["layers"])) == 10
+    assert all(s[0] == layers for s in stacks), stacks
+    assert len(jax.tree_util.tree_leaves(held)) == 4 + 10 + 6 * layers
+
+
+@pytest.mark.parametrize("name", ["decode-held", "prefill-held",
+                                  "decode-held-stacked",
+                                  "prefill-held-stacked"])
 def test_the_packed_entry_adds_next_to_nothing_on_v5e(one_chip, name):
     """What the engine jits (serve/engine.py _packed_entry): the same
     program behind the slices and bitcasts of its ONE host buffer, and

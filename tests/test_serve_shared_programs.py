@@ -227,10 +227,11 @@ def test_gpt_program_gives_the_same_bits_on_the_held_form(program,
                                                            kv_dtype):
     """Next tokens, `bad`, accepted counts and every returned state
     array: equal bit for bit whether the program casts the float32
-    leaves itself or is handed them cast (a draft's tree too); the
-    held form is what the docstring of serve_params says, and is a
-    fixed point; a float32 module's is its input, so its jaxpr is the
-    string it was."""
+    leaves itself, is handed them cast in the module's layout, or is
+    handed the held form, cast and stacked over the layers (a draft's
+    tree too); the held form is what the docstring of serve_params
+    says, and is a fixed point; a float32 module's casts nothing, and
+    its tree in the module's layout traces to the text it did."""
     module, params = _gpt(jnp.bfloat16)
     draft, draft_params = _gpt(jnp.bfloat16, seed=5)
     family = module.serve_family()
@@ -239,34 +240,62 @@ def test_gpt_program_gives_the_same_bits_on_the_held_form(program,
             jax.tree_util.tree_leaves_with_path(held)}
     assert {k: str(a.dtype) for k, a in flat.items()} == {
         k: "float32" if "LayerNorm" in k else "bfloat16" for k in flat}
-    assert sum("LayerNorm" in k for k in flat) == 2 * (2 * 2 + 1)
+    # the final norm's two leaves, and a layer's four stacked over both;
+    # a layer's other leaves but its six kernels stacked the same way,
+    # the kernels one leaf a layer
+    assert sum("LayerNorm" in k for k in flat) == 2 + 2 * 2
+    assert len(flat) == 4 + 10 + 6 * 2
+    stacked = {k: a for k, a in flat.items() if k.startswith("['layers']")}
+    assert len(stacked) == 10 and all(a.shape[0] == 2
+                                      for a in stacked.values())
+    assert all(k.endswith("['kernel']") for k in flat
+               if k.startswith("['layer_"))
+    # in the module's layout: every leaf of the module's tree, cast
+    per_layer = family.module_params(held)
+    by_path = {jax.tree_util.keystr(k): a for k, a in
+               jax.tree_util.tree_leaves_with_path(per_layer)}
+    assert sum("LayerNorm" in k for k in by_path) == 2 * (2 * 2 + 1)
+    assert {k: a.shape for k, a in by_path.items()} == {
+        jax.tree_util.keystr(k): a.shape
+        for k, a in jax.tree_util.tree_leaves_with_path(params)}
     again = family.serve_params(held)
     assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(held),
                                       jax.tree_util.tree_leaves(again)))
-    # a host tree (a checkpoint's) is cast on the host: the same bits
+    # a host tree (a checkpoint's) is cast and stacked on the host: the
+    # same bits
     host = family.serve_params(jax.tree_util.tree_map(np.asarray, params))
     assert all(isinstance(a, np.ndarray)
                for a in jax.tree_util.tree_leaves(host))
     assert _bits(host) == _bits(held)
 
     fn, args = _gpt_program(program, kv_dtype, module, draft)
-    trees = [[params], [held]] if program != "verify" else [
+    draft_family = draft.serve_family()
+    draft_held = draft_family.serve_params(draft_params)
+    trees = [[params], [per_layer], [held]] if program != "verify" else [
         [params, draft_params],
-        [held, draft.serve_family().serve_params(draft_params)]]
-    cast_inside, handed_cast = (fn(*p, *args) for p in trees)
-    assert [a.dtype for a in jax.tree_util.tree_leaves(cast_inside)] == [
-        a.dtype for a in jax.tree_util.tree_leaves(handed_cast)]
-    assert _bits(cast_inside) == _bits(handed_cast)
+        [per_layer, draft_family.module_params(draft_held)],
+        [held, draft_held]]
+    cast_inside, *handed_cast = (fn(*p, *args) for p in trees)
+    for out in handed_cast:
+        assert [a.dtype for a in jax.tree_util.tree_leaves(cast_inside)] \
+            == [a.dtype for a in jax.tree_util.tree_leaves(out)]
+        assert _bits(cast_inside) == _bits(out)
 
     module32, params32 = _gpt(jnp.float32)
-    same = module32.serve_family().serve_params(params32)
-    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(params32),
-                                      jax.tree_util.tree_leaves(same)))
+    family32 = module32.serve_family()
+    same = family32.serve_params(params32)
+    unstacked = family32.module_params(same)
+    assert {str(a.dtype) for a in jax.tree_util.tree_leaves(same)} \
+        == {"float32"}
+    assert _bits(unstacked) == _bits(params32)
     fn32, args32 = _gpt_program(program, kv_dtype, module32, module32)
-    trees32 = [[params32], [same]] if program != "verify" else [
-        [params32, params32], [same, same]]
+    trees32 = [[params32], [unstacked]] if program != "verify" else [
+        [params32, params32], [unstacked, unstacked]]
     assert str(jax.make_jaxpr(fn32)(*trees32[0], *args32)) == str(
         jax.make_jaxpr(fn32)(*trees32[1], *args32))
+    stacked32 = [same] if program != "verify" else [same, same]
+    assert _bits(fn32(*trees32[0], *args32)) == _bits(
+        fn32(*stacked32, *args32))
 
 
 @pytest.mark.parametrize("chunked", [False, True])
@@ -305,18 +334,23 @@ def test_gpt_head_logits_are_the_same_bits_on_the_held_form(chunked):
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("name", ["deepseek_v2", "jamba", "exaone_moe"])
+@pytest.mark.parametrize("name", ["deepseek_v2", "jamba", "exaone_moe",
+                                  "longcat_flash"])
 def test_other_families_hand_back_the_very_tree(name):
     """Their leaves are bfloat16 from the checkpoint on and their
-    float32 ones are read in float32: nothing to hold otherwise."""
+    float32 ones are read in float32: nothing to hold otherwise. Their
+    expert stacks and scan parameters feed Pallas calls whole, so no
+    layout of theirs is stacked either: the held tree is the module's."""
     import importlib
     mod = importlib.import_module(f"kubeml_tpu.models.{name}")
     family = next(v for v in vars(mod).values() if isinstance(v, type)
                   and issubclass(v, mod.ServeFamily)
                   and v is not mod.ServeFamily)
     assert "serve_params" not in vars(family)
+    assert "module_params" not in vars(family)
     tree = {"a": {"kernel": np.ones((2, 2), np.float32)}}
     assert mod.ServeFamily.serve_params(object(), tree) is tree
+    assert mod.ServeFamily.module_params(object(), tree) is tree
 
 
 # ------------------------------------------------- the shared expert layer

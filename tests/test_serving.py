@@ -153,9 +153,10 @@ def _leaf_types(tree):
 def test_every_generation_is_held_in_the_serve_form():
     """An engine built from a float32 tree holds it as its family's
     programs read it (models/gpt.py serve_params: the norms float32,
-    every other leaf in the module's bfloat16), says so in its stats,
-    and holds an installed generation and a rebuilt engine's tree in
-    exactly that form: a hot swap compiles nothing."""
+    every other leaf in the module's bfloat16, a layer's norms and
+    biases stacked over the layers), says so in its stats, and holds an
+    installed generation and a rebuilt engine's tree in exactly that
+    form: a hot swap compiles nothing."""
     import jax
 
     from kubeml_tpu.serve.engine import DecodeEngine
@@ -168,18 +169,27 @@ def test_every_generation_is_held_in_the_serve_form():
     assert {d for _s, d in handed.values()} == {"float32"}
     engine = DecodeEngine(module, variables, slots=2, page=4,
                           prefill_chunk=4)
+    family = engine.family
     types = _leaf_types(engine._params_by_gen[1])
-    assert types == {k: (s, "float32" if "LayerNorm" in k else "bfloat16")
-                     for k, (s, _d) in handed.items()}
+    assert _leaf_types(family.module_params(engine._params_by_gen[1])) \
+        == {k: (s, "float32" if "LayerNorm" in k else "bfloat16")
+            for k, (s, _d) in handed.items()}
+    assert {s[0] for k, (s, _d) in types.items()
+            if k.startswith("['layers']")} == {module.layers}
     norms = sum(4 * int(np.prod(s)) for k, (s, _d) in handed.items()
                 if "LayerNorm" in k)
     whole = sum(4 * int(np.prod(s)) for s, _d in handed.values())
     held = {"param_bytes": norms + (whole - norms) // 2,
             # the two tables, and a layer's six kernels and six biases
-            "param_leaves_cast": 2 + 12 * module.layers}
+            "param_leaves_cast": 2 + 12 * module.layers,
+            # the two tables and the final norm's two, a layer's norms
+            # and biases each stacked over the layers, its six kernels
+            "param_leaves": 4 + 10 + 6 * module.layers}
     assert {k: engine.stats[k] for k in held} == held
     assert held["param_leaves_cast"] == sum(
-        "LayerNorm" not in k for k in types)
+        "LayerNorm" not in k for k in _leaf_types(
+            family.module_params(engine._params_by_gen[1])))
+    assert held["param_leaves"] == len(types)
 
     a = GenerateRequest([5, 6, 7, 8, 9, 10], max_new_tokens=8)
     engine.attach(a)
@@ -211,6 +221,51 @@ def test_every_generation_is_held_in_the_serve_form():
     rebuilt.attach(c)
     _drive(rebuilt)
     assert c.tokens == b.tokens
+    assert (rebuilt.stats["compiles"], rebuilt.stats["prefill_compiles"]) \
+        == (1, 1)
+
+
+@pytest.mark.parametrize("layers", [1, 3, 5])
+def test_a_call_binds_the_held_leaves_at_any_depth(layers):
+    """GPT's held tree is 4 + 10 + 6 L leaves (the module's own is
+    4 + 16 L), the cast still counts in the module's layout, and every
+    enqueue record's `args` is what the jitted call binds: the
+    parameter leaves, the slab's 5 arrays, `prev` for a decode call and
+    the one packed buffer."""
+    import time
+
+    import jax
+
+    from kubeml_tpu.models.gpt import GPTModule
+    from kubeml_tpu.serve.engine import DecodeEngine
+    from kubeml_tpu.serve.slots import GenerateRequest
+    from kubeml_tpu.utils.trace import phases
+
+    module = GPTModule(vocab_size=64, max_len=32, hidden=16, layers=layers,
+                       heads=2, ffn=32, dropout=0.0)
+    variables = module.init(jax.random.PRNGKey(0), np.ones((1, 8), np.int32))
+    engine = DecodeEngine(module, variables, slots=2, page=4,
+                          prefill_chunk=4)
+    held = 4 + 10 + 6 * layers
+    assert engine.stats["param_leaves"] == held
+    assert engine.stats["param_leaves_cast"] == 2 + 12 * layers
+    assert len(jax.tree_util.tree_leaves(variables["params"])) \
+        == 4 + 16 * layers
+    t0 = time.monotonic()
+    for prompt in ([5, 6, 7, 8, 9, 10], [3]):
+        engine.attach(GenerateRequest(prompt, max_new_tokens=4))
+    _drive(engine)
+    engine.drain()
+    recs = [r for r in phases(t0=t0) if r.name in (
+        "serve.step.enqueue", "serve.chunk.enqueue")]
+    decode = [r.args["args"] for r in recs
+              if r.name == "serve.step.enqueue"]
+    chunk = [r.args["args"] for r in recs
+             if r.name == "serve.chunk.enqueue"]
+    assert len(decode) == engine.stats["dispatches"]
+    assert len(chunk) == engine.stats["prefill_dispatches"] > 0
+    assert set(decode) == {held + 5 + 2} and set(chunk) == {held + 5 + 1}
+    assert engine._bound == {"decode": held + 7, "prefill": held + 6}
 
 
 @pytest.mark.parametrize("how", ["solo", "batched"])
