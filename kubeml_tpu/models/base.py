@@ -40,7 +40,8 @@ from jax import lax
 
 from kubeml_tpu.ops.attention import NEG_INF
 from kubeml_tpu.ops.pallas.grouped_matmul import (grouped_mlp,
-                                                   resolve_mlp_impl)
+                                                   clip_up, resolve_mlp_impl,
+                                                   silu_gate, swiglu)
 
 PyTree = Any
 
@@ -256,11 +257,12 @@ def dot_f32(x, w):
                    preferred_element_type=jnp.float32)
 
 
-def gated_mlp(x, p):
+def gated_mlp(x, p, limit=None):
     """W_down(silu(W_gate x) * W_up x), x already normed; p holds the
-    three `kernel` leaves under gate, up, down."""
-    a = jax.nn.silu(dot_f32(x, p["gate"]["kernel"])) \
-        * dot_f32(x, p["up"]["kernel"])
+    three `kernel` leaves under gate, up, down; `limit` is a SwiGLU
+    clamp (ops/pallas/grouped_matmul.py swiglu)."""
+    a = silu_gate(dot_f32(x, p["gate"]["kernel"]), limit) \
+        * clip_up(dot_f32(x, p["up"]["kernel"]), limit)
     return dot_f32(a, p["down"]["kernel"])
 
 
@@ -343,7 +345,7 @@ def held_expert_impl(tokens: int, top_k: int, d: int, f: int, dtype,
 def held_expert_layer(x, p, live, route, *, held: int, rank: int,
                        scaling: float, dtype, dense: bool,
                        impl: str = "auto", interpret: bool = False,
-                       zero: int = 0):
+                       zero: int = 0, limit=None):
     """Shared experts + ONE SHARE's routed experts over normed tokens
     x [N, d] (float32): the dropless expert layer of every family whose
     deployment is expert-parallel (DeepSeek-V2, EXAONE-MoE,
@@ -371,7 +373,8 @@ def held_expert_layer(x, p, live, route, *, held: int, rank: int,
     weights once where `impl` / `interpret` (the deployment's kernel
     choice, as for attention) and the shapes allow it, `lax.ragged_dot`
     elsewhere. Scopes `router`, `experts`, `shared_expert`,
-    `zero_experts`. Returns
+    `zero_experts`. `limit` clamps every SwiGLU of the layer, routed
+    and shared (GigaChat's `swiglu_limit`). Returns
     (output [N, d] float32, counts int32[3]: token-expert pairs chosen,
     those that chose a held expert, held experts with at least one
     token; with `zero`, int32[4]: and the pairs that chose a
@@ -407,7 +410,7 @@ def held_expert_layer(x, p, live, route, *, held: int, rank: int,
                            preferred_element_type=jnp.float32)
             u = jnp.einsum("nd,edf->enf", xb, e["up"]["kernel"],
                            preferred_element_type=jnp.float32)
-            a = (jax.nn.silu(g) * u * per_expert.T[:, :, None]
+            a = (swiglu(g, u, limit) * per_expert.T[:, :, None]
                  ).astype(dtype)
             routed = jnp.einsum("enf,efd->nd", a, e["down"]["kernel"],
                                 preferred_element_type=jnp.float32)
@@ -418,7 +421,7 @@ def held_expert_layer(x, p, live, route, *, held: int, rank: int,
             rows = xb[order // k]
             y = grouped_mlp(rows, e["gate"]["kernel"], e["up"]["kernel"],
                             e["down"]["kernel"], tokens_of, impl=impl,
-                            interpret=interpret)
+                            interpret=interpret, limit=limit)
             # rows past the last group belong to no expert: whatever
             # the product left there (the kernel writes nothing) is
             # selected away, not multiplied
@@ -428,7 +431,7 @@ def held_expert_layer(x, p, live, route, *, held: int, rank: int,
     out = routed
     if "shared" in p:
         with jax.named_scope("shared_expert"):
-            out = gated_mlp(xb, p["shared"]) + routed
+            out = gated_mlp(xb, p["shared"], limit) + routed
     if zero:
         with jax.named_scope("zero_experts"):
             kept = jnp.where(is_zero, scores * scaling, 0.0).sum(-1)

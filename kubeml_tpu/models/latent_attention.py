@@ -13,7 +13,13 @@ angles (`cos`, `sin` [N, rope/2]: YaRN for DeepSeek-V2, plain for
 LongCat-Flash) and its softmax scale, and says whether the two low-rank
 latents are scaled after their norms (`q_scale`, `kv_scale`: LongCat's
 `mla_scale_q_lora` / `mla_scale_kv_lora`; None multiplies nothing, so a
-family without them traces as before the lift).
+family without them traces as before the lift). A family whose blocks
+differ around the attention hands in what differs, each None for the
+plain block (GigaChat3.5's sandwich): `x`, the block's input already
+normed (its own norm in the place of `attn_norm`); `gate`, an output
+gate [N, H * v_head_dim] that multiplies the heads' outputs before
+`o`; `post`, a function of the block's output before the residual
+add.
 
 A token's cache row is `[c_kv | k_pe]` (after the norm, the scale and the
 rotation) padded to whole lane tiles, one row a token in ONE plane of
@@ -48,12 +54,15 @@ def rope(x, cos, sin):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
-def queries_and_row(m, h, p, cos, sin, q_scale=None, kv_scale=None):
-    """From tokens h [N, d]: q_nope [N, H, nope] and rotated q_pe [N, H,
-    rope] (parameter dtype), and the cache row [N, row_lanes] =
-    [RMSNorm(c_kv) (x kv_scale) | rotated k_pe | 0]."""
+def queries_and_row(m, h, p, cos, sin, q_scale=None, kv_scale=None,
+                    x=None):
+    """From tokens h [N, d] (or their normed form `x`): q_nope [N, H,
+    nope] and rotated q_pe [N, H, rope] (parameter dtype), and the cache
+    row [N, row_lanes] = [RMSNorm(c_kv) (x kv_scale) | rotated k_pe |
+    0]."""
     n, H = h.shape[0], m.heads
-    x = _rms(h, p["attn_norm"]["scale"], m.rms_eps).astype(m.dtype)
+    x = _rms(h, p["attn_norm"]["scale"], m.rms_eps).astype(m.dtype) \
+        if x is None else x.astype(m.dtype)
     c_q = _rms(_dot(x, p["q_a"]["kernel"]), p["q_a_norm"]["scale"],
                m.rms_eps)
     if q_scale is not None:
@@ -80,10 +89,19 @@ def kv_b(m, p):
     return w[..., :m.qk_nope_head_dim], w[..., m.qk_nope_head_dim:]
 
 
+def _out(h, p, o, gate, post):
+    """h + (gated) heads' outputs o [N, H * v_head_dim] through `o`."""
+    if gate is not None:
+        o = o * gate
+    y = _dot(o, p["o"]["kernel"])
+    return h + (y if post is None else post(y))
+
+
 def decode_attention(m, h, p, c_pages, plane: int, scope: str, cos, sin,
                      page_tables, lengths, write_page, write_off,
                      scale: float, attn_impl: str, attn_interpret: bool,
-                     q_scale=None, kv_scale=None):
+                     q_scale=None, kv_scale=None, x=None, gate=None,
+                     post=None):
     """h + MLA(RMSNorm(h)) of one decode batch h [S, d] (float32), the
     batch's rows written into `plane` of c_pages at [write_page,
     write_off] before the kernel reads the slots' pages through their
@@ -93,7 +111,7 @@ def decode_attention(m, h, p, c_pages, plane: int, scope: str, cos, sin,
     w_uk, w_uv = kv_b(m, p)
     with jax.named_scope(f"{scope}/mla_q"):
         q_nope, q_pe, row = queries_and_row(m, h, p, cos, sin, q_scale,
-                                            kv_scale)
+                                            kv_scale, x)
         q_lat = jnp.einsum("shd,chd->shc", q_nope, w_uk,
                            preferred_element_type=F32)
         q_cat = jnp.concatenate(
@@ -110,15 +128,14 @@ def decode_attention(m, h, p, c_pages, plane: int, scope: str, cos, sin,
     with jax.named_scope(f"{scope}/mla_out"):
         o = jnp.einsum("shc,chd->shd", o_lat, w_uv,
                        preferred_element_type=F32)
-        o = o.reshape(o.shape[0], -1)
-        h = h + _dot(o, p["o"]["kernel"])
+        h = _out(h, p, o.reshape(o.shape[0], -1), gate, post)
     return h, c_pages
 
 
 def prefill_attention(m, h, p, c_pages, plane: int, scope: str, cos, sin,
                       pos, page_table, write_pages, write_offs, n_blocks,
                       per_block: int, scale: float, q_scale=None,
-                      kv_scale=None):
+                      kv_scale=None, x=None, gate=None, post=None):
     """h + MLA(RMSNorm(h)) of ONE slot's chunk h [C, d] (float32) at
     positions pos [C]: the chunk's rows written into `plane` first, then
     the up-projected form over the slot's pages `per_block` pages at a
@@ -130,7 +147,7 @@ def prefill_attention(m, h, p, c_pages, plane: int, scope: str, cos, sin,
     w_uk, w_uv = kv_b(m, p)
     with jax.named_scope(f"{scope}/mla_q"):
         q_nope, q_pe, rows = queries_and_row(m, h, p, cos, sin, q_scale,
-                                             kv_scale)
+                                             kv_scale, x)
     with jax.named_scope(f"{scope}/mla_kv_write"):
         c_pages = c_pages.at[plane, write_pages, write_offs].set(rows)
     with jax.named_scope(f"{scope}/mla_attn"):
@@ -177,6 +194,5 @@ def prefill_attention(m, h, p, c_pages, plane: int, scope: str, cos, sin,
              jnp.zeros((m.heads, C, m.v_head_dim), F32)))
         o = acc / jnp.where(den > 0, den, 1.0)[..., None]
     with jax.named_scope(f"{scope}/mla_out"):
-        o = o.transpose(1, 0, 2).reshape(C, -1)
-        h = h + _dot(o, p["o"]["kernel"])
+        h = _out(h, p, o.transpose(1, 0, 2).reshape(C, -1), gate, post)
     return h, c_pages

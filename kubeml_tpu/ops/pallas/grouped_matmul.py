@@ -145,8 +145,26 @@ def visit_plan(group_sizes, m: int, tm: int):
             visit_ends[-1].reshape(1))
 
 
+def silu_gate(g, limit=None):
+    """The gate half of a SwiGLU, silu(g); with `limit` (the clamp a
+    config calls `swiglu_limit`) g is clamped above at limit first."""
+    return jax.nn.silu(g if limit is None else jnp.minimum(g, limit))
+
+
+def clip_up(u, limit=None):
+    """The up half: u, clamped to [-limit, limit] where there is one."""
+    return u if limit is None else jnp.clip(u, -limit, limit)
+
+
+def swiglu(g, u, limit=None):
+    """silu(g) * u under an optional clamp. Without one each of these
+    traces as the bare expression did (the order of the traced
+    operations included)."""
+    return silu_gate(g, limit) * clip_up(u, limit)
+
+
 def _kernel(offsets_ref, group_ref, tile_ref, visits_ref, lhs_ref, *refs,
-            tm: int, gated: bool):
+            tm: int, gated: bool, limit=None):
     """One visit of one block of columns: lhs_ref [tm, k]; the stack
     blocks [k, tn] (gate and up when `gated`); out_ref [tm, tn], the
     rows of the visit's group stored, the others left as they are."""
@@ -162,14 +180,15 @@ def _kernel(offsets_ref, group_ref, tile_ref, visits_ref, lhs_ref, *refs,
         x = lhs_ref[...]
         acc = jnp.dot(x, refs[0][...], preferred_element_type=F32)
         if gated:
-            acc = jax.nn.silu(acc) * jnp.dot(
-                x, refs[1][...], preferred_element_type=F32)
+            acc = silu_gate(acc, limit) * clip_up(jnp.dot(
+                x, refs[1][...], preferred_element_type=F32), limit)
         out_ref[...] = jnp.where(mine, acc, out_ref[...].astype(F32)
                                  ).astype(out_ref.dtype)
 
 
-def _call(lhs, stacks, plan, *, interpret: bool):
-    """The kernel over one or two stacks under a ready visit plan."""
+def _call(lhs, stacks, plan, *, interpret: bool, limit=None):
+    """The kernel over one or two stacks under a ready visit plan
+    (`limit`: the gated product's clamp)."""
     offsets, group_of, tile_of, visits = plan
     m, k = lhs.shape
     n = stacks[0].shape[2]
@@ -196,7 +215,7 @@ def _call(lhs, stacks, plan, *, interpret: bool):
         out_specs=pl.BlockSpec((tm, tn), out_map))
     out_dtype = lhs.dtype if gated else F32
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm, gated=gated),
+        functools.partial(_kernel, tm=tm, gated=gated, limit=limit),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (m, n), out_dtype, vma=gate.out_vma(lhs, *stacks)),
@@ -216,16 +235,16 @@ def _matmul_pallas(lhs, rhs, group_sizes, *, interpret: bool):
                  interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "limit"))
 def _mlp_pallas(rows, w_gate, w_up, w_down, group_sizes, *,
-                interpret: bool):
+                interpret: bool, limit=None):
     """The expert MLP's three products as two kernels under ONE visit
     plan, jitted so that a program's expert layers (the same shapes,
     one call a layer) are one traced and lowered function
     (ops/pallas/paged_attention.py _pa_pallas)."""
     m = rows.shape[0]
     plan = visit_plan(group_sizes, m, row_tile(m))
-    a = _call(rows, (w_gate, w_up), plan, interpret=interpret)
+    a = _call(rows, (w_gate, w_up), plan, interpret=interpret, limit=limit)
     return _call(a, (w_down,), plan, interpret=interpret)
 
 
@@ -281,12 +300,13 @@ def resolve_mlp_impl(impl: str, interpret: bool, *, rows: int, d: int,
 
 def grouped_mlp(rows: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                 w_down: jax.Array, group_sizes: jax.Array, *,
-                impl: str = "auto", interpret: bool = False):
+                impl: str = "auto", interpret: bool = False, limit=None):
     """The gated MLP of each group's expert over its rows: `(silu(rows
     @ gate[g]) * (rows @ up[g])).astype(rows.dtype) @ down[g]`, float32
-    [m, d]. rows [m, d]; gate, up [G, d, f]; down [G, f, d]; rows past
-    the groups' sum are the caller's to select away. The fallback is
-    the three `lax.ragged_dot` calls as they stood."""
+    [m, d] (`limit`: swiglu's clamp). rows [m, d]; gate, up [G, d, f];
+    down [G, f, d]; rows past the groups' sum are the caller's to select
+    away. The fallback is the three `lax.ragged_dot` calls as they
+    stood."""
     m, d = rows.shape
     f = w_gate.shape[2]
     dtype = rows.dtype
@@ -300,11 +320,11 @@ def grouped_mlp(rows: jax.Array, w_gate: jax.Array, w_up: jax.Array,
         _check(_geom(m, f, w_down, dtype, 1), dtype, w_down, group_sizes,
                interpret)
         return _mlp_pallas(rows, w_gate, w_up, w_down, group_sizes,
-                           interpret=interpret)
+                           interpret=interpret, limit=limit)
     g = lax.ragged_dot(rows, w_gate, group_sizes,
                        preferred_element_type=F32)
     u = lax.ragged_dot(rows, w_up, group_sizes,
                        preferred_element_type=F32)
-    a = (jax.nn.silu(g) * u).astype(dtype)
+    a = swiglu(g, u, limit).astype(dtype)
     return lax.ragged_dot(a, w_down, group_sizes,
                           preferred_element_type=F32)

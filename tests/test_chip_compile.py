@@ -985,6 +985,154 @@ def test_longcat_flash_serve_programs_compile_at_published_widths_for_v5e(
     print(which, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
 
 
+# ------------------------- GigaChat3.5 (latent pages, matrix slot state)
+
+# the cell gigachat3.5-ep16-serve.decode-heavy: 128 slots, 256 pages of
+# 16 a slot, prefill chunk 512, 4 GatedDeltaNet layers of 64 value heads
+# of [128 x 128] float32 state
+_GIGACHAT_CELL = dict(slots=128, page=16, pages_per_slot=256, chunk=512)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_gated_delta_compiles_for_v5e_in_place(one_chip, which):
+    """Both gated-delta kernels at the cell's geometry: the chip's
+    compiler accepts them, within the scoped VMEM they declare, the
+    state array (2.15 GB) is read and written in place (aliased, no
+    temporary of its size, no instruction yields a copy of it)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubeml_tpu.ops.pallas import gated_delta as gd
+    S, C = _GIGACHAT_CELL["slots"], _GIGACHAT_CELL["chunk"]
+    L, H, D = 4, 64, 128
+    f32, i32 = jnp.float32, jnp.int32
+    rows = S if which == "decode" else C
+    shapes = [((L, S, H, D, D), f32)] + [((rows, H, D), f32)] * 3 \
+        + [((rows, H), f32)] * 2
+    if which == "decode":
+        assert gd.decode_eligible(slots=S, heads=H, dk=D, dv=D)
+
+        def fn(state, q, k, v, g, beta, fresh, layer):
+            return gd.gated_delta_decode(state, q, k, v, g, beta, fresh,
+                                         layer=layer, impl="pallas")
+        shapes += [((S,), i32), ((), i32)]
+    else:
+        assert gd.prefill_eligible(tokens=C, heads=H, dk=D, dv=D)
+
+        def fn(state, q, k, v, g, beta, fresh, layer, slot):
+            return gd.gated_delta_prefill(state, q, k, v, g, beta, fresh,
+                                          layer=layer, slot=slot,
+                                          impl="pallas")
+        shapes += [((), i32)] * 3
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    hlo = compiled.as_text()
+    name = "gated_delta" if which == "decode" else "gated_delta_chunk"
+    assert f"%{name}" in hlo
+    call, = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    limit, used = (
+        int(re.search(key + r'":\[\{[^}]*"size":"(\d+)"', call).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
+    assert 0 < used <= limit == gd.VMEM_LIMIT
+    state_bytes = L * S * H * D * D * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 16
+    whole = [x for x in _result_shapes(hlo)
+             if x[2] == (L, S, H, D, D) and x[4] in ("copy", "copy-start")]
+    assert not whole, whole
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_gigachat_serve_programs_compile_at_published_widths_for_v5e(
+        one_chip, which):
+    """Both programs of models/gigachat.py at the PUBLISHED widths and
+    the cell's geometry (5 layers: GatedDeltaNet at 0, 1, 2, 4, MLA at
+    3; 16 of 256 experts held; 128 slots, 256 pages a slot, prefill
+    chunk 512), bfloat16 parameters: the chip's compiler accepts them,
+    they hold their kernels (the gated-delta kernel in both, the
+    latent-page kernel in decode, the grouped expert product in both),
+    NO instruction yields a copy or a gather of the latent
+    plane or of either per-slot state array, and arguments and
+    temporaries together fit the chip's 16.9 GB."""
+    import importlib.util
+    import math
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "models",
+        "gigachat35_ep16.py")
+    spec = importlib.util.spec_from_file_location("gigachat_ep16", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    m = mod.GigaChat35EP16().module
+    assert (m.hidden, m.layers, m.linear_layers, m.attn_layers,
+            m.n_held_experts) == (7168, 5, (0, 1, 2, 4), (3,), 16)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0)))["params"])
+    c = _GIGACHAT_CELL
+    S, G, Pmax, C = c["slots"], c["page"], c["pages_per_slot"], c["chunk"]
+    family = m.serve_family()
+    cache = family.cache
+    plane = (cache.layers, S * Pmax + 1, G, cache.width)
+    slot_arrays = [(st.layers, S) + tuple(st.shape)
+                   for st in cache.slot_state]
+    assert plane == (1, 32769, 16, 640)
+    assert slot_arrays == [(4, 128, 64, 128, 128), (4, 128, 49152)]
+    state = [sds(plane, cache.dtype)] + [
+        sds(shape, st.dtype)
+        for shape, st in zip(slot_arrays, cache.slot_state)]
+    i32, f32 = jnp.int32, jnp.float32
+    assert family.gdn_impls(S, C, "pallas", False) == ("pallas", "pallas")
+    if which == "decode":
+        assert family.attn_impls(G, Pmax, C, "f32", "pallas", False) \
+            == ("pallas", "gather")
+        fn = family.decode_step("f32", "pallas", False)
+        rest = [sds((S,), i32), sds((S,), i32), sds((S, Pmax), i32),
+                sds((S,), i32), sds((S,), i32), sds((S,), f32),
+                sds((S,), f32), sds((S, 2), jnp.uint32), sds((S,), i32),
+                sds((S,), i32), sds((S,), f32)]
+    else:
+        fn = family.prefill_step(C, "f32", "pallas", False)
+        rest = [sds((C,), i32), sds((C,), i32), sds((Pmax,), i32),
+                sds((C,), i32), sds((C,), i32), sds((C,), f32),
+                sds((), i32)]
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3)).lower(
+        params, *state, *rest).compile()
+    hlo = compiled.as_text()
+    assert ("%gated_delta_chunk" in hlo) == (which == "prefill")
+    assert ("%gated_delta." in hlo or "%gated_delta =" in hlo) \
+        == (which == "decode")
+    assert ("mla_paged_attention" in hlo) == (which == "decode")
+    # a decode batch of 128 tokens is past DENSE_MOE_TOKENS too: both
+    # programs take the grouped expert product
+    assert family.moe_impl(C, "pallas", False) == "pallas" \
+        == family.moe_impl(S, "pallas", False)
+    assert "grouped_matmul_gated" in hlo
+    copies = [(n, dims, op) for n, _, dims, _, op in _result_shapes(hlo)
+              if dims in [plane] + slot_arrays
+              and op in ("copy", "copy-start", "gather")]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 400e6, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+    assert mem.alias_size_in_bytes >= sum(
+        int(jnp.dtype(a.dtype).itemsize) * math.prod(a.shape)
+        for a in state)
+    print(which, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
 # ---------------------------------------------------- flash attention
 
 FLASH_SHAPES = {

@@ -412,7 +412,8 @@ def _parents_moe(m, x, p, live, dense: bool, impl="gather",
     return shared + routed, counts
 
 
-def _family_programs(module, chunk, slots=2, page=16):
+def _family_programs(module, chunk, slots=2, page=16, impl="gather",
+                     interpret=False):
     """(decode jaxpr, prefill jaxpr) of a family's two programs at a
     small geometry, traced from shapes alone."""
     family = module.serve_family()
@@ -440,9 +441,9 @@ def _family_programs(module, chunk, slots=2, page=16):
                sds((C,), f32)]
     if cache.slot_state:
         prefill.append(sds((), i32))
-    return (str(jax.make_jaxpr(family.decode_step("f32", "gather", False))(
+    return (str(jax.make_jaxpr(family.decode_step("f32", impl, interpret))(
         *decode)), str(jax.make_jaxpr(family.prefill_step(
-            C, "f32", "gather", False))(*prefill)))
+            C, "f32", impl, interpret))(*prefill)))
 
 
 @pytest.mark.parametrize("chunk", [32, 80], ids=["dense", "ragged"])
@@ -631,6 +632,48 @@ def test_lifted_latent_blocks_give_deepseek_the_parents_jaxprs(chunk,
                                    "_parents_mla_prefill"]
     assert len(called) == 2 * module.layers
     assert lifted[0] == parents[0] and lifted[1] == parents[1]
+
+
+# sha256 (the first 16 hex digits) of the decode and the prefill
+# program's jaxpr text of each latent or expert-parallel family's tiny
+# preset, at `_family_programs`' geometry, traced on the tree before
+# the expert layer took its optional SwiGLU clamp and the MLA blocks
+# their optional input, output gate and post-norm (GigaChat3.5's):
+# (family, prefill chunk, impl) -> (decode, prefill). A chunk of 32
+# takes the dense mask, 80 ragged_dot, and 'pallas' in interpret mode
+# the paged, latent and grouped-matmul kernels.
+PARENT_JAXPRS = {
+    ("deepseek_v2", 32, "gather"): ("af1eae7ee230ee2a", "394f9779a7b539fc"),
+    ("deepseek_v2", 80, "gather"): ("af1eae7ee230ee2a", "0eadae3f1744c026"),
+    ("deepseek_v2", 80, "pallas"): ("eee681c7dae80d9d", "3f0e468ad1f5c3ce"),
+    ("exaone_moe", 32, "gather"): ("b712a28c8dbdcafe", "9d16664c77b6292a"),
+    ("exaone_moe", 80, "gather"): ("b712a28c8dbdcafe", "f4d297533a96b45c"),
+    ("exaone_moe", 80, "pallas"): ("5a9666a0260bee64", "6584f235b085e142"),
+    ("longcat_flash", 32, "gather"): ("eeb77826dcb437c9",
+                                      "1d4e017c598a365a"),
+    ("longcat_flash", 80, "gather"): ("eeb77826dcb437c9",
+                                      "04c645a89b455173"),
+    ("longcat_flash", 80, "pallas"): ("de4ce901a8fb0e9a",
+                                      "94a91eec678c9e6d"),
+}
+
+
+@pytest.mark.parametrize("family,chunk,impl", sorted(PARENT_JAXPRS))
+def test_the_optional_clamp_and_gate_leave_other_families_jaxprs(
+        family, chunk, impl):
+    """DeepSeek-V2's, EXAONE-MoE's and LongCat-Flash's decode and
+    prefill programs trace to the very text they had before the shared
+    expert layer and MLA blocks grew GigaChat3.5's options: with none of
+    them given, nothing of them is traced."""
+    import hashlib
+    import importlib
+    mod = importlib.import_module(f"kubeml_tpu.models.{family}")
+    module = {"deepseek_v2": "DeepSeekV2Module", "exaone_moe":
+              "ExaoneMoEModule", "longcat_flash": "LongCatFlashModule"}
+    programs = _family_programs(getattr(mod, module[family])(), chunk,
+                                impl=impl, interpret=impl == "pallas")
+    assert tuple(hashlib.sha256(p.encode()).hexdigest()[:16]
+                 for p in programs) == PARENT_JAXPRS[family, chunk, impl]
 
 
 @pytest.mark.parametrize("name", ["gpt", "jamba"])
