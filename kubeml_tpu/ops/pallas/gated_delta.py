@@ -29,14 +29,18 @@ block, with gamma the block's cumulative log decay and S0 the state at
 its start:
 
     A[t, s] = beta_t exp(gamma_t - gamma_s) k_t . k_s      s < t
-    T = (I + A)^-1                    (forward substitution, float32)
+    T = (I + A)^-1                    (a blocked inverse, float32)
     U = T diag(beta) V - T diag(beta exp(gamma)) K S0
     O = diag(exp(gamma)) Q S0 + (M * Q K^T) U,   M[t, s] = exp(gamma_t
         - gamma_s) for s <= t
     S = exp(gamma_last) S0 + (diag(exp(gamma_last - gamma)) K)^T U
 
-Grid (heads / PREFILL_HEADS,); a head's state stays on the core across
-the chunk's blocks and is written back once a chunk.
+Grid (heads / PREFILL_HEADS,). A grid step first forms what does not
+depend on the state (A, T, the products with T, M * Q K^T) for all its
+heads' blocks at once, as batched matrix work (`unit_lower_inverse`);
+then it walks the blocks in order, its heads' states carried together
+on the core and written back once a chunk: only the products with S0
+and U stay on that serial path.
 
 Masks. A lane or a chunk row with g = 0 and beta = 0 is the identity
 on the state (exactly: exp(0) = 1 and the write is beta times
@@ -236,66 +240,119 @@ def gated_delta_decode(state: jax.Array, q: jax.Array, k: jax.Array,
 
 # ------------------------------------------------------------ prefill
 
+SUB = 16                        # rows of a diagonal block of the inverse
+SUB_SHIFT = SUB.bit_length() - 1
+NT = ((1,), (1,))               # contracting dims of one [rows, x] pair
+NN = ((1,), (0,))
+
+
 def _dot(a, b, dims):
-    return lax.dot_general(a, b, (dims, ((), ())), precision=HI,
+    """A float32 product at `highest`; where both operands are 3-D, over
+    their leading (batch) axis, `dims` then counting past it."""
+    if a.ndim == 3:
+        dims = tuple(tuple(d + 1 for d in x) for x in dims)
+        batch = ((0,), (0,))
+    else:
+        batch = ((), ())
+    return lax.dot_general(a, b, (dims, batch), precision=HI,
                            preferred_element_type=F32)
 
 
 def _column(row, eye):
-    """[1, n] -> [n, 1]: the diagonal of the row broadcast over
+    """[..., 1, n] -> [..., n, 1]: the diagonal of the row broadcast over
     sublanes, summed over lanes (no relayout of a vector)."""
-    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=-1, keepdims=True)
 
 
-def _inverse_unit_lower(at, r, c):
-    """(I + A)^-1 for A strictly lower triangular, from its transpose
-    `at`, by forward substitution: row i is e_i - A[i, :] (I + A)^-1,
-    every earlier row final. float32 throughout."""
-    n = at.shape[0]
-    t = jnp.zeros_like(at)
-    for i in range(n):
-        row = (c[:1] == i).astype(F32) - jnp.sum(at[:, i:i + 1] * t, axis=0,
-                                                 keepdims=True)
-        t = jnp.where(r == i, row, t)
-    return t
+def unit_lower_inverse(a_off, at_diag):
+    """(I + A)^-1 for a batch of strictly lower triangular A [n, bt, bt]
+    (bt = 4 SUB), float32, given as A off its diagonal blocks of SUB rows
+    (`a_off`) and the transpose of those blocks (`at_diag`).
+
+    D = blockdiag((I + A_jj)^-1) by forward substitution, row i of every
+    diagonal block of every batch in one step: SUB dependent steps. Then
+    N = D A_off is strictly lower by blocks, N^4 = 0, and (I + A)^-1 =
+    (I + N)^-1 D = (I - N)(I + N^2) D: four batched products. A row of A
+    that is zero (a padded row) comes back exactly a row of I."""
+    bt = at_diag.shape[-1]
+    assert bt == 4 * SUB, bt
+    r = lax.broadcasted_iota(jnp.int32, (bt, bt), 0)
+    c = lax.broadcasted_iota(jnp.int32, (bt, bt), 1)
+    eye = (r == c).astype(F32)
+    first = r >> SUB_SHIFT << SUB_SHIFT         # first row of r's block
+    same = r >> SUB_SHIFT == c >> SUB_SHIFT
+    d = jnp.zeros_like(at_diag)
+    for i in range(SUB):
+        # row i of each block: e_i - A[i, :] D over the block's final rows;
+        # A[first(s) + i, s] down the sublanes s
+        col = jnp.sum(jnp.where(c == first + i, at_diag, 0.0), axis=-1,
+                      keepdims=True)
+        d = jnp.where(same & (r == first + i),
+                      eye - jnp.sum(col * d, axis=-2, keepdims=True), d)
+    n = _dot(d, a_off, NN)
+    return _dot(_dot(eye - n, eye + _dot(n, n, NN), NN), d, NN)
 
 
 def _prefill_kernel(layer_ref, slot_ref, fresh_ref, state_ref, q_ref,
-                    k_ref, v_ref, g_ref, b_ref, gl_ref, state_out, o_ref, *,
-                    hb: int, nb: int, bt: int):
+                    k_ref, v_ref, g_ref, b_ref, gl_ref, state_out, o_ref,
+                    left_ref, u_ref, right_ref, *, hb: int, nb: int,
+                    bt: int):
     """hb heads of one slot over a chunk of nb blocks. state_ref /
     state_out [hb, dk, dv]; q_ref, k_ref [hb, nb, bt, dk]; v_ref, o_ref
     [hb, nb, bt, dv]; g_ref (the block's cumulative log decay), b_ref
     [hb, nb, 1, bt]; gl_ref [hb, nb, 1, dv], the block's whole log decay
-    over the lanes (see _decode_kernel)."""
+    over the lanes (see _decode_kernel).
+
+    First, batched over the hb x nb blocks (block b of head h at j = h
+    nb + b), everything that does not depend on the state, into the
+    scratch: left_ref [n, 2 bt, dk], the two factors that multiply S0
+    (W = T diag(beta exp(gamma)) K over diag(exp(gamma)) Q); u_ref [n,
+    bt, dv], T diag(beta) V; right_ref [n, bt + dk, bt], the two that
+    multiply U (M * Q K^T over (diag(exp(gamma_last - gamma)) K)^T).
+    Then the blocks in order, the hb heads' states carried together:
+    two products a block and head."""
     del layer_ref, slot_ref     # the index maps read them
+    n = hb * nb
+    q, k, v = (x[...].reshape(n, bt, x.shape[-1])
+               for x in (q_ref, k_ref, v_ref))
+    grow, brow = (x[...].reshape(n, 1, bt) for x in (g_ref, b_ref))
     r = lax.broadcasted_iota(jnp.int32, (bt, bt), 0)
     c = lax.broadcasted_iota(jnp.int32, (bt, bt), 1)
-    eye, incl, strict = r == c, r >= c, r < c
-    nt, nn = ((1,), (1,)), ((1,), (0,))
-    for h in range(hb):
-        def block(b, s, h=h):
-            q, k, v = q_ref[h, b], k_ref[h, b], v_ref[h, b]
-            grow, brow = g_ref[h, b], b_ref[h, b]           # [1, bt]
-            gcol, bcol = _column(grow, eye), _column(brow, eye)
-            glast = grow[:, bt - 1:]                        # [1, 1]
-            kk = _dot(k, k, nt)
-            # A^T[s, t] = beta_t exp(gamma_t - gamma_s) k_t . k_s, s < t
-            at = jnp.where(strict, brow * jnp.exp(
-                jnp.where(strict, grow - gcol, 0.0)) * kk, 0.0)
-            t = _inverse_unit_lower(at, r, c)
-            egc = jnp.exp(gcol)
-            w = _dot(t, bcol * egc * k, nn)
-            u = _dot(t, bcol * v, nn) - _dot(w, s, nn)
-            m = jnp.where(incl, jnp.exp(jnp.where(incl, gcol - grow, 0.0)),
-                          0.0)
-            o_ref[h, b] = egc * _dot(q, s, nn) + _dot(m * _dot(q, k, nt),
-                                                      u, nn)
-            kd = (jnp.exp(glast - gcol) * k).T               # [dk, bt]
-            return jnp.exp(gl_ref[h, b]) * s + _dot(kd, u, nn)
+    gcol, bcol = _column(grow, r == c), _column(brow, r == c)
+    kq = _dot(jnp.concatenate([k, q], axis=1), k, NT)   # [K; Q] K^T
+    kk, qk = kq[:, :bt], kq[:, bt:]
+    same = r >> SUB_SHIFT == c >> SUB_SHIFT
+    # decay[t, s] = exp(gamma_t - gamma_s), s <= t;
+    # A[t, s] = beta_t decay[t, s] k_t . k_s, s < t
+    decay = jnp.exp(jnp.where(r >= c, gcol - grow, 0.0))
+    a_off = jnp.where((r > c) & ~same, bcol * decay * kk, 0.0)
+    at_diag = jnp.where((r < c) & same, brow * jnp.exp(
+        jnp.where(r < c, grow - gcol, 0.0)) * kk, 0.0)
+    t = unit_lower_inverse(a_off, at_diag)
+    egc = jnp.exp(gcol)
+    left_ref[:, :bt] = _dot(t, bcol * egc * k, NN)
+    left_ref[:, bt:] = egc * q
+    u_ref[...] = _dot(t, bcol * v, NN)
+    right_ref[:, :bt] = jnp.where(r >= c, decay, 0.0) * qk
+    right_ref[:, bt:] = jnp.swapaxes(
+        jnp.exp(grow[:, :, bt - 1:] - gcol) * k, 1, 2)
 
-        s0 = jnp.where(fresh_ref[0] > 0, 0.0, state_ref[h])
-        state_out[h] = lax.fori_loop(0, nb, block, s0)
+    def block(b, s):
+        out = []
+        for h in range(hb):
+            j = h * nb + b
+            x = _dot(left_ref[j], s[h], NN)             # [W S0; Q' S0]
+            u = u_ref[j] - x[:bt]
+            y = _dot(right_ref[j], u, NN)               # [M' U; K'^T U]
+            o_ref[h, b] = x[bt:] + y[:bt]
+            out.append(jnp.exp(gl_ref[h, b]) * s[h] + y[bt:])
+        return tuple(out)
+
+    fresh = fresh_ref[0] > 0
+    s = lax.fori_loop(0, nb, block, tuple(
+        jnp.where(fresh, 0.0, state_ref[h]) for h in range(hb)))
+    for h in range(hb):
+        state_out[h] = s[h]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -321,6 +378,7 @@ def _prefill_pallas(state, q, k, v, g, beta, fresh, layer, slot, *,
         return j, 0, 0, 0
 
     state_spec = pl.BlockSpec((None, None, hb, dk, dv), state_map)
+    n = hb * nb
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # layer, slot, fresh
         grid=(H // hb,),
@@ -330,7 +388,10 @@ def _prefill_pallas(state, q, k, v, g, beta, fresh, layer, slot, *,
                   pl.BlockSpec((hb, nb, 1, bt), rows_map),
                   pl.BlockSpec((hb, nb, 1, bt), rows_map),
                   pl.BlockSpec((hb, nb, 1, dv), rows_map)],
-        out_specs=[state_spec, pl.BlockSpec((hb, nb, bt, dv), rows_map)])
+        out_specs=[state_spec, pl.BlockSpec((hb, nb, bt, dv), rows_map)],
+        scratch_shapes=[pltpu.VMEM((n, 2 * bt, dk), F32),
+                        pltpu.VMEM((n, bt, dv), F32),
+                        pltpu.VMEM((n, bt + dk, bt), F32)])
     vma = gate.out_vma(state, q, k, v)
     state, o = pl.pallas_call(
         functools.partial(_prefill_kernel, hb=hb, nb=nb, bt=bt),

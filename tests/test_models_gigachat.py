@@ -290,18 +290,24 @@ def _unit(x):
     return x / np.linalg.norm(x, axis=-1, keepdims=True)
 
 
-def _operands(rng, n, heads, decay, dk=128, dv=128):
+def _operands(rng, n, heads, decay, dk=128, dv=128, corr=0.0,
+              beta=(0.01, 0.99)):
     """q (scaled), k unit length, v, a log decay g whose exp(g) lies in
-    `decay`, beta across (0, 1)."""
+    `decay`, beta across `beta`. corr > 0 draws every token's key of a
+    head near one shared direction (k . k' about corr^2 / (corr^2 + (1 -
+    corr)^2)): with beta and decay near 1, what gives (I + A)^-1 its
+    largest entries."""
     lo, hi = decay
-    return (jnp.asarray(_unit(rng.normal(size=(n, heads, dk))) * dk ** -0.5,
-                        jnp.float32),
-            jnp.asarray(_unit(rng.normal(size=(n, heads, dk))), jnp.float32),
+    q = _unit(rng.normal(size=(n, heads, dk))) * dk ** -0.5
+    k = rng.normal(size=(n, heads, dk))
+    if corr:
+        k = corr * rng.normal(size=(1, heads, dk)) + (1 - corr) * k
+    return (jnp.asarray(q, jnp.float32),
+            jnp.asarray(_unit(k), jnp.float32),
             jnp.asarray(rng.normal(size=(n, heads, dv)), jnp.float32),
             jnp.asarray(np.log(rng.uniform(lo, hi, size=(n, heads))),
                         jnp.float32),
-            jnp.asarray(rng.uniform(0.01, 0.99, size=(n, heads)),
-                        jnp.float32))
+            jnp.asarray(rng.uniform(*beta, size=(n, heads)), jnp.float32))
 
 
 def _recurrence(s, q, k, v, g, beta):
@@ -319,29 +325,35 @@ def _recurrence(s, q, k, v, g, beta):
 
 
 # the chunked kernel's tolerance: the same float32 rule in another order
-# of sums (a block's triangular solve, its products at `highest`)
-# against float64 token by token: measured 4.1e-7 of the largest output
-# or state entry over the three cases; 4e-6 holds with ten times room,
-# and a state rounded to bfloat16 once is off by 4e-3
+# of sums (a block's blocked inverse, its products at `highest`) against
+# float64 token by token: measured 4.1e-7 of the largest output or state
+# entry over the three cases of independent keys, 1.9e-6 over correlated
+# keys written with beta near 1 (a 64-step substitution read 1.7e-6
+# there: float32's own rounding of that recurrence); 4e-6 holds, and a
+# state rounded to bfloat16 once is off by 4e-3
 KERNEL_RTOL = 4e-6
 
 
-@pytest.mark.parametrize("tokens,valid,decay", [
-    (128, 100, (0.9, 0.999)),         # ends mid-block, long memory
-    (64, 64, (0.999, 1.0)),           # decays near 1: one whole block
-    (192, 131, (1e-4, 0.05))],        # near 0: a block forgets itself
-    ids=["mid-block", "near-1", "near-0"])
+@pytest.mark.parametrize("tokens,valid,decay,corr", [
+    (128, 100, (0.9, 0.999), 0.0),    # ends mid-block, long memory
+    (64, 64, (0.999, 1.0), 0.0),      # decays near 1: one whole block
+    (192, 131, (1e-4, 0.05), 0.0),    # near 0: a block forgets itself
+    (256, 250, (0.999, 1.0), 0.9)],   # correlated keys, 4 blocks
+    ids=["mid-block", "near-1", "near-0", "correlated"])
 def test_prefill_kernel_is_the_token_by_token_recurrence(tokens, valid,
-                                                         decay):
+                                                         decay, corr):
     """The chunked kernel (interpret mode) from a slot's state over a
     chunk whose tail is padding (g = 0, beta = 0): the state it writes
     back and every real row's output against the rule token by token;
-    no other slot or layer of the array is touched."""
+    no other slot or layer of the array is touched. The correlated case
+    writes with beta near 1 along keys near one direction, where the
+    blocks' (I + A)^-1 have their largest entries."""
     rng = np.random.default_rng(tokens)
     L, S, H = 2, 4, 4
     state = jnp.asarray(0.1 * rng.normal(size=(L, S, H, 128, 128)),
                         jnp.float32)
-    q, k, v, g, beta = _operands(rng, tokens, H, decay)
+    q, k, v, g, beta = _operands(rng, tokens, H, decay, corr=corr,
+                                 beta=(0.9, 1.0) if corr else (0.01, 0.99))
     live = (np.arange(tokens) < valid)[:, None]
     g, beta = jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0)
     for fresh in (0, 1):
@@ -360,6 +372,38 @@ def test_prefill_kernel_is_the_token_by_token_recurrence(tokens, valid,
         others[1, 2] = False
         np.testing.assert_array_equal(np.asarray(got)[others],
                                       np.asarray(state)[others])
+
+
+@pytest.mark.parametrize("corr,decay,beta", [
+    (0.0, (0.9, 0.999), (0.01, 0.99)),
+    (0.9, (0.999, 1.0), (0.95, 1.0)),
+    (0.99, (0.999, 1.0), (0.99, 1.0))],
+    ids=["independent", "correlated", "nearly-parallel"])
+def test_unit_lower_inverse_against_numpy(corr, decay, beta):
+    """The chunked kernel's blocked inverse, as plain JAX over a batch of
+    blocks built as the kernel builds them (A[t, s] = beta_t exp(gamma_t
+    - gamma_s) k_t . k_s, s < t), against numpy's inverse of I + A in
+    float64; the last block's tail is padding (beta = 0, no decay), and
+    its rows come back exactly rows of I."""
+    rng = np.random.default_rng(11)
+    n, bt, pad = 8, gd.BLOCK, 24
+    _q, k, _v, g, b = _operands(rng, n * bt, 1, decay, corr=corr,
+                                beta=beta)
+    k = np.asarray(k, np.float32).reshape(n, bt, -1)
+    g, b = (np.array(x, np.float32).reshape(n, bt) for x in (g, b))
+    g[-1, -pad:], b[-1, -pad:] = 0.0, 0.0
+    gamma = np.cumsum(g, axis=1)
+    a = np.tril(b[:, :, None] * np.exp(gamma[:, :, None] - gamma[:, None, :])
+                * np.einsum("ntd,nsd->nts", k, k), -1).astype(np.float32)
+    blk = np.arange(bt) // gd.SUB
+    same = blk[:, None] == blk[None, :]
+    got = np.asarray(gd.unit_lower_inverse(
+        jnp.asarray(np.where(same, 0.0, a)),
+        jnp.asarray(np.swapaxes(np.where(same, a, 0.0), 1, 2))))
+    want = np.linalg.inv(np.eye(bt) + a.astype(np.float64))
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=KERNEL_RTOL * np.abs(want).max())
+    np.testing.assert_array_equal(got[-1, -pad:], np.eye(bt)[-pad:])
 
 
 def test_decode_kernel_is_one_step_of_the_recurrence():
